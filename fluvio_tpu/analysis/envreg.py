@@ -150,10 +150,6 @@ REGISTRY: Tuple[EnvFlag, ...] = (
        ("ops/regex_dfa.py", "smartengine/tpu/kernels.py"),
        "byte-equivalence-class DFA table packing (0: unpacked "
        "258-column tables + legacy state gate)"),
-    _f("FLUVIO_DFA_PALLAS", "mode", "auto", "auto|1|0|interpret",
-       ("smartengine/tpu/pallas_kernels.py", "smartengine/tpu/kernels.py"),
-       "fused DFA block-compose kernel ladder (auto: off-CPU; demotes "
-       "to the XLA associative scan on failure)"),
     _f("FLUVIO_DONATE", "mode", "auto", "auto|1|0",
        "smartengine/tpu/executor.py",
        "donate_argnums on the chain jits (auto: off-CPU only)"),
@@ -168,12 +164,6 @@ REGISTRY: Tuple[EnvFlag, ...] = (
     _f("FLUVIO_GLZ_CHUNK", "int", "262144", "bytes",
        "smartengine/tpu/glz.py",
        "glz compress_link chunk size (GLZ_CHUNK)"),
-    _f("FLUVIO_GLZ_ENC_PALLAS", "mode", "auto", "auto|1|0",
-       "smartengine/tpu/pallas_kernels.py",
-       "device glz ENCODE ladder: pallas window-match rung policy"),
-    _f("FLUVIO_GLZ_PALLAS", "mode", "auto", "auto|1|0",
-       "smartengine/tpu/pallas_kernels.py",
-       "device glz DECODE ladder: pallas resolve rung policy"),
     _f("FLUVIO_LINK_COMPRESS", "mode", "auto", "on|off|auto",
        "smartengine/tpu/executor.py",
        "compressed H2D staging link policy"),
@@ -291,9 +281,10 @@ REGISTRY: Tuple[EnvFlag, ...] = (
        "pallas kernel family policy (auto: TPU only)"),
     _f("FLUVIO_TPU_VERSIONS_DIR", "path", "~/.fluvio-tpu/versions",
        "directory", "fvm.py", "fvm toolchain versions store"),
-    _f("FLUVIO_TPU_XLA_CACHE", "path", None, "directory|off (default: "
-       "repo .xla_cache)", "smartengine/tpu/__init__.py",
-       "persistent XLA compile cache location"),
+    _f("FLUVIO_TPU_XLA_CACHE", "mode", None, "off (unset: repo "
+       ".xla_cache, or JAX_COMPILATION_CACHE_DIR when set)",
+       "smartengine/tpu/__init__.py",
+       "disable the in-checkout persistent XLA compile cache"),
     _f("FLUVIO_TRACE", "path", "", "file",
        "telemetry/trace.py", "Perfetto trace sink (unset: disabled)"),
     _f("FLUVIO_TRACE_MAX_MB", "float", "64", "MB",

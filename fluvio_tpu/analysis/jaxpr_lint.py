@@ -274,17 +274,15 @@ def trace_chain_entry_points(
             )
         reports.extend(_pallas_reports(executor, buf))
         reports.extend(_glz_reports(executor, buf))
-        reports.extend(_dfa_compose_reports(executor, buf))
     return reports
 
 
 def _glz_reports(executor, buf) -> List[JaxprReport]:
     """Trace the glz link decode the compressed staging would emit for
-    this batch's flat bucket (the decode ladder's device half, at the
-    executor's resolved variant) — synthetic token shapes at the staged
-    pow2/8 buckets, values never read. The signature names the variant
-    and byte bucket: distinct compiled programs the AOT warmup must
-    cover when link compression is on."""
+    this batch's flat bucket (the gather-round device decode) —
+    synthetic token shapes at the staged pow2/8 buckets, values never
+    read. The signature names the byte bucket: distinct compiled
+    programs the AOT warmup must cover when link compression is on."""
     from fluvio_tpu.smartengine.tpu import glz
 
     if not executor._link_compress or not glz.available():
@@ -295,8 +293,6 @@ def _glz_reports(executor, buf) -> List[JaxprReport]:
     # picks which buckets get covered
     seq_pad = executor._bucket_bytes(max(bucket // 24, 8), floor=256)
     lit_pad = executor._bucket_bytes(max(bucket // 3, 8), floor=256)
-    variant = executor._glz_variant
-    chunk = executor._glz_chunk or glz.chunk_bytes()
     seqs = (
         np.zeros(seq_pad, np.uint8),
         np.zeros(seq_pad, np.uint8),
@@ -305,15 +301,13 @@ def _glz_reports(executor, buf) -> List[JaxprReport]:
     return [
         _trace_report(
             "glz_decode",
-            f"glz_decode variant={variant} bytes={bucket} chunk={chunk}",
+            f"glz_decode bytes={bucket}",
             lambda: scan_function(
-                glz.decode_link_flat,
-                seqs,
+                glz.decompress_device,
+                *seqs,
                 np.zeros(lit_pad, np.uint8),
                 np.int32(1),
                 out_len=bucket,
-                variant=variant,
-                chunk=chunk,
             ),
         )
     ]
@@ -461,58 +455,3 @@ def window_update_reports(
         )
         for spec in specs
     ]
-
-
-def _dfa_compose_reports(executor, buf) -> List[JaxprReport]:
-    """Trace the fused DFA block-compose kernel at each distinct table
-    bucket the chain would run it for (mirrors the chooser inside
-    `kernels.dfa_compose_columns`): one AOT-warmup work-list entry per
-    (states, classes) table at this width bucket's compose shape."""
-    from fluvio_tpu.ops.regex_dfa import (
-        UnsupportedRegex,
-        compile_regex_cached,
-        literal_of,
-    )
-    from fluvio_tpu.smartengine.tpu import pallas_kernels, stripes
-    from fluvio_tpu.smartmodule import dsl
-
-    if not pallas_kernels.dfa_pallas_active():
-        return []
-    striped = buf.width > executor._stripe_threshold
-    if striped:
-        s, _v = stripes.stripe_params()
-        t_len = s
-    else:
-        t_len = buf.width + 1  # EOS tail column
-    seen = set()
-    reports = []
-    for prog in getattr(executor, "_programs", []):
-        for expr in _walk_exprs(prog):
-            if not isinstance(expr, dsl.RegexMatch):
-                continue
-            if literal_of(expr.pattern) is not None:
-                continue
-            try:
-                dfa = compile_regex_cached(expr.pattern)
-            except UnsupportedRegex:
-                continue
-            bucket = (dfa.n_states, dfa.n_classes, dfa.packed)
-            if bucket in seen:
-                continue
-            seen.add(bucket)
-            cls = np.zeros((buf.rows, t_len), np.int32)
-            table_t = dfa.table.T.astype(np.int32)
-            reports.append(
-                _trace_report(
-                    "dfa_compose",
-                    f"dfa_compose states={dfa.n_states} "
-                    f"classes={dfa.n_classes} packed={int(dfa.packed)} "
-                    f"shape=({buf.rows}, {t_len})",
-                    lambda c=cls, t=table_t, n=dfa.n_states: scan_function(
-                        pallas_kernels.dfa_compose_columns_pallas,
-                        c, t, n,
-                        interpret=pallas_kernels.interpret_mode(),
-                    ),
-                )
-            )
-    return reports

@@ -86,13 +86,13 @@ class PathPrediction:
     declines: Tuple[str, ...] = ()  # expected TELEMETRY.declines keys
     causes: Tuple[str, ...] = ()  # human explanations for the above
     # predicted H2D staging form for batches in this bucket: "raw" |
-    # "glz-gather" | "glz-pallas" (the TELEMETRY.link_variants keys).
+    # "glz-gather" (the TELEMETRY.link_variants keys).
     # This is the CONFIGURED variant — corpus-dependent declines
     # (glz-ratio, glz-below-min) resolve per batch at runtime and the
     # executor then ships raw with the reason on the decline counter.
     link_variant: str = "raw"
     # predicted D2H (result) form: "down-raw" | "down-packed" |
-    # "down-glz-xla" | "down-glz-pallas" — the result side's own
+    # "down-glz-xla" — the result side's own
     # variant family. Same contract as link_variant: the CONFIGURED
     # variant; per-batch ratio losses ship packed with `glz-enc-ratio`
     # on the decline counter.
@@ -157,7 +157,7 @@ def resolve_gates() -> dict:
     the runtime resolves them (one vocabulary with the knobs' homes)."""
     import jax
 
-    from fluvio_tpu.smartengine.tpu import glz, kernels, pallas_kernels
+    from fluvio_tpu.smartengine.tpu import glz, kernels
     from fluvio_tpu.smartengine.tpu.buffer import MAX_RECORD_WIDTH
     from fluvio_tpu.smartengine.tpu.executor import effective_link_compress
     from fluvio_tpu.smartengine.tpu.lower import _depth_over_work
@@ -167,26 +167,22 @@ def resolve_gates() -> dict:
         "dfa_assoc": _depth_over_work("FLUVIO_DFA_ASSOC"),
         "fast_json": _depth_over_work("FLUVIO_TPU_FAST_JSON"),
         "dfa_assoc_max_states": kernels.dfa_assoc_max_states(),
-        # round-2 DFA engine gates: byte-class table packing (the
-        # raised state default is sized for packed tables) and the
-        # fused Pallas block-compose ladder
+        # round-2 DFA engine gate: byte-class table packing (the
+        # raised state default is sized for packed tables)
         "dfa_classes": regex_classes_enabled(),
-        "dfa_pallas": pallas_kernels.dfa_pallas_active(),
         "stripe_threshold": int(env_int("FLUVIO_STRIPE_THRESHOLD")),
         "max_record_width": MAX_RECORD_WIDTH,
-        # link-staging gates: the H2D variant ladder the executor
-        # resolves at build time (FLUVIO_LINK_COMPRESS / the native
-        # compressor / FLUVIO_GLZ_PALLAS), mirrored here so the
-        # preflight can predict which form each batch's flat crosses in
+        # link-staging gates the executor resolves at build time
+        # (FLUVIO_LINK_COMPRESS / the native compressor), mirrored here
+        # so the preflight can predict which form each batch's flat
+        # crosses in
         "link_compress": effective_link_compress(),
         "glz_available": glz.available(),
-        "glz_pallas": pallas_kernels.glz_pallas_active(),
-        # down-link gates: the result-side compaction + ENCODE ladder
-        # (FLUVIO_RESULT_COMPACT / FLUVIO_RESULT_COMPRESS /
-        # FLUVIO_GLZ_ENC_PALLAS), mirrored for the down_variant arm
+        # down-link gates: the result-side compaction + ENCODE path
+        # (FLUVIO_RESULT_COMPACT / FLUVIO_RESULT_COMPRESS), mirrored
+        # for the down_variant arm
         "result_compact": _executor().effective_result_compact(),
         "result_compress": _executor().effective_result_compress(),
-        "glz_enc_pallas": pallas_kernels.glz_enc_pallas_active(),
         # windowed-state gate: delta-only emission vs full-state every
         # batch (FLUVIO_WINDOW_DELTA), mirrored for the window_variant
         # arm of the prediction
@@ -844,9 +840,7 @@ def predict_down_variant(
             return "down-packed"
         if not gates.get("result_compress"):
             return "down-packed"
-    return (
-        "down-glz-pallas" if gates.get("glz_enc_pallas") else "down-glz-xla"
-    )
+    return "down-glz-xla"
 
 
 def predict_link_variant(gates: dict, path: str, sharded: bool) -> str:
@@ -861,7 +855,7 @@ def predict_link_variant(gates: dict, path: str, sharded: bool) -> str:
         return "raw"
     if sharded and path == "striped":
         return "raw"
-    return "glz-pallas" if gates.get("glz_pallas") else "glz-gather"
+    return "glz-gather"
 
 
 def predict_window_variant(programs, gates: dict) -> str:

@@ -81,6 +81,16 @@ class LocalInstaller:
     async def install(self) -> dict:
         from fluvio_tpu.cluster.check import ClusterChecker
 
+        if self.config.engine == "tpu" and self.config.spus > 1:
+            # one process per chip: only ONE SPU child can hold the
+            # device engine (a second one would die at start — refuse
+            # before spawning anything). The launcher and the SC never
+            # initialize a jax backend themselves.
+            raise LocalClusterError(
+                f"engine='tpu' with spus={self.config.spus}: a chip belongs "
+                "to one process — a local cluster on one chip has exactly "
+                "one SPU with the device engine"
+            )
         if not self.config.skip_checks:
             ClusterChecker.local_preflight(self.data_dir).run_or_fail()
         os.makedirs(self.data_dir, exist_ok=True)

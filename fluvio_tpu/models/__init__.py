@@ -2,7 +2,7 @@
 
 These are the analogs of the reference's example modules
 (`smartmodule/regex-filter`, the cargo template kinds, and the benchmark
-chains from BASELINE.md). Each submodule exposes ``module() ->
+chains from HOST_BASELINE.md). Each submodule exposes ``module() ->
 SmartModuleDef`` carrying a DSL program (TPU-lowerable) and, where the
 reference's example does interesting host-side work (regex compile in init),
 equivalent Python hooks so hook-vs-DSL equivalence is tested.

@@ -26,32 +26,21 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fluvio_tpu.parallel.mesh import RECORD_AXIS, make_record_mesh
 from fluvio_tpu.resilience import faults
-from fluvio_tpu.resilience.policy import TRANSIENT, classify
+from fluvio_tpu.resilience.policy import TRANSIENT, classify, is_program_fault
 from fluvio_tpu.telemetry import TELEMETRY
 from fluvio_tpu.smartengine.tpu import executor as kernels_executor
 from fluvio_tpu.smartengine.tpu import glz, kernels, stripes
 from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer, apply_postops_host
 
-try:  # jax>=0.4.35 exposes shard_map at the top level
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-compatible shard_map: the replication-check knob was
-    renamed check_rep -> check_vma across jax releases; pallas kernels
-    inside the shard body require it off under either name."""
-    try:
-        return _shard_map_raw(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return _shard_map_raw(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    """shard_map with the replication check off: pallas kernels inside
+    the shard body require it."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 class ShardedChainExecutor:
@@ -105,7 +94,7 @@ class ShardedChainExecutor:
         per-shard compacted view descriptors; ``kmax`` bounds their
         cross-stripe carry's outer scan."""
         (_width, kwidth, has_keys, has_offsets, ts_mode,
-         _glz_bytes, _glz_variant, _glz_chunk, _enc, _cap, srows, kmax) = cfg
+         _glz_bytes, _enc, _cap, srows, kmax) = cfg
         ex = self.executor
         s, v = ex._stripe_s, ex._stripe_v
         lengths = uploads["lengths"].astype(jnp.int32)
@@ -176,8 +165,7 @@ class ShardedChainExecutor:
         return header(jnp.max(jnp.where(valid, lengths, 0))), packed, carries
 
     @staticmethod
-    def _shard_flat_words(uploads: Dict, glz_bytes: int, glz_variant: str,
-                          glz_chunk: int):
+    def _shard_flat_words(uploads: Dict, glz_bytes: int):
         """This shard's flat i32 words: the raw upload, or the shard's
         own glz stream inflated on device (traced inside the shard
         body; each shard's token rows arrive as its block of the
@@ -189,9 +177,8 @@ class ShardedChainExecutor:
             uploads["glz_ml"][0],
             uploads["glz_srcs"][0],
         )
-        raw = glz.decode_link_flat(
-            seqs, uploads["glz_lits"][0], uploads["glz_depth"][0],
-            glz_bytes, glz_variant, glz_chunk,
+        raw = glz.decompress_device(
+            *seqs, uploads["glz_lits"][0], uploads["glz_depth"][0], glz_bytes
         )
         return lax.bitcast_convert_type(raw.reshape(-1, 4), jnp.int32)
 
@@ -204,14 +191,11 @@ class ShardedChainExecutor:
         bytes per shard, not rows x width). Compressed staging
         (``glz_bytes > 0``): each shard's flat segment crossed the link
         as its OWN glz stream (per-shard token rows) and inflates
-        shard-locally through the same decode ladder the single-device
-        paths use — pallas kernels run per shard under shard_map, which
-        GSPMD tracing cannot."""
+        shard-locally through the same gather-round decode the
+        single-device paths use."""
         (width, kwidth, has_keys, has_offsets, ts_mode,
-         glz_bytes, glz_variant, glz_chunk, enc, fanout_cap) = cfg
-        flat_words = self._shard_flat_words(
-            uploads, glz_bytes, glz_variant, glz_chunk
-        )
+         glz_bytes, enc, fanout_cap) = cfg
+        flat_words = self._shard_flat_words(uploads, glz_bytes)
         values, lengths = kernels_executor.ragged_repad_words(
             flat_words, uploads["lengths"], width
         )
@@ -285,15 +269,13 @@ class ShardedChainExecutor:
             if enc != "off":
                 # per-shard down-link encode under shard_map (the same
                 # interleaved descriptor stream the single-device chain
-                # emits, one independent token set per shard — pallas
-                # kernels run per shard, which GSPMD tracing cannot)
+                # emits, one independent token set per shard)
                 ll, ml, srcs, lits, n_seq, n_lit, depth = glz.encode_result(
                     ex._desc_stream(
                         compacted[0], compacted[1],
                         arrays["values"].shape[1],
                     ),
                     ex._enc_chunk or glz.GLZ_CHUNK,
-                    enc,
                 )
                 packed["down_ll"] = ll
                 packed["down_ml"] = ml
@@ -339,7 +321,7 @@ class ShardedChainExecutor:
         )
 
     def _jitted(self, uploads: Dict, cfg: tuple):
-        striped = len(cfg) == 12  # (..., enc, fanout_cap, srows, kmax)
+        striped = len(cfg) == 10  # (..., enc, fanout_cap, srows, kmax)
         key = (
             tuple(sorted((k, v.shape, str(v.dtype)) for k, v in uploads.items())),
             cfg,
@@ -357,7 +339,7 @@ class ShardedChainExecutor:
             )
             out_specs = (
                 row,  # per-shard (1, 5) headers stack to (n, 5)
-                self._packed_specs(striped, cfg[8]),
+                self._packed_specs(striped, cfg[6]),
                 jax.tree_util.tree_map(lambda _: rep, self._carries()),
             )
 
@@ -511,7 +493,7 @@ class ShardedChainExecutor:
         ex = self.executor
         need, shard_rows = self._row_blocks(min(buf.count, buf.rows))
         segs, seg_len, _key = self._shard_segments(buf)
-        glz_up, glz_bytes, glz_chunk = None, 0, 0
+        glz_up, glz_bytes = None, 0
         if compress_ok:
             # per-buffer cache (the single-device `_glz_cache` precedent):
             # heal/fanout-cap/transient-retry re-dispatches of the same
@@ -542,7 +524,7 @@ class ShardedChainExecutor:
                 TELEMETRY.add_decline(reason)
                 ex.tag_decline(reason)
             if glz_up is not None:
-                glz_bytes, glz_chunk = seg_len, ex._glz_chunk
+                glz_bytes = seg_len
         flat_words = segs.reshape(-1).view(np.int32)
 
         def pad_rows(a, fill=0):
@@ -570,7 +552,7 @@ class ShardedChainExecutor:
             uploads["timestamp_deltas"] = pad_rows(ts_np)
         cfg = (
             buf.width, buf.keys.shape[1], has_keys, has_offsets, ts_mode,
-            glz_bytes, ex._glz_variant if glz_bytes else "gather", glz_chunk,
+            glz_bytes,
         )
         return uploads, cfg, sum(v.nbytes for v in uploads.values())
 
@@ -680,7 +662,7 @@ class ShardedChainExecutor:
         uploads, cfg, nbytes = self._stage_ragged(
             buf, compress_ok=ex._link_compress and not striped, span=span
         )
-        glz_bytes, glz_variant = cfg[5], cfg[6]
+        glz_bytes = cfg[5]
         if span is not None:
             now = time.perf_counter()
             # the inline n-shard compressor booked its own phase inside
@@ -745,11 +727,16 @@ class ShardedChainExecutor:
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
+            if is_program_fault(e):
+                # lowering/compile errors are the program's, not the
+                # device's: no quieter rung answers them
+                raise
             if enc_sh != "off" and classify(e) != TRANSIENT:
-                # sync half of the sharded ENCODE ladder: demote one
-                # rung and re-dispatch the same batch (the encoder is
-                # output-side; the staged uploads re-ship from cache)
-                ex._enc_demote(e, enc_sh, where="sharded dispatch")
+                # sync half of the sharded ENCODE ladder (runtime
+                # failures): latch encode off and re-dispatch the same
+                # batch (the encoder is output-side; the staged uploads
+                # re-ship from cache)
+                ex._enc_demote(e, where="sharded dispatch")
                 return self._dispatch_buffer_inner(buf, cap_shard, span)
             if not glz_bytes:
                 raise
@@ -760,14 +747,13 @@ class ShardedChainExecutor:
                 # cache) — a transient fault must not cost this
                 # executor a ladder rung
                 raise
-            # the single-device decode ladder, sharded: a pallas chunk
-            # decode that cannot lower under shard_map demotes this
-            # executor to the gather rounds; a gather failure latches
-            # compression off. Either way the batch re-stages and
-            # re-dispatches down-ladder (the compressed token arrays
-            # that already crossed are on the counter below).
+            # the single-device decode heal, sharded: a deterministic
+            # runtime failure of a compressed batch latches compression
+            # off; the batch re-stages and re-dispatches raw (the
+            # compressed token arrays that already crossed are on the
+            # counter below).
             ex.h2d_bytes_total += nbytes
-            ex._glz_demote(e, glz_variant, buf, where="sharded dispatch")
+            ex._glz_demote(e, buf, where="sharded dispatch")
             return self._dispatch_buffer_inner(buf, cap_shard, span)
         if span is not None:
             span.add("dispatch", time.perf_counter() - t_ph)
@@ -780,11 +766,11 @@ class ShardedChainExecutor:
             # streams pipeline; the host mirror commits at finish
             self._pending_carries = new_carries
         TELEMETRY.add_link_variant(
-            f"glz-{glz_variant}" if glz_bytes else "raw"
+            "glz-gather" if glz_bytes else "raw"
         )
         return (
             prev_carries, new_carries, header, packed, cap_shard, span,
-            glz_variant if glz_bytes else None,
+            "gather" if glz_bytes else None,
             enc_sh if enc_sh != "off" else None,
         )
 
@@ -822,8 +808,7 @@ class ShardedChainExecutor:
         )
 
     def _try_down_fetch(
-        self, buf, packed, down_meta, counts, enc_form, _fetch_all,
-        width: int,
+        self, buf, packed, down_meta, counts, _fetch_all, width: int,
     ):
         """Sharded fetch half of the result-encode ladder: download each
         shard's token slices (one concurrent `_fetch_all`, survivor
@@ -883,7 +868,7 @@ class ShardedChainExecutor:
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
-                ex._enc_demote(e, enc_form or "xla", where="sharded fetch")
+                ex._enc_demote(e, where="sharded fetch")
                 return None
             st_s, ln_s = ex._desc_split(stream, int(counts[s]), desc_width)
             st_parts.append(st_s)
@@ -1003,10 +988,10 @@ class ShardedChainExecutor:
             desc_cols = None
             if down_meta is not None:
                 desc_cols = self._try_down_fetch(
-                    buf, packed, down_meta, counts, _enc, _fetch_all, width
+                    buf, packed, down_meta, counts, _fetch_all, width
                 )
                 if desc_cols is not None:
-                    used_tokens = _enc or "xla"
+                    used_tokens = "xla"
             if ex._needs_stripes(buf) and "span_start" not in packed:
                 # striped survivors are whole records: the segment mask
                 # is the entire download; spans derive host-side (span

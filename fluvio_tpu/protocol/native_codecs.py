@@ -5,7 +5,7 @@ shared library builds once per source hash with the baked-in g++ and
 loads via ctypes. When no toolchain is available the loader returns
 None and protocol/compression.py falls back to the bundled pure-Python
 codecs (with an operator-visible warning — the fallbacks are 20-100x
-slower; see BASELINE.md's codec table).
+slower; `bench.py`'s codec table measures both).
 
 Parity: fluvio-compression/src/lib.rs links the native lz4/snappy
 libraries; this is the equivalent native path.

@@ -61,10 +61,42 @@ _TRANSIENT_MARKERS = (
 )
 
 
+# substrings that mark an XLA/Mosaic runtime-error as raised by the
+# COMPILER (lowering, legalization, compile-time memory planning), not
+# by an execution on the device
+_PROGRAM_FAULT_MARKERS = ("mosaic", "compil", "lowering", "legaliz")
+
+
+def is_program_fault(exc: BaseException) -> bool:
+    """True when ``exc`` is what only tracing/lowering/compiling raises:
+    the program is wrong for this backend, and no re-run, quieter rung
+    or interpreter pass makes it right. Program faults propagate out of
+    every heal ladder and the engine's interpreter demotion — the run
+    stops with the compiler's own error. Device weather (execution-time
+    runtime errors, decode mismatches, every `InjectedFault`) is not a
+    program fault and heals exactly as before."""
+    if isinstance(exc, InjectedFault):
+        return False
+    if isinstance(exc, (NotImplementedError, TypeError)):
+        # includes the jax tracer/type errors (JAXTypeError subclasses)
+        return True
+    cls = type(exc)
+    if cls.__module__.startswith("jax") and (
+        "Lowering" in cls.__name__ or "Tracer" in cls.__name__
+    ):
+        return True
+    if cls.__name__ in ("XlaRuntimeError", "JaxRuntimeError"):
+        msg = str(exc).lower()
+        return any(m in msg for m in _PROGRAM_FAULT_MARKERS)
+    return False
+
+
 def classify(exc: BaseException) -> str:
     """``transient`` | ``deterministic`` for a fused-path failure."""
     if isinstance(exc, InjectedFault):
         return TRANSIENT if exc.transient else DETERMINISTIC
+    if is_program_fault(exc):
+        return DETERMINISTIC
     if isinstance(exc, (ConnectionError, TimeoutError, BrokenPipeError)):
         return TRANSIENT
     name = type(exc).__name__

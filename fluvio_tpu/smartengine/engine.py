@@ -44,6 +44,21 @@ class EngineError(Exception):
     pass
 
 
+def touch_device() -> None:
+    """Open the jax backend (``backend="tpu"``: once at SPU start and
+    at every chain build): a chip that cannot be opened is an
+    `EngineError` here, not a failure of the first batch that the fused
+    path's catch-all would answer with an interpreter re-run."""
+    import jax
+
+    try:
+        jax.block_until_ready(jax.device_put(0, jax.devices()[0]))
+    except Exception as e:  # noqa: BLE001 — backend init boundary
+        raise EngineError(
+            f"backend='tpu': the jax device backend cannot be opened: {e}"
+        ) from e
+
+
 class StoreMemoryExceeded(EngineError):
     """Input slab exceeds the engine memory bound (parity: limiter.rs)."""
 
@@ -134,25 +149,37 @@ class SmartModuleChainBuilder:
         if backend in ("tpu", "auto") and self.entries:
             try:
                 from fluvio_tpu.smartengine.tpu.executor import TpuChainExecutor
-
+            except ImportError:
+                # `auto` without jax serves from the host engines; an
+                # explicit device backend has nothing to fall back to
+                if backend == "tpu":
+                    raise
+                TpuChainExecutor = None
+            if backend == "tpu":
+                touch_device()
+            if TpuChainExecutor is not None:
                 tpu_chain = TpuChainExecutor.try_build(
                     [(e.module, e.config) for e in self.entries]
                 )
-            except ImportError:
-                tpu_chain = None
             if tpu_chain is not None:
                 tpu_chain.attach(instances)
                 if engine.mesh_devices and engine.mesh_devices > 1:
                     try:
                         tpu_chain.enable_sharded(engine.mesh_devices)
                     except ValueError as e:
-                        # not enough devices / unshardable chain: stay on
-                        # the single-device executor rather than failing
+                        if backend == "tpu":
+                            raise EngineError(
+                                f"backend='tpu' with mesh_devices="
+                                f"{engine.mesh_devices}: sharded engine "
+                                f"mode unavailable: {e}"
+                            ) from e
+                        # auto: not enough devices / unshardable chain —
+                        # stay on the single-device executor
                         logger.warning("sharded engine mode unavailable: %s", e)
             if tpu_chain is None and backend == "tpu":
                 raise EngineError(
                     "backend='tpu' requires every module in the chain to "
-                    "carry a DSL program (or jax is unavailable)"
+                    "carry a DSL program"
                 )
         # native (C++) per-record engine: the compiled host path — auto
         # falls back to it when the TPU path is unavailable
@@ -290,6 +317,13 @@ class SmartModuleChainInstance:
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
+                from fluvio_tpu.resilience.policy import is_program_fault
+
+                if is_program_fault(e):
+                    # a lowering/compile error is the program's fault,
+                    # not the device's: the interpreter must not answer
+                    # for a kernel the compiler refused
+                    raise
                 # non-spill fused failure (deterministic fault, or a
                 # transient one that exhausted its retry budget): same
                 # demotion as a spill — the executor restored the carry

@@ -24,23 +24,33 @@ jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: a broker must not stall ~25s on the
 # first consume of each chain/shape bucket in every process. Compiled
-# executables persist across processes keyed by HLO hash; set
-# FLUVIO_TPU_XLA_CACHE=off to disable (e.g. hermetic tests).
+# executables persist across processes keyed by HLO hash.
 #
-# The default lives INSIDE the repo so warmed entries survive anything
-# that preserves the checkout (driver bench runs happen in the same
-# tree a build session warmed; ~/.cache does not reliably persist).
-_repo_cache = os.path.join(os.path.dirname(__file__), "..", "..", "..", ".xla_cache")
-_cache_dir = os.environ.get(
-    "FLUVIO_TPU_XLA_CACHE", os.path.abspath(_repo_cache)
-)
+# Placement comes from OUTSIDE when ``JAX_COMPILATION_CACHE_DIR`` is set
+# (jax reads it itself; no directory is set in code, so the caller's
+# choice is never overridden); otherwise the fixed ``<checkout>/.xla_cache``
+# (the path is part of the cache key, so it must not move).
+# ``FLUVIO_TPU_XLA_CACHE=off`` disables the in-checkout default.
+
+
+def _resolve_cache_dir() -> str:
+    """The persistent-cache directory this process compiles into ("" =
+    none), setting it in code only when the environment did not."""
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if outside:
+        return outside
+    if os.environ.get("FLUVIO_TPU_XLA_CACHE") == "off":
+        return ""
+    fixed = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "..", "..", ".xla_cache")
+    )
+    jax.config.update("jax_compilation_cache_dir", fixed)
+    return fixed
+
+
 #: the resolved persistent-cache directory ("" when disabled) — the single
-#: source of truth; bench.py reads this for its cache-evidence section
-XLA_CACHE_DIR = "" if _cache_dir == "off" else os.path.expanduser(_cache_dir)
+#: source of truth for telemetry/compiles.py and bench.py's cache evidence
+XLA_CACHE_DIR = _resolve_cache_dir()
 if XLA_CACHE_DIR:
-    try:
-        jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 — older jax without these flags
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -200,18 +200,7 @@ def dfa_compose_columns(
     bounds live transition material at rows x block x S elements
     (block shrinks as rows x S grows) instead of rows x T x S, while
     keeping the sequential depth at T/block instead of T.
-
-    When the FLUVIO_DFA_PALLAS ladder is active the whole composition
-    runs as one fused Pallas kernel instead (compositions never leave
-    VMEM); bit-equal by associativity, demoted back here by the
-    executor's self-heal rung on any failure.
     """
-    from fluvio_tpu.smartengine.tpu import pallas_kernels
-
-    if pallas_kernels.dfa_pallas_active():
-        return pallas_kernels.dfa_compose_columns_pallas(
-            cls, table_t, n_states, interpret=pallas_kernels.interpret_mode()
-        )
     rows = cls.shape[0]
     blocks, tv_of = _dfa_column_blocks(cls, n_states)
     ident = jnp.broadcast_to(

@@ -1,10 +1,9 @@
 """Pallas TPU kernels: fused single-pass byte-state machines.
 
 The XLA lowering of ``json_get`` costs ~12 separate gather/scan
-primitives per call; on a remotely-attached chip each primitive pays
-dispatch overhead, so collapsing the whole field extraction into ONE
-pallas kernel is the difference between ~600ms and a few ms per batch
-(BASELINE.md round-1 optimization roadmap).
+primitives per call, each a full pass over the byte matrix through
+HBM; collapsing the whole field extraction into ONE pallas kernel keeps
+the automaton's state in VMEM for a single pass.
 
 Layout: the byte matrix is processed TRANSPOSED — (width, rows) — so the
 sequential scan walks sublanes (cheap dynamic index) while records ride
@@ -12,8 +11,9 @@ the 128-wide lanes. The state machine is the *sequential* reference
 automaton of ``dsl.json_get_bytes`` (exact semantics, including the
 malformed-input corners where the parallel structural kernel deviates).
 
-Falls back cleanly: callers use :func:`json_get_available` /
-``try`` the build and keep the XLA kernel otherwise.
+Every kernel here compiles for the v5e at bench shapes — pinned by the
+described-chip compiles in tests/test_chip_compile.py. A kernel the
+chip's compiler refuses is deleted, not demoted from at run time.
 """
 
 from __future__ import annotations
@@ -26,31 +26,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax moved the context manager out of the top-level namespace
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:  # pragma: no cover — older jax keeps the alias
-    _enable_x64 = jax.enable_x64
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from fluvio_tpu.analysis.envreg import env_raw
 from fluvio_tpu.telemetry import instrument_jit
 
-try:  # pallas availability is platform-dependent
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS = True
-except Exception:  # noqa: BLE001 — optional dependency surface
-    _PALLAS = False
 
 LANES = 512  # records per block (lane axis, multiple of 128)
 MAX_PALLAS_WIDTH = 1024  # VMEM: width x LANES x int32 blocks must fit
 
 # scan phases
 _SCAN, _SKIP_KEY, _SEEK_COLON, _SEEK_VAL, _STR_VAL, _RAW_VAL, _DONE = range(7)
-
-
-def json_get_available() -> bool:
-    return _PALLAS
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +72,7 @@ def pallas_active(width: int = 0) -> bool:
     (slow) interpreter on CPU for equivalence testing, ``auto`` (default)
     enables on real TPU backends only.
     """
-    if _disable_depth or not _PALLAS:
+    if _disable_depth:
         return False
     if width > MAX_PALLAS_WIDTH:
         return False
@@ -296,8 +283,6 @@ def json_get_span_pallas(
     feeds either `extract_pallas` (materialized bytes) or the executor's
     descriptor D2H path (late materialization on the host).
     """
-    if not _PALLAS:
-        raise RuntimeError("pallas unavailable")
     needle = b'"' + key.encode("utf-8") + b'"'
     n, width = values.shape
     blocks = max(1, (n + LANES - 1) // LANES)
@@ -312,7 +297,7 @@ def json_get_span_pallas(
     # kernels trace with x64 off: under the package-wide x64 every weak
     # Python-int literal becomes i64 and Mosaic's convert lowering recurses
     # infinitely on the resulting i64->i32 casts
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         start, vlen = pl.pallas_call(
             scan,
             grid=(blocks,),
@@ -341,8 +326,6 @@ def extract_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Materialize per-record substrings with the pallas shift kernel."""
-    if not _PALLAS:
-        raise RuntimeError("pallas unavailable")
     n, width = values.shape
     blocks = max(1, (n + LANES - 1) // LANES)
     padded_n = blocks * LANES
@@ -353,7 +336,7 @@ def extract_pallas(
         vt = jnp.pad(vt, ((0, 0), (0, padded_n - n)))
         start = jnp.pad(start, (0, padded_n - n))
         vlen = jnp.pad(vlen, (0, padded_n - n))
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         extract = functools.partial(_extract_kernel, width)
         outT = pl.pallas_call(
             extract,
@@ -400,230 +383,6 @@ def _describe_json_get(*a, **k) -> str:
 json_get_pallas = instrument_jit(
     json_get_pallas, "pallas", describe=_describe_json_get
 )
-
-
-# ---------------------------------------------------------------------------
-# glz link decompression (per-chunk VMEM chain resolve)
-# ---------------------------------------------------------------------------
-
-# pointer-squaring rounds: after k rounds every byte's source index has
-# followed its match chain 2^k links, and literal bytes are fixpoints
-# (midx == self), so ceil(log2(MAX_DEPTH=6)) = 3 rounds flatten every
-# chain to its literal root regardless of the stream's actual depth
-GLZ_SQUARE_ROUNDS = 3
-GLZ_CHUNK_LANES = 128  # lane width of the per-chunk block layout
-
-
-def glz_pallas_active() -> bool:
-    """Should the executor's compressed staging decode with the Pallas
-    chunk kernel? ``FLUVIO_GLZ_PALLAS``: ``0`` disables (gather rounds
-    only), ``1``/``interpret`` forces it (interpreted on CPU for
-    equivalence testing), ``auto`` (default) enables off-CPU only —
-    the same ladder shape as ``FLUVIO_TPU_PALLAS``. Resolved once per
-    executor build, never per dispatch."""
-    if _disable_depth or not _PALLAS:
-        return False
-    mode = env_raw("FLUVIO_GLZ_PALLAS")
-    if mode == "0":
-        return False
-    if mode in ("interpret", "1"):
-        return True
-    return not interpret_mode()
-
-
-def _glz_resolve_kernel(rows: int, base_ref, midx_ref, out_ref):
-    """One chunk: resolve glz match chains entirely in VMEM.
-
-    ``base_ref`` is the literal-resolved chunk (match bytes zero),
-    ``midx_ref`` the per-byte gather source — CHUNK-LOCAL by the
-    `compress_link` invariant (chunks compress independently, so no
-    match reaches outside its own chunk). Both are (rows, 128) int32
-    blocks. Pointer squaring (`GLZ_SQUARE_ROUNDS`) flattens every match
-    chain to its literal root, then ONE byte gather materializes the
-    chunk — the whole-buffer formulation's depth× HBM round trips
-    collapse to in-VMEM resolves plus a single output write.
-
-    NOTE: the in-kernel gathers index the flattened VMEM block with a
-    vector of dynamic indices. Mosaic's dynamic-gather lowering is
-    version-dependent; a backend that rejects it fails at compile time
-    and the executor's self-heal ladder demotes the batch to the
-    gather-round variant (tested seam) — correctness never rides on
-    this kernel lowering.
-    """
-    n = rows * GLZ_CHUNK_LANES
-    base = base_ref[:, :].reshape(n)
-    idx = midx_ref[:, :].reshape(n)
-    for _ in range(GLZ_SQUARE_ROUNDS):
-        idx = jnp.take(idx, idx)
-    out = jnp.take(base, idx)
-    out_ref[:, :] = out.reshape(rows, GLZ_CHUNK_LANES)
-
-
-def glz_decode_pallas(base, midx, chunk: int, interpret: bool = False):
-    """Inflate a chunk-local glz byte plan with the Pallas resolver.
-
-    ``base``/``midx`` come from `glz.byte_plan_device` over a stream
-    produced by `glz.compress_link` (absolute sources, chunk-local by
-    construction). The grid walks chunks; each grid step resolves one
-    chunk in VMEM. Returns uint8[len(base)].
-    """
-    if not _PALLAS:
-        raise RuntimeError("pallas unavailable")
-    out_len = base.shape[0]
-    if chunk % GLZ_CHUNK_LANES:
-        raise ValueError(f"glz chunk {chunk} not lane-aligned")
-    n_chunks = max(1, (out_len + chunk - 1) // chunk)
-    padded = n_chunks * chunk
-    rows = chunk // GLZ_CHUNK_LANES
-    base_i = base.astype(jnp.int32)
-    idx = jnp.arange(out_len, dtype=jnp.int32)
-    # chunk-local sources; literal/pad bytes stay self-referencing so
-    # the squaring rounds fix them in place
-    local = midx.astype(jnp.int32) - (idx // jnp.int32(chunk)) * jnp.int32(chunk)
-    if padded != out_len:
-        base_i = jnp.pad(base_i, (0, padded - out_len))
-        # pad bytes live in the last chunk and self-reference: their
-        # within-chunk offset continues where the real bytes stopped
-        tail0 = out_len - (n_chunks - 1) * chunk
-        tail = tail0 + jnp.arange(padded - out_len, dtype=jnp.int32)
-        local = jnp.concatenate([local, tail])
-    base2 = base_i.reshape(n_chunks * rows, GLZ_CHUNK_LANES)
-    local2 = local.reshape(n_chunks * rows, GLZ_CHUNK_LANES)
-    resolve = functools.partial(_glz_resolve_kernel, rows)
-    with _enable_x64(False):  # see the x64/Mosaic note in json_get_pallas
-        out2 = pl.pallas_call(
-            resolve,
-            grid=(n_chunks,),
-            in_specs=[
-                pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-                pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-            ],
-            out_specs=pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-            out_shape=jax.ShapeDtypeStruct(
-                (n_chunks * rows, GLZ_CHUNK_LANES), jnp.int32
-            ),
-            interpret=interpret,
-        )(base2, local2)
-    return out2.reshape(padded)[:out_len].astype(jnp.uint8)
-
-
-# ---------------------------------------------------------------------------
-# glz result ENCODE (per-chunk VMEM window match)
-# ---------------------------------------------------------------------------
-
-# static candidate distances (in 8-byte groups) the window matcher
-# probes: the contiguous short range covers every group period <= 32
-# (an odd byte period P repeats at group distance P), the sparse tail
-# larger power-of-two-ish repeats. The XLA hash rung has no such
-# window limit — a corpus whose period the window misses still
-# compresses after one ladder demotion; the two rungs only promise
-# VALID streams, not identical ones.
-GLZ_ENC_DISTANCES = tuple(range(1, 33)) + (40, 48, 56, 64, 80, 96, 128)
-
-
-def glz_enc_pallas_active() -> bool:
-    """Should result buffers encode with the Pallas window kernel?
-    ``FLUVIO_GLZ_ENC_PALLAS``: ``0`` disables (XLA hash rung),
-    ``1``/``interpret`` forces (interpreted on CPU for equivalence
-    testing), ``auto`` (default) enables off-CPU only — the same ladder
-    shape as the decode's ``FLUVIO_GLZ_PALLAS``. Resolved once per
-    executor build, never per dispatch."""
-    if _disable_depth or not _PALLAS:
-        return False
-    mode = env_raw("FLUVIO_GLZ_ENC_PALLAS")
-    if mode == "0":
-        return False
-    if mode in ("interpret", "1"):
-        return True
-    return not interpret_mode()
-
-
-def _glz_enc_match_kernel(gpc: int, rounds: int,
-                          w0_ref, w1_ref, nc_ref, root_ref):
-    """One chunk: window-match groups against earlier equal groups and
-    resolve each match chain to its literal root, entirely in VMEM.
-
-    Blocks are (gpc/128, 128) int32 views of the chunk's per-group
-    words (``w0``/``w1``) and a not-const eligibility flag (``nc``:
-    const-run groups get their closed-form sources in shared XLA code
-    and must not become window targets, or chains would exceed the
-    depth bound). Every candidate edge requires exact value equality,
-    so pointer-squaring (the decode kernel's trick, reversed) lands on
-    an equal-valued literal root — depth-1 sources by construction.
-    ``root_ref`` is CHUNK-LOCAL group indices; self == literal.
-    """
-    w0 = w0_ref[:, :].reshape(gpc)
-    w1 = w1_ref[:, :].reshape(gpc)
-    nc = nc_ref[:, :].reshape(gpc)
-    idx = jax.lax.iota(jnp.int32, gpc)
-    cand = idx
-    # largest distance first: the LAST write (smallest d) wins, which
-    # keeps chains short for tight periods
-    for d in reversed(GLZ_ENC_DISTANCES):
-        if d >= gpc:
-            continue
-        zeros = jnp.zeros((d,), jnp.int32)
-        s0 = jnp.concatenate([zeros, w0[:-d]])
-        s1 = jnp.concatenate([zeros, w1[:-d]])
-        snc = jnp.concatenate([zeros, nc[:-d]])
-        eq = (w0 == s0) & (w1 == s1) & (idx >= d) & (snc != 0) & (nc != 0)
-        cand = jnp.where(eq, idx - d, cand)
-    for _ in range(rounds):
-        cand = jnp.take(cand, cand)
-    root_ref[:, :] = cand.reshape(-1, GLZ_CHUNK_LANES)
-
-
-def glz_encode_match(w0, w1, const_m, chunk_groups: int,
-                     interpret: bool = False):
-    """Pallas rung of the result-encode ladder: per-group literal-root
-    sources. Inputs are the full buffer's group words plus the shared
-    const-run mask; the grid walks chunks and each step resolves one
-    chunk's match graph in VMEM. Returns GLOBAL root indices (root == g
-    means literal; const-run groups return self and are overridden by
-    the caller's closed-form sources)."""
-    if not _PALLAS:
-        raise RuntimeError("pallas unavailable")
-    G = w0.shape[0]
-    if chunk_groups % GLZ_CHUNK_LANES:
-        raise ValueError(f"glz chunk groups {chunk_groups} not lane-aligned")
-    n_chunks = max(1, (G + chunk_groups - 1) // chunk_groups)
-    padded = n_chunks * chunk_groups
-    w0 = w0.astype(jnp.int32)
-    w1 = w1.astype(jnp.int32)
-    nc = (~const_m).astype(jnp.int32)
-    if padded != G:
-        # pad groups are self-roots: give them a value no real group
-        # can alias within the pad-only tail and mark them ineligible
-        w0 = jnp.pad(w0, (0, padded - G))
-        w1 = jnp.pad(w1, (0, padded - G))
-        nc = jnp.pad(nc, (0, padded - G))
-    rows = chunk_groups // GLZ_CHUNK_LANES
-    shape2 = (n_chunks * rows, GLZ_CHUNK_LANES)
-    rounds = max(1, int(np.ceil(np.log2(max(chunk_groups, 2)))))
-    kernel = functools.partial(_glz_enc_match_kernel, chunk_groups, rounds)
-    with _enable_x64(False):  # see the x64/Mosaic note in json_get_pallas
-        root2 = pl.pallas_call(
-            kernel,
-            grid=(n_chunks,),
-            in_specs=[
-                pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-                pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-                pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-            ],
-            out_specs=pl.BlockSpec((rows, GLZ_CHUNK_LANES), lambda b: (b, 0)),
-            out_shape=jax.ShapeDtypeStruct(shape2, jnp.int32),
-            interpret=interpret,
-        )(
-            w0.reshape(shape2),
-            w1.reshape(shape2),
-            nc.reshape(shape2),
-        )
-    # chunk-local roots -> global
-    local = root2.reshape(padded)[:G]
-    base = (
-        jnp.arange(G, dtype=jnp.int32) // jnp.int32(chunk_groups)
-    ) * jnp.int32(chunk_groups)
-    return base + local
 
 
 # ---------------------------------------------------------------------------
@@ -728,8 +487,6 @@ def dfa_match_pallas(
     replacing the XLA `lax.scan` whose per-step dual gathers dominate
     the regex stage's 0.58s/1M-record cost.
     """
-    if not _PALLAS:
-        raise RuntimeError("pallas unavailable")
     if not dfa_supported(dfa):
         raise ValueError("DFA too large for the select-chain kernel")
     n, width = values.shape
@@ -761,7 +518,7 @@ def dfa_match_pallas(
         dfa.start,
         width,
     )
-    with _enable_x64(False):  # see the x64/Mosaic note in json_get_pallas
+    with jax.enable_x64(False):  # see the x64/Mosaic note in json_get_pallas
         out = pl.pallas_call(
             kernel,
             grid=(blocks,),
@@ -774,160 +531,3 @@ def dfa_match_pallas(
             interpret=interpret,
         )(vt, len2d)
     return out[0, :n] != 0
-
-
-# ---------------------------------------------------------------------------
-# DFA block-compose fusion (associative-engine compose stage in VMEM)
-# ---------------------------------------------------------------------------
-#
-# The XLA associative-scan engine (kernels.dfa_compose_columns)
-# materializes [rows, block, S] transition vectors per column block and
-# round-trips them through HBM between the scan tree's levels — the
-# compose/reduce stage, not the per-byte classify, is the bandwidth hog.
-# This rung folds each row's class stream through the transition table
-# with ONE fused kernel: only the class block, the C x S table, and the
-# running [rows, S] composition are ever live, all in VMEM.
-
-DFA_COMPOSE_LANES = 128  # lane alignment of the class/state blocks
-_DFA_COMPOSE_ROW_ELEMS = 1 << 20  # class-block element budget per grid step
-
-# self-heal ladder state (process-wide, like the glz executor latches
-# but global: the compose chooser sits inside kernels.py, below any
-# executor). `_dfa_pallas_engaged` flips at trace time so a demotion
-# request from an executor whose chain never traced the kernel is a
-# no-op — the dispatch seam offers every failure to this rung.
-_dfa_pallas_off = False
-_dfa_pallas_engaged = False
-
-
-def dfa_pallas_active() -> bool:
-    """Should `kernels.dfa_compose_columns` run the fused Pallas rung?
-    ``FLUVIO_DFA_PALLAS``: ``0`` disables (XLA associative scan),
-    ``1``/``interpret`` forces it (interpreted on CPU for equivalence
-    testing), ``auto`` (default) enables off-CPU only — the same ladder
-    shape as the glz ``FLUVIO_GLZ_PALLAS`` rungs. A runtime demotion
-    (`dfa_pallas_demote`) latches it off process-wide."""
-    if _disable_depth or not _PALLAS or _dfa_pallas_off:
-        return False
-    mode = env_raw("FLUVIO_DFA_PALLAS")
-    if mode == "0":
-        return False
-    if mode in ("interpret", "1"):
-        return True
-    return not interpret_mode()
-
-
-def dfa_pallas_demote(e=None, where: str = "dispatch") -> bool:
-    """One rung down the DFA compose ladder: latch the Pallas rung off
-    so the next trace takes the XLA associative-scan path. Returns True
-    iff this call newly demoted (callers retry the batch on True) —
-    False when the kernel never engaged (the failure is someone else's)
-    or the latch was already down (no double-count)."""
-    global _dfa_pallas_off
-    if not _dfa_pallas_engaged or _dfa_pallas_off:
-        return False
-    _dfa_pallas_off = True
-    from fluvio_tpu.telemetry.registry import TELEMETRY
-
-    TELEMETRY.add_heal()
-    TELEMETRY.add_decline("dfa-pallas-demoted")
-    import logging
-
-    logging.getLogger(__name__).warning(
-        "fused DFA compose kernel failed at %s; demoting to the XLA "
-        "associative-scan path: %s", where, e,
-    )
-    return True
-
-
-def _dfa_pallas_reset() -> None:
-    """Test hook: clear the demotion latch + engagement flag."""
-    global _dfa_pallas_off, _dfa_pallas_engaged
-    _dfa_pallas_off = False
-    _dfa_pallas_engaged = False
-
-
-def _dfa_compose_kernel(s_pad: int, t_len: int, cls_ref, table_ref, out_ref):
-    """One row-block: fold the class stream through the transition table.
-
-    ``cls_ref`` (rows, t_len) int32 class per column (-1 = identity:
-    padding / un-owned stripe bytes), ``table_ref`` (C_pad, s_pad) the
-    padded transposed table. The carry is the running transition vector
-    f[row, s] = state after the consumed columns starting from s; each
-    column updates it with one table gather — sequential over columns
-    but with zero HBM traffic, which beats the log-depth XLA tree that
-    streams [rows, block, S] material per level. Bit-equal to
-    `kernels.dfa_compose_columns` by associativity (exact int ops, same
-    composition order up to regrouping).
-
-    NOTE: the in-kernel gather indexes the flattened VMEM table with a
-    vector of dynamic indices (same construct as `_glz_resolve_kernel`).
-    Mosaic's dynamic-gather lowering is version-dependent; a backend
-    that rejects it fails at compile time and the executor's self-heal
-    rung (`dfa_pallas_demote`) re-traces on the XLA path — correctness
-    never rides on this kernel lowering.
-    """
-    blk = cls_ref[:, :]
-    rows = blk.shape[0]
-    flat = table_ref[:, :].reshape(-1)
-    f0 = jax.lax.broadcasted_iota(jnp.int32, (rows, s_pad), 1)
-
-    def step(t, f):
-        c = jax.lax.dynamic_slice_in_dim(blk, t, 1, axis=1)  # (rows, 1)
-        idx = c * jnp.int32(s_pad) + f
-        nxt = jnp.take(
-            flat, jnp.clip(idx, jnp.int32(0), jnp.int32(flat.shape[0] - 1))
-        )
-        return jnp.where(c >= 0, nxt, f)
-
-    out_ref[:, :] = jax.lax.fori_loop(jnp.int32(0), jnp.int32(t_len), step, f0)
-
-
-def dfa_compose_columns_pallas(
-    cls: jnp.ndarray, table_t: jnp.ndarray, n_states: int,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Fused rung of `kernels.dfa_compose_columns` (same contract:
-    ``cls`` int32[rows, T] with -1 identity, ``table_t`` int32[C, S],
-    returns int32[rows, S]).
-
-    The grid walks row blocks sized so each class block stays under the
-    element budget; states and columns pad to lane multiples (padded
-    states compose to garbage that the final slice drops — real states
-    never reach them because table entries stay < n_states)."""
-    if not _PALLAS:
-        raise RuntimeError("pallas unavailable")
-    global _dfa_pallas_engaged
-    _dfa_pallas_engaged = True
-    rows, t_len = cls.shape
-    lanes = DFA_COMPOSE_LANES
-    s_pad = -(-max(n_states, 1) // lanes) * lanes
-    t_pad = -(-max(t_len, 1) // lanes) * lanes
-    rb = max(8, min(512, _DFA_COMPOSE_ROW_ELEMS // t_pad))
-    rb = -(-rb // 8) * 8
-    nb = -(-max(rows, 1) // rb)
-    r_pad = nb * rb
-    cls_i = jnp.pad(
-        cls.astype(jnp.int32),
-        ((0, r_pad - rows), (0, t_pad - t_len)),
-        constant_values=-1,
-    )
-    c_pad = -(-table_t.shape[0] // 8) * 8
-    table_p = jnp.pad(
-        table_t.astype(jnp.int32),
-        ((0, c_pad - table_t.shape[0]), (0, s_pad - table_t.shape[1])),
-    )
-    kernel = functools.partial(_dfa_compose_kernel, s_pad, t_pad)
-    with _enable_x64(False):  # see the x64/Mosaic note in json_get_pallas
-        out = pl.pallas_call(
-            kernel,
-            grid=(nb,),
-            in_specs=[
-                pl.BlockSpec((rb, t_pad), lambda b: (b, 0)),
-                pl.BlockSpec((c_pad, s_pad), lambda b: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((rb, s_pad), lambda b: (b, 0)),
-            out_shape=jax.ShapeDtypeStruct((r_pad, s_pad), jnp.int32),
-            interpret=interpret,
-        )(cls_i, table_p)
-    return out[:rows, :n_states]

@@ -504,10 +504,20 @@ class StreamFetchHandler:
             await self._run()
         except (SocketClosed, ConnectionError, asyncio.CancelledError):
             pass
-        except Exception:
+        except Exception as e:
             logger.exception(
                 "stream fetch failed (%s-%s)", self.req.topic, self.req.partition
             )
+            # the consumer must learn the stream died (a program fault
+            # propagating out of the fused path lands here): without an
+            # error frame it waits forever on a stream nobody serves
+            try:
+                await self._send_error(
+                    ErrorCode.SMARTMODULE_RUNTIME_ERROR, hw=-1, log_start=-1,
+                    message=f"{type(e).__name__}: {e}",
+                )
+            except (SocketClosed, ConnectionError, OSError):
+                pass
         finally:
             # stream died mid-hold: release through the same path as a
             # re-admit so the gauge drops AND the hold duration is

@@ -67,6 +67,14 @@ class SpuServer:
         from fluvio_tpu.analysis.envreg import warn_unknown_env
 
         warn_unknown_env()
+        if self.config.smart_engine.backend == "tpu":
+            # one process per chip: an SPU asked to serve from the device
+            # opens it NOW, so a chip another process holds (or one that
+            # cannot be opened) kills this start loudly — never a broker
+            # that comes up and serves from the interpreter
+            from fluvio_tpu.smartengine.engine import touch_device
+
+            touch_device()
         if self.config.smart_engine.backend in ("auto", "native"):
             # warm the native engine's g++ build off the event loop so the
             # first SmartModule chain build doesn't stall request handling

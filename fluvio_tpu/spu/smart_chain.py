@@ -84,6 +84,7 @@ def _encoded_record_sizes_at(
     return _varint_sizes(inner) + inner
 
 from fluvio_tpu.protocol.error import ErrorCode
+from fluvio_tpu.resilience.policy import is_program_fault
 from fluvio_tpu.protocol.record import Batch, RecordSet
 from fluvio_tpu.schema.smartmodule import (
     SmartModuleInvocation,
@@ -731,6 +732,8 @@ def tpu_stage_dispatch(
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception as e:
+        if is_program_fault(e):
+            raise  # compiler-refused program: never a per-record rerun
         logging.getLogger(__name__).warning(
             "fused slice dispatch failed (%s: %s); per-record fallback",
             type(e).__name__, e,
@@ -911,6 +914,8 @@ def _tpu_finish_inner(
         # decides per batch (carries were rolled back by the executor)
         for _, h in pending.chunks[finished + 1 :]:
             tpu.discard_dispatch(h)
+        if is_program_fault(e):
+            raise  # compiler-refused program: never a per-record rerun
         logging.getLogger(__name__).warning(
             "fused slice finish failed (%s: %s); per-record fallback",
             type(e).__name__, e,

@@ -191,7 +191,7 @@ def render_prometheus(
     w.header(
         f"{_PREFIX}_link_variants_total",
         "Dispatched batches by H2D link staging form "
-        "(raw / glz-gather / glz-pallas).",
+        "(raw / glz-gather).",
         "counter",
     )
     for variant, n in sorted(link_variants.items()):

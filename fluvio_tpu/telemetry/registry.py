@@ -101,7 +101,7 @@ class PipelineTelemetry:
         self.spills: Dict[str, int] = {}
         self.declines: Dict[str, int] = {}
         # which form each dispatched batch's flat crossed the H2D link
-        # in: "raw" | "glz-gather" | "glz-pallas" (the bench's per-config
+        # in: "raw" | "glz-gather" (the bench's per-config
         # link breakdown and the preflight link-variant prediction both
         # read this family)
         self.link_variants: Dict[str, int] = {}

@@ -1,4 +1,4 @@
-"""Byte-equivalence-class DFA packing + Pallas block-compose fusion.
+"""Byte-equivalence-class DFA packing.
 
 ISSUE-16 differential suite. The packed table (one column per byte
 EQUIVALENCE class instead of 258 raw symbols) must be bit-equal to the
@@ -8,9 +8,9 @@ table equivalence, fuzzed verdict equivalence against Python ``re``
 across narrow / striped / sharded layouts. The raised default state
 gate (64, packed) with its class-ceiling reduction
 (``dfa-classes-overflow``), the ``FLUVIO_DFA_CLASSES=0`` zero-cost
-tripwire (legacy tables byte-for-byte + legacy 16-state gate), and the
-``FLUVIO_DFA_PALLAS`` self-healing ladder (interpret-mode equivalence,
-executor demotion seam, compile-size smoke gate) ride along.
+tripwire (legacy tables byte-for-byte + legacy 16-state gate), the
+compose stage's fault seams (a runtime fault heals, a lowering error
+propagates — ISSUE 22) and its compile-size smoke gate ride along.
 """
 
 from __future__ import annotations
@@ -34,7 +34,7 @@ from fluvio_tpu.ops.regex_dfa import (
 )
 from fluvio_tpu.protocol.record import Record
 from fluvio_tpu.smartengine import SmartEngine, SmartModuleConfig
-from fluvio_tpu.smartengine.tpu import kernels, pallas_kernels
+from fluvio_tpu.smartengine.tpu import kernels
 from fluvio_tpu.smartmodule import SmartModuleInput, dsl
 from fluvio_tpu.smartmodule.sdk import SmartModuleDef
 from fluvio_tpu.smartmodule.types import SmartModuleKind
@@ -55,13 +55,6 @@ OVERFLOW_PATTERN = "abcdefghijklmnopqrstuvwxyz0123456789ABCD[0-9]?"
 def small_stripes(monkeypatch):
     for k, v in STRIPE_ENV.items():
         monkeypatch.setenv(k, v)
-
-
-@pytest.fixture
-def pallas_reset():
-    pallas_kernels._dfa_pallas_reset()
-    yield
-    pallas_kernels._dfa_pallas_reset()
 
 
 def _pack(data):
@@ -263,11 +256,10 @@ class TestStateGate:
 
 class TestZeroCostTripwire:
     def test_flags_off_reproduce_legacy_tables_and_paths(self, monkeypatch):
-        """FLUVIO_DFA_CLASSES=0 + FLUVIO_DFA_PALLAS=0 is byte-for-byte
+        """FLUVIO_DFA_CLASSES=0 is byte-for-byte
         legacy: identity class map, full 258-column table, 16-state
         gate, identical chain verdicts, and NO new ISSUE-16 declines."""
         monkeypatch.setenv("FLUVIO_DFA_CLASSES", "0")
-        monkeypatch.setenv("FLUVIO_DFA_PALLAS", "0")
         monkeypatch.delenv("FLUVIO_DFA_ASSOC_MAX_STATES", raising=False)
         dfa = compile_regex_cached("flu[vV]io")
         assert not dfa.packed
@@ -277,82 +269,65 @@ class TestZeroCostTripwire:
         )
         assert (dfa.eos_class, dfa.pad_class) == (EOS, PAD)
         assert kernels.dfa_assoc_max_states() == 16
-        assert not pallas_kernels.dfa_pallas_active()
-        d0 = (_declines("dfa-classes-overflow"), _declines("dfa-pallas-demoted"))
+        d0 = _declines("dfa-classes-overflow")
         vals = [b"x" * n + (b"fluVio" if n % 3 else b"flub") + b"y" * 10
                 for n in range(60)]
         mods = lambda: [(filter_module("flu[vV]io"), None)]
         assert _run(_build("tpu", mods()), vals) == _run(
             _build("python", mods()), vals
         )
-        assert (
-            _declines("dfa-classes-overflow"),
-            _declines("dfa-pallas-demoted"),
-        ) == d0
+        assert _declines("dfa-classes-overflow") == d0
 
 
-class TestPallasCompose:
-    def test_interpret_mode_bit_equal_narrow(self, monkeypatch, pallas_reset):
-        """FLUVIO_DFA_PALLAS=interpret routes the associative compose
-        through the fused kernel (engaged flag proves it) and stays
-        bit-equal to the XLA scan."""
-        rng = np.random.default_rng(77)
-        data = _boundary_corpus(rng, n=120)
-        values, lengths = _pack(data)
-        for pattern in ("flu[vV]io", "^(fluvio|kafka|pulsar)-[0-3]$"):
-            dfa = compile_regex(pattern)
-            ref = np.asarray(kernels.dfa_match_assoc(values, lengths, dfa))
-            monkeypatch.setenv("FLUVIO_DFA_PALLAS", "interpret")
-            assert pallas_kernels.dfa_pallas_active()
-            got = np.asarray(kernels.dfa_match_assoc(values, lengths, dfa))
-            assert pallas_kernels._dfa_pallas_engaged
-            monkeypatch.delenv("FLUVIO_DFA_PALLAS")
-            assert (got == ref).all(), pattern
+class TestComposeFaults:
+    def test_runtime_fault_heals_exactly(self, small_stripes, monkeypatch):
+        """An injected TRANSIENT dispatch fault on the striped compose
+        chain retries against the same staged batch and completes
+        exactly — device weather heals as before."""
+        from fluvio_tpu.resilience import faults
 
-    def test_interpret_mode_striped_chain(
-        self, small_stripes, monkeypatch, pallas_reset
-    ):
-        monkeypatch.setenv("FLUVIO_DFA_PALLAS", "interpret")
-        vals = [b"x" * pad + b"flu7io" + b"y" * 40 for pad in range(0, 90, 3)]
-        vals += [b"x" * pad + b"flu77io" for pad in range(0, 45, 3)]
-        mods = lambda: [(filter_module(r"flu\d+io"), None)]
-        tpu = _build("tpu", mods())
-        assert tpu.tpu_chain._striped_chain() is not None
-        got = _run(tpu, vals)
-        assert pallas_kernels._dfa_pallas_engaged
-        monkeypatch.delenv("FLUVIO_DFA_PALLAS")
-        assert got == _run(_build("python", mods()), vals)
-
-    def test_executor_demotes_to_xla_on_pallas_failure(
-        self, small_stripes, monkeypatch, pallas_reset
-    ):
-        """Self-healing ladder: a compose kernel that dies at dispatch
-        demotes the process to the XLA associative scan (heal + decline
-        counted) and the batch still completes exactly."""
-        monkeypatch.setenv("FLUVIO_DFA_PALLAS", "1")
-
-        def boom(*a, **k):
-            pallas_kernels._dfa_pallas_engaged = True
-            raise RuntimeError("Mosaic lowering failed (synthetic)")
-
-        monkeypatch.setattr(
-            pallas_kernels, "dfa_compose_columns_pallas", boom
-        )
-        d0 = _declines("dfa-pallas-demoted")
-        h0 = TELEMETRY.snapshot()["counters"]["heals"]
+        monkeypatch.setenv("FLUVIO_RETRY_BASE_MS", "0")
         vals = [b"x" * n + (b"fluVio" if n % 2 else b"kafka") + b"y" * 40
                 for n in range(80)]
         mods = lambda: [(filter_module("flu[vV]io"), None)]
-        got = _run(_build("tpu", mods()), vals)
+        tpu = _build("tpu", mods())
+        r0 = dict(TELEMETRY.snapshot()["counters"]["retries"])
+        faults.FAULTS.inject("dispatch", first=1)
+        try:
+            got = _run(tpu, vals)
+        finally:
+            faults.FAULTS.clear()
         assert got == _run(_build("python", mods()), vals)
-        assert _declines("dfa-pallas-demoted") == d0 + 1
-        assert TELEMETRY.snapshot()["counters"]["heals"] == h0 + 1
-        assert not pallas_kernels.dfa_pallas_active()  # latched off
+        r1 = TELEMETRY.snapshot()["counters"]["retries"]
+        assert r1.get("dispatch", 0) == r0.get("dispatch", 0) + 1
 
-    def test_compose_compile_time_bounded(self, monkeypatch, pallas_reset):
-        """Compile-size smoke gate: the fused compose at the headline
-        shape must jit in bounded time on CPU CI (interpret mode)."""
-        monkeypatch.setenv("FLUVIO_DFA_PALLAS", "interpret")
+    def test_lowering_error_raises_through_process(
+        self, small_stripes, monkeypatch
+    ):
+        """A compose stage the compiler refuses is a program fault: it
+        raises through `process()` under backend="tpu" — no heal, no
+        fused-error spill, no interpreter re-run."""
+
+        def refuse(*a, **k):
+            raise NotImplementedError(
+                "Unimplemented primitive in Pallas TPU lowering: dynamic_slice"
+            )
+
+        monkeypatch.setattr(kernels, "dfa_compose_columns", refuse)
+        c0 = TELEMETRY.snapshot()["counters"]
+        vals = [b"x" * n + b"fluVio" + b"y" * 40 for n in range(80)]
+        tpu = _build("tpu", [(filter_module("flu[vV]io"), None)])
+        with pytest.raises(NotImplementedError):
+            _run(tpu, vals)
+        c1 = TELEMETRY.snapshot()["counters"]
+        assert c1["heals"] == c0["heals"]
+        assert c1["spills"].get("fused-error", 0) == c0["spills"].get(
+            "fused-error", 0
+        )
+
+    def test_compose_compile_time_bounded(self):
+        """Compile-size smoke gate: the associative compose at the
+        headline shape must jit in bounded time on CPU CI."""
         dfa = compile_regex("fluvio[0-9]+")
         cls = jnp.zeros((2048, 512), jnp.int32)
         table_t = jnp.asarray(dfa.table.T.astype(np.int32))
@@ -362,8 +337,7 @@ class TestPallasCompose:
         t0 = time.time()
         fn(cls).block_until_ready()
         elapsed = time.time() - t0
-        assert pallas_kernels._dfa_pallas_engaged
-        assert elapsed < 60.0, f"fused compose compiled in {elapsed:.1f}s"
+        assert elapsed < 60.0, f"compose compiled in {elapsed:.1f}s"
 
 
 class TestJsonGetDfa:

@@ -59,46 +59,47 @@ def _corpora():
     }
 
 
-def _encode(raw, chunk, variant):
-    kwargs = {"interpret": True} if variant == "pallas" else {}
-    f = jax.jit(
-        lambda r: glz.encode_result(r, chunk, variant, **kwargs)
-    )
+def _encode(raw, chunk):
+    f = jax.jit(lambda r: glz.encode_result(r, chunk))
     ll, ml, srcs, lits, n_seq, n_lit, depth = [
         np.asarray(x) for x in f(jnp.asarray(raw))
     ]
     return ll, ml, srcs, lits, int(n_seq), int(n_lit), int(depth)
 
 
-@pytest.mark.parametrize("variant", ["xla", "pallas"])
+_CORPUS_NAMES = (
+    "json", "periodic5", "const", "zeros_tail", "random", "tiny", "vocab",
+)
+
+
+@pytest.mark.parametrize("name", _CORPUS_NAMES)
 @pytest.mark.parametrize("chunk", [4096, 16384])
-def test_encode_roundtrip_differential(variant, chunk):
-    """Device compressor vs host decode vs raw, across corpora: the
-    native reference decoder AND the numpy device-mirror must both
-    reproduce the raw bytes from either rung's tokens."""
-    for name, raw in _corpora().items():
-        ll, ml, srcs, lits, n_seq, n_lit, depth = _encode(raw, chunk, variant)
-        got = glz.decode_result_host(
-            ll, ml, srcs, lits, n_seq, n_lit, len(raw), depth
-        )
-        assert np.array_equal(got, raw), (variant, chunk, name, "host")
-        comp = glz.Compressed(
-            ll[:n_seq], ml[:n_seq], srcs[:n_seq], lits[:n_lit],
-            depth, len(raw),
-        )
-        got2 = glz.decompress_numpy(comp)
-        assert np.array_equal(got2, raw), (variant, chunk, name, "numpy")
+def test_encode_roundtrip_differential(name, chunk):
+    """Device compressor vs host decode vs raw, per corpus: the native
+    reference decoder AND the numpy device-mirror must both reproduce
+    the raw bytes from the device encoder's tokens."""
+    raw = _corpora()[name]
+    ll, ml, srcs, lits, n_seq, n_lit, depth = _encode(raw, chunk)
+    got = glz.decode_result_host(
+        ll, ml, srcs, lits, n_seq, n_lit, len(raw), depth
+    )
+    assert np.array_equal(got, raw), (chunk, name, "host")
+    comp = glz.Compressed(
+        ll[:n_seq], ml[:n_seq], srcs[:n_seq], lits[:n_lit],
+        depth, len(raw),
+    )
+    got2 = glz.decompress_numpy(comp)
+    assert np.array_equal(got2, raw), (chunk, name, "numpy")
 
 
-@pytest.mark.parametrize("variant", ["xla", "pallas"])
-def test_encode_wire_legality(variant):
+def test_encode_wire_legality():
     """Stream invariants the decoders rely on: sequence lengths fit the
     u8 fields, every match's source region lies strictly before its own
     output AND inside its own chunk, and the reported depth bounds the
     real chain depth (<= MAX_DEPTH)."""
     chunk = 4096
     for name, raw in _corpora().items():
-        ll, ml, srcs, lits, n_seq, n_lit, depth = _encode(raw, chunk, variant)
+        ll, ml, srcs, lits, n_seq, n_lit, depth = _encode(raw, chunk)
         assert depth <= glz.MAX_DEPTH
         ll, ml, srcs = ll[:n_seq], ml[:n_seq], srcs[:n_seq]
         assert int(ll.astype(np.int64).sum()) == n_lit, name
@@ -118,9 +119,7 @@ def test_encode_compile_size_smoke_gate():
     compile-size smoke the decode ladder pins, mirrored."""
     raw = _pad8(b'{"name":"fluvio-1","n":1}' * 40000)  # ~1 MB headline flat
     t0 = time.time()
-    ll, ml, srcs, lits, n_seq, n_lit, depth = _encode(
-        raw, glz.GLZ_CHUNK, "xla"
-    )
+    ll, ml, srcs, lits, n_seq, n_lit, depth = _encode(raw, glz.GLZ_CHUNK)
     elapsed = time.time() - t0
     assert elapsed < 60, f"encode jit took {elapsed:.1f}s"
     got = glz.decode_result_host(
@@ -262,24 +261,36 @@ def test_result_compact_off_parity(monkeypatch):
 # -- demotion ladder ----------------------------------------------------------
 
 
-def test_dispatch_seam_demotes_to_xla_then_off(enc_on, monkeypatch):
-    """Sync (trace-time) encode failures walk pallas -> xla -> off; the
+def test_dispatch_seam_runtime_fault_latches_off(enc_on, monkeypatch):
+    """A sync RUNTIME failure of the encoder latches encode off; the
     same staged arrays re-dispatch and outputs stay exact."""
-    monkeypatch.setenv("FLUVIO_GLZ_ENC_PALLAS", "interpret")
-    from fluvio_tpu.smartengine.tpu import pallas_kernels
-
     calls = {"n": 0}
 
     def bomb(*a, **k):
         calls["n"] += 1
-        raise RuntimeError("simulated pallas encode lowering failure")
+        raise RuntimeError("simulated device encode runtime failure")
 
-    monkeypatch.setattr(pallas_kernels, "glz_encode_match", bomb)
+    monkeypatch.setattr(glz, "enc_match_xla", bomb)
     heals0 = TELEMETRY.heals
     tc, tv = _run_both(SPAN_MODS, _span_corpus(1000))
     assert calls["n"] >= 1
-    assert tc.tpu_chain._enc_variant == "xla", "one rung down, encode stays on"
+    assert tc.tpu_chain._enc_variant == "off"
     assert TELEMETRY.heals > heals0
+
+
+def test_dispatch_seam_lowering_error_propagates(enc_on, monkeypatch):
+    """A LOWERING error of the encoder is a program fault (ISSUE 22):
+    it raises through `process()` under backend="tpu" — no heal, no
+    quieter rung, no interpreter re-run."""
+
+    def refuse(*a, **k):
+        raise NotImplementedError("Unimplemented primitive in lowering")
+
+    monkeypatch.setattr(glz, "enc_match_xla", refuse)
+    heals0 = TELEMETRY.heals
+    with pytest.raises(NotImplementedError):
+        _run_both(SPAN_MODS, _span_corpus(1000))
+    assert TELEMETRY.heals == heals0
 
 
 def test_dispatch_seam_injected_fault_demotes(enc_on, monkeypatch):

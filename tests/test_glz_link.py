@@ -1,15 +1,12 @@
-"""Pallas glz decode + compressed-staging ladder (ISSUE-8).
+"""glz link compression: device decode + compressed staging (ISSUE-8).
 
-Differential contract: FOUR decoders must agree byte-for-byte on every
+Differential contract: THREE decoders must agree byte-for-byte on every
 corpus — the native sequential oracle (glz.cpp), the numpy mirror of
-the gather rounds, the traced gather-round device decode, and the
-Pallas per-chunk VMEM resolver — including chunked streams, padded
-token arrays, striped wide records, sharded staging, and the
-heal/retry interleavings that demote the decode ladder mid-stream.
-
-The Pallas kernel runs interpreted on the CPU test backend
-(``FLUVIO_GLZ_PALLAS=interpret``), exactly like the json_get kernel
-equivalence suite.
+the gather rounds, and the traced gather-round device decode —
+including chunked streams, padded token arrays, striped wide records,
+sharded staging, and the heal/retry interleavings that latch
+compression off mid-stream. Runtime faults heal; lowering/compile
+errors are program faults and propagate (ISSUE 22).
 """
 
 import os
@@ -19,7 +16,6 @@ import numpy as np
 import pytest
 
 from fluvio_tpu.smartengine.tpu import glz
-from fluvio_tpu.smartengine.tpu import pallas_kernels as pk
 
 pytestmark = pytest.mark.skipif(
     not glz.available(), reason="native glz library unavailable"
@@ -64,8 +60,8 @@ CORPORA = {
 }
 
 
-def _pallas_decode(comp, chunk=None, seq_extra=0, lit_extra=0):
-    """Decode via the Pallas ladder rung, optionally with zero-padded
+def _gather_decode(comp, seq_extra=0, lit_extra=0):
+    """Decode via the traced gather rounds, optionally with zero-padded
     token arrays (the executor's bucketed staging form)."""
     import jax.numpy as jnp
 
@@ -79,33 +75,16 @@ def _pallas_decode(comp, chunk=None, seq_extra=0, lit_extra=0):
     lits = np.zeros(comp.lits.size + lit_extra, np.uint8)
     lits[: comp.lits.size] = comp.lits
     return np.asarray(
-        glz.decode_link_flat(
-            (jnp.asarray(ll), jnp.asarray(ml), jnp.asarray(srcs)),
-            jnp.asarray(lits),
-            jnp.int32(comp.depth),
-            comp.out_len,
-            "pallas",
-            chunk or comp.chunk_bytes,
-            interpret=True,
-        )
-    )
-
-
-def _gather_decode(comp):
-    import jax.numpy as jnp
-
-    return np.asarray(
         glz.decompress_device(
-            jnp.asarray(comp.lit_lens), jnp.asarray(comp.match_lens),
-            jnp.asarray(comp.srcs), jnp.asarray(comp.lits),
-            jnp.int32(comp.depth), comp.out_len,
+            jnp.asarray(ll), jnp.asarray(ml), jnp.asarray(srcs),
+            jnp.asarray(lits), jnp.int32(comp.depth), comp.out_len,
         )
     )
 
 
 @pytest.mark.parametrize("name", sorted(CORPORA))
 @pytest.mark.parametrize("chunk", [16 * 1024, 64 * 1024])
-def test_four_decoder_differential(name, chunk):
+def test_three_decoder_differential(name, chunk):
     raw = CORPORA[name]()
     comp, reason = glz.compress_link(raw, max_ratio=1.0, chunk=chunk)
     assert comp is not None, f"{name}: {reason}"
@@ -114,16 +93,15 @@ def test_four_decoder_differential(name, chunk):
     assert np.array_equal(glz.decompress_host(comp), raw), "host oracle"
     assert np.array_equal(glz.decompress_numpy(comp), raw), "numpy mirror"
     assert np.array_equal(_gather_decode(comp), raw), "gather rounds"
-    assert np.array_equal(_pallas_decode(comp), raw), "pallas chunks"
     # the executor's padded-token staging form must decode identically
     assert np.array_equal(
-        _pallas_decode(comp, seq_extra=37, lit_extra=11), raw
-    ), "pallas w/ padded tokens"
+        _gather_decode(comp, seq_extra=37, lit_extra=11), raw
+    ), "gather w/ padded tokens"
 
 
 def test_chunk_locality_invariant():
-    """Every match source stays inside its own chunk — the invariant
-    the Pallas per-chunk resolve is built on."""
+    """Every match source stays inside its own chunk — the wire
+    format's chunk-locality invariant."""
     raw = CORPORA["json"]()
     comp, _ = glz.compress_link(raw, max_ratio=1.0, chunk=16 * 1024)
     cs = comp.chunk_seqs
@@ -139,13 +117,10 @@ def test_chunk_locality_invariant():
 
 def test_deep_match_chains_at_max_depth():
     """A corpus whose greedy parse chains matches to the depth cap —
-    the pathological case the pointer-squaring rounds must still cover
-    (GLZ_SQUARE_ROUNDS flattens chains up to 2**3 = 8 >= MAX_DEPTH)."""
+    the pathological case the gather rounds must still cover."""
     raw = _json_corpus(9000)
     comp, _ = glz.compress_link(raw, max_ratio=1.0, chunk=64 * 1024)
     assert comp.depth == glz.MAX_DEPTH, comp.depth
-    assert (1 << pk.GLZ_SQUARE_ROUNDS) >= glz.MAX_DEPTH
-    assert np.array_equal(_pallas_decode(comp), raw)
     assert np.array_equal(_gather_decode(comp), raw)
 
 
@@ -163,8 +138,7 @@ def test_compress_link_decline_reasons():
 
 def test_merged_stream_valid_for_legacy_decoders():
     """A chunked stream is a plain glz stream (absolute sources): the
-    whole-buffer decoders need no sidecar, so the gather/host ladder
-    rungs work on the exact arrays the pallas rung ships."""
+    whole-buffer decoders need no sidecar."""
     raw = CORPORA["period28"]()
     comp, _ = glz.compress_link(raw, max_ratio=1.0, chunk=16 * 1024)
     legacy = glz.Compressed(
@@ -177,7 +151,7 @@ def test_merged_stream_valid_for_legacy_decoders():
 
 
 # ---------------------------------------------------------------------------
-# Executor-level: compressed staging through the pallas rung
+# Executor-level: compressed staging
 # ---------------------------------------------------------------------------
 
 
@@ -221,9 +195,8 @@ def _json_vals(n=6000, seed=7):
 
 
 @pytest.fixture
-def glz_pallas_env(monkeypatch):
+def glz_env(monkeypatch):
     monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
-    monkeypatch.setenv("FLUVIO_GLZ_PALLAS", "interpret")
 
 
 @pytest.mark.parametrize(
@@ -235,7 +208,7 @@ def glz_pallas_env(monkeypatch):
     ],
     ids=["filter_map", "aggregate", "array_map"],
 )
-def test_executor_pallas_staging_parity(glz_pallas_env, specs):
+def test_executor_compressed_staging_parity(glz_env, specs):
     from fluvio_tpu.telemetry import TELEMETRY
 
     if specs[0][0] == "array-map-json":
@@ -248,17 +221,16 @@ def test_executor_pallas_staging_parity(glz_pallas_env, specs):
     chain = _build("tpu", specs)
     got = _run_chain(chain, vals)
     ex = chain.tpu_chain
-    assert ex._glz_variant == "pallas"
     assert ex._link_compress
     lv = TELEMETRY.link_variant_counts()
-    assert lv.get("glz-pallas", 0) > lv0.get("glz-pallas", 0), (
-        "pallas variant should have shipped this batch"
+    assert lv.get("glz-gather", 0) > lv0.get("glz-gather", 0), (
+        "the compressed form should have shipped this batch"
     )
     ref = _run_chain(_build("python", specs), vals)
     assert got == ref
 
 
-def test_striped_wide_records_ship_compressed(glz_pallas_env, monkeypatch):
+def test_striped_wide_records_ship_compressed(glz_env, monkeypatch):
     """The wide-record (striped) layout crosses the link compressed and
     re-stripes entirely on device — the wide300/fat70k class."""
     monkeypatch.setenv("FLUVIO_STRIPE_THRESHOLD", "16384")
@@ -280,9 +252,9 @@ def test_striped_wide_records_ship_compressed(glz_pallas_env, monkeypatch):
     assert got == ref
 
 
-def test_sharded_staging_ships_compressed(glz_pallas_env):
+def test_sharded_staging_ships_compressed(glz_env):
     """Sharded dispatch: per-shard glz streams decode inside the shard
-    body (pallas per shard under shard_map)."""
+    body."""
     from fluvio_tpu.telemetry import TELEMETRY
 
     vals = _json_vals(8000)
@@ -294,12 +266,12 @@ def test_sharded_staging_ships_compressed(glz_pallas_env):
     raw_bytes = sum(len(v) for v in vals)
     assert ex.h2d_bytes_total < raw_bytes, "sharded upload should undercut raw"
     lv = TELEMETRY.link_variant_counts()
-    assert lv.get("glz-pallas", 0) > lv0.get("glz-pallas", 0)
+    assert lv.get("glz-gather", 0) > lv0.get("glz-gather", 0)
     ref = _run_chain(_build("python", specs), vals)
     assert got == ref
 
 
-def test_sharded_aggregate_carries_exact_across_stream(glz_pallas_env):
+def test_sharded_aggregate_carries_exact_across_stream(glz_env):
     vals_a = [f"{(i * 3) & 63:06d}".encode() for i in range(6000)]
     vals_b = [f"{(i * 5) & 63:06d}".encode() for i in range(6000)]
     specs = [("aggregate-sum", None)]
@@ -312,7 +284,7 @@ def test_sharded_aggregate_carries_exact_across_stream(glz_pallas_env):
     assert got_a == ref_a and got_b == ref_b
 
 
-def test_sharded_striped_declines_wide(glz_pallas_env, monkeypatch):
+def test_sharded_striped_declines_wide(glz_env, monkeypatch):
     """The one wide-path exclusion left: sharded STRIPED batches ship
     raw, with the per-batch `glz-wide-unsupported` decline counted."""
     from fluvio_tpu.telemetry import TELEMETRY
@@ -338,7 +310,7 @@ def test_sharded_striped_declines_wide(glz_pallas_env, monkeypatch):
     assert got == ref
 
 
-def test_decline_reason_counted_per_batch(glz_pallas_env):
+def test_decline_reason_counted_per_batch(glz_env):
     """An incompressible corpus ships raw with `glz-ratio` on the
     decline counter — once per dispatched batch, from the cached
     compression verdict."""
@@ -373,59 +345,102 @@ def test_decline_reason_counted_per_batch(glz_pallas_env):
 
 
 # ---------------------------------------------------------------------------
-# Heal ladder: pallas -> gather -> raw
+# Heal: a RUNTIME failure of a compressed batch latches raw staging; a
+# LOWERING/compile error is a program fault and propagates (ISSUE 22)
 # ---------------------------------------------------------------------------
 
 
-def test_dispatch_heal_demotes_pallas_to_gather(glz_pallas_env, monkeypatch):
-    def boom(*a, **k):
-        raise RuntimeError("mosaic rejected the chunk gather")
+def _heals():
+    from fluvio_tpu.telemetry import TELEMETRY
 
-    monkeypatch.setattr(pk, "glz_decode_pallas", boom)
+    return TELEMETRY.snapshot()["counters"]["heals"]
+
+
+def test_dispatch_runtime_fault_latches_raw(glz_env):
+    """The injected decode seam (a runtime-class fault, deterministic so
+    no retry masks it): the SAME batch re-stages raw, compression
+    latches off for this executor, outputs stay exact."""
+    from fluvio_tpu.resilience import faults
+
     vals = _json_vals()
     specs = [("regex-filter", {"regex": "fluvio"})]
     chain = _build("tpu", specs)
-    got = _run_chain(chain, vals)
+    h0 = _heals()
+    faults.FAULTS.inject("glz_decode", first=1, exc="deterministic")
+    try:
+        got = _run_chain(chain, vals)
+    finally:
+        faults.FAULTS.clear()
     ex = chain.tpu_chain
-    assert ex._glz_variant == "gather", "ladder should demote one rung"
-    assert ex._link_compress, "compression must STAY ON after demotion"
+    assert not ex._link_compress, "a decode runtime fault latches raw"
+    assert _heals() == h0 + 1
     ref = _run_chain(_build("python", specs), vals)
     assert got == ref
 
 
-def test_dispatch_heal_full_ladder_to_raw(glz_pallas_env, monkeypatch):
+def test_dispatch_runtime_error_latches_raw(glz_env, monkeypatch):
     def boom(*a, **k):
-        raise RuntimeError("no decode at all")
+        raise RuntimeError("device decode failed at run time")
 
-    monkeypatch.setattr(pk, "glz_decode_pallas", boom)
     monkeypatch.setattr(glz, "decompress_device", boom)
     vals = _json_vals()
     specs = [("regex-filter", {"regex": "fluvio"})]
     chain = _build("tpu", specs)
     got = _run_chain(chain, vals)
     ex = chain.tpu_chain
-    assert not ex._link_compress, "bottom of the ladder latches raw"
+    assert not ex._link_compress, "a runtime failure latches raw"
     ref = _run_chain(_build("python", specs), vals)
     assert got == ref
 
 
-def test_sharded_dispatch_heal_demotes(glz_pallas_env, monkeypatch):
-    def boom(*a, **k):
-        raise RuntimeError("mosaic rejected the chunk gather under shard_map")
+@pytest.mark.parametrize("mesh", [None, 4], ids=["single", "sharded"])
+@pytest.mark.parametrize(
+    "exc",
+    [
+        NotImplementedError("Only 2D gather is supported"),
+        TypeError("lowering rejected the operand type"),
+    ],
+    ids=["notimplemented", "typeerror"],
+)
+def test_lowering_error_propagates(glz_env, monkeypatch, mesh, exc):
+    """What only lowering raises is a program fault: under
+    backend="tpu" it raises through `process()` with NO heal counted,
+    no rung demoted and no interpreter re-run."""
+    from fluvio_tpu.telemetry import TELEMETRY
 
-    monkeypatch.setattr(pk, "glz_decode_pallas", boom)
+    def refuse(*a, **k):
+        raise exc
+
+    monkeypatch.setattr(glz, "decompress_device", refuse)
+    vals = _json_vals(8000)
+    specs = [("regex-filter", {"regex": "fluvio"})]
+    chain = _build("tpu", specs, mesh=mesh)
+    h0 = _heals()
+    sp0 = dict(TELEMETRY.snapshot()["counters"]["spills"])
+    with pytest.raises(type(exc)):
+        _run_chain(chain, vals)
+    ex = chain.tpu_chain
+    assert ex._link_compress, "a program fault must not demote anything"
+    assert _heals() == h0
+    assert TELEMETRY.snapshot()["counters"]["spills"] == sp0
+
+
+def test_sharded_dispatch_runtime_error_latches_raw(glz_env, monkeypatch):
+    def boom(*a, **k):
+        raise RuntimeError("device decode failed under shard_map")
+
+    monkeypatch.setattr(glz, "decompress_device", boom)
     vals = _json_vals(8000)
     specs = [("regex-filter", {"regex": "fluvio"})]
     chain = _build("tpu", specs, mesh=4)
     got = _run_chain(chain, vals)
     ex = chain.tpu_chain
-    assert ex._glz_variant == "gather"
-    assert ex._link_compress
+    assert not ex._link_compress
     ref = _run_chain(_build("python", specs), vals)
     assert got == ref
 
 
-def test_sharded_transient_fetch_fault_keeps_compression(glz_pallas_env):
+def test_sharded_transient_fetch_fault_keeps_compression(glz_env):
     """A TRANSIENT finish-side fault on a compressed sharded batch must
     ride the bounded retry with the ladder untouched: the retry re-ships
     the same compressed form (from the per-buffer cache), and a
@@ -443,7 +458,6 @@ def test_sharded_transient_fetch_fault_keeps_compression(glz_pallas_env):
     finally:
         faults.FAULTS.clear()
     ex = chain.tpu_chain
-    assert ex._glz_variant == "pallas", "transient fault must not demote"
     assert ex._link_compress, "transient fault must not latch glz off"
     lv = {
         k: v - lv0.get(k, 0)
@@ -452,14 +466,14 @@ def test_sharded_transient_fetch_fault_keeps_compression(glz_pallas_env):
     }
     # the H2D family only: the down-* keys are the result side's own
     # variant family (PR-12) and move independently
-    assert {k for k in lv if not k.startswith("down-")} == {"glz-pallas"}, lv
+    assert {k for k in lv if not k.startswith("down-")} == {"glz-gather"}, lv
     assert got == _run_chain(_build("python", specs), vals)
 
 
-def test_sharded_deterministic_finish_failure_demotes(glz_pallas_env):
-    """A DETERMINISTIC finish-side failure of a compressed sharded batch
-    walks the decode ladder: demote pallas -> gather and re-dispatch the
-    same batch down-ladder (compression stays on)."""
+def test_sharded_deterministic_finish_failure_latches_raw(glz_env):
+    """A DETERMINISTIC finish-side runtime failure of a compressed
+    sharded batch makes the decode the prime suspect: compression
+    latches off and the same batch re-dispatches raw."""
     from fluvio_tpu.resilience import faults
 
     faults.FAULTS.inject("device", first=1, exc="deterministic")
@@ -471,8 +485,7 @@ def test_sharded_deterministic_finish_failure_demotes(glz_pallas_env):
     finally:
         faults.FAULTS.clear()
     ex = chain.tpu_chain
-    assert ex._glz_variant == "gather"
-    assert ex._link_compress
+    assert not ex._link_compress
     assert got == _run_chain(_build("python", specs), vals)
 
 
@@ -496,14 +509,14 @@ def _int_bufs(n_bufs, n=6000):
     return bufs, val_lists
 
 
-def test_fetch_heal_demotes_and_preserves_carry_lineage(
-    glz_pallas_env, monkeypatch
+def test_fetch_heal_latches_raw_and_preserves_carry_lineage(
+    glz_env, monkeypatch
 ):
-    """The async heal under the PALLAS variant: batch k's decode failure
-    surfaces at fetch while k+1 (already dispatched compressed, carries
-    chained) is in flight. The heal must demote to gather — compression
-    stays on — and the carry-lineage epoch machinery must still
-    re-dispatch k+1 from the healed tip, bit-exact vs the interpreter."""
+    """The async heal: batch k's decode runtime failure surfaces at
+    fetch while k+1 (already dispatched compressed, carries chained) is
+    in flight. The heal latches compression off and the carry-lineage
+    epoch machinery must still re-dispatch k+1 from the healed tip,
+    bit-exact vs the interpreter."""
     from fluvio_tpu.smartengine.tpu.executor import TpuChainExecutor
 
     real_fetch = TpuChainExecutor._fetch
@@ -512,8 +525,7 @@ def test_fetch_heal_demotes_and_preserves_carry_lineage(
     def fetch_bomb(self, buf, header, packed, spec=None, defer=False):
         if spec and spec.get("glz_used") and not state["bombed"]:
             state["bombed"] = True
-            assert spec.get("glz_variant") == "pallas"
-            raise RuntimeError("simulated pallas decode runtime failure")
+            raise RuntimeError("simulated decode runtime failure")
         return real_fetch(self, buf, header, packed, spec, defer)
 
     monkeypatch.setattr(TpuChainExecutor, "_fetch", fetch_bomb)
@@ -522,8 +534,7 @@ def test_fetch_heal_demotes_and_preserves_carry_lineage(
     bufs, val_lists = _int_bufs(2)
     outs = list(ex.process_stream(iter(bufs)))
     assert state["bombed"]
-    assert ex._glz_variant == "gather", "fetch heal demotes the variant"
-    assert ex._link_compress, "compression stays on after demotion"
+    assert not ex._link_compress, "fetch heal latches compression off"
     assert len(outs) == 2
 
     py = _build("python", [("aggregate-sum", None)])
@@ -547,11 +558,11 @@ def test_fetch_heal_demotes_and_preserves_carry_lineage(
 # ---------------------------------------------------------------------------
 
 
-def test_pallas_decode_compile_size_gate():
-    """Interpret-mode jit of the pallas decode at a bench-shaped bucket
-    must stay well-bounded (the PR-4 DFA gate's methodology): a
-    pathological lowering would blow up trace/compile time long before
-    it blew up the chip."""
+def test_gather_decode_compile_size_gate():
+    """jit of the gather-round decode at a bench-shaped bucket must stay
+    well-bounded (the PR-4 DFA gate's methodology): a pathological
+    lowering would blow up trace/compile time long before it blew up
+    the chip."""
     import jax
     import jax.numpy as jnp
 
@@ -561,9 +572,8 @@ def test_pallas_decode_compile_size_gate():
     lits = np.zeros(1 << 19, np.uint8)
 
     fn = jax.jit(
-        lambda a, b, c, d: glz.decode_link_flat(
-            (a, b, c), d, jnp.int32(glz.MAX_DEPTH), out_len,
-            "pallas", glz.GLZ_CHUNK, interpret=True,
+        lambda a, b, c, d: glz.decompress_device(
+            a, b, c, d, jnp.int32(glz.MAX_DEPTH), out_len
         )
     )
     t0 = time.perf_counter()
@@ -572,13 +582,13 @@ def test_pallas_decode_compile_size_gate():
         jnp.asarray(lits),
     ).block_until_ready()
     wall = time.perf_counter() - t0
-    assert wall < 60.0, f"pallas glz decode compile took {wall:.1f}s"
+    assert wall < 60.0, f"glz gather decode compile took {wall:.1f}s"
 
 
 def test_variant_chooser_zero_cost_when_disabled(monkeypatch):
     """With link compression off, the staging-variant chooser must cost
-    NOTHING per dispatch: no compressor calls, no pallas-gate reads, no
-    glz module work at all (the overhead-gate companion to the perf
+    NOTHING per dispatch: no compressor calls, no glz module work at
+    all (the overhead-gate companion to the perf
     arms in test_telemetry_overhead.py)."""
     monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)  # auto->off on CPU
 
@@ -587,9 +597,7 @@ def test_variant_chooser_zero_cost_when_disabled(monkeypatch):
 
     monkeypatch.setattr(glz, "compress_link", tripwire)
     monkeypatch.setattr(glz, "compress", tripwire)
-    monkeypatch.setattr(glz, "decode_link_flat", tripwire)
-    monkeypatch.setattr(pk, "glz_pallas_active", tripwire)
-    monkeypatch.setattr(pk, "glz_decode_pallas", tripwire)
+    monkeypatch.setattr(glz, "decompress_device", tripwire)
     vals = _json_vals(2000)
     specs = [("regex-filter", {"regex": "fluvio"})]
     chain = _build("tpu", specs)
@@ -606,19 +614,21 @@ def test_variant_chooser_zero_cost_when_disabled(monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "mode,expected",
-    [("interpret", "glz-pallas"), ("0", "glz-gather")],
+    "specs",
+    [
+        [("regex-filter", {"regex": "fluvio"})],
+        [("regex-filter", {"regex": "fluvio"}), ("json-map", {"field": "name"})],
+    ],
+    ids=["filter", "filter_map"],
 )
-def test_preflight_link_variant_matches_telemetry(monkeypatch, mode, expected):
+def test_preflight_link_variant_matches_telemetry(monkeypatch, specs):
     from fluvio_tpu.analysis import preflight_for_specs
     from fluvio_tpu.telemetry import TELEMETRY
 
     monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
-    monkeypatch.setenv("FLUVIO_GLZ_PALLAS", mode)
     vals = _json_vals(4000)
-    specs = [("regex-filter", {"regex": "fluvio"})]
     pred = preflight_for_specs(specs, max(len(v) for v in vals))
-    assert pred["link_variant"] == expected
+    assert pred["link_variant"] == "glz-gather"
     lv0 = TELEMETRY.link_variant_counts()
     chain = _build("tpu", specs)
     _run_chain(chain, vals)

