@@ -1,0 +1,477 @@
+"""The benchmark's own tests; none needs the chip.
+
+The loops are rehearsed on the CPU at a tiny size through a TEST-SIDE
+switch (`spubench.device.REQUIRED_PLATFORM` is monkeypatched); the command
+itself still refuses to run without a TPU (`test_command_refuses_without_tpu`).
+A rate or a time read here is never a device number: the tests assert
+counts, correctness and the shape of the result line only.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+REPO = Path(__file__).resolve().parents[2]
+BENCH = REPO / "benchmark"
+for _p in (str(REPO), str(BENCH)):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import run as bench_run  # noqa: E402
+from spubench import check, device, manifest, shapes, trace_reduce  # noqa: E402
+from spubench.ragged import to_values  # noqa: E402
+
+MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def _line(s) -> bool:
+    return isinstance(s, str) and 1 <= len(s) <= 200 and not re.search(r"[\t\n]", s)
+
+
+# -- the manifest ------------------------------------------------------------
+
+
+def test_manifest_keys_and_limits():
+    m = MANIFEST
+    assert set(m) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    assert 1 <= len(m["command"]) <= 32 and all(_line(c) for c in m["command"])
+    assert 1 <= len(m["paths"]) <= 16 and all(PATH.match(p) for p in m["paths"])
+    assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
+    assert (REPO / "BENCHMARK.json").stat().st_size <= 64 * 1024
+    # the full check with 24 cells must fit the driver's 43200 s
+    runs = 2 + 14 * 24
+    assert runs * (m["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+    assert 1 <= len(m["configs"]) <= 24 and 1 <= len(m["workloads"]) <= 24
+    assert 1 <= len(m["end_to_end"]) <= 16 and 1 <= len(m["per_layer"]) <= 128
+
+
+def test_manifest_configs():
+    names = [c["name"] for c in MANIFEST["configs"]]
+    assert len(set(names)) == len(names)
+    files = [c["file"] for c in MANIFEST["configs"]]
+    assert len(set(files)) == len(files)
+    used = {w["config"] for w in MANIFEST["workloads"]}
+    for c in MANIFEST["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and c["name"] in used
+        assert _line(c["source"]) and _line(c["why"])
+        assert any(c["file"].startswith(p + "/") for p in MANIFEST["paths"])
+        assert len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"])
+        body = json.loads((REPO / c["file"]).read_text())
+        assert body["name"] == c["name"] and body["source"] == c["source"]
+        assert body["reduced"] == c["reduced"]
+        assert body["guarantees"] and body["assumed"]
+
+
+def test_manifest_workloads():
+    pairs = [(w["config"], w["traffic"]) for w in MANIFEST["workloads"]]
+    assert len(set(pairs)) == len(pairs) and len(set(CELLS)) == len(CELLS)
+    configs = {c["name"] for c in MANIFEST["configs"]}
+    for w in MANIFEST["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["config"] in configs and w["chips"] in (1, 4) and _line(w["why"])
+    four = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
+    assert four <= max(1, len(CELLS) // 2)
+
+
+def test_manifest_metrics():
+    e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
+    names = [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+    assert len(set(names)) == len(names)
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+
+    def cells_of(m):
+        return set(m.get("workloads", CELLS))
+
+    for m in MANIFEST["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in MANIFEST["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert m["source"] in SOURCES and _line(m["layer"])
+        # the metric it should move is reported in every cell where it is
+        assert m["moves"] in e2e and cells_of(m) <= cells_of(e2e[m["moves"]])
+    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+        assert cells_of(m) <= set(CELLS) and cells_of(m)
+    for cell in CELLS:
+        assert any(cell in cells_of(m) and m["name"] != "setup_s"
+                   for m in MANIFEST["end_to_end"])
+        assert any(cell in cells_of(m) for m in MANIFEST["per_layer"])
+
+
+def test_files_under_paths_are_legally_named():
+    out = subprocess.run(
+        ["git", "ls-files", "--cached", "--others", "--exclude-standard", "--"]
+        + MANIFEST["paths"], cwd=REPO, capture_output=True, text=True,
+    )
+    if out.returncode != 0:   # not a git checkout: walk the directories
+        files = [str(p.relative_to(REPO)) for d in MANIFEST["paths"]
+                 for p in (REPO / d).rglob("*")
+                 if p.is_file() and "__pycache__" not in p.parts]
+    else:
+        files = out.stdout.split()
+    assert files
+    for f in files:
+        assert PATH.match(f), f
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_file_resolves_by_name(cell):
+    c = manifest.load_cell(cell)
+    assert manifest.load_plugin(c.bench_dir, "modes", c.traffic["mode"]).run
+    assert manifest.load_plugin(
+        c.bench_dir, "corpora", c.config["corpus"]["generator"]).generate
+    ref = manifest.load_plugin(
+        c.bench_dir, "references", c.config["reference"]["name"])
+    assert ref.expect and ref.OFFSETS in ("exact", "nondecreasing")
+    assert bench_run.ROOT == REPO
+    for kind, entries in (("end_to_end", c.end_to_end),
+                          ("layer_metrics", c.per_layer)):
+        assert entries
+        for m in entries:
+            assert callable(manifest.load_plugin(c.bench_dir, kind, m["name"]).read)
+
+
+def test_benchmark_imports_neither_script():
+    for p in list(BENCH.rglob("*.py")):
+        text = p.read_text()
+        assert not re.search(r"^\s*(import|from)\s+(bench|chip_smoke)\b", text, re.M), p
+
+
+# -- corpora and references --------------------------------------------------
+
+
+def test_corpora_equal_their_per_record_form():
+    gj = manifest.load_plugin(BENCH, "corpora", "gen_json")
+    ga = manifest.load_plugin(BENCH, "corpora", "gen_arrays")
+    n, seed = 3000, [2**31 + 7, 0]
+    rng = np.random.default_rng(seed)
+    picks = rng.integers(0, len(gj.NAMES), size=n)
+    nums = rng.integers(0, 100000, size=n)
+    want = [f'{{"name":"{gj.NAMES[picks[i]]}-{i & 1023}","n":{nums[i]}}}'.encode()
+            for i in range(n)]
+    assert to_values(*gj.generate(n, seed)) == want
+    rng = np.random.default_rng(seed)
+    a = rng.integers(0, 10000, size=(n, 3))
+    want = [f'["a{i & 255}","b{a[i][0]}",{a[i][1]},{a[i][2]},"x","y"]'.encode()
+            for i in range(n)]
+    assert to_values(*ga.generate(n, seed)) == want
+    # the same seed gives the same inputs; another seed gives others
+    assert to_values(*gj.generate(50, 5)) == to_values(*gj.generate(50, 5))
+    assert to_values(*gj.generate(50, 5)) != to_values(*gj.generate(50, 6))
+
+
+def _python_backend(specs, values):
+    from fluvio_tpu.models import lookup
+    from fluvio_tpu.protocol.record import Record
+    from fluvio_tpu.smartengine import SmartEngine, SmartModuleConfig
+    from fluvio_tpu.smartmodule import SmartModuleInput
+
+    b = SmartEngine(backend="python").builder()
+    for name, params in specs:
+        b.add_smart_module(SmartModuleConfig(params=params or {}), lookup(name))
+    chain = b.initialize()
+    records = [Record(value=v) for v in values]
+    for i, r in enumerate(records):
+        r.offset_delta = i
+    out = chain.process(SmartModuleInput.from_records(records, 0, 1_000_000))
+    assert out.error is None, out.error
+    return [(r.offset_delta, r.value) for r in out.successes]
+
+
+@pytest.mark.parametrize("config,specs", [
+    ("fluvio-northstar-1p",
+     [("regex-filter", {"regex": "fluvio"}), ("json-map", {"field": "name"})]),
+    ("fluvio-array-explode-1p", [("array-map-json", None)]),
+])
+def test_reference_agrees_with_python_backend(config, specs):
+    cfg = json.loads((BENCH / "configs" / f"{config}.json").read_text())
+    gen = manifest.load_plugin(BENCH, "corpora", cfg["corpus"]["generator"])
+    refmod = manifest.load_plugin(BENCH, "references", cfg["reference"]["name"])
+    values = to_values(*gen.generate(3000, 11))
+    ref = check.Reference(refmod, values, 0, cfg["reference"]["params"])
+    got = _python_backend(specs, values)
+    assert len(got) == len(ref.lens) == ref.count(0, len(values))
+    assert [len(v) for _, v in got] == ref.lens.tolist()
+    assert b"".join(v for _, v in got) == ref.flat.tobytes()
+    if ref.offsets_rule == "exact":
+        assert [o for o, _ in got] == ref.src.tolist()
+
+
+# -- the trace reduction -----------------------------------------------------
+
+
+def test_trace_reduction_on_recorded_trace():
+    import jax
+
+    data = jax.profiler.ProfileData.from_text_proto(
+        (BENCH / "testdata" / "trace_small.textproto").read_text()
+    )
+    # host clock: the open marker was written at perf_counter 100.0 s
+    spans = [("device", 100.0040, 100.0070), ("fetch", 100.0081, 100.0094),
+             ("stage", 100.0001, 100.0004)]
+    r = trace_reduce.reduce_profile(data, (100.0, 100.010), spans)
+    assert r["devices"] == 1
+    assert r["window_s"] == pytest.approx(0.010)
+    assert r["busy_s"] == pytest.approx(0.0045)
+    ops = dict(r["device_ops"])
+    assert ops["fusion.1"] == pytest.approx(0.003)
+    assert ops["copy.2"] == pytest.approx(0.002)
+    assert ops["fusion.3"] == pytest.approx(0.0005)
+    assert "fusion.9" not in ops and "jit_chain" not in ops
+    gaps = dict(r["idle_gaps"])
+    # 5.0-8.0 ms is covered by the `device` span (4.0-7.0 of the window),
+    # 9.0-10.5 by `fetch` (8.1-9.4 of the window); 1.0-2.0 by nothing
+    assert gaps["device"] == pytest.approx(0.003)
+    assert gaps["fetch"] == pytest.approx(0.0015)
+    assert gaps["outside-executor-spans"] == pytest.approx(0.001)
+    assert sum(gaps.values()) + r["busy_s"] == pytest.approx(r["window_s"])
+
+
+def test_operation_names_are_cut_to_name_shape_opcode():
+    hlo = ("%fusion.136 = u8[2621440]{0:T(1024)(128)(4,1)S(1)} fusion(u8[2621440]"
+           "{0:T(1024)(128)(4,1)S(1)} %get-tuple-element.134), kind=kCustom, "
+           "calls=%fused_computation.1.clone.clone")
+    assert trace_reduce.short_name(hlo) == (
+        "%fusion.136 u8[2621440] fusion(u8[2621440] %get-tuple-element.134), "
+        "kind=kCustom")
+    assert trace_reduce.short_name("copy.2") == "copy.2"
+    assert len(trace_reduce.short_name("x" * 500)) == trace_reduce.NAME_CHARS
+    tup = ("%while.1 = (s32[]{:T(128)}, u8[2621440]{0:T(1024)S(1)}) "
+           "while((s32[]{:T(128)}) %tuple.72), condition=%c, body=%b")
+    assert trace_reduce.short_name(tup).startswith(
+        "%while.1 (s32[], u8[2621440]) while((s32[]) %tuple.72)")
+
+
+def test_trace_without_device_operations_reads_nothing():
+    import jax
+
+    data = jax.profiler.ProfileData.from_text_proto(
+        'planes { id: 1 name: "/host:CPU" }'
+    )
+    assert trace_reduce.reduce_profile(data) is None
+
+
+def test_peaks_and_shapes():
+    assert device.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        device.peaks_for("TPU v99")
+    with pytest.raises(KeyError):
+        device.peaks_for("_source")
+    from fluvio_tpu.smartengine.tpu import buffer
+
+    for n in (1, 31, 33, 64, 100, 129, 300, 70 * 1024):
+        assert shapes.bucket_width(n) == buffer.bucket_width(n)
+    shape = {"max_in_len": 41, "max_out_len": 14, "fanout": 1}
+    assert shapes.span_bytes(65536, shape) == 65536 * (64 + 4) + 65536 * (32 + 4)
+
+
+# -- the loops, rehearsed on the CPU through the test-side switch ------------
+
+
+def _tiny_root(tmp_path, backlog=2048, extra=None) -> Path:
+    """A checkout-shaped directory with the benchmark's files, every
+    configuration cut to a tiny backlog, and optional extra entries."""
+    root = tmp_path / "root"
+    shutil.copytree(BENCH, root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    m = json.loads(json.dumps(MANIFEST))
+    for c in m["configs"]:
+        f = root / c["file"]
+        cfg = json.loads(f.read_text())
+        cfg["backlog_records"] = backlog
+        cfg["stored_batch_records"] = 512
+        f.write_text(json.dumps(cfg))
+    t = root / "benchmark" / "traffic" / "paced-16k.json"
+    if t.exists():
+        tr = json.loads(t.read_text())
+        tr |= {"rate_batches_per_s": 10, "warm_single_batches": 2,
+               "warm_max_batches": 2, "grace_s": 30}
+        t.write_text(json.dumps(tr))
+    if extra:
+        extra(root, m)
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+    return root
+
+
+PACED_E2E = ("age_p50_ms", "age_p95_ms")
+PACED_LAYER = ("slice_records_mean.paced", "exec_slice_ms_p50.paced",
+               "compiles_in_window.paced", "gen_late_p95_ms.paced")
+
+
+def _add_paced_cell(root, m):
+    """`ns-paced`, the cell this benchmark keeps for later (PERF.md Open
+    questions): its mode, mix and readers are in the tree, so manifest
+    entries alone add it."""
+    m["workloads"].append({
+        "name": "ns-paced", "config": "fluvio-northstar-1p",
+        "traffic": "paced-16k", "chips": 1, "why": "test"})
+    for name in PACED_E2E:
+        m["end_to_end"].append({
+            "name": name, "unit": "ms", "better": "lower", "bound": 0.25,
+            "source": "host_clock", "workloads": ["ns-paced"]})
+    for name in PACED_LAYER:
+        m["per_layer"].append({
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "program_span", "layer": "executor",
+            "moves": "age_p95_ms", "workloads": ["ns-paced"]})
+
+
+def _rehearse(monkeypatch, root, cell, trace=False, seconds=1.0):
+    monkeypatch.setattr(device, "REQUIRED_PLATFORM", "cpu")
+    return bench_run.run_cell(cell, 2**31 + 11, seconds, trace, root=root,
+                              t_process_start=time.perf_counter())
+
+
+@pytest.mark.parametrize("cell", CELLS + ["ns-paced"])
+def test_cell_rehearsal_on_cpu(monkeypatch, tmp_path, cell):
+    root = _tiny_root(tmp_path, extra=_add_paced_cell)
+    r = _rehearse(monkeypatch, root, cell)
+    assert r["faults"] == [] and r["correct"] is True
+    assert r["attempted"] >= 1 and r["failed"] == 0
+    assert r["device"]["platform"] == "cpu"   # named for what it ran on
+    c = manifest.load_cell(cell, root)
+    assert set(r["metrics"]) == {m["name"] for m in c.end_to_end}
+    assert r["counts"]["fastpath_slices"] > 0
+    assert r["counts"]["fallback_slices"] == 0
+    assert r["counts"]["records_in"] > 0
+    json.dumps(r)   # the result line is plain JSON
+
+
+def test_paced_layer_metrics_on_cpu(monkeypatch, tmp_path):
+    root = _tiny_root(tmp_path, extra=_add_paced_cell)
+    r = _rehearse(monkeypatch, root, "ns-paced", trace=True, seconds=2.0)
+    assert r["correct"] is True and r["counts"]["samples"] == r["attempted"] == 20
+    assert set(r["metrics"]) == set(PACED_LAYER)
+    assert r["metrics"]["compiles_in_window.paced"]["value"] == 0.0
+    assert 1 <= r["metrics"]["slice_records_mean.paced"]["value"] <= 20 * 400
+
+
+def test_seed_orders_the_same_batches(tmp_path):
+    """Every seed serves the same stored batches in another order."""
+    from spubench.session import Session
+
+    root = _tiny_root(tmp_path, backlog=2048 + 100)
+    cell = manifest.load_cell("ns-drain", root)
+
+    def batches(seed):
+        s = Session(cell, seed, 1.0, False, 0.0)
+        try:
+            v = to_values(*s.generate(2048 + 100))
+        finally:
+            s.cleanup()
+        return [tuple(v[i:i + 512]) for i in range(0, len(v), 512)]
+
+    a, b, a2 = batches(2**31 + 5), batches(7), batches(2**31 + 5)
+    assert a == a2 and a != b
+    assert sorted(a[:4]) == sorted(b[:4]) and a[4] == b[4] and len(a[4]) == 100
+
+
+def test_traced_rehearsal_reports_no_device_number(monkeypatch, tmp_path):
+    """A CPU trace has no device plane: the device readers return nothing
+    and `main` would refuse the line (no busy time)."""
+    root = _tiny_root(tmp_path)
+    r = _rehearse(monkeypatch, root, "ns-drain", trace=True, seconds=2.0)
+    assert r["correct"] is True
+    assert "busy_s" not in r["device"] and "breakdown" not in r
+    for name in ("device_busy_ms_per_mrec", "device_idle_share",
+                 "hbm_roofline_share"):
+        assert name not in r["metrics"]
+    for name in ("wire_out_mb_per_s", "fastpath_share", "spill_records",
+                 "exec_up_ms_per_mrec", "exec_down_ms_per_mrec"):
+        assert name in r["metrics"]
+    assert r["metrics"]["fastpath_share"]["value"] == 100.0
+    assert r["metrics"]["spill_records"]["value"] == 0.0
+
+
+def test_wrong_output_is_not_correct(monkeypatch, tmp_path):
+    """`correct` is decided against the reference: a reference that states
+    other values flips it."""
+    def extra(root, m):
+        p = root / "benchmark" / "references" / "northstar.py"
+        p.write_text(p.read_text().replace(".upper()", ".lower()"))
+
+    root = _tiny_root(tmp_path, extra=extra)
+    r = _rehearse(monkeypatch, root, "ns-drain", seconds=0.2)
+    assert r["correct"] is False
+    assert any("bytes differ" in f for f in r["faults"])
+
+
+def test_dummy_cell_is_added_by_files_alone(monkeypatch, tmp_path):
+    """A configuration, a traffic mix, a cell and a per-layer metric are
+    added by new files plus manifest entries; no existing file is edited."""
+    def extra(root, m):
+        b = root / "benchmark"
+        before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+        cfg = json.loads((b / "configs" / "fluvio-northstar-1p.json").read_text())
+        cfg["name"] = "dummy-config"
+        cfg["backlog_records"] = 1024
+        (b / "configs" / "dummy-config.json").write_text(json.dumps(cfg))
+        (b / "traffic" / "dummy-drain.json").write_text(
+            json.dumps({"mode": "drain", "max_bytes": 20000}))
+        (b / "layer_metrics" / "dummy_responses.py").write_text(
+            "def read(obs):\n    return obs['responses']\n")
+        m["configs"].append({
+            "name": "dummy-config", "source": cfg["source"],
+            "file": "benchmark/configs/dummy-config.json", "reduced": [],
+            "why": "test"})
+        m["workloads"].append({
+            "name": "dummy-cell", "config": "dummy-config",
+            "traffic": "dummy-drain", "chips": 1, "why": "test"})
+        m["per_layer"].append({
+            "name": "dummy_responses", "unit": "count", "better": "higher",
+            "source": "program_counter", "layer": "client / socket",
+            "moves": "records_in_per_s", "workloads": ["dummy-cell"]})
+        for e in m["end_to_end"]:
+            if e["name"] == "records_in_per_s":
+                e["workloads"].append("dummy-cell")
+        assert all(p.read_bytes() == data for p, data in before.items())
+
+    root = _tiny_root(tmp_path, extra=extra)
+    r = _rehearse(monkeypatch, root, "dummy-cell", trace=True, seconds=0.5)
+    assert r["correct"] is True
+    # 20 kB slices hold one 512-record stored batch: two responses to a pass
+    assert r["metrics"]["dummy_responses"]["value"] == r["counts"]["responses"]
+    assert r["counts"]["responses"] > r["attempted"]
+    assert set(r["metrics"]) == {"dummy_responses"}
+
+
+def test_unknown_names_are_errors(tmp_path):
+    with pytest.raises(manifest.ManifestError):
+        manifest.load_cell("no-such-cell")
+    with pytest.raises(manifest.ManifestError):
+        manifest.load_plugin(BENCH, "modes", "no-such-mode")
+
+
+def test_command_refuses_without_tpu():
+    """The real command, unsteered: no TPU -> non-zero exit, no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload", CELLS[0],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "nothing is measured without the chip" in out.stderr
