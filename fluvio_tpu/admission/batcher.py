@@ -295,7 +295,11 @@ class ShapeBucketBatcher:
         ]
         for f in flows:
             f.end_batcher(cause, len(bucket.items))
-            f.mark_dispatch()
+            # ONE dispatch serves every co-batched slice: its span
+            # carries the lead slice's id, and each flow names it
+            f.batch_id = flows[0].flow_id
+        if flows:
+            merged._flow = flows[0]
         flush = Flush(
             chain=chain,
             width_bucket=merged.width,

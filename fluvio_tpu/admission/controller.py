@@ -472,8 +472,6 @@ class AdmissionPipeline:
             items=[buf], bases=[0], buffer=buf, cause="solo",
         )
         flw = getattr(buf, "_flow", None)
-        if flw is not None:
-            flw.mark_dispatch()
         flush.result = self._solo_dispatch(flush)
         if flw is not None:
             TELEMETRY.end_flow(

@@ -28,6 +28,7 @@ from fluvio_tpu.parallel.mesh import RECORD_AXIS, make_record_mesh
 from fluvio_tpu.resilience import faults
 from fluvio_tpu.resilience.policy import TRANSIENT, classify, is_program_fault
 from fluvio_tpu.telemetry import TELEMETRY
+from fluvio_tpu.telemetry.spans import scoped_program, stage_scope, timed
 from fluvio_tpu.smartengine.tpu import executor as kernels_executor
 from fluvio_tpu.smartengine.tpu import glz, kernels, stripes
 from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer, apply_postops_host
@@ -97,28 +98,31 @@ class ShardedChainExecutor:
          _glz_bytes, _enc, _cap, srows, kmax) = cfg
         ex = self.executor
         s, v = ex._stripe_s, ex._stripe_v
-        lengths = uploads["lengths"].astype(jnp.int32)
-        n_local = lengths.shape[0]
-        g0 = lax.axis_index(RECORD_AXIS) * n_local
-        live = (g0 + jnp.arange(n_local, dtype=jnp.int32)) < count
-        plan = stripes.plan_device(lengths, live, srows, s, v)
-        sv = stripes.striped_repad_words(uploads["flat_words"], lengths, plan, s)
-        keys, key_lengths, offset_deltas, timestamp_deltas = (
-            kernels_executor.derived_meta_columns(
-                n_local, kwidth,
-                has_keys, uploads.get("keys"), uploads.get("key_lengths"),
-                has_offsets, uploads.get("offset_deltas"),
-                ts_mode, uploads.get("timestamp_deltas"),
-                idx_base=g0,
+        with jax.named_scope("repad"):
+            lengths = uploads["lengths"].astype(jnp.int32)
+            n_local = lengths.shape[0]
+            g0 = lax.axis_index(RECORD_AXIS) * n_local
+            live = (g0 + jnp.arange(n_local, dtype=jnp.int32)) < count
+            plan = stripes.plan_device(lengths, live, srows, s, v)
+            sv = stripes.striped_repad_words(
+                uploads["flat_words"], lengths, plan, s
             )
-        )
-        arrays = {
-            "keys": keys,
-            "key_lengths": key_lengths,
-            "offset_deltas": offset_deltas,
-            "timestamp_deltas": timestamp_deltas,
-        }
-        seg_state = stripes.seg_state_of(plan, sv, lengths, arrays, s)
+            keys, key_lengths, offset_deltas, timestamp_deltas = (
+                kernels_executor.derived_meta_columns(
+                    n_local, kwidth,
+                    has_keys, uploads.get("keys"), uploads.get("key_lengths"),
+                    has_offsets, uploads.get("offset_deltas"),
+                    ts_mode, uploads.get("timestamp_deltas"),
+                    idx_base=g0,
+                )
+            )
+            arrays = {
+                "keys": keys,
+                "key_lengths": key_lengths,
+                "offset_deltas": offset_deltas,
+                "timestamp_deltas": timestamp_deltas,
+            }
+            seg_state = stripes.seg_state_of(plan, sv, lengths, arrays, s)
         ctx = {
             "sv": sv, "plan": plan, "seg_state": seg_state, "n": n_local,
             "kmax": kmax,
@@ -127,6 +131,15 @@ class ShardedChainExecutor:
             ctx, live, carries, base_ts,
             {"fanout_cap": None, "axis_name": RECORD_AXIS, "g0": g0},
         )
+        with jax.named_scope("compact"):
+            return self._striped_outputs(
+                valid, seg_state, carries, vspan, lengths
+            )
+
+    def _striped_outputs(self, valid, seg_state, carries, vspan, lengths):
+        """`_local_step_striped`'s tail under the ``compact`` device
+        scope (the single-device `_chain_outputs` vocabulary)."""
+        ex = self.executor
         cnt = jnp.sum(valid.astype(jnp.int32))
 
         def header(max_v):
@@ -177,10 +190,9 @@ class ShardedChainExecutor:
             uploads["glz_ml"][0],
             uploads["glz_srcs"][0],
         )
-        raw = glz.decompress_device(
-            *seqs, uploads["glz_lits"][0], uploads["glz_depth"][0], glz_bytes
+        return kernels_executor.TpuChainExecutor._link_decode(
+            seqs, uploads["glz_lits"][0], uploads["glz_depth"][0], glz_bytes
         )
-        return lax.bitcast_convert_type(raw.reshape(-1, 4), jnp.int32)
 
     def _local_step_ragged(
         self, uploads: Dict, count, base_ts, carries, *, cfg: tuple
@@ -196,20 +208,21 @@ class ShardedChainExecutor:
         (width, kwidth, has_keys, has_offsets, ts_mode,
          glz_bytes, enc, fanout_cap) = cfg
         flat_words = self._shard_flat_words(uploads, glz_bytes)
-        values, lengths = kernels_executor.ragged_repad_words(
-            flat_words, uploads["lengths"], width
-        )
-        n_local = lengths.shape[0]
-        g0 = lax.axis_index(RECORD_AXIS) * n_local
-        keys, key_lengths, offset_deltas, timestamp_deltas = (
-            kernels_executor.derived_meta_columns(
-                n_local, kwidth,
-                has_keys, uploads.get("keys"), uploads.get("key_lengths"),
-                has_offsets, uploads.get("offset_deltas"),
-                ts_mode, uploads.get("timestamp_deltas"),
-                idx_base=g0,
+        with jax.named_scope("repad"):
+            values, lengths = kernels_executor.ragged_repad_words(
+                flat_words, uploads["lengths"], width
             )
-        )
+            n_local = lengths.shape[0]
+            g0 = lax.axis_index(RECORD_AXIS) * n_local
+            keys, key_lengths, offset_deltas, timestamp_deltas = (
+                kernels_executor.derived_meta_columns(
+                    n_local, kwidth,
+                    has_keys, uploads.get("keys"), uploads.get("key_lengths"),
+                    has_offsets, uploads.get("offset_deltas"),
+                    ts_mode, uploads.get("timestamp_deltas"),
+                    idx_base=g0,
+                )
+            )
         arrays = {
             "values": values,
             "lengths": lengths,
@@ -236,8 +249,15 @@ class ShardedChainExecutor:
         # fanout_cap is PER SHARD: each shard scatters into its own
         # capacity block; src_row stays global so the host gather works
         ctx = {"fanout_cap": fanout_cap, "axis_name": ax, "g0": g0}
-        for stage in ex.stages:
-            state, carries = stage.apply(state, carries, base_ts, ctx)
+        for i, stage in enumerate(ex.stages):
+            with jax.named_scope(stage_scope(i, stage.kind)):
+                state, carries = stage.apply(state, carries, base_ts, ctx)
+        with jax.named_scope("compact"):
+            return self._local_outputs(arrays, state, carries, enc)
+
+    def _local_outputs(self, arrays: Dict, state: Dict, carries, enc: str):
+        """`_local_step`'s tail under the ``compact`` device scope."""
+        ex = self.executor
         valid = state["valid"]
         cnt = jnp.sum(valid.astype(jnp.int32))
         fan_err = state.get("fan_err", jnp.asarray(False))
@@ -270,20 +290,15 @@ class ShardedChainExecutor:
                 # per-shard down-link encode under shard_map (the same
                 # interleaved descriptor stream the single-device chain
                 # emits, one independent token set per shard)
-                ll, ml, srcs, lits, n_seq, n_lit, depth = glz.encode_result(
+                ex._down_encode(
+                    packed,
                     ex._desc_stream(
                         compacted[0], compacted[1],
                         arrays["values"].shape[1],
                     ),
-                    ex._enc_chunk or glz.GLZ_CHUNK,
+                    enc,
                 )
-                packed["down_ll"] = ll
-                packed["down_ml"] = ml
-                packed["down_src"] = srcs
-                packed["down_lits"] = lits
-                packed["down_meta"] = jnp.stack(
-                    [n_seq, n_lit, depth]
-                ).astype(jnp.int32)[None, :]
+                packed["down_meta"] = packed["down_meta"][None, :]
             return header(jnp.max(compacted[1]), jnp.int32(0)), packed, carries
         if ex._int_output:
             windowed = bool(ex.stages[-1].window_ms)
@@ -362,7 +377,7 @@ class ShardedChainExecutor:
             fn = instrument_jit(
                 jax.jit(
                     _shard_map(
-                        step,
+                        scoped_program(step),
                         mesh=self.mesh,
                         in_specs=in_specs,
                         out_specs=out_specs,
@@ -888,12 +903,15 @@ class ShardedChainExecutor:
         # device-side failures surface at the first blocking sync
         faults.maybe_fire("device")
         down_meta = None
-        if "down_meta" in packed:
-            hdr_got = jax.device_get([header, packed["down_meta"]])
-            hdrs = np.asarray(hdr_got[0])  # (n_shards, 5)
-            down_meta = np.asarray(hdr_got[1])  # (n_shards, 3)
-        else:
-            hdrs = np.asarray(jax.device_get(header))  # (n_shards, 5)
+        # the stacked-header sync is where this thread blocks on the
+        # mesh: the span's `wait` (the single-device `_fetch_inner` pair)
+        with timed(span, "wait"):
+            if "down_meta" in packed:
+                hdr_got = jax.device_get([header, packed["down_meta"]])
+                hdrs = np.asarray(hdr_got[0])  # (n_shards, 5)
+                down_meta = np.asarray(hdr_got[1])  # (n_shards, 3)
+            else:
+                hdrs = np.asarray(jax.device_get(header))  # (n_shards, 5)
         if span is not None:
             span.mark_device_ready()
         counts = hdrs[:, 0].astype(np.int64)
@@ -926,12 +944,13 @@ class ShardedChainExecutor:
                 )
                 (_prev, new_carries, header, packed, cap_shard, _,
                  _glz, _enc) = handle
-                down_meta = (
-                    np.asarray(jax.device_get(packed["down_meta"]))
-                    if "down_meta" in packed
-                    else None
-                )
-                hdrs = np.asarray(jax.device_get(header))
+                with timed(span, "wait"):
+                    down_meta = (
+                        np.asarray(jax.device_get(packed["down_meta"]))
+                        if "down_meta" in packed
+                        else None
+                    )
+                    hdrs = np.asarray(jax.device_get(header))
                 if span is not None:
                     span.mark_device_ready()
                 if int(hdrs[:, 4].max()) > cap_shard:  # pragma: no cover

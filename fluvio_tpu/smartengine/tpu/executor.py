@@ -31,6 +31,12 @@ import numpy as np
 from jax import lax
 
 from fluvio_tpu.telemetry import TELEMETRY, instrument_jit
+from fluvio_tpu.telemetry.spans import (
+    annotate,
+    scoped_program,
+    stage_scope,
+    timed,
+)
 from fluvio_tpu.resilience import faults
 from fluvio_tpu.resilience.policy import RetryPolicy, is_program_fault
 
@@ -98,6 +104,8 @@ class _FanoutOverflow(Exception):
 class _FilterStage:
     predicate: Callable
 
+    kind = "filter"  # device-scope label (`stage<i>.<kind>`), no user data
+
     # structural invariants the executor checks at build time (ADVICE r2):
     # stages that break them force the off/ts columns onto the D2H path
     preserves_rows = True      # output row i corresponds to input row i
@@ -117,6 +125,7 @@ class _MapStage:
     span_fn: Optional[Callable] = None    # value is a view of current values
     span_postops: Tuple[str, ...] = ()    # static byte-wise folds on the view
 
+    kind = "map"
     preserves_rows = True
     rewrites_offsets = False
 
@@ -159,6 +168,7 @@ class _ArrayMapStage:
     mode: str  # "json_array" | "split"
     sep: bytes
 
+    kind = "array_map"
     preserves_rows = False
     rewrites_offsets = True
 
@@ -235,6 +245,7 @@ class _AggregateStage:
     index: int  # carry slot
     contribution_fn: Callable  # state -> i64[N] per-record contribution
 
+    kind = "aggregate"
     preserves_rows = True
     rewrites_offsets = False
 
@@ -642,7 +653,7 @@ class TpuChainExecutor:
         # FLUVIO_TELEMETRY=0 — see telemetry/compiles.py)
         self._jit_ragged = instrument_jit(
             jax.jit(
-                self._chain_fn_ragged,
+                scoped_program(self._chain_fn_ragged),
                 static_argnames=(
                     "width", "kwidth", "has_keys", "has_offsets", "ts_mode",
                     "fanout_cap", "glz_bytes", "enc", "pack",
@@ -663,7 +674,7 @@ class TpuChainExecutor:
         self._stripe_threshold = int(env_int("FLUVIO_STRIPE_THRESHOLD"))
         self._jit_striped = instrument_jit(
             jax.jit(
-                self._chain_fn_striped,
+                scoped_program(self._chain_fn_striped),
                 static_argnames=(
                     "srows", "kmax", "kwidth", "has_keys", "has_offsets",
                     "ts_mode", "fanout_cap", "glz_bytes", "enc", "pack",
@@ -913,14 +924,17 @@ class TpuChainExecutor:
         the two must not fork). Padded to an 8-byte boundary for the
         encoder's group alignment."""
         f_st, f_ln = TpuChainExecutor._desc_fields(width)
-        cols = []
-        for col, f in ((st.astype(jnp.int32), f_st), (ln.astype(jnp.int32), f_ln)):
-            for b in range(f):
-                cols.append((col >> (8 * b)) & 0xFF)
-        desc = jnp.stack(cols, axis=1).astype(jnp.uint8).reshape(-1)
-        pad = (-desc.shape[0]) % 8
-        if pad:
-            desc = jnp.concatenate([desc, jnp.zeros((pad,), jnp.uint8)])
+        with jax.named_scope("pack"):
+            cols = []
+            for col, f in (
+                (st.astype(jnp.int32), f_st), (ln.astype(jnp.int32), f_ln)
+            ):
+                for b in range(f):
+                    cols.append((col >> (8 * b)) & 0xFF)
+            desc = jnp.stack(cols, axis=1).astype(jnp.uint8).reshape(-1)
+            pad = (-desc.shape[0]) % 8
+            if pad:
+                desc = jnp.concatenate([desc, jnp.zeros((pad,), jnp.uint8)])
         return desc
 
     @staticmethod
@@ -945,16 +959,17 @@ class TpuChainExecutor:
         payload caps are). The fetch decides per batch whether the
         tokens beat the raw slice — losing costs nothing extra on the
         wire (the raw columns are in ``packed`` either way)."""
-        ll, ml, srcs, lits, n_seq, n_lit, depth = glz.encode_result(
-            stream, self._enc_chunk or glz.GLZ_CHUNK
-        )
-        packed["down_ll"] = ll
-        packed["down_ml"] = ml
-        packed["down_src"] = srcs
-        packed["down_lits"] = lits
-        packed["down_meta"] = jnp.stack(
-            [n_seq, n_lit, depth]
-        ).astype(jnp.int32)
+        with jax.named_scope("link_encode"):
+            ll, ml, srcs, lits, n_seq, n_lit, depth = glz.encode_result(
+                stream, self._enc_chunk or glz.GLZ_CHUNK
+            )
+            packed["down_ll"] = ll
+            packed["down_ml"] = ml
+            packed["down_src"] = srcs
+            packed["down_lits"] = lits
+            packed["down_meta"] = jnp.stack(
+                [n_seq, n_lit, depth]
+            ).astype(jnp.int32)
 
     @staticmethod
     def _packed_payload(values_c, lengths_c):
@@ -964,21 +979,22 @@ class TpuChainExecutor:
         download as a flat-backed output buffer with zero reshaping).
         Returns (payload u8[rows*width], payload_len scalar)."""
         rows, width = values_c.shape
-        l4 = (lengths_c.astype(jnp.int32) + 3) & ~3
-        # i32 accumulator is safe: lengths <= the bucketed width, and
-        # the staging guard (_check_matrix_addressing) bounds
-        # rows * width — hence sum(l4) — under i32
-        starts = jnp.cumsum(l4) - l4  # noqa: FLV303
-        cap = rows * width
-        col = jnp.arange(width, dtype=jnp.int32)[None, :]
-        dst = jnp.where(col < l4[:, None], starts[:, None] + col, cap)
-        payload = (
-            jnp.zeros((cap,), jnp.uint8)
-            .at[dst.reshape(-1)]
-            .set(values_c.reshape(-1), mode="drop")
-        )
-        # same staging bound as the cumsum above: total fits i32
-        return payload, jnp.sum(l4)  # noqa: FLV303
+        with jax.named_scope("pack"):
+            l4 = (lengths_c.astype(jnp.int32) + 3) & ~3
+            # i32 accumulator is safe: lengths <= the bucketed width, and
+            # the staging guard (_check_matrix_addressing) bounds
+            # rows * width — hence sum(l4) — under i32
+            starts = jnp.cumsum(l4) - l4  # noqa: FLV303
+            cap = rows * width
+            col = jnp.arange(width, dtype=jnp.int32)[None, :]
+            dst = jnp.where(col < l4[:, None], starts[:, None] + col, cap)
+            payload = (
+                jnp.zeros((cap,), jnp.uint8)
+                .at[dst.reshape(-1)]
+                .set(values_c.reshape(-1), mode="drop")
+            )
+            # same staging bound as the cumsum above: total fits i32
+            return payload, jnp.sum(l4)  # noqa: FLV303
 
     # -- execution ----------------------------------------------------------
 
@@ -1009,8 +1025,19 @@ class TpuChainExecutor:
         state["view_start"] = jnp.zeros((n,), dtype=jnp.int32)
         state["src_row"] = jnp.arange(n, dtype=jnp.int32)
         ctx = {"fanout_cap": fanout_cap}
-        for stage in self.stages:
-            state, carries = stage.apply(state, carries, base_ts, ctx)
+        for i, stage in enumerate(self.stages):
+            with jax.named_scope(stage_scope(i, stage.kind)):
+                state, carries = stage.apply(state, carries, base_ts, ctx)
+        with jax.named_scope("compact"):
+            return self._chain_outputs(arrays, state, carries, enc, pack)
+
+    def _chain_outputs(self, arrays: Dict, state: Dict, carries,
+                       enc: str, pack: bool):
+        """The chain body's tail, traced under the ``compact`` device
+        scope (`_desc_stream`/`_packed_payload` and `_down_encode` open
+        their own ``pack`` / ``link_encode`` scopes inside it; the
+        innermost scope names an operation): survivor compaction, the
+        mask, the header and the down-link form of the result."""
         valid = state["valid"]
         out_count = jnp.sum(valid.astype(jnp.int32))
         fan_err = state.get("fan_err", jnp.asarray(False))
@@ -1109,6 +1136,16 @@ class TpuChainExecutor:
         header = _header(jnp.max(packed["lengths"]), jnp.max(packed["key_lengths"]))
         return header, packed, carries
 
+    @staticmethod
+    def _link_decode(glz_seqs, glz_lits, glz_depth, glz_bytes: int):
+        """The flat that crossed the link compressed, inflated on device
+        to the i32 words the raw path ships (``link_decode`` scope)."""
+        with jax.named_scope("link_decode"):
+            raw = glz.decompress_device(
+                *glz_seqs, glz_lits, glz_depth, glz_bytes
+            )
+            return lax.bitcast_convert_type(raw.reshape(-1, 4), jnp.int32)
+
     def _chain_fn_ragged(
         self,
         flat,
@@ -1153,18 +1190,16 @@ class TpuChainExecutor:
         bitcasts to the same i32 words the raw path ships.
         """
         if glz_bytes:
-            raw = glz.decompress_device(
-                *glz_seqs, glz_lits, glz_depth, glz_bytes
+            flat = self._link_decode(glz_seqs, glz_lits, glz_depth, glz_bytes)
+        with jax.named_scope("repad"):
+            values, lengths = ragged_repad_words(flat, lengths, width)
+            n = lengths.shape[0]
+            keys, key_lengths, offset_deltas, timestamp_deltas = (
+                derived_meta_columns(
+                    n, kwidth, has_keys, keys, key_lengths,
+                    has_offsets, offset_deltas, ts_mode, timestamp_deltas,
+                )
             )
-            flat = lax.bitcast_convert_type(raw.reshape(-1, 4), jnp.int32)
-        values, lengths = ragged_repad_words(flat, lengths, width)
-        n = lengths.shape[0]
-        keys, key_lengths, offset_deltas, timestamp_deltas = (
-            derived_meta_columns(
-                n, kwidth, has_keys, keys, key_lengths,
-                has_offsets, offset_deltas, ts_mode, timestamp_deltas,
-            )
-        )
         arrays = {
             "values": values,
             "lengths": lengths,
@@ -1283,29 +1318,27 @@ class TpuChainExecutor:
         (0 when the chain has no span stage).
         """
         if glz_bytes:
-            raw = glz.decompress_device(
-                *glz_seqs, glz_lits, glz_depth, glz_bytes
+            flat = self._link_decode(glz_seqs, glz_lits, glz_depth, glz_bytes)
+        with jax.named_scope("repad"):
+            lengths = lengths.astype(jnp.int32)
+            n = lengths.shape[0]
+            s, v = self._stripe_s, self._stripe_v
+            live = jnp.arange(n, dtype=jnp.int32) < count
+            plan = stripes.plan_device(lengths, live, srows, s, v)
+            sv = stripes.striped_repad_words(flat, lengths, plan, s)
+            keys, key_lengths, offset_deltas, timestamp_deltas = (
+                derived_meta_columns(
+                    n, kwidth, has_keys, keys, key_lengths,
+                    has_offsets, offset_deltas, ts_mode, timestamp_deltas,
+                )
             )
-            flat = lax.bitcast_convert_type(raw.reshape(-1, 4), jnp.int32)
-        lengths = lengths.astype(jnp.int32)
-        n = lengths.shape[0]
-        s, v = self._stripe_s, self._stripe_v
-        live = jnp.arange(n, dtype=jnp.int32) < count
-        plan = stripes.plan_device(lengths, live, srows, s, v)
-        sv = stripes.striped_repad_words(flat, lengths, plan, s)
-        keys, key_lengths, offset_deltas, timestamp_deltas = (
-            derived_meta_columns(
-                n, kwidth, has_keys, keys, key_lengths,
-                has_offsets, offset_deltas, ts_mode, timestamp_deltas,
-            )
-        )
-        arrays = {
-            "keys": keys,
-            "key_lengths": key_lengths,
-            "offset_deltas": offset_deltas,
-            "timestamp_deltas": timestamp_deltas,
-        }
-        seg_state = stripes.seg_state_of(plan, sv, lengths, arrays, s)
+            arrays = {
+                "keys": keys,
+                "key_lengths": key_lengths,
+                "offset_deltas": offset_deltas,
+                "timestamp_deltas": timestamp_deltas,
+            }
+            seg_state = stripes.seg_state_of(plan, sv, lengths, arrays, s)
         ctx = {
             "sv": sv, "plan": plan, "seg_state": seg_state, "n": n,
             "kmax": kmax,
@@ -1313,6 +1346,16 @@ class TpuChainExecutor:
         valid, seg_state, carries, fan, vspan = self._striped.run(
             ctx, live, carries, base_ts, {"fanout_cap": fanout_cap}
         )
+        with jax.named_scope("compact"):
+            return self._striped_outputs(
+                valid, seg_state, carries, fan, vspan, plan, lengths,
+                srows, fanout_cap, enc,
+            )
+
+    def _striped_outputs(self, valid, seg_state, carries, fan, vspan, plan,
+                         lengths, srows: int, fanout_cap, enc: str):
+        """`_chain_fn_striped`'s tail under the ``compact`` device scope
+        (see `_chain_outputs`)."""
         packed: Dict = {}
         if fan is not None:
             flag, st_g, len_g = fan
@@ -1500,21 +1543,18 @@ class TpuChainExecutor:
             # striped batches land in their own latency/record family
             span.path = "striped"
         enc_now, pack_now = self._down_axes(striped)
-        t_ph = time.perf_counter() if span is not None else 0.0
-        faults.maybe_fire("stage")
-        flat, bucket = self._flat_and_bucket(buf)
-        if span is not None:
-            now = time.perf_counter()
-            span.add("stage", now - t_ph)
-            t_ph = now
-        faults.maybe_fire("h2d")
-        (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
-         flat_h2d) = self._stage_flat(buf, flat, bucket)
-        if span is not None:
-            now = time.perf_counter()
-            # the compressed form's staging IS the compressor (plus token
-            # padding); the raw form's is the pad + device enqueue
-            span.add("glz_compress" if glz_bytes else "h2d", now - t_ph)
+        with timed(span, "stage"):
+            faults.maybe_fire("stage")
+            flat, bucket = self._flat_and_bucket(buf)
+        with timed(span, "h2d") as ph:
+            faults.maybe_fire("h2d")
+            (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
+             flat_h2d) = self._stage_flat(buf, flat, bucket)
+            if glz_bytes:
+                # the compressed form's staging IS the compressor (plus
+                # token padding); the raw form's is the pad + device
+                # enqueue. Which it was is known only now.
+                ph.rename("glz_compress")
         lengths_up, has_keys, has_offsets, ts_mode, ts_np = (
             stage_link_columns(buf)
         )
@@ -1564,44 +1604,42 @@ class TpuChainExecutor:
                 )
             return self._jit_ragged(*args, width=buf.width, **kwargs)
 
-        t_ph = time.perf_counter() if span is not None else 0.0
-        while True:
-            try:
-                header, packed, new_carries = _call(enc_now, pack_now)
-                break
-            except (KeyboardInterrupt, SystemExit):
-                # operator interrupts must unwind, never convert into a
-                # heal/spill (they are BaseException, but be explicit: no
-                # broadened rewrite of this handler may ever swallow them)
-                raise
-            except Exception as e:
-                if is_program_fault(e):
-                    # what only lowering/compiling raises is a fault of
-                    # the PROGRAM, not device weather: no quieter rung
-                    # answers it — the compiler's own error stops the run
+        with timed(span, "dispatch"):
+            while True:
+                try:
+                    header, packed, new_carries = _call(enc_now, pack_now)
+                    break
+                except (KeyboardInterrupt, SystemExit):
+                    # operator interrupts must unwind, never convert into a
+                    # heal/spill (they are BaseException, but be explicit: no
+                    # broadened rewrite of this handler may ever swallow them)
                     raise
-                if enc_now != "off":
-                    # sync half of the ENCODE heal (runtime failures
-                    # only): the encoder is output-side, so the batch
-                    # re-dispatches in the same link form with encode
-                    # latched off
-                    enc_now = self._enc_demote(e, where="dispatch")
-                elif glz_bytes:
-                    # sync half of the decode heal (async failures heal
-                    # in finish_buffer): ship the batch raw and latch
-                    # compression off for this executor
-                    self._glz_demote(e, buf)
-                else:
-                    raise
-                # the failed attempt's arrays already crossed the link —
-                # keep them on the counter — and may have been DONATED
-                # into the failed call: a healed re-dispatch stages
-                # fresh device arrays, never a consumed buffer
-                self.h2d_bytes_total += flat_h2d
-                (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
-                 flat_h2d) = self._stage_flat(buf, flat, bucket)
-        if span is not None:
-            span.add("dispatch", time.perf_counter() - t_ph)
+                except Exception as e:
+                    if is_program_fault(e):
+                        # what only lowering/compiling raises is a fault of
+                        # the PROGRAM, not device weather: no quieter rung
+                        # answers it — the compiler's own error stops the run
+                        raise
+                    if enc_now != "off":
+                        # sync half of the ENCODE heal (runtime failures
+                        # only): the encoder is output-side, so the batch
+                        # re-dispatches in the same link form with encode
+                        # latched off
+                        enc_now = self._enc_demote(e, where="dispatch")
+                    elif glz_bytes:
+                        # sync half of the decode heal (async failures heal
+                        # in finish_buffer): ship the batch raw and latch
+                        # compression off for this executor
+                        self._glz_demote(e, buf)
+                    else:
+                        raise
+                    # the failed attempt's arrays already crossed the link —
+                    # keep them on the counter — and may have been DONATED
+                    # into the failed call: a healed re-dispatch stages
+                    # fresh device arrays, never a consumed buffer
+                    self.h2d_bytes_total += flat_h2d
+                    (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
+                     flat_h2d) = self._stage_flat(buf, flat, bucket)
         self._glz_last = bool(glz_bytes)
         # ledger attribution: how many of THIS dispatch's flat-link
         # bytes were compressed token arrays (glz_tokens owner)
@@ -1930,13 +1968,11 @@ class TpuChainExecutor:
         routes through it too, so the counters cannot silently miss a
         path). Accumulates: a batch whose fetch runs twice (fan-out
         capacity retry) reports its total traffic."""
-        t_ph = time.perf_counter() if span is not None else 0.0
-        faults.maybe_fire("fetch")
-        for s in slices:
-            s.copy_to_host_async()
-        host = jax.device_get(slices)
-        if span is not None:
-            span.add("d2h", time.perf_counter() - t_ph)
+        with timed(span, "d2h"):
+            faults.maybe_fire("fetch")
+            for s in slices:
+                s.copy_to_host_async()
+            host = jax.device_get(slices)
         self.d2h_bytes_total += 64 + sum(np.asarray(a).nbytes for a in host)
         return host
 
@@ -2093,32 +2129,35 @@ class TpuChainExecutor:
             tail.append(spec.get("down_meta", packed["down_meta"]))
         if "payload_meta" in packed:
             tail.append(spec.get("payload_meta", packed["payload_meta"]))
-        if self._fanout:
-            d, mx, mn, b = (
-                spec["fan_probe"]
-                if "fan_probe" in spec
-                else self._fan_probe(header, packed)
-            )
-            got = jax.device_get([header, mx, mn, b] + tail)
-            hdr, mx, mn, b = got[:4]
-            tail = got[4:]
-            if int(mx) < (1 << 8) and int(mn) >= 0:
-                src_delta = (d.astype(jnp.uint8), int(b))
-        elif self._int_output:
-            # the delta-probe scalars ride the header sync — one blocking
-            # round-trip, not two
-            a_d, w_d, probes = (
-                spec["int_probe"]
-                if "int_probe" in spec
-                else self._int_probe(header, packed)
-            )
-            got = jax.device_get(probes)
-            hdr = got[0]
-            int_probe = (a_d, w_d, [int(x) for x in got[1:]])
-        else:
-            got = jax.device_get([header] + tail)
-            hdr = got[0]
-            tail = got[1:]
+        # the ONE place the calling thread blocks on this batch's device
+        # work: the header sync (its probe scalars ride the same sync)
+        with timed(span, "wait"):
+            if self._fanout:
+                d, mx, mn, b = (
+                    spec["fan_probe"]
+                    if "fan_probe" in spec
+                    else self._fan_probe(header, packed)
+                )
+                got = jax.device_get([header, mx, mn, b] + tail)
+                hdr, mx, mn, b = got[:4]
+                tail = got[4:]
+                if int(mx) < (1 << 8) and int(mn) >= 0:
+                    src_delta = (d.astype(jnp.uint8), int(b))
+            elif self._int_output:
+                # the delta-probe scalars ride the header sync — one blocking
+                # round-trip, not two
+                a_d, w_d, probes = (
+                    spec["int_probe"]
+                    if "int_probe" in spec
+                    else self._int_probe(header, packed)
+                )
+                got = jax.device_get(probes)
+                hdr = got[0]
+                int_probe = (a_d, w_d, [int(x) for x in got[1:]])
+            else:
+                got = jax.device_get([header] + tail)
+                hdr = got[0]
+                tail = got[1:]
         down_meta = None
         payload_len = None
         if "down_meta" in packed:
@@ -2128,7 +2167,8 @@ class TpuChainExecutor:
             payload_len = int(tail[0][0])
         if span is not None:
             # the header sync is the first blocking wait on this batch's
-            # results: everything up to here since dispatch-end is device
+            # results: everything up to here since dispatch-end is the
+            # batch's time out on the device (queue + compute)
             span.mark_device_ready()
         count, max_v, max_k = int(hdr[0]), int(hdr[1]), int(hdr[2])
         if int(hdr[3]):
@@ -2868,22 +2908,28 @@ class TpuChainExecutor:
                 attempt += 1
                 handle = self._sharded_dispatch(buf, reuse_span=handle[5])
 
-    def dispatch_buffer(self, buf: RecordBuffer):
+    def dispatch_buffer(self, buf: RecordBuffer, flow_id: int = 0):
         """Phase 1: stage + dispatch without blocking on results.
 
         JAX dispatch is async, so the H2D transfer and device compute
         proceed in the background; the returned handle feeds
         `finish_buffer`. The broker's pipelined stream loop dispatches
         slice k+1 here while slice k's results download and hit the
-        socket.
+        socket. ``flow_id`` names the slice flow that caused this
+        dispatch on its span (0 = none; a buffer the admission
+        pipeline tagged with its flow names it itself).
         """
+        if not flow_id:
+            flow = getattr(buf, "_flow", None)
+            if flow is not None:
+                flow_id = flow.batch_id
         if self._sharded is not None:
             # one span threads through every retry attempt (the fan-out
             # retry convention: phase time accumulates onto the batch's
             # single span — the batch really paid staging twice — and a
             # failed attempt's span is never orphaned)
             sh_span = TELEMETRY.begin_batch(
-                chain=self.span_chain or self._chain_sig
+                chain=self.span_chain or self._chain_sig, flow_id=flow_id
             )
             h0 = self.h2d_bytes_total
             handle = self._dispatch_with_retry(
@@ -2894,7 +2940,9 @@ class TpuChainExecutor:
         # chain identity on the span: the per-chain windowed latency
         # family the SLO engine's e2e_p99 verdicts key on — partitioned
         # dispatches carry the chain@partition identity instead
-        span = TELEMETRY.begin_batch(chain=self.span_chain or self._chain_sig)
+        span = TELEMETRY.begin_batch(
+            chain=self.span_chain or self._chain_sig, flow_id=flow_id
+        )
         prev_carries = self._device_carries
         h0 = self.h2d_bytes_total
         header, packed = self._dispatch_with_retry(
@@ -2902,12 +2950,11 @@ class TpuChainExecutor:
                 buf, fanout_cap=self._fanout_cap(buf), span=span
             )
         )
-        t_ph = time.perf_counter() if span is not None else 0.0
-        spec = self._start_result_copies(buf, header, packed)
+        # the probe math + async D2H registration: charged to d2h — it
+        # is the download's initiation half
+        with timed(span, "d2h"):
+            spec = self._start_result_copies(buf, header, packed)
         if span is not None:
-            # the probe math + async D2H registration: charged to d2h —
-            # it is the download's initiation half
-            span.add("d2h", time.perf_counter() - t_ph)
             span.mark_dispatched()
             spec["span"] = span
         # finish-side self-heal markers: whether THIS dispatch shipped a
@@ -2924,7 +2971,9 @@ class TpuChainExecutor:
         )
         return handle
 
-    def dispatch_buffers(self, bufs: List[RecordBuffer]) -> List[tuple]:
+    def dispatch_buffers(
+        self, bufs: List[RecordBuffer], flow_id: int = 0
+    ) -> List[tuple]:
         """Dispatch several buffers with ONE-AHEAD compress-ahead:
         while buffer k stages and issues, the shared glz worker
         compresses buffer k+1 (settle-before-dispatch, so staging never
@@ -2933,7 +2982,8 @@ class TpuChainExecutor:
         keeps the process-wide worker fair to other executors. Returns
         [(buf, handle), ...] for `finish_buffer`. The SPU slice bridge
         (spu/smart_chain.py) builds on this; the stream loop below
-        inlines the same pattern around its yields."""
+        inlines the same pattern around its yields. ``flow_id``: the
+        slice flow every one of these chunks belongs to."""
         out = []
         fut = None
         try:
@@ -2945,7 +2995,7 @@ class TpuChainExecutor:
                     job = self._precompress_fn(bufs[i + 1])
                     if job is not None:
                         fut = _compress_pool().submit(job, bufs[i + 1])
-                out.append((buf, self.dispatch_buffer(buf)))
+                out.append((buf, self.dispatch_buffer(buf, flow_id)))
         except BaseException:
             # a mid-list dispatch failure (post-retries) must not leak
             # the earlier chunks' in-flight handles: discard them so
@@ -3069,20 +3119,12 @@ class TpuChainExecutor:
         finally:
             self._gauge_release(handle)
 
-    def _finish_buffer_inner(self, buf: RecordBuffer, handle,
-                             defer: bool = False):
-        if self._sharded is not None:
-            return self._finish_sharded(buf, handle)
+    def _fetch_or_recover(self, buf: RecordBuffer, handle, span, defer: bool):
+        """The blocking half of a finish: the fetch, and on a failure
+        the ladder that answers it (fan-out capacity retry, encode and
+        glz heals, the bounded transient retry). Returns the output
+        buffer, or the deferred split-back thunk."""
         prev_carries, header, packed, spec = handle
-        if (
-            self.agg_configs
-            and spec is not None
-            and spec.get("epoch", self._heal_epoch) != self._heal_epoch
-        ):
-            return self._finish_stale_epoch(buf, handle)
-        span = spec.get("span") if spec else None
-        t_f0 = time.perf_counter() if span is not None else 0.0
-        d2h0 = span.phase("d2h") if span is not None else 0.0
         try:
             out = self._fetch(buf, header, packed, spec, defer=defer)
         except _FanoutOverflow as o:
@@ -3153,6 +3195,28 @@ class TpuChainExecutor:
                 # transient device/fetch failure outside glz: bounded
                 # retry against the handle's carry snapshot
                 out = self._finish_retry(buf, handle, span, e)
+        return out
+
+    def _finish_buffer_inner(self, buf: RecordBuffer, handle,
+                             defer: bool = False):
+        if self._sharded is not None:
+            return self._finish_sharded(buf, handle)
+        spec = handle[3]
+        if (
+            self.agg_configs
+            and spec is not None
+            and spec.get("epoch", self._heal_epoch) != self._heal_epoch
+        ):
+            return self._finish_stale_epoch(buf, handle)
+        span = spec.get("span") if spec else None
+        t_f0 = time.perf_counter() if span is not None else 0.0
+        d2h0 = span.phase("d2h") if span is not None else 0.0
+        # `fetch` is computed by subtraction below, so its annotation is
+        # the enclosing interval: this blocking half (``fluvio/wait`` and
+        # ``fluvio/d2h`` nest inside it) and, deferred, the worker-side
+        # split-back
+        with annotate(span, "fetch"):
+            out = self._fetch_or_recover(buf, handle, span, defer)
 
         def _complete(result):
             if span is not None:
@@ -3179,7 +3243,11 @@ class TpuChainExecutor:
             # deferred materialization: the recovery ladders above all
             # return finished buffers, so a thunk here is the pure
             # happy-path split-back
-            return lambda: _complete(out())
+            def _deferred():
+                with annotate(span, "fetch"):
+                    return _complete(out())
+
+            return _deferred
         return _complete(out)
 
     def _finish_stale_epoch(self, buf: RecordBuffer, handle) -> RecordBuffer:
@@ -3254,6 +3322,7 @@ class TpuChainExecutor:
         it = iter(bufs)
         cur = next(it, None)
         pending = None
+        handle = None
         fut = None
         mat = None  # in-flight deferred materialization (Future)
         try:
@@ -3295,10 +3364,19 @@ class TpuChainExecutor:
                     yield mat.result()
                     mat = None
                 yield out() if callable(out) else out
-        except GeneratorExit:
-            # consumer closed us mid-stream: no further yields allowed
-            raise
-        except BaseException:
+        except BaseException as e:
+            # the stream dies with a dispatch still in flight (the batch
+            # behind the one whose finish raised, or the one ahead of a
+            # failed dispatch): nobody will finish it, so its staged
+            # bytes leave the memory ledger and the live-handle gauge
+            # here (idempotent; carries are the failure ladder's to
+            # settle, not this release's)
+            for h in (pending[1] if pending is not None else None, handle):
+                if h is not None:
+                    self._gauge_release(h)
+            if isinstance(e, GeneratorExit):
+                # consumer closed us mid-stream: no further yields allowed
+                raise
             # a later batch's dispatch/finish failure must not swallow a
             # batch that ALREADY finished and whose pure materialization
             # is in flight on the worker — the serialized path had

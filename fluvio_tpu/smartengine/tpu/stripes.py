@@ -69,6 +69,7 @@ from fluvio_tpu.smartmodule import dsl
 from fluvio_tpu.smartengine.tpu import kernels
 from fluvio_tpu.smartengine.tpu.lower import Unlowerable, apply_postops, lower_expr
 from fluvio_tpu.telemetry import TELEMETRY
+from fluvio_tpu.telemetry.spans import stage_scope
 
 STRIPE_WIDTH = 8192    # bytes per device row (pow2; must be 4-aligned)
 STRIPE_OVERLAP = 128   # shared bytes between consecutive stripes
@@ -905,25 +906,28 @@ class StripedChain:
         (start, length) view descriptors for span chains (else None)."""
         fan = None
         vspan = None
-        for kind, arg in self.ops:
-            if kind == "filter":
-                valid = valid & arg(ctx)
-            elif kind == "postops":
-                ctx["sv"] = apply_postops(ctx["sv"], arg)
-                ctx["seg_state"]["values"] = apply_postops(
-                    ctx["seg_state"]["values"], arg
-                )
-            elif kind == "span":
-                vspan = arg(ctx)
-            elif kind == "agg":
-                st = dict(ctx["seg_state"])
-                st["valid"] = valid
-                st, carries = arg.apply(st, carries, base_ts, agg_ctx)
-                ctx["seg_state"] = st
-            else:  # fanout (terminal)
-                fan = striped_split_bounds(
-                    ctx["sv"], ctx["plan"], arg, ctx["n"]
-                )
+        for i, (kind, arg) in enumerate(self.ops):
+            # device scope per striped op (the op list is the striped
+            # lowering's own: a filter_map is a filter op and a span op)
+            with jax.named_scope(stage_scope(i, kind)):
+                if kind == "filter":
+                    valid = valid & arg(ctx)
+                elif kind == "postops":
+                    ctx["sv"] = apply_postops(ctx["sv"], arg)
+                    ctx["seg_state"]["values"] = apply_postops(
+                        ctx["seg_state"]["values"], arg
+                    )
+                elif kind == "span":
+                    vspan = arg(ctx)
+                elif kind == "agg":
+                    st = dict(ctx["seg_state"])
+                    st["valid"] = valid
+                    st, carries = arg.apply(st, carries, base_ts, agg_ctx)
+                    ctx["seg_state"] = st
+                else:  # fanout (terminal)
+                    fan = striped_split_bounds(
+                        ctx["sv"], ctx["plan"], arg, ctx["n"]
+                    )
         return valid, ctx["seg_state"], carries, fan, vspan
 
 

@@ -69,6 +69,7 @@ from fluvio_tpu.smartengine.metering import SmartModuleFuelError
 from fluvio_tpu.telemetry import TELEMETRY
 from fluvio_tpu.telemetry import lag as lag_mod
 from fluvio_tpu.telemetry.registry import tenant_label
+from fluvio_tpu.telemetry.spans import timed
 from fluvio_tpu.transport.service import FluvioService
 from fluvio_tpu.transport.sink import ExclusiveSink, FluvioSink
 from fluvio_tpu.transport.socket import FluvioSocket, SocketClosed
@@ -445,11 +446,11 @@ def _schedule_chain_warmup(chain) -> None:
 
 def _process_batches_from(
     chain, batches, max_bytes, metrics, start_offset,
-    topic=None, partition=None,
+    topic=None, partition=None, flow=None,
 ):
     return process_batches(
         chain, batches, max_bytes, metrics, start_offset=start_offset,
-        topic=topic, partition=partition,
+        topic=topic, partition=partition, flow=flow,
     )
 
 
@@ -647,16 +648,25 @@ class StreamFetchHandler:
                                 "breaker-open" if rej is not None
                                 else "admit"
                             )
-                    sent_next = await self._send_back_records(
+                    sent_next, served = await self._send_back_records(
                         leader, chain, current, flow=flow
                     )
-                    flow = None
-                    if self._ended:
-                        return
-                    if sent_next > current:
-                        await self._wait_for_ack(sent_next, end_wait)
-                        current = sent_next
-                        continue
+                    served_flow, flow = flow, None
+                    try:
+                        if self._ended:
+                            return
+                        if sent_next > current:
+                            await self._wait_for_ack(
+                                sent_next, end_wait, flow=served_flow
+                            )
+                            current = sent_next
+                            continue
+                    finally:
+                        # the flow closes AFTER its ack wait: `ack_wait`
+                        # belongs to the slice it waits for (a slice that
+                        # pushed nothing leaves no record, as before)
+                        if served is not None:
+                            TELEMETRY.end_flow(served_flow, records=served)
                 # no data (or empty slice): wait for the log to advance
                 listener = leader.offset_publisher(req.isolation).change_listener()
                 if leader.read_bound(req.isolation) > current:
@@ -723,8 +733,8 @@ class StreamFetchHandler:
                 ):
                     nxt_flow.decision = "admit"
                 try:
-                    rslice = leader.read_records(
-                        planned, req.max_bytes, req.isolation
+                    _rslice, nxt_batches = self._read_slice(
+                        leader, planned, nxt_flow
                     )
                 except FluvioError as e:
                     info = leader.offsets()
@@ -732,8 +742,7 @@ class StreamFetchHandler:
                         e.code, hw=info.hw, log_start=info.start_offset
                     )
                     return
-                if rslice.file_slice is not None and rslice.next_offset is not None:
-                    nxt_batches = rslice.decode_batches(parse_records=False)
+                if nxt_batches is not None:
                     nxt = tpu_stage_dispatch(
                         chain, nxt_batches, self.metrics, start_offset=planned,
                         topic=req.topic, partition=req.partition,
@@ -752,22 +761,30 @@ class StreamFetchHandler:
                     result = await _chain_off_loop(
                         chain, process_batches_per_record,
                         chain, pending.batches, req.max_bytes, self.metrics,
+                        pending.flow,
                     )
-                sent_next = await self._push_processed(leader, result)
-                TELEMETRY.end_flow(
-                    pending.flow, records=result.records.total_records()
-                )
-                if self._ended:
-                    return
-                truncated = sent_next != pending.planned_next
-                pending = None
-                if truncated and nxt is not None:
-                    # the speculative slice read from the wrong offset
-                    # (its flow record dies with it — never served)
-                    nxt.discard(chain.tpu_chain)
-                    nxt = None
-                    nxt_batches = None
-                await self._wait_for_ack(sent_next, end_wait)
+                served_flow = pending.flow
+                served = result.records.total_records()
+                try:
+                    sent_next = await self._push_processed(
+                        leader, result, flow=served_flow
+                    )
+                    if self._ended:
+                        return
+                    truncated = sent_next != pending.planned_next
+                    pending = None
+                    if truncated and nxt is not None:
+                        # the speculative slice read from the wrong offset
+                        # (its flow record dies with it — never served)
+                        nxt.discard(chain.tpu_chain)
+                        nxt = None
+                        nxt_batches = None
+                    await self._wait_for_ack(
+                        sent_next, end_wait, flow=served_flow
+                    )
+                finally:
+                    # closed AFTER the ack wait (see `_run`)
+                    TELEMETRY.end_flow(served_flow, records=served)
                 current = sent_next
                 if truncated:
                     continue
@@ -788,18 +805,24 @@ class StreamFetchHandler:
                 result = await _chain_off_loop(
                     chain, _process_batches_from, chain, nxt_batches,
                     req.max_bytes, self.metrics, read_from,
-                    req.topic, req.partition,
+                    req.topic, req.partition, nxt_flow,
                 )
-                sent_next = await self._push_processed(leader, result)
-                TELEMETRY.end_flow(
-                    nxt_flow, records=result.records.total_records()
-                )
-                if self._ended:
-                    return
-                sent_next = max(sent_next, read_from)
-                if sent_next > current:
-                    await self._wait_for_ack(sent_next, end_wait)
-                    current = sent_next
+                try:
+                    sent_next = await self._push_processed(
+                        leader, result, flow=nxt_flow
+                    )
+                    if self._ended:
+                        return
+                    sent_next = max(sent_next, read_from)
+                    if sent_next > current:
+                        await self._wait_for_ack(
+                            sent_next, end_wait, flow=nxt_flow
+                        )
+                        current = sent_next
+                finally:
+                    TELEMETRY.end_flow(
+                        nxt_flow, records=result.records.total_records()
+                    )
                 continue
 
             # no pending, no data: wait for the log to advance
@@ -814,8 +837,29 @@ class StreamFetchHandler:
                 listen.cancel()
                 return
 
-    async def _push_processed(self, leader, result: BatchProcessResult) -> int:
-        """Send one processed-slice response; returns the next offset."""
+    def _read_slice(self, leader, offset: int, flow, decode: bool = True):
+        """One slice off the log, under its flow's ``read`` phase: the
+        leader's bounded read plus the shallow batch decode (headers
+        and raw record slabs; no per-record parse). Returns (read
+        slice, batches) — ``batches`` None when the slice is empty or
+        ``decode`` is off (the zero-copy path sends the file slice)."""
+        req = self.req
+        with timed(flow, "read"):
+            rslice = leader.read_records(offset, req.max_bytes, req.isolation)
+            batches = None
+            if (
+                decode
+                and rslice.file_slice is not None
+                and rslice.next_offset is not None
+            ):
+                batches = rslice.decode_batches(parse_records=False)
+        return rslice, batches
+
+    async def _push_processed(
+        self, leader, result: BatchProcessResult, flow=None
+    ) -> int:
+        """Send one processed-slice response (the flow's ``send``
+        phase); returns the next offset."""
         info = leader.offsets()
         partition = FetchablePartitionResponse(
             partition_index=self.req.partition,
@@ -834,9 +878,10 @@ class StreamFetchHandler:
             stream_id=self.stream_id,
             partition=partition,
         )
-        await self.sink.send_response(
-            ResponseMessage(self.correlation_id, resp), self.version
-        )
+        with timed(flow, "send"):
+            await self.sink.send_response(
+                ResponseMessage(self.correlation_id, resp), self.version
+            )
         nbytes = sum(b.write_size() for b in result.records.batches)
         self.ctx.metrics.outbound.add(result.records.total_records(), nbytes)
         if TELEMETRY.enabled and result.records.batches:
@@ -852,20 +897,25 @@ class StreamFetchHandler:
             TELEMETRY.add_tenant_age(self._tenant, age_s)
         return result.next_offset
 
-    async def _wait_for_ack(self, target: int, end_wait: asyncio.Future) -> None:
-        """Backpressure: hold the next push until the consumer acks."""
+    async def _wait_for_ack(
+        self, target: int, end_wait: asyncio.Future, flow=None
+    ) -> None:
+        """Backpressure: hold the next push until the consumer acks (the
+        ``ack_wait`` phase of the flow whose push is being acked; the
+        consumer's own decode of the response is inside it)."""
         listener = self.ack_publisher.change_listener()
-        while (
-            self.ack_publisher.current_value() < target
-            and not self.conn.end.is_set()
-        ):
-            listen = asyncio.ensure_future(listener.listen())
-            done, _ = await asyncio.wait(
-                [listen, end_wait], return_when=asyncio.FIRST_COMPLETED
-            )
-            if end_wait in done:
-                listen.cancel()
-                return
+        with timed(flow, "ack_wait"):
+            while (
+                self.ack_publisher.current_value() < target
+                and not self.conn.end.is_set()
+            ):
+                listen = asyncio.ensure_future(listener.listen())
+                done, _ = await asyncio.wait(
+                    [listen, end_wait], return_when=asyncio.FIRST_COMPLETED
+                )
+                if end_wait in done:
+                    listen.cancel()
+                    return
         if TELEMETRY.enabled:
             # the consumer's ack IS the committed offset: the lag
             # engine's join reads hw - committed from here
@@ -875,18 +925,23 @@ class StreamFetchHandler:
 
     async def _send_back_records(
         self, leader, chain, offset: int, flow=None
-    ) -> int:
-        """Push one chunk; returns the next offset (== offset if nothing sent)."""
+    ) -> tuple:
+        """Push one chunk; returns (next offset, records served): the
+        offset unchanged and None when nothing was sent. ``flow`` picks
+        up the slice's phases here; the CALLER closes it, after the ack
+        wait."""
         req = self.req
         try:
-            rslice = leader.read_records(offset, req.max_bytes, req.isolation)
+            rslice, batches = self._read_slice(
+                leader, offset, flow, decode=chain is not None
+            )
         except FluvioError as e:
             info = leader.offsets()
             await self._send_error(e.code, hw=info.hw, log_start=info.start_offset)
             self._ended = True
-            return offset
+            return offset, None
         if rslice.file_slice is None or rslice.next_offset is None:
-            return offset
+            return offset, None
 
         info = rslice.start
         if chain is None:
@@ -908,19 +963,18 @@ class StreamFetchHandler:
                 header.bytes(), [rslice.file_slice]
             )
             self.ctx.metrics.outbound.add(0, rslice.file_slice.length)
-            return rslice.next_offset
+            return rslice.next_offset, None
 
         # SmartModule path: decode -> chain -> re-batch -> push.
-        # Shallow decode: the TPU fast path stages raw record slabs into
-        # columnar buffers natively; the per-record path parses on demand.
-        batches = rslice.decode_batches(parse_records=False)
+        # Shallow decode (`_read_slice`): the TPU fast path stages raw
+        # record slabs into columnar buffers natively; the per-record
+        # path parses on demand.
         result: BatchProcessResult = await _chain_off_loop(
             chain, _process_batches_from, chain, batches, req.max_bytes,
-            self.metrics, offset, req.topic, req.partition,
+            self.metrics, offset, req.topic, req.partition, flow,
         )
-        sent_next = await self._push_processed(leader, result)
-        TELEMETRY.end_flow(flow, records=result.records.total_records())
-        return max(sent_next, offset)
+        sent_next = await self._push_processed(leader, result, flow=flow)
+        return max(sent_next, offset), result.records.total_records()
 
     async def _send_error(
         self,

@@ -103,6 +103,7 @@ from fluvio_tpu.smartmodule.types import (
 from fluvio_tpu.spu.context import GlobalContext
 from fluvio_tpu.spu.replica import LeaderReplicaState
 from fluvio_tpu.telemetry import TELEMETRY
+from fluvio_tpu.telemetry.spans import timed
 from fluvio_tpu.types import NO_TIMESTAMP
 
 
@@ -578,133 +579,139 @@ def tpu_stage_dispatch(
         return _decline(metrics, "breaker-open")
     t_stage0 = time.perf_counter() if TELEMETRY.enabled else 0.0
     glz_decode_s = 0.0
-    staged: List[tuple] = []
-    total_raw = 0
-    for batch in batches:
-        raw = batch.raw_records
-        if raw is None:
-            return _decline(metrics, "no-raw-records")
-        if batch.header.compression() != Compression.NONE:
-            if TELEMETRY.enabled:
-                t_dc = time.perf_counter()
-                raw = decompress(batch.header.compression(), raw)
-                glz_decode_s += time.perf_counter() - t_dc
-            else:
-                raw = decompress(batch.header.compression(), raw)
-        cols = native_backend.decode_record_columns_aligned(raw)
-        if cols is None:
-            return _decline(metrics, "no-native-decoder")
-        if cols["count"] != batch.records_len() or cols["parsed"] != len(raw):
-            return _decline(metrics, "malformed-slab")
-        staged.append((batch, cols))
-        total_raw += len(raw)
-    # the per-record path's input-size guard (engine.py StoreMemoryExceeded)
-    engine = getattr(chain, "engine", None)
-    if engine is not None and total_raw > engine.store_max_memory:
-        return _decline(metrics, "store-memory")  # per-record path raises
+    # flow phases (one clock pair each, per SLICE): `wire_decode` is the
+    # native decode loop and its guards, net of stored-batch
+    # decompression, which it is cut from (`ph.less`)
+    with timed(flow, "wire_decode") as ph:
+        staged: List[tuple] = []
+        total_raw = 0
+        for batch in batches:
+            raw = batch.raw_records
+            if raw is None:
+                return _decline(metrics, "no-raw-records")
+            if batch.header.compression() != Compression.NONE:
+                if TELEMETRY.enabled:
+                    t_dc = time.perf_counter()
+                    raw = decompress(batch.header.compression(), raw)
+                    glz_decode_s += time.perf_counter() - t_dc
+                    ph.less = glz_decode_s
+                else:
+                    raw = decompress(batch.header.compression(), raw)
+            cols = native_backend.decode_record_columns_aligned(raw)
+            if cols is None:
+                return _decline(metrics, "no-native-decoder")
+            if cols["count"] != batch.records_len() or cols["parsed"] != len(raw):
+                return _decline(metrics, "malformed-slab")
+            staged.append((batch, cols))
+            total_raw += len(raw)
+        # the per-record path's input-size guard (engine.py StoreMemoryExceeded)
+        engine = getattr(chain, "engine", None)
+        if engine is not None and total_raw > engine.store_max_memory:
+            return _decline(metrics, "store-memory")  # per-record path raises
 
-    # Coalesce the whole read slice into ONE device dispatch: per-batch
-    # dispatches pay fixed host<->device round trips that dwarf a 16k-record
-    # batch's compute. Offset deltas rebase to the first batch's base
-    # offset; timestamp deltas rebase to its base timestamp.
-    base0 = staged[0][0].base_offset
-    ts0 = staged[0][0].header.first_timestamp
-    ts_list = [b.header.first_timestamp for b, _ in staged]
-    if any(t < 0 for t in ts_list) and any(t >= 0 for t in ts_list):
-        # mixed absent/present base timestamps: rebase undefined
-        return _decline(metrics, "mixed-base-timestamps")
-    merged = {
-        "count": sum(c["count"] for _, c in staged),
-        # per-batch flats are 4-aligned (every record padded to 4), so a
-        # straight concat preserves alignment for the whole slice
-        "val_flat": np.concatenate([c["val_flat"] for _, c in staged]),
-        "val_len": np.concatenate([c["val_len"] for _, c in staged]),
-        "key_flat": np.concatenate([c["key_flat"] for _, c in staged]),
-        "key_present": np.concatenate([c["key_present"] for _, c in staged]),
-    }
-    off_parts, ts_parts, val_offs, key_offs = [], [], [], []
-    v_base = k_base = 0
-    for b, c in staged:
-        off_parts.append(c["off_delta"] + (b.base_offset - base0))
-        ts_parts.append(
-            c["ts_delta"] + (b.header.first_timestamp - ts0 if ts0 >= 0 else 0)
-        )
-        val_offs.append(c["val_off"][:-1] + v_base)
-        key_offs.append(c["key_off"][:-1] + k_base)
-        v_base += int(c["val_off"][-1])
-        k_base += int(c["key_off"][-1])
-    merged["off_delta"] = np.concatenate(off_parts)
-    merged["ts_delta"] = np.concatenate(ts_parts)
-    merged["val_off"] = np.concatenate(
-        [np.concatenate(val_offs), np.array([v_base], dtype=np.int64)]
-    )
-    merged["key_off"] = np.concatenate(
-        [np.concatenate(key_offs), np.array([k_base], dtype=np.int64)]
-    )
-    # Chunked dispatch (stateless chains): one huge slice is one device
-    # call with ZERO overlap — host staging, device compute, and result
-    # materialization run strictly serially. Splitting into fixed-size
-    # record chunks and dispatching them ALL up front keeps every chunk
-    # in flight while the first one downloads/encodes, so the slice's
-    # wall time approaches max(host, device) instead of the sum. Equal
-    # chunk sizes reuse one compiled shape bucket. Stateful chains chain
-    # their carries through dispatch order (safe), but fan-out capacity
-    # retries and aggregate delta-fetches are tuned for one dispatch —
-    # keep those single-chunk.
-    n_total = merged["count"]
-    chunk_rows = _DISPATCH_CHUNK_ROWS
-    stateless = not tpu.agg_configs and not tpu._fanout
-    if stateless and n_total > chunk_rows * 3 // 2:
-        bounds = list(range(0, n_total, chunk_rows)) + [n_total]
-        if bounds[-1] == bounds[-2]:
-            bounds.pop()
-    else:
-        bounds = [0, n_total]  # n_total == 0 still stages one empty chunk
-    # whole-slice width guard BEFORE any dispatch: a too-wide record
-    # declines the slice without leaving earlier chunks' device work
-    # abandoned mid-flight. The bound is the CHAIN's: stripe-capable
-    # chains stage wide records as striped segments (tpu/stripes.py) up
-    # to the hard ceiling, others decline at the narrow layout width.
-    if n_total and int(merged["val_len"].max()) > tpu.max_stageable_width():
-        return _decline(metrics, "record-too-wide")
-    # EVERY chunk builds (and passes its guards) before ANY dispatch:
-    # a mid-loop decline (staging-cap depends on each chunk's local
-    # padded width) must never abandon earlier chunks' in-flight device
-    # work. The build pass is view-based numpy slicing (flat-backed
-    # buffers are born in upload form), so the device idles ~ms per
-    # slice for it — the invariant is worth more than the overlap.
-    chunk_bufs: List = []
-    for lo, hi in zip(bounds[:-1], bounds[1:]):
-        part = _slice_columns(merged, lo, hi)
-        try:
-            buf = RecordBuffer.from_flat(
-                part, base_offset=base0, base_timestamp=ts0
+    with timed(flow, "stage"):
+        # Coalesce the whole read slice into ONE device dispatch: per-batch
+        # dispatches pay fixed host<->device round trips that dwarf a 16k-record
+        # batch's compute. Offset deltas rebase to the first batch's base
+        # offset; timestamp deltas rebase to its base timestamp.
+        base0 = staged[0][0].base_offset
+        ts0 = staged[0][0].header.first_timestamp
+        ts_list = [b.header.first_timestamp for b, _ in staged]
+        if any(t < 0 for t in ts_list) and any(t >= 0 for t in ts_list):
+            # mixed absent/present base timestamps: rebase undefined
+            return _decline(metrics, "mixed-base-timestamps")
+        merged = {
+            "count": sum(c["count"] for _, c in staged),
+            # per-batch flats are 4-aligned (every record padded to 4), so a
+            # straight concat preserves alignment for the whole slice
+            "val_flat": np.concatenate([c["val_flat"] for _, c in staged]),
+            "val_len": np.concatenate([c["val_len"] for _, c in staged]),
+            "key_flat": np.concatenate([c["key_flat"] for _, c in staged]),
+            "key_present": np.concatenate([c["key_present"] for _, c in staged]),
+        }
+        off_parts, ts_parts, val_offs, key_offs = [], [], [], []
+        v_base = k_base = 0
+        for b, c in staged:
+            off_parts.append(c["off_delta"] + (b.base_offset - base0))
+            ts_parts.append(
+                c["ts_delta"] + (b.header.first_timestamp - ts0 if ts0 >= 0 else 0)
             )
-        except ValueError:  # value beyond the hard ceiling: per-record path
+            val_offs.append(c["val_off"][:-1] + v_base)
+            key_offs.append(c["key_off"][:-1] + k_base)
+            v_base += int(c["val_off"][-1])
+            k_base += int(c["key_off"][-1])
+        merged["off_delta"] = np.concatenate(off_parts)
+        merged["ts_delta"] = np.concatenate(ts_parts)
+        merged["val_off"] = np.concatenate(
+            [np.concatenate(val_offs), np.array([v_base], dtype=np.int64)]
+        )
+        merged["key_off"] = np.concatenate(
+            [np.concatenate(key_offs), np.array([k_base], dtype=np.int64)]
+        )
+        # Chunked dispatch (stateless chains): one huge slice is one device
+        # call with ZERO overlap — host staging, device compute, and result
+        # materialization run strictly serially. Splitting into fixed-size
+        # record chunks and dispatching them ALL up front keeps every chunk
+        # in flight while the first one downloads/encodes, so the slice's
+        # wall time approaches max(host, device) instead of the sum. Equal
+        # chunk sizes reuse one compiled shape bucket. Stateful chains chain
+        # their carries through dispatch order (safe), but fan-out capacity
+        # retries and aggregate delta-fetches are tuned for one dispatch —
+        # keep those single-chunk.
+        n_total = merged["count"]
+        chunk_rows = _DISPATCH_CHUNK_ROWS
+        stateless = not tpu.agg_configs and not tpu._fanout
+        if stateless and n_total > chunk_rows * 3 // 2:
+            bounds = list(range(0, n_total, chunk_rows)) + [n_total]
+            if bounds[-1] == bounds[-2]:
+                bounds.pop()
+        else:
+            bounds = [0, n_total]  # n_total == 0 still stages one empty chunk
+        # whole-slice width guard BEFORE any dispatch: a too-wide record
+        # declines the slice without leaving earlier chunks' device work
+        # abandoned mid-flight. The bound is the CHAIN's: stripe-capable
+        # chains stage wide records as striped segments (tpu/stripes.py) up
+        # to the hard ceiling, others decline at the narrow layout width.
+        if n_total and int(merged["val_len"].max()) > tpu.max_stageable_width():
             return _decline(metrics, "record-too-wide")
-        # dense-amplification guard: one huge value would pad every
-        # row of the DEVICE-side re-padded matrix (rows x width in
-        # HBM) to its pow2 width — the host stays flat-backed either way
-        if buf.rows * buf.width > _MAX_STAGING_BYTES:
-            return _decline(metrics, "staging-cap")
-        if tpu._fanout:
-            # fan-out outputs inherit their source batch's rebase
-            # deltas ("fresh" records, delta 0 relative to their own
-            # batch); fan-out is always single-chunk so the staged
-            # batch walk covers the whole slice
-            rows = buf.offset_deltas.shape[0]
-            fo = np.zeros(rows, dtype=np.int32)
-            ft = np.zeros(rows, dtype=np.int64)
-            pos = 0
-            for b, c in staged:
-                n_b = c["count"]
-                fo[pos : pos + n_b] = b.base_offset - base0
-                if ts0 >= 0:
-                    ft[pos : pos + n_b] = b.header.first_timestamp - ts0
-                pos += n_b
-            buf.fresh_offset_deltas = fo
-            buf.fresh_timestamp_deltas = ft
-        chunk_bufs.append(buf)
+        # EVERY chunk builds (and passes its guards) before ANY dispatch:
+        # a mid-loop decline (staging-cap depends on each chunk's local
+        # padded width) must never abandon earlier chunks' in-flight device
+        # work. The build pass is view-based numpy slicing (flat-backed
+        # buffers are born in upload form), so the device idles ~ms per
+        # slice for it — the invariant is worth more than the overlap.
+        chunk_bufs: List = []
+        for lo, hi in zip(bounds[:-1], bounds[1:]):
+            part = _slice_columns(merged, lo, hi)
+            try:
+                buf = RecordBuffer.from_flat(
+                    part, base_offset=base0, base_timestamp=ts0
+                )
+            except ValueError:  # value beyond the hard ceiling: per-record path
+                return _decline(metrics, "record-too-wide")
+            # dense-amplification guard: one huge value would pad every
+            # row of the DEVICE-side re-padded matrix (rows x width in
+            # HBM) to its pow2 width — the host stays flat-backed either way
+            if buf.rows * buf.width > _MAX_STAGING_BYTES:
+                return _decline(metrics, "staging-cap")
+            if tpu._fanout:
+                # fan-out outputs inherit their source batch's rebase
+                # deltas ("fresh" records, delta 0 relative to their own
+                # batch); fan-out is always single-chunk so the staged
+                # batch walk covers the whole slice
+                rows = buf.offset_deltas.shape[0]
+                fo = np.zeros(rows, dtype=np.int32)
+                ft = np.zeros(rows, dtype=np.int64)
+                pos = 0
+                for b, c in staged:
+                    n_b = c["count"]
+                    fo[pos : pos + n_b] = b.base_offset - base0
+                    if ts0 >= 0:
+                        ft[pos : pos + n_b] = b.header.first_timestamp - ts0
+                    pos += n_b
+                buf.fresh_offset_deltas = fo
+                buf.fresh_timestamp_deltas = ft
+            chunk_bufs.append(buf)
     if TELEMETRY.enabled:
         # slice-level staging cost (native decode, column merge, chunk
         # builds), net of stored-batch decompression; the per-chunk
@@ -726,7 +733,12 @@ def tpu_stage_dispatch(
     # carries are already per-partition)
     pscope = _enter_partition_scope(topic, partition, tpu)
     try:
-        chunks: List[tuple] = tpu.dispatch_buffers(chunk_bufs)
+        # the chunks' BatchSpans are this phase's children: each carries
+        # the slice's flow id
+        with timed(flow, "dispatch"):
+            chunks: List[tuple] = tpu.dispatch_buffers(
+                chunk_bufs, flow_id=flow.flow_id if flow is not None else 0
+            )
     except TpuSpill:
         return _decline(metrics, "transform-error-spill")
     except (KeyboardInterrupt, SystemExit):
@@ -742,10 +754,6 @@ def tpu_stage_dispatch(
     finally:
         if pscope is not None:
             pscope.__exit__(None, None, None)
-    if flow is not None:
-        # causal flow link: the renderer joins batch spans against the
-        # [dispatch, serve] window of this slice's flow record
-        flow.mark_dispatch()
     pending = PendingSlice(
         batches=batches,
         chunks=chunks,
@@ -877,112 +885,119 @@ def _tpu_finish_inner(
     )
     outbufs = []
     finished = 0
-    try:
-        if overlap:
-            parts = []
-            for b, h in pending.chunks:
-                out = tpu.finish_buffer_deferred(b, h)
-                finished += 1
-                parts.append(
-                    tpu_executor._fetch_mat_pool().submit(out)
-                    if callable(out)
-                    else out
-                )
-            outbufs = [
-                p.result() if hasattr(p, "result") else p for p in parts
-            ]
-        else:
-            for b, h in pending.chunks:
-                outbufs.append(tpu.finish_buffer(b, h))
-                finished += 1
-    except TpuSpill:
-        # later chunks' dispatch-time D2H copies still crossed the link;
-        # discard them so the executor's byte accounting stays honest.
-        # NOT counted as a telemetry spill here: the per-record rerun
-        # re-enters chain.process, whose own TpuSpill handler counts one
-        # spill per batch — counting the slice here too would inflate
-        # spills_total for the single logical event (the slice-level
-        # decline counter below already records it once)
-        for _, h in pending.chunks[finished + 1 :]:
-            tpu.discard_dispatch(h)
-        return _decline(metrics, "transform-error-spill")
-    except (KeyboardInterrupt, SystemExit):
-        raise
-    except Exception as e:
-        # a device/fetch failure that survived the executor's bounded
-        # retries: same containment as a spill — the per-record path
-        # decides per batch (carries were rolled back by the executor)
-        for _, h in pending.chunks[finished + 1 :]:
-            tpu.discard_dispatch(h)
-        if is_program_fault(e):
-            raise  # compiler-refused program: never a per-record rerun
-        logging.getLogger(__name__).warning(
-            "fused slice finish failed (%s: %s); per-record fallback",
-            type(e).__name__, e,
-        )
-        return _decline(metrics, "fused-error")
-    outbuf = outbufs[0] if len(outbufs) == 1 else _MergedOut(outbufs)
-    n_out = outbuf.count
-    # survivors keep their stored offsets (deltas are already rebased to
-    # base0), so a consumer resuming mid-slice filters correctly
-    out_deltas = outbuf.offset_deltas[:n_out].astype(np.int64)
-    out_ts = outbuf.timestamp_deltas[:n_out].astype(np.int64)
-    drop = 0
-    stateless = not tpu.agg_configs and not tpu._fanout
-    if (
-        stateless
-        and n_out
-        and pending.read_from is not None
-        and pending.read_from > base0
-    ):
-        # resuming mid-batch: outputs below the consume cursor were
-        # already served in a previous (truncated) response — drop them
-        # so the stream always advances (survivor deltas are ascending)
-        drop = int(
-            np.searchsorted(out_deltas, pending.read_from - base0, side="left")
-        )
-        out_deltas = out_deltas[drop:]
-        out_ts = out_ts[drop:]
-        n_out -= drop
-    if n_out and stateless and max_bytes > 0:
-        # stateless chains honor max_bytes: keep the longest record prefix
-        # whose encoded size fits (>= semantics: always keep one batch's
-        # worth of progress by including at least the first record)
-        sizes = _encoded_record_sizes_at(outbuf, drop, out_deltas, out_ts)
-        cum = np.cumsum(sizes)
-        keep = int(np.searchsorted(cum, max_bytes, side="left")) + 1
-        if keep < n_out:
-            n_out = max(keep, 1)
-            result.next_offset = base0 + int(out_deltas[n_out - 1]) + 1
-    if n_out:
-        cols = outbuf.to_columns()
-        vo = cols["val_off"]
-        ko = cols["key_off"]
-        v0 = int(vo[drop])
-        k0 = int(ko[drop])
-        raw_out = native_backend.encode_record_columns(
-            cols["val_flat"][v0 : int(vo[drop + n_out])],
-            vo[drop : drop + n_out + 1] - v0,
-            cols["key_flat"][k0 : int(ko[drop + n_out])],
-            ko[drop : drop + n_out + 1] - k0,
-            cols["key_present"][drop : drop + n_out],
-            out_deltas[:n_out],
-            out_ts[:n_out],
-        )
-        if raw_out is None:
-            return _decline(metrics, "encode-failed")
-        out_batch = Batch(
-            base_offset=base0,
-            raw_records=raw_out,
-            raw_record_count=n_out,
-        )
-        now = int(time.time() * 1000) if ts0 == NO_TIMESTAMP else ts0
-        out_batch.header.first_timestamp = now
-        out_batch.header.max_time_stamp = now
-        # span the full consumed offset range so the consumer's next fetch
-        # advances past every input record (incl. filtered-out ones)
-        out_batch.header.last_offset_delta = result.next_offset - 1 - base0
-        result.records.add(out_batch)
+    flow = pending.flow
+    # `finish`: the blocking result syncs and split-back of every chunk
+    # (each chunk's BatchSpan books its own wait/d2h/fetch inside it)
+    with timed(flow, "finish"):
+        try:
+            if overlap:
+                parts = []
+                for b, h in pending.chunks:
+                    out = tpu.finish_buffer_deferred(b, h)
+                    finished += 1
+                    parts.append(
+                        tpu_executor._fetch_mat_pool().submit(out)
+                        if callable(out)
+                        else out
+                    )
+                outbufs = [
+                    p.result() if hasattr(p, "result") else p for p in parts
+                ]
+            else:
+                for b, h in pending.chunks:
+                    outbufs.append(tpu.finish_buffer(b, h))
+                    finished += 1
+        except TpuSpill:
+            # later chunks' dispatch-time D2H copies still crossed the link;
+            # discard them so the executor's byte accounting stays honest.
+            # NOT counted as a telemetry spill here: the per-record rerun
+            # re-enters chain.process, whose own TpuSpill handler counts one
+            # spill per batch — counting the slice here too would inflate
+            # spills_total for the single logical event (the slice-level
+            # decline counter below already records it once)
+            for _, h in pending.chunks[finished + 1 :]:
+                tpu.discard_dispatch(h)
+            return _decline(metrics, "transform-error-spill")
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except Exception as e:
+            # a device/fetch failure that survived the executor's bounded
+            # retries: same containment as a spill — the per-record path
+            # decides per batch (carries were rolled back by the executor)
+            for _, h in pending.chunks[finished + 1 :]:
+                tpu.discard_dispatch(h)
+            if is_program_fault(e):
+                raise  # compiler-refused program: never a per-record rerun
+            logging.getLogger(__name__).warning(
+                "fused slice finish failed (%s: %s); per-record fallback",
+                type(e).__name__, e,
+            )
+            return _decline(metrics, "fused-error")
+    # `encode`: output merge, resume drop, max_bytes cut, to_columns, the
+    # native record encode and the response Batch
+    with timed(flow, "encode"):
+        outbuf = outbufs[0] if len(outbufs) == 1 else _MergedOut(outbufs)
+        n_out = outbuf.count
+        # survivors keep their stored offsets (deltas are already rebased to
+        # base0), so a consumer resuming mid-slice filters correctly
+        out_deltas = outbuf.offset_deltas[:n_out].astype(np.int64)
+        out_ts = outbuf.timestamp_deltas[:n_out].astype(np.int64)
+        drop = 0
+        stateless = not tpu.agg_configs and not tpu._fanout
+        if (
+            stateless
+            and n_out
+            and pending.read_from is not None
+            and pending.read_from > base0
+        ):
+            # resuming mid-batch: outputs below the consume cursor were
+            # already served in a previous (truncated) response — drop them
+            # so the stream always advances (survivor deltas are ascending)
+            drop = int(
+                np.searchsorted(out_deltas, pending.read_from - base0, side="left")
+            )
+            out_deltas = out_deltas[drop:]
+            out_ts = out_ts[drop:]
+            n_out -= drop
+        if n_out and stateless and max_bytes > 0:
+            # stateless chains honor max_bytes: keep the longest record prefix
+            # whose encoded size fits (>= semantics: always keep one batch's
+            # worth of progress by including at least the first record)
+            sizes = _encoded_record_sizes_at(outbuf, drop, out_deltas, out_ts)
+            cum = np.cumsum(sizes)
+            keep = int(np.searchsorted(cum, max_bytes, side="left")) + 1
+            if keep < n_out:
+                n_out = max(keep, 1)
+                result.next_offset = base0 + int(out_deltas[n_out - 1]) + 1
+        if n_out:
+            cols = outbuf.to_columns()
+            vo = cols["val_off"]
+            ko = cols["key_off"]
+            v0 = int(vo[drop])
+            k0 = int(ko[drop])
+            raw_out = native_backend.encode_record_columns(
+                cols["val_flat"][v0 : int(vo[drop + n_out])],
+                vo[drop : drop + n_out + 1] - v0,
+                cols["key_flat"][k0 : int(ko[drop + n_out])],
+                ko[drop : drop + n_out + 1] - k0,
+                cols["key_present"][drop : drop + n_out],
+                out_deltas[:n_out],
+                out_ts[:n_out],
+            )
+            if raw_out is None:
+                return _decline(metrics, "encode-failed")
+            out_batch = Batch(
+                base_offset=base0,
+                raw_records=raw_out,
+                raw_record_count=n_out,
+            )
+            now = int(time.time() * 1000) if ts0 == NO_TIMESTAMP else ts0
+            out_batch.header.first_timestamp = now
+            out_batch.header.max_time_stamp = now
+            # span the full consumed offset range so the consumer's next fetch
+            # advances past every input record (incl. filtered-out ones)
+            out_batch.header.last_offset_delta = result.next_offset - 1 - base0
+            result.records.add(out_batch)
     # metrics only after the last possible fallback return: the per-record
     # path re-counts bytes_in when this path bails out
     if metrics is not None:
@@ -1009,16 +1024,18 @@ def _tpu_process_batches(
     start_offset: Optional[int] = None,
     topic: Optional[str] = None,
     partition: Optional[int] = None,
+    flow=None,
 ) -> Optional[BatchProcessResult]:
     """Coalesced TPU fast path, serial form: stage+dispatch then finish.
 
     The stream-fetch handler's pipelined loop uses the two phases
     directly so slice k+1 dispatches while slice k downloads and hits
-    the socket.
+    the socket. ``flow`` is the slice's flow record: both loops' slices
+    carry one, so both record the same phases.
     """
     pending = tpu_stage_dispatch(
         chain, batches, metrics, start_offset,
-        topic=topic, partition=partition,
+        topic=topic, partition=partition, flow=flow,
     )
     if pending is None:
         return None
@@ -1036,6 +1053,7 @@ def process_batches(
     start_offset: Optional[int] = None,
     topic: Optional[str] = None,
     partition: Optional[int] = None,
+    flow=None,
 ) -> BatchProcessResult:
     """Run stored batches through the chain, re-batch the outputs.
 
@@ -1053,11 +1071,13 @@ def process_batches(
     """
     fast = _tpu_process_batches(
         chain, batches, max_bytes, metrics, start_offset,
-        topic=topic, partition=partition,
+        topic=topic, partition=partition, flow=flow,
     )
     if fast is not None:
         return fast
-    return process_batches_per_record(chain, batches, max_bytes, metrics)
+    return process_batches_per_record(
+        chain, batches, max_bytes, metrics, flow=flow
+    )
 
 
 def process_batches_per_record(
@@ -1065,11 +1085,24 @@ def process_batches_per_record(
     batches: List[Batch],
     max_bytes: int,
     metrics=None,
+    flow=None,
 ) -> BatchProcessResult:
     """The interpreting per-batch loop (exact reference semantics);
     also the direct target for slices the fast path already declined —
     re-entering `process_batches` would re-stage and re-dispatch the
-    failed slice and double-count the fallback metrics."""
+    failed slice and double-count the fallback metrics. Books ONE flow
+    phase, ``interpret``, so a declined slice is not a hole in its
+    flow."""
+    with timed(flow, "interpret"):
+        return _process_batches_per_record(chain, batches, max_bytes, metrics)
+
+
+def _process_batches_per_record(
+    chain: SmartModuleChainInstance,
+    batches: List[Batch],
+    max_bytes: int,
+    metrics=None,
+) -> BatchProcessResult:
     result = BatchProcessResult()
     total_bytes = 0
     for batch in batches:

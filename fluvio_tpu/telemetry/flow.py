@@ -13,11 +13,37 @@ up wall-positioned lifecycle phases as the slice moves —
 - ``serve``       arrival -> served end-to-end (recorded implicitly
                   from ``t0``/``t_end`` at close),
 
-— and closes when the slice's output is served back. Completed flows
+and the served path's own steps, each one clock pair per SLICE taken
+where the work happens (`spans.timed(flow, phase)`), so the slice is
+the root span of everything the serving task did for it —
+
+- ``read``        `leader.read_records` + the shallow batch decode
+                  (both stream loops of `spu/public_service.py`),
+- ``wire_decode`` the native per-batch wire decode and its guards in
+                  `tpu_stage_dispatch`, net of stored-batch
+                  decompression (that stays the ``glz_decode`` phase
+                  histogram),
+- ``stage``       column merge, chunk bounds, chunk buffer builds,
+- ``dispatch``    wall of `dispatch_buffers`; the chunks' `BatchSpan`s
+                  (each carrying this flow's id) are its children,
+- ``finish``      wall of `tpu_finish`'s chunk loop: the blocking
+                  result syncs and the split-back of every chunk,
+- ``encode``      output merge, resume drop, ``max_bytes`` cut,
+                  `to_columns`, the native record encode, `Batch` build,
+- ``send``        `sink.send_response`,
+- ``ack_wait``    until the consumer's ack reaches the pushed offset
+                  (the consumer's own decode is inside it),
+- ``interpret``   the per-record fallback pass, so a slice the fast
+                  path declined is not a hole,
+
+— and closes AFTER its ack wait (`end_flow` follows `_wait_for_ack` in
+both stream loops): ``ack_wait`` belongs to the slice it waits for, so
+``t0..t_end`` covers everything up to the consumer's ack. Completed flows
 land in a bounded :class:`FlowRing` (capacity ``FLUVIO_SLICE_RING``)
 and render as their own ``slice`` lane group in the Perfetto export,
-connected to the batch spans they rode via Chrome-trace flow events
-(``ph: s/t/f`` with a shared ``id`` — see telemetry/trace.py).
+connected to the batch spans they caused via Chrome-trace flow events
+(``ph: s/t/f`` with a shared ``id`` — see telemetry/trace.py); a span
+names its slice by ``flow_id``.
 
 Cost contract: one object + a handful of clock reads per SLICE (never
 per record, never per batch chunk); `PipelineTelemetry.begin_flow`
@@ -34,7 +60,11 @@ from fluvio_tpu.telemetry.spans import _BoundedRing
 
 #: fixed slice-phase vocabulary (the registry's per-phase histograms
 #: and the Prometheus ``slice_wait_seconds`` family key on it)
-SLICE_PHASES = ("queue_wait", "batcher", "hold", "serve")
+SLICE_PHASES = (
+    "queue_wait", "batcher", "hold", "serve",
+    "read", "wire_decode", "stage", "dispatch", "finish", "encode", "send",
+    "ack_wait", "interpret",
+)
 
 
 class SliceFlow:
@@ -51,7 +81,7 @@ class SliceFlow:
 
     __slots__ = (
         "flow_id", "chain", "tenant", "t0", "t_end", "records", "phases",
-        "decision", "holds", "cause", "sources", "dispatch_t",
+        "decision", "holds", "cause", "sources", "batch_id",
         "_q_t0", "_b_t0",
     )
 
@@ -73,9 +103,11 @@ class SliceFlow:
         #: flows only) — "which batch did this slice ride, and why"
         self.cause: Optional[str] = None
         self.sources = 0
-        #: when the slice's device dispatch was enqueued (the renderer
-        #: joins batch spans against [dispatch_t, t_end])
-        self.dispatch_t: Optional[float] = None
+        #: the ``flow_id`` the slice's batch spans carry: its own, or —
+        #: when the batcher coalesced it behind another tenant's slice
+        #: into ONE dispatch — the lead slice's (the renderer and the
+        #: benchmark's readers join spans to flows on it)
+        self.batch_id = flow_id
         self._q_t0: Optional[float] = None
         self._b_t0: Optional[float] = None
 
@@ -110,9 +142,6 @@ class SliceFlow:
             now = time.perf_counter()
             self.add_phase("batcher", self._b_t0, now - self._b_t0)
             self._b_t0 = None
-
-    def mark_dispatch(self) -> None:
-        self.dispatch_t = time.perf_counter()
 
     def close(self, records: int = 0) -> None:
         self.t_end = time.perf_counter()
@@ -156,6 +185,12 @@ class SliceFlow:
             d["phases_ms"] = {
                 k: round(v * 1000, 3) for k, v in totals.items()
             }
+            # wall-positioned: readers and gap attribution need where a
+            # phase sat, not only how long it was
+            d["phases"] = [
+                [name, round(start, 6), round(s, 6)]
+                for name, start, s in self.phases
+            ]
         return d
 
 

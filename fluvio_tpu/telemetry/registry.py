@@ -227,11 +227,11 @@ class PipelineTelemetry:
     # -- span lifecycle ------------------------------------------------------
 
     def begin_batch(
-        self, path: str = "fused", chain: str = ""
+        self, path: str = "fused", chain: str = "", flow_id: int = 0
     ) -> Optional[BatchSpan]:
         if not self.enabled:
             return None
-        return BatchSpan(path, chain)
+        return BatchSpan(path, chain, flow_id)
 
     def end_batch(self, span: Optional[BatchSpan], records: int = 0) -> None:
         if span is None:

@@ -109,7 +109,8 @@ def span_trace_events(span: BatchSpan, lane: int, base: float) -> List[dict]:
             "tid": tid,
             "ts": _us(span.t0, base),
             "dur": round(max(t_end - span.t0, 0.0) * 1e6, 3),
-            "args": {"path": span.path, "records": span.records},
+            "args": {"path": span.path, "records": span.records}
+            | ({"flow_id": span.flow_id} if span.flow_id else {}),
         }
     ]
     cursor = span.t0
@@ -133,38 +134,23 @@ def span_trace_events(span: BatchSpan, lane: int, base: float) -> List[dict]:
     return out
 
 
-def _flow_matches_span(flow: SliceFlow, span: BatchSpan) -> bool:
-    """Does this batch span plausibly carry (part of) this slice's
-    work? Join rule: base chain signatures agree (a flow keyed
-    ``sig@topic/partition`` matches spans labelled ``sig`` or
-    ``sig@...``) and the span overlaps the flow's dispatch->serve
-    window."""
-    if span.t_end is None:
-        return False
-    lo = flow.dispatch_t if flow.dispatch_t is not None else flow.t0
-    hi = flow.t_end if flow.t_end is not None else lo
-    if span.t_end < lo or span.t0 > hi:
-        return False
-    fbase = (flow.chain or "").split("@", 1)[0]
-    sbase = (span.chain or "").split("@", 1)[0]
-    return not fbase or not sbase or fbase == sbase
-
-
 def flow_trace_events(
     flow: SliceFlow,
     lane: int,
     base: float,
     span_tracks: Optional[List[tuple]] = None,
 ) -> List[dict]:
-    """One slice envelope on the ``slice`` lane group, its lifecycle
-    phases (hold / queue-wait / batcher) at their wall positions, and
-    the Chrome-trace flow chain: ``s`` (arrival) on the slice track,
-    one ``t`` step per batch span the slice rode (bound to that span's
-    track by ts), and ``f`` at serve — so Perfetto draws arrows from
-    slice arrival through the coalesced batch to the served response.
-    ``span_tracks`` is ``[(BatchSpan, tid)]`` from the span pass; the
-    continuous sink passes None (it renders incrementally and leaves
-    the batch join to the on-demand renderer)."""
+    """One slice envelope on the ``slice`` lane group, its phases
+    (hold / queue-wait / batcher, and the served path's read ..
+    ack_wait) at their wall positions, and the Chrome-trace flow chain:
+    ``s`` (arrival) on the slice track, one ``t`` step per batch span
+    the slice caused (bound to that span's track by ts), and ``f`` at
+    serve — so Perfetto draws arrows from slice arrival through its
+    dispatches to the served response. ``span_tracks`` is the
+    ``[(BatchSpan, tid)]`` of the spans that carry this flow's id (the
+    span pass groups them by ``flow_id``); the continuous sink passes
+    None (it renders incrementally and leaves the batch join to the
+    on-demand renderer)."""
     tid = _tid("slice", lane)
     t_end = flow.t_end if flow.t_end is not None else flow.t0
     args: Dict = {"flow_id": flow.flow_id, "records": flow.records}
@@ -205,13 +191,12 @@ def flow_trace_events(
             "pid": _PID}
     out.append(dict(head, ph="s", tid=tid, ts=_us(flow.t0, base)))
     for span, stid in span_tracks or ():
-        if _flow_matches_span(flow, span):
-            out.append(
-                dict(
-                    head, ph="t", tid=stid,
-                    ts=_us(max(span.t0, flow.t0), base),
-                )
+        out.append(
+            dict(
+                head, ph="t", tid=stid,
+                ts=_us(max(span.t0, flow.t0), base),
             )
+        )
     out.append(dict(head, ph="f", bp="e", tid=tid, ts=_us(t_end, base)))
     return out
 
@@ -266,13 +251,17 @@ def build_trace(
     out = list(_base_meta())
     alloc = _LaneAllocator()
     seen: set = set()
-    span_tracks: List[tuple] = []
+    # spans name the slice that caused them: {flow id: [(span, tid)]}
+    span_tracks: Dict[int, List[tuple]] = {}
     for span in sorted(spans, key=lambda s: s.t0):
         lane = alloc.lane(span)
         if (span.path, lane) not in seen:
             seen.add((span.path, lane))
             out.extend(_thread_meta(span.path, lane))
-        span_tracks.append((span, _tid(span.path, lane)))
+        if span.flow_id:
+            span_tracks.setdefault(span.flow_id, []).append(
+                (span, _tid(span.path, lane))
+            )
         out.extend(span_trace_events(span, lane, base))
     for ev in events:
         out.append(instant_trace_event(ev, base))
@@ -281,7 +270,11 @@ def build_trace(
         if ("slice", lane) not in seen:
             seen.add(("slice", lane))
             out.extend(_thread_meta("slice", lane))
-        out.extend(flow_trace_events(flow, lane, base, span_tracks))
+        out.extend(
+            flow_trace_events(
+                flow, lane, base, span_tracks.get(flow.batch_id)
+            )
+        )
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 

@@ -149,8 +149,10 @@ class TestFlowTraceParity:
         flow.hold(0.003)
         flow.decision = "admit"
         chain = _filter_chain()
-        span = TELEMETRY.begin_batch(chain=chain.tpu_chain._chain_sig)
-        flow.mark_dispatch()
+        # the span names its slice: the renderer joins on the id
+        span = TELEMETRY.begin_batch(
+            chain=chain.tpu_chain._chain_sig, flow_id=flow.flow_id
+        )
         chain.tpu_chain.process_buffer(_buf(4))
         TELEMETRY.end_batch(span, records=4)
         TELEMETRY.end_flow(flow, records=4)
@@ -317,6 +319,14 @@ class TestLagEngine:
 
 
 class TestLagKeyedShedding:
+    @staticmethod
+    def _serve(ex, flow, n: int = 8) -> None:
+        """Serve one admitted slice: the dispatch's span carries the
+        flow's id (the id join the trace renderer draws arrows on)."""
+        buf = _buf(n)
+        ex.finish_buffer(buf, ex.dispatch_buffer(buf, flow_id=flow.flow_id))
+        TELEMETRY.end_flow(flow, records=n)
+
     def _controller(self, clk):
         from fluvio_tpu.admission import AdmissionController
 
@@ -361,9 +371,7 @@ class TestLagKeyedShedding:
         # connected flow record
         flow = TELEMETRY.begin_flow(cold)
         flow.decision = "admit"
-        flow.mark_dispatch()
-        ex.process_buffer(_buf(8))
-        TELEMETRY.end_flow(flow, records=8)
+        self._serve(ex, flow)
 
         # the held hot slice keeps retrying and keeps shedding
         clk["t"] += 1.0
@@ -380,9 +388,7 @@ class TestLagKeyedShedding:
         flow = TELEMETRY.begin_flow(hot)
         flow.decision = "admit"
         flow.hold(0.002)  # the hold it survived
-        flow.mark_dispatch()
-        ex.process_buffer(_buf(8))
-        TELEMETRY.end_flow(flow, records=8)
+        self._serve(ex, flow)
 
         # every SERVED slice's flow chain is connected in the doc
         doc = render_trace()

@@ -22,6 +22,7 @@ from fluvio_tpu.protocol.record import Record
 from fluvio_tpu.smartengine import SmartEngine, SmartModuleConfig
 from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
 from fluvio_tpu.telemetry import TELEMETRY
+from fluvio_tpu.telemetry.spans import timed
 
 # records/sec delta gate; FLUVIO_TELEMETRY_GATE overrides for tuning
 GATE = float(os.environ.get("FLUVIO_TELEMETRY_GATE", "0.02"))
@@ -53,6 +54,22 @@ def _corpus_buf():
     for i, r in enumerate(records):
         r.offset_delta = i
     return RecordBuffer.from_records(records)
+
+
+def _served_slice(flow, executor, buf) -> None:
+    """One slice as the served path books it: the eight flow phases,
+    each one `timed()` clock pair + trace annotation, with the chunk's
+    span (its own phases, `wait` included) under `dispatch`/`finish`."""
+    for name in ("read", "wire_decode", "stage"):
+        with timed(flow, name):
+            pass
+    with timed(flow, "dispatch"):
+        handle = executor.dispatch_buffer(buf, flow_id=flow.flow_id)
+    with timed(flow, "finish"):
+        executor.finish_buffer(buf, handle)
+    for name in ("encode", "send", "ack_wait"):
+        with timed(flow, name):
+            pass
 
 
 def _one_pass(executor, buf) -> float:
@@ -507,8 +524,7 @@ def test_flow_tracing_armed_overhead_under_gate():
                 for _i in range(BATCHES_PER_PASS):
                     if arm == "armed":
                         f = TELEMETRY.begin_flow(sig)
-                        f.mark_dispatch()
-                        executor.process_buffer(buf)
+                        _served_slice(f, executor, buf)
                         TELEMETRY.end_flow(f, records=N_RECORDS)
                     else:
                         executor.process_buffer(buf)
@@ -617,8 +633,7 @@ def test_soak_accounting_armed_overhead_under_gate():
                         d = ctl.admit(sig, tenant="acme")
                         assert d.admitted
                         f = TELEMETRY.begin_flow(sig, tenant="acme")
-                        f.mark_dispatch()
-                        executor.process_buffer(buf)
+                        _served_slice(f, executor, buf)
                         TELEMETRY.add_tenant_served("acme", N_RECORDS)
                         TELEMETRY.add_tenant_age("acme", 0.001)
                         TELEMETRY.end_flow(f, records=N_RECORDS)

@@ -42,11 +42,17 @@ from fluvio_tpu.telemetry import (
     render_trace,
 )
 from fluvio_tpu.telemetry.spans import BatchSpan, InstantEvent, SpanRing
+from fluvio_tpu.telemetry import memory as memory_mod
 from fluvio_tpu.telemetry import trace as trace_mod
 
 
 @pytest.fixture(autouse=True)
 def _fresh_registry():
+    # the memory ledger behind `hbm_staged_bytes` survives
+    # `TELEMETRY.reset()` by design: drop it too, or a handle an earlier
+    # test FILE on this worker abandoned in flight keeps its bytes booked
+    # and the gauge never reads 0 here (tests/test_memory.py's convention)
+    memory_mod.reset_engine()
     TELEMETRY.reset()
     prior = TELEMETRY.enabled
     TELEMETRY.enabled = True
@@ -54,6 +60,7 @@ def _fresh_registry():
     TELEMETRY.enabled = prior
     TELEMETRY.trace_sink = None
     TELEMETRY.reset()
+    memory_mod.reset_engine()
 
 
 def _span(t0: float, dur: float, path: str = "fused", records: int = 8):
