@@ -166,7 +166,8 @@ REGISTRY: Tuple[EnvFlag, ...] = (
        "glz compress_link chunk size (GLZ_CHUNK)"),
     _f("FLUVIO_LINK_COMPRESS", "mode", "auto", "on|off|auto",
        "smartengine/tpu/executor.py",
-       "compressed H2D staging link policy"),
+       "compressed H2D staging: only `on` compresses; `auto` ships the "
+       "flat raw on every backend (the device inflate lost on the v5e)"),
     _f("FLUVIO_LOCKWATCH", "mode", "0", "0|1|record|assert",
        "analysis/lockwatch.py",
        "runtime lock-order watchdog (assert: raise on new edges)"),

@@ -456,11 +456,14 @@ def stage_link_columns(buf):
 
 def effective_link_compress() -> bool:
     """Resolve ``FLUVIO_LINK_COMPRESS`` (on/off/auto) to the mode
-    executors actually run with: "auto" enables it off-CPU only — on
-    the CPU backend there is no link to save. The ONE home for this
-    policy (the bench records it next to every capture)."""
-    mode = env_raw("FLUVIO_LINK_COMPRESS")
-    return mode == "on" or (mode == "auto" and jax.default_backend() != "cpu")
+    executors actually run with: only "on" compresses; "auto" ships the
+    staged flat raw on every backend. On the v5e the device-side
+    inflate ran at about 9 MB/s (283 ms per 2.6 MB flat, 95 % of the
+    north star's device time: PERF.md §6, PR 27), so compression can
+    only pay across a link slower than that, which no locally attached
+    chip has. The ONE home for this policy (the bench records it next
+    to every capture)."""
+    return env_raw("FLUVIO_LINK_COMPRESS") == "on"
 
 
 def effective_result_compact() -> bool:
@@ -727,10 +730,10 @@ class TpuChainExecutor:
         # failures retry against the handle's carry snapshot; budgets
         # come from the FLUVIO_RETRY_* env knobs at construction
         self._retry_policy = RetryPolicy()
-        # glz link compression (smartengine/tpu/glz.py): record bytes
-        # cross the H2D link compressed and inflate ON DEVICE in the
-        # same jit as the chain; tests opt in explicitly with
-        # FLUVIO_LINK_COMPRESS=on
+        # glz link compression (smartengine/tpu/glz.py): with
+        # FLUVIO_LINK_COMPRESS=on record bytes cross the H2D link
+        # compressed and inflate ON DEVICE in the same jit as the
+        # chain; unset, the flat ships raw
         # (resolved ONCE here; a runtime decode failure latches it off
         # for this executor and ships raw — `_glz_demote`)
         self._link_compress = effective_link_compress()

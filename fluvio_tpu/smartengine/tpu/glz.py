@@ -20,8 +20,13 @@ Decode algorithm (all traced, static shapes):
 
 Parity: the reference inflates wire compression on the CPU before its
 engine sees bytes (fluvio-compression/src/lib.rs); a CPU-side engine
-has nothing to gain from device-side inflation. Here it multiplies the
-effective link bandwidth by the corpus ratio (2-25x on JSON streams).
+has nothing to gain from device-side inflation. Nor has a locally
+attached chip: on the v5e the gather rounds inflate a 2.6 MB flat in
+about 283 ms (9 MB/s, 95 % of the north star's device time; PERF.md §6,
+PR 27), against 1.06 ms to ship it raw. So the up-link runs
+this decode only under ``FLUVIO_LINK_COMPRESS=on``
+(`executor.effective_link_compress`); the result ENCODER further down
+is the down-link's and is armed by its own flag.
 """
 
 from __future__ import annotations

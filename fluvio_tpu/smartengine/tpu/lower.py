@@ -30,9 +30,9 @@ BytesVal = Tuple[jnp.ndarray, jnp.ndarray]
 
 
 def _depth_over_work(env: str) -> bool:
-    """Resolve an auto/1/0 kernel-policy knob the way link compression
-    resolves: "auto" (default) picks the log-depth parallel kernel
-    off-CPU only — the TPU's VPU is latency-bound on sequential column
+    """Resolve an auto/1/0 kernel-policy knob by backend: "auto"
+    (default) picks the log-depth parallel kernel off-CPU only — the
+    TPU's VPU is latency-bound on sequential column
     scans, while CPU lanes are work-bound and the parallel forms' S x
     work multiplier measurably loses there (4-20x on the headline
     shapes). Explicit off values pin the sequential kernel; anything

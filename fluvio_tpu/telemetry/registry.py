@@ -46,7 +46,10 @@ from fluvio_tpu.telemetry.spans import (
 from fluvio_tpu.analysis.lockwatch import make_lock
 from fluvio_tpu.analysis.envreg import env_bool, env_float, env_int
 
-SPAN_RING_CAPACITY = 256
+# one entry per dispatched CHUNK: a served north-star drain dispatches
+# about 30 chunks a second once the flat ships raw (PERF.md §5), and a
+# reader of a 33 s window needs every span of it still in the ring
+SPAN_RING_CAPACITY = 4096
 EVENT_RING_CAPACITY = 512
 # completed per-slice lifecycle records retained for the flow-trace
 # export (one entry per SLICE, so 512 covers minutes of broker serving)

@@ -456,8 +456,8 @@ def test_effective_link_compress_resolution(monkeypatch):
     assert b._effective_link_compress() == "on"
     monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "off")
     assert b._effective_link_compress() == "off"
-    # unset -> "auto" resolves per backend exactly like the executor
-    # (tests pin the CPU backend, where auto means off)
+    # unset -> "auto", which the executor resolves to off on every
+    # backend (only "on" compresses the up-link: ISSUE 27)
     monkeypatch.delenv("FLUVIO_LINK_COMPRESS")
     assert b._effective_link_compress() == "off"
 

@@ -72,8 +72,9 @@ def _no_compile_cache():
 @pytest.fixture
 def as_tpu(monkeypatch):
     """Steer the repo's `auto` policies onto their TPU branch: Pallas
-    kernels for real (no interpreter), glz link compression both ways,
-    donation, the associative DFA, the fast JSON kernel."""
+    kernels for real (no interpreter), the glz result encoder on the way
+    down, donation, the associative DFA, the fast JSON kernel. The
+    up-link's `auto` ships raw on every backend (PR 27)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for flag in (
         "FLUVIO_TPU_PALLAS", "FLUVIO_LINK_COMPRESS", "FLUVIO_RESULT_COMPRESS",
@@ -235,12 +236,15 @@ NORTH_STAR = [("regex-filter", {"regex": "fluvio"}), ("json-map", {"field": "nam
 
 
 @pytest.mark.parametrize("glz", [False, True], ids=["raw-link", "glz-link"])
-def test_ragged_north_star(one_chip, as_tpu, glz):
+def test_ragged_north_star(one_chip, as_tpu, monkeypatch, glz):
     """2_filter_map at 1M records: Pallas DFA + Pallas JSON span inside
-    the fused chain, the XLA result encoder on the way down, and (glz)
-    the gather-round link decode on the way up."""
+    the fused chain, the XLA result encoder on the way down, and the
+    raw flat on the way up (what `auto` serves) or, with
+    `FLUVIO_LINK_COMPRESS=on`, the gather-round link decode."""
+    if glz:
+        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
     ex = _chain(NORTH_STAR)
-    assert ex._link_compress and ex._enc_variant == "xla"
+    assert ex._link_compress == glz and ex._enc_variant == "xla"
     hlo = _compile_ragged(ex, _json_probe(), one_chip, glz=glz)
     assert "tpu_custom_call" in hlo
 
@@ -249,12 +253,12 @@ def test_ragged_filter(one_chip, as_tpu):
     """1_filter: a literal pattern lowers to the XLA window compare (no
     Pallas kernel expected), mask-only downlink."""
     ex = _chain([("regex-filter", {"regex": "fluvio"})])
-    _compile_ragged(ex, _json_probe(), one_chip, glz=True)
+    _compile_ragged(ex, _json_probe(), one_chip, glz=ex._link_compress)
 
 
 def test_ragged_aggregate(one_chip, as_tpu):
     ex = _chain([("aggregate-field", {"field": "n", "combine": "add"})])
-    hlo = _compile_ragged(ex, _json_probe(), one_chip, glz=True)
+    hlo = _compile_ragged(ex, _json_probe(), one_chip, glz=ex._link_compress)
     assert "tpu_custom_call" in hlo  # the Pallas JSON span feeds the sum
 
 
@@ -266,7 +270,9 @@ def _compile_striped(ex, n_records, sharding):
     rec = int(probe.lengths[0])
     flat_bytes = n_records * ((rec + 3) // 4 * 4)
     rows = 1024  # pack() pads 976 records to the next pow2
-    args, kwargs = _program_args(ex, probe, rows, flat_bytes, sharding, glz=True)
+    args, kwargs = _program_args(
+        ex, probe, rows, flat_bytes, sharding, glz=ex._link_compress
+    )
     shape = type(
         "B", (), {"rows": rows, "count": n_records, "width": probe.width,
                   "lengths": np.full(rows, rec, np.int32)},

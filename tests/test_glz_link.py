@@ -585,19 +585,23 @@ def test_gather_decode_compile_size_gate():
     assert wall < 60.0, f"glz gather decode compile took {wall:.1f}s"
 
 
+def _trip_glz(monkeypatch):
+    """Every glz entry point the staging could reach raises."""
+
+    def tripwire(*a, **k):
+        raise AssertionError("glz touched with link compression off")
+
+    for name in ("compress_link", "compress", "decompress_device"):
+        monkeypatch.setattr(glz, name, tripwire)
+
+
 def test_variant_chooser_zero_cost_when_disabled(monkeypatch):
     """With link compression off, the staging-variant chooser must cost
     NOTHING per dispatch: no compressor calls, no glz module work at
     all (the overhead-gate companion to the perf
     arms in test_telemetry_overhead.py)."""
-    monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)  # auto->off on CPU
-
-    def tripwire(*a, **k):
-        raise AssertionError("glz touched with link compression off")
-
-    monkeypatch.setattr(glz, "compress_link", tripwire)
-    monkeypatch.setattr(glz, "compress", tripwire)
-    monkeypatch.setattr(glz, "decompress_device", tripwire)
+    monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)  # auto -> off
+    _trip_glz(monkeypatch)
     vals = _json_vals(2000)
     specs = [("regex-filter", {"regex": "fluvio"})]
     chain = _build("tpu", specs)
@@ -648,3 +652,93 @@ def test_preflight_predicts_raw_when_disabled(monkeypatch):
     monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "off")
     pred = preflight_for_specs([("regex-filter", {"regex": "fluvio"})], 64)
     assert pred["link_variant"] == "raw"
+
+
+# ---------------------------------------------------------------------------
+# Policy (ISSUE 27): `auto` ships the staged flat raw on an attached
+# chip too; `on` is the only way onto the compressed link
+# ---------------------------------------------------------------------------
+
+
+def _compressible_buf():
+    """A 64 KiB ragged flat of the north star's JSON shape (about half
+    its bytes are matches for the compressor)."""
+    from fluvio_tpu.protocol.record import Record
+    from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
+    from fluvio_tpu.smartmodule import SmartModuleInput
+
+    vals, size = [], 0
+    for v in _json_vals(4000):
+        if size + len(v) > 64 * 1024:
+            break
+        vals.append(v)
+        size += len(v)
+    records = [Record(value=v) for v in vals]
+    for i, r in enumerate(records):
+        r.offset_delta = i
+    buf = RecordBuffer.from_smartmodule_input(
+        SmartModuleInput.from_records(records)
+    )
+    return vals, buf
+
+
+@pytest.fixture
+def attached_chip(monkeypatch):
+    """`jax.default_backend()` says "tpu" to the `auto` policies. The
+    other autos that read the same call are pinned to their CPU side:
+    the program still runs on the CPU (donation warns there, and a
+    Pallas kernel outside the interpreter does not lower)."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("FLUVIO_DONATE", "off")
+    monkeypatch.setenv("FLUVIO_RESULT_COMPRESS", "off")
+    monkeypatch.setenv("FLUVIO_TPU_PALLAS", "0")
+
+
+@pytest.mark.parametrize("flag", [None, "auto", "off"])
+def test_auto_ships_raw_on_an_attached_chip(monkeypatch, attached_chip, flag):
+    from fluvio_tpu.smartengine.tpu.executor import effective_link_compress
+    from fluvio_tpu.telemetry import TELEMETRY
+
+    if flag is None:
+        monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)
+    else:
+        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", flag)
+    assert not effective_link_compress()
+    _trip_glz(monkeypatch)
+    specs = [("regex-filter", {"regex": "fluvio"})]
+    vals, buf = _compressible_buf()
+    ex = _build("tpu", specs).tpu_chain
+    assert not ex._link_compress
+    # no compress-ahead job for the stream loops to schedule
+    assert ex._precompress_fn(buf) is None
+    lv0 = TELEMETRY.link_variant_counts()
+    outs = list(ex.process_stream(iter([buf])))
+    lv = TELEMETRY.link_variant_counts()
+    assert lv.get("raw", 0) - lv0.get("raw", 0) == 1
+    assert lv.get("glz-gather", 0) == lv0.get("glz-gather", 0)
+    assert getattr(buf, "_glz_cache", None) is None
+    assert outs[0].count == sum(b"fluvio" in v for v in vals)
+
+
+def test_flag_on_still_ships_glz_gather(monkeypatch, attached_chip):
+    """The path the next `simplicity` issue deletes is honest until
+    then: pinned on, the same buffer crosses compressed, inflates on the
+    device and comes back byte-equal."""
+    from fluvio_tpu.smartengine.tpu.executor import effective_link_compress
+    from fluvio_tpu.telemetry import TELEMETRY
+
+    monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
+    assert effective_link_compress()
+    specs = [("regex-filter", {"regex": "fluvio"}), ("json-map", {"field": "name"})]
+    vals, buf = _compressible_buf()
+    chain = _build("tpu", specs)
+    ex = chain.tpu_chain
+    assert ex._link_compress and ex._precompress_fn(buf) is not None
+    lv0 = TELEMETRY.link_variant_counts()
+    got = _run_chain(chain, vals)
+    lv = TELEMETRY.link_variant_counts()
+    assert lv.get("glz-gather", 0) - lv0.get("glz-gather", 0) == 1
+    assert lv.get("raw", 0) == lv0.get("raw", 0)
+    assert got == _run_chain(_build("python", specs), vals)

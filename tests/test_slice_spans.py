@@ -287,6 +287,58 @@ def test_byte_mode_program_packs_its_payload(both_links):
                      "pack", "link_encode"}, found
 
 
+@pytest.mark.parametrize("config_name", [
+    "fluvio-northstar-1p", "fluvio-array-explode-1p",
+], ids=["pipelined-loop", "serial-loop"])
+@pytest.mark.parametrize("flag", [None, "on"], ids=["unset", "on"])
+def test_served_slice_inflates_only_when_pinned_on(
+        tmp_path, monkeypatch, config_name, flag):
+    """ISSUE-27: the program a served slice runs takes its flat raw
+    unless `FLUVIO_LINK_COMPRESS=on`: no `link_decode` scope in it, and
+    every dispatched chunk books the `raw` link variant."""
+    from fluvio_tpu.smartengine.tpu.executor import TpuChainExecutor
+
+    if flag is None:
+        monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)
+    else:
+        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", flag)
+    calls = []
+    init = TpuChainExecutor.__init__
+
+    def spying_init(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        jit = self._jit_ragged
+
+        def spy(*a, **k):
+            calls.append((jit, a, k))
+            return jit(*a, **k)
+
+        self._jit_ragged = spy
+
+    monkeypatch.setattr(TpuChainExecutor, "__init__", spying_init)
+    lv0 = TELEMETRY.link_variant_counts()
+    responses, counts = _serve_two_slices(tmp_path, config_name)
+    assert len(responses) == 2
+    assert counts["fastpath_slices"] >= 2 and counts["fallback_slices"] == 0
+    lv = TELEMETRY.link_variant_counts()
+    # the family also counts the down-link's forms; the chain's
+    # 2-record warm-up ships raw under either setting (`glz-below-min`),
+    # and a fan-out slice that outgrows its capacity dispatches again
+    up = {k: lv.get(k, 0) - lv0.get(k, 0) for k in ("raw", "glz-gather")}
+    if flag == "on":
+        assert up["glz-gather"] >= 2, lv
+    else:
+        assert up["glz-gather"] == 0 and up["raw"] >= 2, lv
+
+    served = [c for c in calls if c[1][1].shape[0] >= PER_BATCH]
+    assert served
+    jit, args, kwargs = served[-1]
+    assert bool(kwargs["glz_bytes"]) == (flag == "on")
+    found = _scopes_in(jit.__wrapped__.lower(*args, **kwargs).compile().as_text())
+    assert "repad" in found
+    assert ("link_decode" in found) == (flag == "on"), found
+
+
 def test_striped_program_carries_its_scopes(monkeypatch):
     for k, v in (("THRESHOLD", "64"), ("WIDTH", "64"), ("OVERLAP", "16")):
         monkeypatch.setenv(f"FLUVIO_STRIPE_{k}", v)

@@ -244,9 +244,9 @@ def test_lockwatch_seam_zero_cost_when_disabled(monkeypatch):
 
 
 def test_glz_chooser_zero_cost_when_disabled(monkeypatch):
-    """ISSUE-8 CI satellite: with link compression off (the CPU
-    default), the staging-variant chooser must be ZERO work per
-    dispatch — the variant resolves once at executor build, and the
+    """ISSUE-8 CI satellite: with link compression off (the default
+    on every backend), the staging-variant chooser must be ZERO work
+    per dispatch — the variant resolves once at executor build, and the
     raw staging path never touches the glz module, the compressor, or
     the pallas gate. Tripwires on every glz entry point prove it over
     a full pipelined pass."""
