@@ -129,6 +129,7 @@ def _result(cell, session, dev, obs, workload, seed, trace) -> dict:
         result["metrics"] = _read_metrics(
             cell, "end_to_end", cell.end_to_end, obs
         )
+    result["compared"] = window.compared(obs, session.c_start, obs["c_close"])
     return result
 
 
@@ -152,6 +153,10 @@ def main(argv=None) -> int:
         return 1
     for f in result["faults"]:
         print(f"benchmark: NOT CORRECT: {f}", file=sys.stderr)
+    for name, c in result["compared"].items():
+        print(f"benchmark: compared {name} {c['value']} limit "
+              f"{'>= ' if c.get('at_least') else ''}{c['limit']}",
+              file=sys.stderr)
     print(json.dumps(result))
     return 0
 

@@ -86,8 +86,16 @@ class Tracer:
             return None
         import jax
 
-        data = jax.profiler.ProfileData.from_file(max(paths, key=os.path.getmtime))
-        return reduce_profile(data, (self.t0, self.t1), host_spans)
+        path = max(paths, key=os.path.getmtime)
+        reduced = reduce_profile(
+            jax.profiler.ProfileData.from_file(path), (self.t0, self.t1),
+            host_spans,
+        )
+        if reduced is not None:
+            # the file this run wrote: `xplane_scopes.reduce_run` reads
+            # the scopes from it and from no other
+            reduced["path"] = path
+        return reduced
 
 
 def short_name(hlo: str) -> str:

@@ -58,6 +58,11 @@ def delta(c0: dict, c1: dict) -> dict:
     }
 
 
+# counters of the program that may not move in a run
+STILL_COUNTERS = ("heals", "stripe_fallbacks", "spills", "retries",
+                  "quarantined", "interp_records")
+
+
 def truth_faults(c_start: dict, c_end: dict) -> list:
     """`correct` part (c) over the WHOLE run (set-up and window): the fast
     path served every slice and nothing healed, spilled or interpreted."""
@@ -67,8 +72,7 @@ def truth_faults(c_start: dict, c_end: dict) -> list:
         faults.append("no slice took the fast path")
     if s1["fallback_slices"] != s0["fallback_slices"]:
         faults.append(f"slices fell back: {s1.get('fallback_reasons')}")
-    for key in ("heals", "stripe_fallbacks", "spills", "retries",
-                "quarantined", "interp_records"):
+    for key in STILL_COUNTERS:
         if c_end[key] != c_start[key]:
             faults.append(f"{key} moved by {c_end[key] - c_start[key]}")
     if c_end["paths"].get("interpreter", 0) != c_start["paths"].get(
@@ -77,10 +81,36 @@ def truth_faults(c_start: dict, c_end: dict) -> list:
     return faults
 
 
+def compared(obs: dict, c_start: dict, c_end: dict) -> dict:
+    """Each number `correct` rests on beside its limit, for the result
+    line and the last lines of standard error. The comparison with the
+    reference is exact, so every limit is 0 but the floor of one
+    fast-path slice (``at_least``)."""
+    s0, s1 = c_start["slices"], c_end["slices"]
+    out = {
+        # what the mode found against the reference: warm-up output not
+        # byte-equal, a response's count or order off, a compile in a
+        # drain window
+        "reference_faults": {"value": len(obs["faults"]), "limit": 0},
+        "failed_operations": {"value": int(obs["failed"]), "limit": 0},
+        "fastpath_slices": {
+            "value": s1["fastpath_slices"] - s0["fastpath_slices"],
+            "limit": 1, "at_least": True},
+        "fallback_slices": {
+            "value": s1["fallback_slices"] - s0["fallback_slices"], "limit": 0},
+        "interpreter_path_records": {
+            "value": c_end["paths"].get("interpreter", 0)
+            - c_start["paths"].get("interpreter", 0), "limit": 0},
+    }
+    for key in STILL_COUNTERS:
+        out[key] = {"value": c_end[key] - c_start[key], "limit": 0}
+    return out
+
+
 def spans_between(t0: float, t1: float) -> list:
     """The executor's per-dispatch spans (as dicts) that ended inside
     [t0, t1] on perf_counter, the clock the benchmark stamps with. The
-    program's ring keeps the most recent 256."""
+    program's ring keeps the most recent 4,096 (`SPAN_RING_CAPACITY`)."""
     from fluvio_tpu.telemetry import TELEMETRY
 
     return [

@@ -11,18 +11,14 @@ the ``tf_op`` stat of the event's METADATA record
 which `ProfileData` does not expose. So this file reads the serialized
 XSpace itself, with a wire-format reader for the dozen fields it needs.
 
-A run's trace is found where `trace_reduce.Tracer` wrote it
-(`<tmp>/spubench-*/trace/`), newest first, and is accepted only if its
-busy time between the two markers equals the run's own reduction: two
-runs that share a temporary directory never read one another's trace.
+A run's trace is the file the session's own `trace_reduce.Tracer` wrote
+and reduced (`obs["trace"]["path"]`); `reduce_run` opens no other.
 """
 
 from __future__ import annotations
 
-import glob
 import os
 import re
-import tempfile
 from collections import defaultdict
 
 from spubench.trace_reduce import (
@@ -387,25 +383,44 @@ def _vocabulary():
     return DEVICE_SCOPES, SLICE_PHASES
 
 
+# `trace_reduce` reads the file through `jax.profiler.ProfileData`, whose
+# event times are whole nanoseconds; this reader keeps the XSpace's
+# picoseconds. The two busy times of ONE file then differ by about half a
+# nanosecond an event (42.9 us of 5.28 s over `ns-drain`'s 93,064 events:
+# 8e-6 of the busy time), and by whole percents where the two reductions
+# have drifted apart or the path names another run's file.
+BUSY_TOLERANCE = 1e-3
+
+
 def reduce_run(obs) -> dict | None:
     """The reduction of THIS run's trace, or None: no traced run, a
-    program without scopes, or no trace file whose busy time is the
-    run's own."""
+    program without scopes, or no file at the path the session's tracer
+    reduced (`obs["trace"]["path"]`). That file is parsed and no other:
+    nothing is looked for under the temporary directory, so another
+    run's trace there, newer or not, is never opened.
+
+    One guard stays, so that the two reductions of one file cannot drift
+    apart unnoticed: None when this reduction's busy time and
+    `trace_reduce`'s differ by more than `BUSY_TOLERANCE` (0.1 %) of the
+    busy time. The limit is a share of the busy time and not a time per
+    event, because it has to hold for a fixture of a dozen events and
+    for a run of 93,064 alike, and needs no event count from either side:
+    rounding reads 1e-5 of the busy time at the most, a fault percents."""
     t = obs.get("trace")
     vocab = _vocabulary()
     if not t or vocab is None:
         return None
-    pattern = os.path.join(tempfile.gettempdir(), "spubench-*", "trace",
-                           "plugins", "profile", "*", "*.xplane.pb")
-    for path in sorted(glob.glob(pattern), key=os.path.getmtime, reverse=True):
-        key = (path, os.path.getmtime(path))
-        if key not in _CACHE:
-            with open(path, "rb") as f:
-                _CACHE[key] = reduce_xspace(f.read(), *vocab)
-        r = _CACHE[key]
-        if r and abs(r["busy_s"] - t["busy_s"]) <= 1e-6 * max(t["busy_s"], 1e-3):
-            return r
-    return None
+    path = t.get("path")
+    if not path or not os.path.isfile(path):
+        return None
+    key = (path, os.path.getmtime(path))
+    if key not in _CACHE:
+        with open(path, "rb") as f:
+            _CACHE[key] = reduce_xspace(f.read(), *vocab)
+    r = _CACHE[key]
+    if not r or abs(r["busy_s"] - t["busy_s"]) > BUSY_TOLERANCE * t["busy_s"]:
+        return None
+    return r
 
 
 def device_scope_ms_per_mrec(obs, prefixes) -> float | None:

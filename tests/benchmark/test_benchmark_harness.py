@@ -46,14 +46,13 @@ def _line(s) -> bool:
 # -- the manifest ------------------------------------------------------------
 
 
-def test_manifest_keys_and_limits():
-    m = MANIFEST
+def _check_keys_and_limits(m, root):
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert 1 <= len(m["command"]) <= 32 and all(_line(c) for c in m["command"])
     assert 1 <= len(m["paths"]) <= 16 and all(PATH.match(p) for p in m["paths"])
     assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
-    assert (REPO / "BENCHMARK.json").stat().st_size <= 64 * 1024
+    assert (root / "BENCHMARK.json").stat().st_size <= 64 * 1024
     # the full check with 24 cells must fit the driver's 43200 s
     runs = 2 + 14 * 24
     assert runs * (m["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
@@ -61,63 +60,89 @@ def test_manifest_keys_and_limits():
     assert 1 <= len(m["end_to_end"]) <= 16 and 1 <= len(m["per_layer"]) <= 128
 
 
-def test_manifest_configs():
-    names = [c["name"] for c in MANIFEST["configs"]]
+def _check_configs(m, root):
+    names = [c["name"] for c in m["configs"]]
     assert len(set(names)) == len(names)
-    files = [c["file"] for c in MANIFEST["configs"]]
+    files = [c["file"] for c in m["configs"]]
     assert len(set(files)) == len(files)
-    used = {w["config"] for w in MANIFEST["workloads"]}
-    for c in MANIFEST["configs"]:
+    used = {w["config"] for w in m["workloads"]}
+    for c in m["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
         assert _line(c["source"]) and _line(c["why"])
-        assert any(c["file"].startswith(p + "/") for p in MANIFEST["paths"])
+        assert any(c["file"].startswith(p + "/") for p in m["paths"])
         assert len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"])
-        body = json.loads((REPO / c["file"]).read_text())
+        body = json.loads((root / c["file"]).read_text())
         assert body["name"] == c["name"] and body["source"] == c["source"]
         assert body["reduced"] == c["reduced"]
         assert body["guarantees"] and body["assumed"]
 
 
-def test_manifest_workloads():
-    pairs = [(w["config"], w["traffic"]) for w in MANIFEST["workloads"]]
-    assert len(set(pairs)) == len(pairs) and len(set(CELLS)) == len(CELLS)
-    configs = {c["name"] for c in MANIFEST["configs"]}
-    for w in MANIFEST["workloads"]:
+def _check_workloads(m, root):
+    cells = [w["name"] for w in m["workloads"]]
+    pairs = [(w["config"], w["traffic"]) for w in m["workloads"]]
+    assert len(set(pairs)) == len(pairs) and len(set(cells)) == len(cells)
+    configs = {c["name"] for c in m["configs"]}
+    for w in m["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["config"] in configs and w["chips"] in (1, 4) and _line(w["why"])
-    four = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
-    assert four <= max(1, len(CELLS) // 2)
+    four = sum(w["chips"] == 4 for w in m["workloads"])
+    assert four <= max(1, len(cells) // 2)
 
 
-def test_manifest_metrics():
-    e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
-    names = [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+def _check_metrics(m, root):
+    cells = [w["name"] for w in m["workloads"]]
+    e2e = {x["name"]: x for x in m["end_to_end"]}
+    names = [x["name"] for x in m["end_to_end"] + m["per_layer"]]
     assert len(set(names)) == len(names)
     assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
 
-    def cells_of(m):
-        return set(m.get("workloads", CELLS))
+    def cells_of(x):
+        return set(x.get("workloads", cells))
 
-    for m in MANIFEST["end_to_end"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.25
-    for m in MANIFEST["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+    for x in m["end_to_end"]:
+        assert set(x) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert x["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= x["bound"] <= 0.25
+    for x in m["per_layer"]:
+        assert set(x) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
-        assert m["source"] in SOURCES and _line(m["layer"])
+        assert x["source"] in SOURCES and _line(x["layer"])
         # the metric it should move is reported in every cell where it is
-        assert m["moves"] in e2e and cells_of(m) <= cells_of(e2e[m["moves"]])
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["better"] in ("lower", "higher")
-        assert cells_of(m) <= set(CELLS) and cells_of(m)
-    for cell in CELLS:
-        assert any(cell in cells_of(m) and m["name"] != "setup_s"
-                   for m in MANIFEST["end_to_end"])
-        assert any(cell in cells_of(m) for m in MANIFEST["per_layer"])
+        assert x["moves"] in e2e and cells_of(x) <= cells_of(e2e[x["moves"]])
+    for x in m["end_to_end"] + m["per_layer"]:
+        assert NAME.match(x["name"]) and UNIT.match(x["unit"])
+        assert x["better"] in ("lower", "higher")
+        assert cells_of(x) <= set(cells) and cells_of(x)
+        # a list names a cell once
+        assert len(x.get("workloads", ())) == len(set(x.get("workloads", ())))
+    for cell in cells:
+        assert any(cell in cells_of(x) and x["name"] != "setup_s"
+                   for x in m["end_to_end"])
+        assert any(cell in cells_of(x) for x in m["per_layer"])
+
+
+# every check takes (manifest, the root it lies in): the accepted manifest
+# here, a copy with a cell added in `test_added_cell_joins_every_list`
+MANIFEST_CHECKS = (_check_keys_and_limits, _check_configs, _check_workloads,
+                   _check_metrics)
+
+
+def test_manifest_keys_and_limits():
+    _check_keys_and_limits(MANIFEST, REPO)
+
+
+def test_manifest_configs():
+    _check_configs(MANIFEST, REPO)
+
+
+def test_manifest_workloads():
+    _check_workloads(MANIFEST, REPO)
+
+
+def test_manifest_metrics():
+    _check_metrics(MANIFEST, REPO)
 
 
 def test_files_under_paths_are_legally_named():
@@ -125,7 +150,9 @@ def test_files_under_paths_are_legally_named():
         ["git", "ls-files", "--cached", "--others", "--exclude-standard", "--"]
         + MANIFEST["paths"], cwd=REPO, capture_output=True, text=True,
     )
-    if out.returncode != 0:   # not a git checkout: walk the directories
+    # not a git checkout, or one unpacked inside an ignored directory of
+    # another (git then lists nothing): walk the directories
+    if out.returncode != 0 or not out.stdout.split():
         files = [str(p.relative_to(REPO)) for d in MANIFEST["paths"]
                  for p in (REPO / d).rglob("*")
                  if p.is_file() and "__pycache__" not in p.parts]
@@ -290,6 +317,21 @@ def test_peaks_and_shapes():
 # -- the loops, rehearsed on the CPU through the test-side switch ------------
 
 
+REHEARSAL_BATCH = 512     # records to a stored batch in a rehearsal, at most
+
+
+def _tiny_config(cfg: dict, backlog: int) -> dict:
+    """A configuration cut to a rehearsal's size. It keeps its own stored
+    batch where that is smaller than `REHEARSAL_BATCH` (a deployment of
+    wide records states a few to a batch: 512 of them would be tens of MB
+    a batch), and then holds four such batches and whatever short tail
+    ``backlog`` asks for beyond whole rehearsal batches."""
+    per = min(REHEARSAL_BATCH, int(cfg["stored_batch_records"]))
+    cfg["stored_batch_records"] = per
+    cfg["backlog_records"] = min(backlog, 4 * per + backlog % REHEARSAL_BATCH)
+    return cfg
+
+
 def _tiny_root(tmp_path, backlog=2048, extra=None) -> Path:
     """A checkout-shaped directory with the benchmark's files, every
     configuration cut to a tiny backlog, and optional extra entries."""
@@ -299,10 +341,7 @@ def _tiny_root(tmp_path, backlog=2048, extra=None) -> Path:
     m = json.loads(json.dumps(MANIFEST))
     for c in m["configs"]:
         f = root / c["file"]
-        cfg = json.loads(f.read_text())
-        cfg["backlog_records"] = backlog
-        cfg["stored_batch_records"] = 512
-        f.write_text(json.dumps(cfg))
+        f.write_text(json.dumps(_tiny_config(json.loads(f.read_text()), backlog)))
     t = root / "benchmark" / "traffic" / "paced-16k.json"
     if t.exists():
         tr = json.loads(t.read_text())
@@ -357,6 +396,10 @@ def test_cell_rehearsal_on_cpu(monkeypatch, tmp_path, cell):
     assert r["counts"]["fallback_slices"] == 0
     assert r["counts"]["records_in"] > 0
     json.dumps(r)   # the result line is plain JSON
+    # ... and ends with each number compared beside its limit; all hold
+    assert list(r)[-1] == "compared" and len(r["compared"]) == 11
+    assert all((c["value"] >= c["limit"]) if c.get("at_least")
+               else (c["value"] <= c["limit"]) for c in r["compared"].values())
 
 
 def test_paced_layer_metrics_on_cpu(monkeypatch, tmp_path):
@@ -416,45 +459,151 @@ def test_wrong_output_is_not_correct(monkeypatch, tmp_path):
     r = _rehearse(monkeypatch, root, "ns-drain", seconds=0.2)
     assert r["correct"] is False
     assert any("bytes differ" in f for f in r["faults"])
+    assert r["compared"]["reference_faults"]["value"] >= 1
+
+
+def _add_dummy_cell(root, m):
+    """A configuration, a traffic mix, a cell and a per-layer metric of
+    its own, by new files plus manifest entries; no file that is there
+    changes."""
+    b = root / "benchmark"
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    cfg = json.loads((b / "configs" / "fluvio-northstar-1p.json").read_text())
+    cfg["name"] = "dummy-config"
+    cfg["backlog_records"] = 1024
+    (b / "configs" / "dummy-config.json").write_text(json.dumps(cfg))
+    (b / "traffic" / "dummy-drain.json").write_text(
+        json.dumps({"mode": "drain", "max_bytes": 20000}))
+    (b / "layer_metrics" / "dummy_responses.py").write_text(
+        "def read(obs):\n    return obs['responses']\n")
+    m["configs"].append({
+        "name": "dummy-config", "source": cfg["source"],
+        "file": "benchmark/configs/dummy-config.json", "reduced": [],
+        "why": "test"})
+    m["workloads"].append({
+        "name": "dummy-cell", "config": "dummy-config",
+        "traffic": "dummy-drain", "chips": 1, "why": "test"})
+    m["per_layer"].append({
+        "name": "dummy_responses", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "client / socket",
+        "moves": "records_in_per_s", "workloads": ["dummy-cell"]})
+    for e in m["end_to_end"]:
+        if e["name"] == "records_in_per_s":
+            e["workloads"].append("dummy-cell")
+    assert all(p.read_bytes() == data for p, data in before.items())
 
 
 def test_dummy_cell_is_added_by_files_alone(monkeypatch, tmp_path):
     """A configuration, a traffic mix, a cell and a per-layer metric are
     added by new files plus manifest entries; no existing file is edited."""
-    def extra(root, m):
-        b = root / "benchmark"
-        before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
-        cfg = json.loads((b / "configs" / "fluvio-northstar-1p.json").read_text())
-        cfg["name"] = "dummy-config"
-        cfg["backlog_records"] = 1024
-        (b / "configs" / "dummy-config.json").write_text(json.dumps(cfg))
-        (b / "traffic" / "dummy-drain.json").write_text(
-            json.dumps({"mode": "drain", "max_bytes": 20000}))
-        (b / "layer_metrics" / "dummy_responses.py").write_text(
-            "def read(obs):\n    return obs['responses']\n")
-        m["configs"].append({
-            "name": "dummy-config", "source": cfg["source"],
-            "file": "benchmark/configs/dummy-config.json", "reduced": [],
-            "why": "test"})
-        m["workloads"].append({
-            "name": "dummy-cell", "config": "dummy-config",
-            "traffic": "dummy-drain", "chips": 1, "why": "test"})
-        m["per_layer"].append({
-            "name": "dummy_responses", "unit": "count", "better": "higher",
-            "source": "program_counter", "layer": "client / socket",
-            "moves": "records_in_per_s", "workloads": ["dummy-cell"]})
-        for e in m["end_to_end"]:
-            if e["name"] == "records_in_per_s":
-                e["workloads"].append("dummy-cell")
-        assert all(p.read_bytes() == data for p, data in before.items())
-
-    root = _tiny_root(tmp_path, extra=extra)
+    root = _tiny_root(tmp_path, extra=_add_dummy_cell)
     r = _rehearse(monkeypatch, root, "dummy-cell", trace=True, seconds=0.5)
     assert r["correct"] is True
     # 20 kB slices hold one 512-record stored batch: two responses to a pass
     assert r["metrics"]["dummy_responses"]["value"] == r["counts"]["responses"]
     assert r["counts"]["responses"] > r["attempted"]
     assert set(r["metrics"]) == {"dummy_responses"}
+
+
+def test_added_cell_joins_every_list(monkeypatch, tmp_path):
+    """A later PR appends its cell to the `workloads` of every per-layer
+    metric whose reader reads on it. Over a manifest copy whose new cell
+    is in EVERY such list, the manifest checks of both test files hold
+    (none pins a list, the number of cells or of configurations to what
+    is accepted today), and the readers that are here read the new cell."""
+    import test_tracing_readers as readers
+
+    def extra(root, m):
+        _add_dummy_cell(root, m)
+        for e in m["per_layer"]:
+            if "dummy-cell" not in e.get("workloads", ["dummy-cell"]):
+                e["workloads"].append("dummy-cell")
+
+    root = _tiny_root(tmp_path, extra=extra)
+    m = json.loads((root / "BENCHMARK.json").read_text())
+    assert len(m["workloads"]) == len(MANIFEST["workloads"]) + 1
+    for check_manifest in MANIFEST_CHECKS:
+        check_manifest(m, root)
+    for name in readers.NEW_HOST + readers.NEW_DEVICE:
+        readers.check_new_entry(m, name)
+    r = _rehearse(monkeypatch, root, "dummy-cell", trace=True, seconds=0.5)
+    assert r["correct"] is True
+    # the host readers of the slice path read the new cell as they stand;
+    # a CPU trace has no device plane, so the device readers stay silent
+    assert set(readers.NEW_HOST) | {"dummy_responses", "fastpath_share",
+                                    "exec_up_ms_per_mrec"} <= set(r["metrics"])
+    assert not set(readers.NEW_DEVICE) & set(r["metrics"])
+
+
+@pytest.mark.parametrize("own,backlog,want", [
+    (8, 2048, (8, 32)),               # wide records: a few to a stored batch
+    (8, 2048 + 100, (8, 132)),
+    (100, 2048, (100, 400)),
+    (512, 2048, (512, 2048)),
+    (16384, 2048, (512, 2048)),       # the accepted two: as before
+    (16384, 2048 + 100, (512, 2148)),
+])
+def test_rehearsal_size_keeps_a_smaller_stored_batch(own, backlog, want):
+    cfg = _tiny_config({"stored_batch_records": own,
+                        "backlog_records": 1_000_000, "name": "x"}, backlog)
+    assert (cfg["stored_batch_records"], cfg["backlog_records"]) == want
+    assert cfg["name"] == "x"
+
+
+def test_configuration_of_eight_is_rehearsed_with_eight(monkeypatch, tmp_path):
+    """A copied configuration that states 8 records to a stored batch is
+    rehearsed with 8; the accepted configurations at 2,048 in 512s."""
+    def extra(root, m):
+        b = root / "benchmark"
+        cfg = json.loads((b / "configs" / "fluvio-northstar-1p.json").read_text())
+        assert (cfg["backlog_records"], cfg["stored_batch_records"]) == (2048, 512)
+        cfg |= {"name": "narrow-config", "backlog_records": 976,
+                "stored_batch_records": 8}
+        (b / "configs" / "narrow-config.json").write_text(
+            json.dumps(_tiny_config(cfg, 2048)))
+        # one 8-record stored batch of 36 B records fits 500 B, two do not
+        (b / "traffic" / "narrow-drain.json").write_text(
+            json.dumps({"mode": "drain", "max_bytes": 500}))
+        m["configs"].append({
+            "name": "narrow-config", "source": cfg["source"],
+            "file": "benchmark/configs/narrow-config.json", "reduced": [],
+            "why": "test"})
+        m["workloads"].append({
+            "name": "narrow-cell", "config": "narrow-config",
+            "traffic": "narrow-drain", "chips": 1, "why": "test"})
+        for e in m["end_to_end"] + m["per_layer"]:
+            if e["name"] in ("records_in_per_s", "fastpath_share"):
+                e["workloads"].append("narrow-cell")
+
+    root = _tiny_root(tmp_path, extra=extra)
+    for c in MANIFEST["configs"]:
+        cfg = json.loads((root / c["file"]).read_text())
+        assert (cfg["backlog_records"], cfg["stored_batch_records"]) == (2048, 512)
+    cell = manifest.load_cell("narrow-cell", root)
+    assert cell.config["stored_batch_records"] == 8
+    assert cell.config["backlog_records"] == 32
+    r = _rehearse(monkeypatch, root, "narrow-cell", seconds=0.2)
+    assert r["correct"] is True
+    # every response carries one stored batch of 8
+    assert r["counts"]["records_in"] == 8 * r["counts"]["responses"]
+
+
+def test_result_line_ends_with_the_numbers_compared(monkeypatch, capsys):
+    """`main` prints each number compared beside its limit as the last
+    lines of standard error, and the result line carries them last."""
+    compared = {"reference_faults": {"value": 2, "limit": 0},
+                "fastpath_slices": {"value": 7, "limit": 1, "at_least": True}}
+    monkeypatch.setattr(bench_run, "run_cell", lambda *a, **k: {
+        "correct": False, "faults": ["warm-up pass: bytes differ"],
+        "device": {"busy_s": 1.0}, "metrics": {}, "compared": compared})
+    assert bench_run.main(["--workload", "ns-drain", "--seed", str(2**31 + 3),
+                           "--seconds", "1", "--trace", "1"]) == 0
+    out, err = capsys.readouterr()
+    assert list(json.loads(out.strip().splitlines()[-1]))[-1] == "compared"
+    assert err.strip().splitlines()[-3:] == [
+        "benchmark: NOT CORRECT: warm-up pass: bytes differ",
+        "benchmark: compared reference_faults 2 limit 0",
+        "benchmark: compared fastpath_slices 7 limit >= 1"]
 
 
 def test_unknown_names_are_errors(tmp_path):

@@ -64,11 +64,14 @@ def _fresh_registry():
 # -- the manifest entries ----------------------------------------------------
 
 
-@pytest.mark.parametrize("name", NEW_HOST + NEW_DEVICE)
-def test_new_entries_are_additions_for_both_drain_cells(name):
-    m = json.loads((REPO / "BENCHMARK.json").read_text())
+def check_new_entry(m, name):
+    """PR 26's entry ``name`` in the manifest ``m``. Its `workloads` is
+    held to a FLOOR: both drain cells are in it, and whatever else is in
+    it is a cell of the manifest, once. A later PR appends its own."""
     (entry,) = [e for e in m["per_layer"] if e["name"] == name]
-    assert entry["workloads"] == ["ns-drain", "explode-drain"]
+    cells = [w["name"] for w in m["workloads"]]
+    assert {"ns-drain", "explode-drain"} <= set(entry["workloads"]) <= set(cells)
+    assert len(set(entry["workloads"])) == len(entry["workloads"])
     assert entry["moves"] == "records_in_per_s"
     assert entry["source"] == (
         "device_trace" if name in NEW_DEVICE else "program_span")
@@ -76,6 +79,11 @@ def test_new_entries_are_additions_for_both_drain_cells(name):
     # added at the end of the list, after what PR 24 accepted
     names = [e["name"] for e in m["per_layer"]]
     assert names.index(name) > names.index("device_idle_share")
+
+
+@pytest.mark.parametrize("name", NEW_HOST + NEW_DEVICE)
+def test_new_entries_are_additions_for_both_drain_cells(name):
+    check_new_entry(json.loads((REPO / "BENCHMARK.json").read_text()), name)
 
 
 # -- the xplane reduction ----------------------------------------------------
@@ -128,8 +136,8 @@ def test_reduction_on_recorded_shape_fixture():
 
 def test_reduction_agrees_with_the_accepted_busy_time():
     """Same trace, same markers: this reduction's busy time and window
-    are `trace_reduce.reduce_profile`'s (the run's trace is recognised by
-    that equality)."""
+    are `trace_reduce.reduce_profile`'s (`reduce_run` refuses a file on
+    which the two differ by more than rounding)."""
     import jax
     from spubench import trace_reduce
 
@@ -159,6 +167,7 @@ def test_no_device_plane_reads_nothing():
 
 
 def _plant_trace(tmp: Path, run: str, raw: bytes) -> Path:
+    """A trace file where a session's `Tracer` writes one."""
     d = tmp / f"spubench-{run}" / "trace" / "plugins" / "profile" / "t0"
     d.mkdir(parents=True)
     p = d / "host.xplane.pb"
@@ -166,26 +175,37 @@ def _plant_trace(tmp: Path, run: str, raw: bytes) -> Path:
     return p
 
 
-def _device_obs(busy_s=0.00775):
-    # a 20 s window whose traced span is the fixture's 10 ms; 2M records
-    return {"trace": {"busy_s": busy_s, "window_s": 0.010},
-            "window_s": 20.0, "records_in": 2_000_000}
+def _unscoped_bytes() -> bytes:
+    import jax
+
+    return jax.profiler.ProfileData.text_proto_to_serialized_xspace(
+        (BENCH / "testdata" / "trace_small.textproto").read_text())
+
+
+def _device_obs(path=None, busy_s=0.00775):
+    # a 20 s window whose traced span is the fixture's 10 ms; 2M records;
+    # `path` is the trace file the session's tracer reduced
+    trace = {"busy_s": busy_s, "window_s": 0.010}
+    if path is not None:
+        trace["path"] = str(path)
+    return {"trace": trace, "window_s": 20.0, "records_in": 2_000_000}
 
 
 def test_device_readers_take_this_runs_trace(tmp_path, monkeypatch):
-    import jax
-
     monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
-    other = jax.profiler.ProfileData.text_proto_to_serialized_xspace(
-        (BENCH / "testdata" / "trace_small.textproto").read_text())
     mine = _plant_trace(tmp_path, "aaa", _fixture_bytes())
-    newer = _plant_trace(tmp_path, "bbb", other)   # another run's, newer
+    # another run's trace in a sibling directory, newer, and one whose
+    # busy time is this run's own: found by path, neither is ever opened
+    newer = _plant_trace(tmp_path, "bbb", _unscoped_bytes())
+    twin = _plant_trace(tmp_path, "ccc", _fixture_bytes())
     os.utime(mine, (1000, 1000))
     os.utime(newer, (2000, 2000))
-    obs = _device_obs()
+    os.utime(twin, (3000, 3000))
+    obs = _device_obs(mine)
     named = _reader("device_named_share")(obs)
     link = _reader("device_link_ms_per_mrec")(obs)
     chain = _reader("device_chain_ms_per_mrec")(obs)
+    assert [path for path, _mtime in xs._CACHE] == [str(mine)]
     assert named == pytest.approx(100 * 0.00745 / 0.00775)
     # seconds of the span / span x window seconds per million records
     assert link == pytest.approx((0.0053 + 0.0005 + 0.0002 + 0.0005) / 0.010
@@ -201,19 +221,65 @@ def test_device_readers_take_this_runs_trace(tmp_path, monkeypatch):
 def test_device_readers_stay_silent(tmp_path, monkeypatch, name):
     monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
     read = _reader(name)
+    mine = _plant_trace(tmp_path, "ccc", _fixture_bytes())
+    assert read(_device_obs(mine)) is not None
     assert read({"records_in": 5}) is None                   # no traced run
-    assert read(_device_obs()) is None                       # no trace file
-    _plant_trace(tmp_path, "ccc", _fixture_bytes())
-    assert read(_device_obs(busy_s=0.5)) is None             # another run's
-    import jax
-
-    unscoped = jax.profiler.ProfileData.text_proto_to_serialized_xspace(
-        (BENCH / "testdata" / "trace_small.textproto").read_text())
-    _plant_trace(tmp_path, "ddd", unscoped)
-    assert read(_device_obs(busy_s=0.0045)) is None          # no scope in it
+    assert read({"trace": None, "records_in": 5}) is None
+    # a reduction that names no file: nothing is looked for, though a
+    # trace with this very busy time lies under the temporary directory
+    assert read(_device_obs()) is None
+    assert read(_device_obs(tmp_path / "spubench-gone" / "x.xplane.pb")) is None
+    unscoped = _plant_trace(tmp_path, "ddd", _unscoped_bytes())
+    assert read(_device_obs(unscoped, busy_s=0.0045)) is None  # no scope in it
+    empty = _plant_trace(tmp_path, "eee", b"")
+    assert read(_device_obs(empty)) is None                  # no device plane
     # a program without the vocabulary (a parent commit): nothing, no raise
     monkeypatch.setattr(xs, "_vocabulary", lambda: None)
-    assert read(_device_obs()) is None
+    assert read(_device_obs(mine)) is None
+
+
+@pytest.mark.parametrize("off,reads", [
+    # `trace_reduce` reads whole nanoseconds (ProfileData), this reader
+    # picoseconds: 42.9 us of 5.28 s over `ns-drain`'s 93,064 events
+    (+1e-5, True), (-1e-5, True),
+    # the two reductions of one file have drifted apart: silence
+    (+0.05, False), (-0.05, False),
+])
+@pytest.mark.parametrize("name", NEW_DEVICE)
+def test_device_readers_allow_rounding_and_no_more(tmp_path, name, off, reads):
+    mine = _plant_trace(tmp_path, "aaa", _fixture_bytes())
+    exact = _reader(name)(_device_obs(mine))
+    got = _reader(name)(_device_obs(mine, busy_s=0.00775 * (1 + off)))
+    if not reads:
+        assert got is None
+    elif name == "device_named_share":
+        assert got == exact        # a share of the file's own busy time
+    else:
+        assert got == pytest.approx(exact)
+
+
+def test_tracer_hands_the_readers_its_own_file(tmp_path):
+    """`Tracer.reduce` names the file it reduced (the newest in ITS
+    directory) in the dict that becomes `obs["trace"]`."""
+    from spubench.trace_reduce import Tracer
+
+    tr = Tracer(str(tmp_path / "spubench-aaa" / "trace"), enabled=True)
+    tr.t0, tr.t1 = 100.0, 100.010
+    assert tr.reduce() is None                               # nothing written
+    old = _plant_trace(tmp_path, "aaa", _unscoped_bytes())
+    mine = old.parent.parent / "t1" / "host.xplane.pb"
+    mine.parent.mkdir()
+    mine.write_bytes(_fixture_bytes())
+    os.utime(old, (1000, 1000))
+    os.utime(mine, (2000, 2000))
+    other = _plant_trace(tmp_path, "bbb", _fixture_bytes())  # a sibling run's
+    os.utime(other, (3000, 3000))
+    reduced = tr.reduce()
+    assert reduced["path"] == str(mine)
+    assert reduced["busy_s"] == pytest.approx(0.00775)
+    obs = {"trace": reduced, "window_s": 20.0, "records_in": 2_000_000}
+    assert _reader("device_named_share")(obs) == pytest.approx(
+        100 * 0.00745 / 0.00775)
 
 
 # -- the host readers over a seeded TELEMETRY --------------------------------
