@@ -98,6 +98,7 @@ def render_metrics_table(data: dict) -> str:
         for k in (
             "bytes_in", "records_out", "invocation_count", "fuel_used",
             "fastpath_slices", "fallback_slices",
+            "stream_chain_hits", "stream_chain_builds",
         )
     ]
     sections.append(

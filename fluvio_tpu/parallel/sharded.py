@@ -250,6 +250,7 @@ class ShardedChainExecutor:
         # capacity block; src_row stays global so the host gather works
         ctx = {"fanout_cap": fanout_cap, "axis_name": ax, "g0": g0}
         for i, stage in enumerate(ex.stages):
+            ctx["stage_index"] = i
             with jax.named_scope(stage_scope(i, stage.kind)):
                 state, carries = stage.apply(state, carries, base_ts, ctx)
         with jax.named_scope("compact"):

@@ -94,6 +94,32 @@ class SmartEngine:
         return SmartModuleChainBuilder(engine=self)
 
 
+def _init_instances(entries, engine: SmartEngine) -> List[PythonInstance]:
+    """One interpreter instance per (module, config), its init hook
+    run: what a chain build and a further stream of a built chain both
+    start from."""
+    from fluvio_tpu.smartengine.metering import run_metered
+
+    instances = []
+    for module, config in entries:
+        inst = PythonInstance(module, config)
+        try:
+            # init is user code too: a looping init must become a
+            # typed chain-init error, not a wedged chain build
+            run_metered(
+                inst.call_init,
+                engine.hook_budget_ms,
+                module.name,
+                key=getattr(module, "meter_key", ""),
+            )
+        except Exception as e:  # noqa: BLE001 — user code boundary
+            raise SmartModuleChainInitError(
+                f"init failed for SmartModule {module.name!r}: {e}"
+            ) from e
+        instances.append(inst)
+    return instances
+
+
 @dataclass
 class _ChainEntry:
     module: SmartModuleDef
@@ -121,25 +147,9 @@ class SmartModuleChainBuilder:
 
     def initialize(self, engine: Optional[SmartEngine] = None) -> "SmartModuleChainInstance":
         engine = engine or self.engine
-        instances = []
-        from fluvio_tpu.smartengine.metering import run_metered
-
-        for entry in self.entries:
-            inst = PythonInstance(entry.module, entry.config)
-            try:
-                # init is user code too: a looping init must become a
-                # typed chain-init error, not a wedged chain build
-                run_metered(
-                    inst.call_init,
-                    engine.hook_budget_ms,
-                    entry.module.name,
-                    key=getattr(entry.module, "meter_key", ""),
-                )
-            except Exception as e:  # noqa: BLE001 — user code boundary
-                raise SmartModuleChainInitError(
-                    f"init failed for SmartModule {entry.module.name!r}: {e}"
-                ) from e
-            instances.append(inst)
+        instances = _init_instances(
+            [(e.module, e.config) for e in self.entries], engine
+        )
 
         backend = engine.backend
         tpu_chain = None
@@ -251,6 +261,9 @@ class SmartModuleChainInstance:
         # the chain fails fast with this error instead of re-entering
         # user code whose previous invocation is still running
         self._poisoned = None
+        # the chain this one is a stream of (`open_stream`), which
+        # shares its modules: a poisoning reaches it too
+        self._origin: Optional["SmartModuleChainInstance"] = None
         # per-chain circuit breaker (resilience/policy.py): M fused
         # failures in a window demote the chain to the interpreter path
         # outright; probe batches re-promote it after the cooldown. Only
@@ -265,6 +278,37 @@ class SmartModuleChainInstance:
 
     def __len__(self) -> int:
         return len(self.instances)
+
+    def open_stream(self) -> "SmartModuleChainInstance":
+        """A further consumer stream of this chain (fused chains only):
+        the compiled executor is shared, everything a stream advances
+        is new: interpreter instances (each module's init hook runs
+        again, as a per-stream instantiate does), the executor's
+        `StreamState` from the chain spec's seed, breaker and retry
+        budget. The SPU's stream-chain cache hands these out for a
+        stateful chain, so that a stream's open costs no re-trace and
+        no executable load."""
+        instances = _init_instances(
+            [(i.module, i.config) for i in self.instances], self.engine
+        )
+        tpu_chain = self.tpu_chain.open_stream()
+        tpu_chain.attach(instances)
+        stream = SmartModuleChainInstance(
+            engine=self.engine,
+            instances=instances,
+            tpu_chain=tpu_chain,
+            chain_spec=self.chain_spec,
+        )
+        stream._origin = self
+        return stream
+
+    def _poison(self, error) -> None:
+        """A fuel trap left user code of this chain's modules running:
+        the chain, and the chain it is a stream of, fail fast from here
+        on."""
+        self._poisoned = error
+        if self._origin is not None:
+            self._origin._poisoned = error
 
     @property
     def backend_in_use(self) -> str:
@@ -506,7 +550,7 @@ class SmartModuleChainInstance:
                 # the accumulator may be half-mutated even when the hook
                 # unwound cleanly.
                 if e.abandoned or instance.kind is SmartModuleKind.AGGREGATE:
-                    self._poisoned = output.error
+                    self._poison(output.error)
                 break
             if output.error is not None:
                 # stop processing, return partial output (engine.rs:159-161)
@@ -570,8 +614,10 @@ class SmartModuleChainInstance:
                         SmartModuleTransformRuntimeError,
                     )
 
-                    self._poisoned = SmartModuleTransformRuntimeError(
-                        hint=str(e), kind=instance.kind
+                    self._poison(
+                        SmartModuleTransformRuntimeError(
+                            hint=str(e), kind=instance.kind
+                        )
                     )
                 raise
             # keep any device/native-side state in sync after host replay

@@ -28,6 +28,11 @@ class SmartModuleChainMetrics:
     fastpath_slices: int = 0
     fallback_slices: int = 0
     fallback_reasons: dict = field(default_factory=dict)
+    # stream opens served from the SPU's stream-chain cache, and those
+    # that built their chain (a re-trace and an executable load per
+    # shape bucket: hundreds of ms a stream)
+    stream_chain_hits: int = 0
+    stream_chain_builds: int = 0
     _lock: object = field(
         default_factory=lambda: make_lock("smartengine.metrics"), repr=False
     )
@@ -56,6 +61,13 @@ class SmartModuleChainMetrics:
                 self.fallback_reasons.get(reason, 0) + 1
             )
 
+    def add_stream_chain(self, hit: bool) -> None:
+        with self._lock:
+            if hit:
+                self.stream_chain_hits += 1
+            else:
+                self.stream_chain_builds += 1
+
     def to_dict(self) -> dict:
         # snapshot under the lock: a scrape concurrent with add_* must
         # never see torn multi-field state (e.g. bytes_in advanced but
@@ -69,6 +81,8 @@ class SmartModuleChainMetrics:
                 "fastpath_slices": self.fastpath_slices,
                 "fallback_slices": self.fallback_slices,
                 "fallback_reasons": dict(self.fallback_reasons),
+                "stream_chain_hits": self.stream_chain_hits,
+                "stream_chain_builds": self.stream_chain_builds,
             }
 
     def to_json(self) -> str:

@@ -265,44 +265,50 @@ class _AggregateStage:
         else:
             w = jnp.zeros(x.shape[0], dtype=jnp.int64)
 
-        ax = ctx.get("axis_name")
-        if ax is not None:
-            return self._apply_sharded(
-                state, carries, ctx, valid, xm, w, acc_in, win_in, has_in,
-                neutral, ax,
-            )
+        # the contribution above (e.g. a JSON span + ParseInt) stays
+        # under the stage's own scope; the carry chain and the scan get
+        # one of their own inside it, so a profile tells the two apart
+        with jax.named_scope(
+            stage_scope(ctx.get("stage_index", 0), "aggregate_scan")
+        ):
+            ax = ctx.get("axis_name")
+            if ax is not None:
+                return self._apply_sharded(
+                    state, carries, ctx, valid, xm, w, acc_in, win_in, has_in,
+                    neutral, ax,
+                )
 
-        # prepend the carry as a virtual row
-        x_all = jnp.concatenate([jnp.where(has_in, acc_in, neutral)[None], xm])
-        w_all = jnp.concatenate([win_in[None], w])
-        valid_all = jnp.concatenate([has_in[None], valid])
+            # prepend the carry as a virtual row
+            x_all = jnp.concatenate([jnp.where(has_in, acc_in, neutral)[None], xm])
+            w_all = jnp.concatenate([win_in[None], w])
+            valid_all = jnp.concatenate([has_in[None], valid])
 
-        prevw_incl, prevhas_incl = kernels.propagate_last_valid(w_all, valid_all)
-        prevw = jnp.concatenate([jnp.int64(0)[None], prevw_incl[:-1]])
-        prevhas = jnp.concatenate([jnp.asarray(False)[None], prevhas_incl[:-1]])
-        reset_all = valid_all & (~prevhas | (w_all != prevw))
+            prevw_incl, prevhas_incl = kernels.propagate_last_valid(w_all, valid_all)
+            prevw = jnp.concatenate([jnp.int64(0)[None], prevw_incl[:-1]])
+            prevhas = jnp.concatenate([jnp.asarray(False)[None], prevhas_incl[:-1]])
+            reset_all = valid_all & (~prevhas | (w_all != prevw))
 
-        scan = kernels.segmented_scan(x_all, reset_all, op)
-        out_vals = scan[1:]
+            scan = kernels.segmented_scan(x_all, reset_all, op)
+            out_vals = scan[1:]
 
-        new_acc = kernels.last_true_value(valid_all, scan, acc_in)
-        new_win = kernels.last_true_value(valid_all, w_all, win_in)
-        new_has = has_in | jnp.any(valid)
+            new_acc = kernels.last_true_value(valid_all, scan, acc_in)
+            new_win = kernels.last_true_value(valid_all, w_all, win_in)
+            new_has = has_in | jnp.any(valid)
 
-        new_state = dict(state)
-        v, l = kernels.int_to_ascii(out_vals)
-        new_state["values"], new_state["lengths"] = v, l.astype(jnp.int32)
-        if self.window_ms:
-            kv, kl = kernels.int_to_ascii(w)
-            new_state["keys"], new_state["key_lengths"] = kv, kl.astype(jnp.int32)
-        # raw integers for the int-output D2H mode (8 bytes/row instead of
-        # a padded ASCII matrix); the ascii materialization above is
-        # DCE'd when the executor ships these instead
-        new_state["agg_out_int"] = out_vals
-        new_state["agg_win_int"] = w
-        new_carries = list(carries)
-        new_carries[self.index] = (new_acc, new_win, new_has)
-        return new_state, tuple(new_carries)
+            new_state = dict(state)
+            v, l = kernels.int_to_ascii(out_vals)
+            new_state["values"], new_state["lengths"] = v, l.astype(jnp.int32)
+            if self.window_ms:
+                kv, kl = kernels.int_to_ascii(w)
+                new_state["keys"], new_state["key_lengths"] = kv, kl.astype(jnp.int32)
+            # raw integers for the int-output D2H mode (8 bytes/row instead of
+            # a padded ASCII matrix); the ascii materialization above is
+            # DCE'd when the executor ships these instead
+            new_state["agg_out_int"] = out_vals
+            new_state["agg_win_int"] = w
+            new_carries = list(carries)
+            new_carries[self.index] = (new_acc, new_win, new_has)
+            return new_state, tuple(new_carries)
 
     def _apply_sharded(
         self, state, carries, ctx, valid, xm, w, acc_in, win_in, has_in,
@@ -618,23 +624,89 @@ def _fetch_mat_pool():
     return _FETCH_POOL  # noqa: FLV202 — published once, never rebound
 
 
+class StreamState:
+    """What ONE consumer stream owns of a chain: everything that a
+    dispatch reads or advances and that must not be seen by another
+    stream of the same compiled chain.
+
+    - ``carries`` / ``device_carries``: the aggregate state, host
+      mirror and device-resident form (the carry stays on the device
+      between the slices of the stream; the host mirror syncs on
+      demand),
+    - ``instances``: the interpreter's instances of this stream, which
+      mirror the carries for backend parity,
+    - the heal lineage (``heal_epoch``, ``heal_carries``,
+      ``heal_dispatch_seq``, ``dispatch_seq``): a heal invalidates the
+      device carry lineage of every aggregate dispatch of this stream
+      already in flight; the epoch marks them stale and the dispatch
+      sequence tells a stale finish whether the healed carry tip is
+      still current (safe to re-dispatch from) or already consumed by
+      later dispatches,
+    - the partition identity (``span_chain``, ``partition_tag``) the
+      partition layer installs around a slice: the span chain label
+      gains the chain@partition suffix (SLO and admission key on it)
+      and down-link/decline telemetry a per-partition:group label;
+      None (the default) costs one attribute read on the seams that
+      check it.
+
+    A state starts from the chain spec's seed
+    (`TpuChainExecutor.initial_carries`)."""
+
+    __slots__ = (
+        "carries", "device_carries", "instances", "heal_epoch",
+        "heal_carries", "heal_dispatch_seq", "dispatch_seq",
+        "span_chain", "partition_tag",
+    )
+
+    def __init__(self, carries: List[Tuple[int, int, bool]]) -> None:
+        self.carries = carries
+        self.device_carries = None
+        self.instances: List = []
+        self.heal_epoch = 0
+        self.heal_carries = None
+        self.heal_dispatch_seq = -1
+        self.dispatch_seq = 0
+        self.span_chain: Optional[str] = None
+        self.partition_tag: Optional[str] = None
+
+
+def _stream_field(name: str) -> property:
+    """An executor attribute that lives in its `StreamState`."""
+    return property(
+        lambda self: getattr(self.state, name),
+        lambda self, value: setattr(self.state, name, value),
+    )
+
+
 class TpuChainExecutor:
-    """Compiled chain + device-resident aggregate state."""
+    """A compiled chain, and the state of the one stream that owns it.
+
+    The compiled half (stages, the instrumented jits and their
+    shape-bucket caches, the striped lowering, learned fan-out
+    capacity, link latches, byte counters) is everything below that is
+    not a `StreamState` field. The stream half is ``self.state``; the
+    names the dispatch/finish code reads it by (``carries``,
+    ``_device_carries``, ``_heal_epoch``, ...) are properties onto it.
+    A chain built for one owner (an engine chain, the bench, a
+    partition runtime's bank) uses the executor's own state;
+    `open_stream` gives a further stream the same compiled half with a
+    state of its own."""
+
+    carries = _stream_field("carries")
+    _device_carries = _stream_field("device_carries")
+    _instances = _stream_field("instances")
+    _heal_epoch = _stream_field("heal_epoch")
+    _heal_carries = _stream_field("heal_carries")
+    _heal_dispatch_seq = _stream_field("heal_dispatch_seq")
+    _dispatch_seq = _stream_field("dispatch_seq")
+    span_chain = _stream_field("span_chain")
+    partition_tag = _stream_field("partition_tag")
 
     def __init__(self, stages: List, agg_configs: List[Tuple[str, Optional[int], bytes]]):
         self.stages = stages
         # agg_configs rows are (combine_op, window_ms, initial_data)
         self.agg_configs = agg_configs
-        self.carries: List[Tuple[int, int, bool]] = self.initial_carries()
-        self._instances: List = []
-        self._device_carries = None
-        # partition-layer identity (fluvio_tpu/partition): when set, the
-        # span chain label carries the chain@partition suffix (SLO and
-        # admission key on it) and down-link/decline telemetry gains a
-        # per-partition:group label. None (the default) costs one attr
-        # read on the seams that check it.
-        self.span_chain: Optional[str] = None
-        self.partition_tag: Optional[str] = None
+        self.state = StreamState(self.initial_carries())
         # short chain signature for compile-event attribution: which
         # chain shape a trace-cache miss compiled for
         self._chain_sig = (
@@ -687,15 +759,6 @@ class TpuChainExecutor:
             "striped",
             describe=self._describe_striped,
         )
-        # glz self-heal bookkeeping: a heal invalidates the device carry
-        # lineage of every aggregate dispatch already in flight; the
-        # epoch marks them stale and the dispatch sequence tells a stale
-        # finish whether the healed carry tip is still current (safe to
-        # re-dispatch from) or already consumed by later dispatches
-        self._heal_epoch = 0
-        self._heal_carries = None
-        self._heal_dispatch_seq = -1
-        self._dispatch_seq = 0
         # do any stages write key columns? (drives D2H key download)
         self._writes_keys = any(
             (isinstance(s, _MapStage) and s.key_fn is not None)
@@ -904,6 +967,13 @@ class TpuChainExecutor:
         """Python-side instances mirror aggregate state for backend parity."""
         self._instances = instances
 
+    def open_stream(self) -> "TpuChainExecutor":
+        """A further consumer stream over this compiled chain: the same
+        programs, caches and latches by reference, and a `StreamState`
+        of its own that starts from the chain spec's seed. Nothing of
+        one stream's state is reachable from another's."""
+        return _StreamExecutor(self)
+
     # -- device-side result compaction / down-link encode (traced) ----------
 
     @staticmethod
@@ -1029,6 +1099,7 @@ class TpuChainExecutor:
         state["src_row"] = jnp.arange(n, dtype=jnp.int32)
         ctx = {"fanout_cap": fanout_cap}
         for i, stage in enumerate(self.stages):
+            ctx["stage_index"] = i  # for a stage's own inner scopes
             with jax.named_scope(stage_scope(i, stage.kind)):
                 state, carries = stage.apply(state, carries, base_ts, ctx)
         with jax.named_scope("compact"):
@@ -2634,6 +2705,11 @@ class TpuChainExecutor:
             return col, False
 
         a_col, a_is_delta = _pick(packed["agg_int"], a_d, scal[0])
+        # which form the accumulator column crossed the down-link in,
+        # next to the `down-*` variants (`_count_down_variant`)
+        TELEMETRY.add_link_variant(
+            f"agg-delta-{a_col.dtype.name}" if a_is_delta else "agg-full"
+        )
         slices = [packed["mask"], lax.slice(a_col, (0,), (rows,))]
         if windowed:
             w_col, w_is_delta = _pick(packed["agg_win"], w_d, scal[2])
@@ -3444,3 +3520,33 @@ class TpuChainExecutor:
             has = True if not window_ms else inst._window_start is not None
             self.carries[slot] = (acc, win, has)
             slot += 1
+
+
+class _StreamExecutor(TpuChainExecutor):
+    """One stream of another executor's compiled chain
+    (`TpuChainExecutor.open_stream`).
+
+    It holds two things, the compiled executor and its own
+    `StreamState`. Every method is the compiled class's own, run with
+    this object as ``self``: a `StreamState` field resolves through the
+    class properties to THIS stream's state, every other attribute is
+    read from and written to the compiled executor, so capacity
+    learning, link latches and byte counters stay shared while carries
+    and heal lineage do not."""
+
+    def __init__(self, compiled: TpuChainExecutor) -> None:
+        self.__dict__["_compiled"] = compiled
+        self.__dict__["state"] = StreamState(compiled.initial_carries())
+
+    def __getattr__(self, name: str):
+        # reached only for names this object does not hold itself
+        return getattr(self.__dict__["_compiled"], name)
+
+    def __setattr__(self, name: str, value) -> None:
+        if isinstance(getattr(TpuChainExecutor, name, None), property):
+            object.__setattr__(self, name, value)
+        else:
+            setattr(self._compiled, name, value)
+
+    def open_stream(self) -> TpuChainExecutor:
+        return self._compiled.open_stream()

@@ -922,6 +922,7 @@ class StripedChain:
                 elif kind == "agg":
                     st = dict(ctx["seg_state"])
                     st["valid"] = valid
+                    agg_ctx["stage_index"] = i
                     st, carries = arg.apply(st, carries, base_ts, agg_ctx)
                     ctx["seg_state"] = st
                 else:  # fanout (terminal)

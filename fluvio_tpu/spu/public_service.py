@@ -557,18 +557,23 @@ class StreamFetchHandler:
             return
 
         chain = None
+        # the flow of the stream's first slice is born with the stream:
+        # the first thing that slice waits for is its chain
+        first_flow = None
         if req.smartmodules:
+            first_flow = TELEMETRY.begin_flow(tenant=self._tenant)
             try:
                 # chain build runs @init hooks (user code, metered):
                 # keep it off the loop so a looping init stalls only
                 # this stream, not every connection
-                chain = await asyncio.to_thread(
-                    acquire_stream_chain,
-                    req.smartmodules,
-                    self.ctx,
-                    self.version,
-                )
-                await chain_look_back(chain, leader)
+                with timed(first_flow, "chain_acquire"):
+                    chain = await asyncio.to_thread(
+                        acquire_stream_chain,
+                        req.smartmodules,
+                        self.ctx,
+                        self.version,
+                    )
+                    await chain_look_back(chain, leader)
             except (
                 SmartModuleResolutionError,
                 SmartModuleChainInitError,
@@ -605,13 +610,22 @@ class StreamFetchHandler:
             # lag until its first ack (which would false-breach the
             # consumer_lag SLO and shed a caught-up partition)
             lag_mod.note_commit(self._lag_key, current)
+        if first_flow is not None:
+            first_flow.chain = self._lag_key
+            if current >= bound:
+                # nothing to serve yet: the open stands on its own, and
+                # the first slice's flow is born when the slice arrives
+                TELEMETRY.end_flow(first_flow)
+                first_flow = None
 
         end_wait = asyncio.ensure_future(self.conn.end.wait())
         try:
             if chain is not None and tpu_pipelinable(chain):
-                await self._run_pipelined(leader, chain, end_wait, current)
+                await self._run_pipelined(
+                    leader, chain, end_wait, current, first_flow
+                )
                 return
-            flow = None  # the current slice's causal flow record
+            flow = first_flow  # the current slice's causal flow record
             while not self.conn.end.is_set() and not self._ended:
                 bound = leader.read_bound(req.isolation)
                 if current < bound:
@@ -681,7 +695,9 @@ class StreamFetchHandler:
         finally:
             end_wait.cancel()
 
-    async def _run_pipelined(self, leader, chain, end_wait, current: int) -> None:
+    async def _run_pipelined(
+        self, leader, chain, end_wait, current: int, first_flow=None
+    ) -> None:
         """Dispatch-ahead stream loop for stateless TPU chains.
 
         Slice k+1 is read, staged, and dispatched (JAX dispatch is async:
@@ -694,8 +710,10 @@ class StreamFetchHandler:
         """
         req = self.req
         pending: Optional[PendingSlice] = None
-        held_flow = None  # the next slice's flow, born at arrival and
-        # carried across shed-hold retries until it stages or serves
+        # the next slice's flow, born at arrival and carried across
+        # shed-hold retries until it stages or serves; the stream's
+        # first one comes with its `chain_acquire` phase on it
+        held_flow = first_flow
         while not self.conn.end.is_set() and not self._ended:
             planned = pending.planned_next if pending is not None else current
             nxt: Optional[PendingSlice] = None

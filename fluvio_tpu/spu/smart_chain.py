@@ -197,7 +197,7 @@ def acquire_stream_chain(
     ctx: GlobalContext,
     version: Optional[int] = None,
 ) -> SmartModuleChainInstance:
-    """build_chain with an SPU-level cache for STATELESS chains.
+    """build_chain with an SPU-level cache of COMPILED chains.
 
     Every stream-fetch request builds its chain from wire invocations
     (matching the reference, which instantiates the wasm store per
@@ -205,17 +205,25 @@ def acquire_stream_chain(
     cheap: a fresh executor re-traces its jitted chain function and
     reloads the XLA executable for each shape bucket — hundreds of ms
     per stream even with the persistent compile cache hot, which
-    dominated the broker end-to-end benchmark. Pure DSL chains with no
-    device state make sharing sound:
+    dominated the broker end-to-end benchmark. What is compiled does
+    not depend on the stream, so pure DSL chains on the TPU backend
+    are built once per key:
 
-    - no aggregate carries (nothing crosses calls),
-    - no lookback (nothing seeded per replica),
-    - the TPU backend is in use (the DSL program is the semantic spec;
+    - a stateless chain is shared as it is: nothing crosses calls,
       dispatch handles are explicit, so interleaved slices from
-      concurrent streams on one executor do not interact).
+      concurrent streams on one executor do not interact;
+    - a stateful chain (aggregate carries) is shared by its compiled
+      half only: the cache keeps the built chain and every stream gets
+      `open_stream()` of it, i.e. its own interpreter instances and
+      its own `StreamState` from the chain spec's seed (the key
+      includes the invocation's accumulator). The cached chain itself
+      never serves, so its state stays at the seed.
 
-    Anything else — stateful, lookback-seeded, python-only — gets a
-    fresh chain per stream exactly as before.
+    Anything else — lookback-seeded (state comes from the replica),
+    python-only, sharded-and-stateful (the sharded delegate keeps its
+    own carry) — gets a fresh chain per stream exactly as before.
+    Books `stream_chain_hits` / `stream_chain_builds` on the SPU's
+    chain metrics.
     """
     key_parts = [str(version)]
     cacheable = True
@@ -250,28 +258,39 @@ def acquire_stream_chain(
         if chain is not None:
             if getattr(chain, "_poisoned", None) is not None:
                 # a fuel trap poisoned this chain (abandoned hook thread
-                # or trapped stateful instance); never serve it to new
-                # streams — rebuild instead. A module that traps cleanly
-                # every time pays chain build + its budget per stream,
+                # or trapped stateful instance, its own or of a stream
+                # opened from it); never serve it to new streams —
+                # rebuild instead. A module that traps cleanly every
+                # time pays chain build + its budget per stream,
                 # matching the reference, where each stream instantiates
                 # the wasm and burns fuel to the trap; only ABANDONED
                 # threads escalate to the per-module quarantine.
                 del ctx.stream_chains[key]
             else:
                 ctx.stream_chains.move_to_end(key)
-                return chain
+                ctx.metrics.smartmodule.add_stream_chain(hit=True)
+                return _stream_of(chain)
     chain = build_chain(invocations, ctx, version)
+    ctx.metrics.smartmodule.add_stream_chain(hit=False)
+    TELEMETRY.add_chain_build(chain.chain_label)
     tpu = getattr(chain, "tpu_chain", None)
     if (
         cacheable
         and tpu is not None
-        and not tpu.agg_configs
         and chain.backend_in_use == "tpu"
+        and not (tpu.agg_configs and tpu._sharded is not None)
     ):
         ctx.stream_chains[key] = chain
         while len(ctx.stream_chains) > _STREAM_CHAIN_CACHE_MAX:
             ctx.stream_chains.popitem(last=False)
+        return _stream_of(chain)
     return chain
+
+
+def _stream_of(cached: SmartModuleChainInstance) -> SmartModuleChainInstance:
+    """What a stream gets of a cached chain: the chain itself when it
+    holds no state, else a stream of it with a state of its own."""
+    return cached.open_stream() if cached.tpu_chain.agg_configs else cached
 
 
 async def ensure_dedup_chain(ctx: GlobalContext, leader: LeaderReplicaState) -> None:

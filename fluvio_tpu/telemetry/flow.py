@@ -17,6 +17,12 @@ and the served path's own steps, each one clock pair per SLICE taken
 where the work happens (`spans.timed(flow, phase)`), so the slice is
 the root span of everything the serving task did for it —
 
+- ``chain_acquire`` `acquire_stream_chain` for the stream this slice is
+                  the first of: a cache hit (and, for a stateful chain,
+                  a `StreamState` of its own) or a chain build, then
+                  the chain's look-back seed from the replica. On the
+                  flow of a stream's FIRST slice only; a stream that
+                  opens on an empty log leaves a flow of this one phase,
 - ``read``        `leader.read_records` + the shallow batch decode
                   (both stream loops of `spu/public_service.py`),
 - ``wire_decode`` the native per-batch wire decode and its guards in
@@ -62,7 +68,7 @@ from fluvio_tpu.telemetry.spans import _BoundedRing
 #: and the Prometheus ``slice_wait_seconds`` family key on it)
 SLICE_PHASES = (
     "queue_wait", "batcher", "hold", "serve",
-    "read", "wire_decode", "stage", "dispatch", "finish", "encode", "send",
+    "chain_acquire", "read", "wire_decode", "stage", "dispatch", "finish", "encode", "send",
     "ack_wait", "interpret",
 )
 

@@ -594,6 +594,8 @@ def _render_spu(w: _Writer, m: dict) -> None:
         ("fuel_used", "Metered fuel units consumed."),
         ("fastpath_slices", "Read slices that ran the coalesced TPU fast path."),
         ("fallback_slices", "Read slices that fell back to the per-record loop."),
+        ("stream_chain_hits", "Stream opens served from the stream-chain cache."),
+        ("stream_chain_builds", "Stream opens that built their chain."),
     )
     for field, help_text in scalar_fields:
         name = f"{_PREFIX}_smartmodule_{field}_total"

@@ -517,6 +517,15 @@ class PipelineTelemetry:
             self.heals += 1
         self._event("heal")
 
+    def add_chain_build(self, chain: str) -> None:
+        """A stream open BUILT its chain (a re-trace and an executable
+        load per shape bucket) instead of finding it in the SPU's
+        stream-chain cache: an instant event, so the flight recorder
+        shows the build beside the compiles it causes and a reader can
+        count the builds of a time window. The running counter is the
+        SPU's own (`metrics.smartmodule.stream_chain_builds`)."""
+        self._event("chain-build", chain)
+
     def add_stripe_fallback(self) -> None:
         with self._lock:
             self.stripe_fallbacks += 1

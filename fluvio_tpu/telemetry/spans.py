@@ -94,7 +94,7 @@ DEVICE_SCOPES = (
 #: checkout sharing the cache directory) is otherwise loaded as "the
 #: same program" and profiles under the OLD names — or none. Bump it
 #: whenever a scope is added, renamed or moved.
-DEVICE_SCOPES_TAG = "s1"
+DEVICE_SCOPES_TAG = "s2"
 
 
 def scoped_program(fn):
@@ -114,7 +114,12 @@ def scoped_program(fn):
 def stage_scope(index: int, kind: str) -> str:
     """The device scope of one chain stage: position + the stage's
     kind (`filter`, `map`, `array_map`, `aggregate`, or a striped op
-    kind) — never a module name or a parameter (no user data)."""
+    kind) — never a module name or a parameter (no user data). An
+    aggregate opens `stage<i>.aggregate_scan` inside its own scope
+    around the carry chain and the scan, so its contribution (the
+    field extraction and parse) keeps `stage<i>.aggregate`; the inner
+    name has the stage form because a reader names an operation by the
+    innermost path component that is one of these scopes."""
     return f"stage{index}.{kind}"
 
 
