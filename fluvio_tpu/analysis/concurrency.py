@@ -111,6 +111,10 @@ EXTRA_THREAD_ROOTS = (
     "smartengine.tpu.executor.TpuChainExecutor._redispatch_refetch",
     "spu.smart_chain.tpu_stage_dispatch",
     "spu.smart_chain.tpu_finish",
+    "spu.smart_chain.tpu_stage",
+    "spu.smart_chain.tpu_dispatch",
+    "spu.smart_chain.tpu_fetch",
+    "spu.smart_chain.tpu_materialize",
     "spu.monitoring.MonitoringServer._handle",
     "smartengine.metering.run_metered",
 )

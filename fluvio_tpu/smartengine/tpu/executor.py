@@ -1920,11 +1920,23 @@ class TpuChainExecutor:
         words = self._padded(flat, bucket).view(np.int32)
         return jnp.asarray(words), None, None, None, 0, words.nbytes
 
-    def _ensure_host_state(self) -> None:
+    def _download_carries(self):
+        """The device carries as host values; None where the host
+        mirror is the authority (nothing dispatched yet)."""
         if self._device_carries is None:
-            return
+            return None
         with transfer_guard_fetch():
-            host = jax.device_get(self._device_carries)
+            return jax.device_get(self._device_carries)
+
+    def _ensure_host_state(self, host=None) -> None:
+        """Bring the host mirror (and the interpreter's instances) up to
+        the device carries, or to ``host``: the carries a caller
+        downloaded earlier, before a later dispatch replaced the device
+        ones by futures of its own."""
+        if host is None:
+            host = self._download_carries()
+        if host is None:
+            return
         self.carries = [(int(a), int(w), bool(h)) for a, w, h in host]
         self._sync_instances()
 
@@ -2363,11 +2375,13 @@ class TpuChainExecutor:
 
         if self._int_output:
             self._count_down_variant(None)
-            return self._fetch_ints(buf, count, packed, int_probe, span)
+            return self._fetch_ints(
+                buf, count, packed, int_probe, span, defer=defer
+            )
 
         return self._fetch_bytes(
             buf, count, packed, max_v, max_k, _src_col, _src_decode, span,
-            down_meta=down_meta, payload_len=payload_len,
+            down_meta=down_meta, payload_len=payload_len, defer=defer,
         )
 
     @staticmethod
@@ -2508,11 +2522,13 @@ class TpuChainExecutor:
     def _fetch_bytes(
         self, buf: RecordBuffer, count: int, packed, max_v, max_k,
         _src_col, _src_decode, span=None, down_meta=None,
-        payload_len=None,
-    ) -> RecordBuffer:
+        payload_len=None, defer: bool = False,
+    ):
         """Byte-mode materialization: compacted value/key columns cross
         the link sliced to count x used-width (tail of `_fetch`; the
-        src-column helpers close over its probe state).
+        src-column helpers close over its probe state). ``defer``: as
+        in the view mode's `_mat`, everything after the download is
+        numpy over host arrays and is returned as a thunk.
 
         With result compaction armed (``packed["payload"]``) the value
         matrix never crosses at all: ONE packed 4-aligned payload does —
@@ -2579,60 +2595,65 @@ class TpuChainExecutor:
             slices.append(lax.slice(packed["offset_deltas"], (0,), (rows,)))
             slices.append(lax.slice(packed["timestamp_deltas"], (0,), (rows,)))
         host = self._download(slices, span)
-        pos = 0
-        out_values = None
-        if use_payload:
-            if payload_np is None:
-                payload_np = np.asarray(host[pos])
-                pos += 1
-        else:
-            out_values = host[pos]
-            pos += 1
-        out_lengths = np.asarray(host[pos]).astype(np.int32)
-        pos += 1
-        src = None
-        if self._fanout:
-            src = _src_decode(host[pos])
-            pos += 1
-        elif want_mask:
-            src = self._mask_to_src(host[pos], buf)
-            pos += 1
-        if want_keys:
-            out_klens = host[pos]
-            out_keys = host[pos + 1] if kw else np.zeros((rows, 1), dtype=np.uint8)
-            pos += 1 + (1 if kw else 0)
-        else:
-            out_klens = np.full((rows,), -1, dtype=np.int32)
-            out_keys = np.zeros((rows, 1), dtype=np.uint8)
-        flat = starts = None
-        if use_payload:
-            # adopt the payload flat-backed: per-row aligned starts are
-            # one cumsum over the downloaded lengths (bit-identical to
-            # the device's packing by construction)
-            out_lengths = out_lengths.copy()
-            out_lengths[count:] = 0
-            l4 = (out_lengths.astype(np.int64) + 3) & ~3
-            starts_all = np.cumsum(l4) - l4
-            starts = starts_all.astype(np.int32)
-            flat = np.ascontiguousarray(payload_np[: int(l4.sum())])
         self._count_down_variant(used_tokens)
-        if want_dev_offsets:
-            out_off = np.asarray(host[pos]).astype(np.int32)
-            out_ts = np.asarray(host[pos + 1]).astype(np.int64)
-            out_off[count:] = 0
-            out_ts[count:] = 0
-            return RecordBuffer(
-                values=out_values, lengths=out_lengths, keys=out_keys,
-                key_lengths=out_klens, offset_deltas=out_off,
-                timestamp_deltas=out_ts, count=count,
-                base_offset=buf.base_offset, base_timestamp=buf.base_timestamp,
-                _flat=flat, _starts=starts,
-                _width=vw if use_payload else 0,
-                _rows=rows if use_payload else 0,
-            )
-        return self._assemble(buf, count, rows, out_values, out_lengths,
-                              out_keys, out_klens, src,
-                              flat=flat, starts=starts, vw=vw)
+
+        def _split_back() -> RecordBuffer:
+            payload = payload_np
+            pos = 0
+            out_values = None
+            if use_payload:
+                if payload is None:
+                    payload = np.asarray(host[pos])
+                    pos += 1
+            else:
+                out_values = host[pos]
+                pos += 1
+            out_lengths = np.asarray(host[pos]).astype(np.int32)
+            pos += 1
+            src = None
+            if self._fanout:
+                src = _src_decode(host[pos])
+                pos += 1
+            elif want_mask:
+                src = self._mask_to_src(host[pos], buf)
+                pos += 1
+            if want_keys:
+                out_klens = host[pos]
+                out_keys = host[pos + 1] if kw else np.zeros((rows, 1), dtype=np.uint8)
+                pos += 1 + (1 if kw else 0)
+            else:
+                out_klens = np.full((rows,), -1, dtype=np.int32)
+                out_keys = np.zeros((rows, 1), dtype=np.uint8)
+            flat = starts = None
+            if use_payload:
+                # adopt the payload flat-backed: per-row aligned starts are
+                # one cumsum over the downloaded lengths (bit-identical to
+                # the device's packing by construction)
+                out_lengths = out_lengths.copy()
+                out_lengths[count:] = 0
+                l4 = (out_lengths.astype(np.int64) + 3) & ~3
+                starts_all = np.cumsum(l4) - l4
+                starts = starts_all.astype(np.int32)
+                flat = np.ascontiguousarray(payload[: int(l4.sum())])
+            if want_dev_offsets:
+                out_off = np.asarray(host[pos]).astype(np.int32)
+                out_ts = np.asarray(host[pos + 1]).astype(np.int64)
+                out_off[count:] = 0
+                out_ts[count:] = 0
+                return RecordBuffer(
+                    values=out_values, lengths=out_lengths, keys=out_keys,
+                    key_lengths=out_klens, offset_deltas=out_off,
+                    timestamp_deltas=out_ts, count=count,
+                    base_offset=buf.base_offset, base_timestamp=buf.base_timestamp,
+                    _flat=flat, _starts=starts,
+                    _width=vw if use_payload else 0,
+                    _rows=rows if use_payload else 0,
+                )
+            return self._assemble(buf, count, rows, out_values, out_lengths,
+                                  out_keys, out_klens, src,
+                                  flat=flat, starts=starts, vw=vw)
+
+        return _split_back if defer else _split_back()
 
     @staticmethod
     def _ints_to_ascii_host(ints: np.ndarray):
@@ -2680,8 +2701,9 @@ class TpuChainExecutor:
         return out_values, out_lengths, out_keys, out_klens
 
     def _fetch_ints(
-        self, buf: RecordBuffer, count: int, packed, probe, span=None
-    ) -> RecordBuffer:
+        self, buf: RecordBuffer, count: int, packed, probe, span=None,
+        defer: bool = False,
+    ):
         """Int-output D2H: survivor mask + accumulator column(s); the host
         renders decimals (and window keys) itself.
 
@@ -2691,7 +2713,9 @@ class TpuChainExecutor:
         int16/int32 deltas plus a scalar base whenever the batch's max
         |delta| fits (decided per batch by a tiny scalar sync), and the
         host reconstructs with one cumsum. Window ids are non-decreasing
-        and delta-compress the same way."""
+        and delta-compress the same way. ``defer``: the delta decode, the
+        int -> ASCII render and the assembly are numpy over the
+        downloaded arrays and are returned as a thunk."""
         windowed = bool(self.stages[-1].window_ms)
         n_c = packed["agg_int"].shape[0]
         rows = min(self._bucket_bytes(max(count, 1), 8), n_c)
@@ -2715,24 +2739,28 @@ class TpuChainExecutor:
             w_col, w_is_delta = _pick(packed["agg_win"], w_d, scal[2])
             slices.append(lax.slice(w_col, (0,), (rows,)))
         host = self._download(slices, span)
-        src = self._mask_to_src(host[0], buf)
-        ints = (
-            self._delta_decode(host[1], scal[1], count)
-            if a_is_delta
-            else np.asarray(host[1][:count]).astype(np.int64)
-        )
-        wins = None
-        if windowed:
-            wins = (
-                self._delta_decode(host[2], scal[3], count)
-                if w_is_delta
-                else np.asarray(host[2][:count]).astype(np.int64)
+
+        def _split_back() -> RecordBuffer:
+            src = self._mask_to_src(host[0], buf)
+            ints = (
+                self._delta_decode(host[1], scal[1], count)
+                if a_is_delta
+                else np.asarray(host[1][:count]).astype(np.int64)
             )
-        out_values, out_lengths, out_keys, out_klens = self._int_output_columns(
-            buf, ints, wins, src, rows, count
-        )
-        return self._assemble(buf, count, rows, out_values, out_lengths,
-                              out_keys, out_klens, src)
+            wins = None
+            if windowed:
+                wins = (
+                    self._delta_decode(host[2], scal[3], count)
+                    if w_is_delta
+                    else np.asarray(host[2][:count]).astype(np.int64)
+                )
+            out_values, out_lengths, out_keys, out_klens = self._int_output_columns(
+                buf, ints, wins, src, rows, count
+            )
+            return self._assemble(buf, count, rows, out_values, out_lengths,
+                                  out_keys, out_klens, src)
+
+        return _split_back if defer else _split_back()
 
     def _assemble(self, buf, count, rows, out_values, out_lengths, out_keys,
                   out_klens, src, flat=None, starts=None,
@@ -2992,9 +3020,9 @@ class TpuChainExecutor:
 
         JAX dispatch is async, so the H2D transfer and device compute
         proceed in the background; the returned handle feeds
-        `finish_buffer`. The broker's pipelined stream loop dispatches
-        slice k+1 here while slice k's results download and hit the
-        socket. ``flow_id`` names the slice flow that caused this
+        `finish_buffer`. The broker's stream loop dispatches slice k+1
+        here once slice k is fetched, so the device works while slice k
+        is split back, encoded and sent. ``flow_id`` names the slice flow that caused this
         dispatch on its span (0 = none; a buffer the admission
         pipeline tagged with its flow names it itself).
         """
@@ -3166,6 +3194,19 @@ class TpuChainExecutor:
             # corrupt chain the heal rolled away from
             return
         self._device_carries = handle[0]
+
+    def rollback_finished(self, handle) -> None:
+        """Restore the carries a FINISHED dispatch started from: its
+        slice declined after the fetch (the broker's record encode
+        refused the output) and is re-run per record, which must start
+        where the slice did. Nothing of the stream may be in flight: a
+        caller that dispatched ahead discards that first."""
+        if not self.agg_configs:
+            return
+        if self._sharded is not None:
+            self._sharded._pending_carries = handle[0]
+        else:
+            self._device_carries = handle[0]
 
     def finish_buffer(self, buf: RecordBuffer, handle) -> RecordBuffer:
         """Phase 2: block on results and materialize the output buffer.

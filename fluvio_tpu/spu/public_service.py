@@ -13,7 +13,7 @@ import asyncio
 import logging
 import time
 import weakref
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from fluvio_tpu.protocol.api import (
     ApiVersionKey,
@@ -60,9 +60,10 @@ from fluvio_tpu.spu.smart_chain import (
     ensure_dedup_chain,
     process_batches,
     process_batches_per_record,
-    tpu_finish,
-    tpu_pipelinable,
-    tpu_stage_dispatch,
+    tpu_dispatch,
+    tpu_fetch,
+    tpu_materialize,
+    tpu_stage,
 )
 from fluvio_tpu.smartengine.engine import EngineError, SmartModuleChainInitError
 from fluvio_tpu.smartengine.metering import SmartModuleFuelError
@@ -454,6 +455,36 @@ def _process_batches_from(
     )
 
 
+def _advance_slices(
+    chain, pending, inflight, nxt_batches, metrics, start_offset, topic,
+    partition, nxt_flow,
+):
+    """Steps 1-3 of the stream loop (`_run_pipelined`), one pass on a
+    worker thread: stage slice k+1 (``nxt_batches``; host only), fetch
+    slice k (``pending``), then dispatch k+1. ``inflight`` is the
+    stream's register of slices with live handles, kept HERE so that a
+    cancelled stream still finds what this pass dispatched. Returns
+    (slice k fetched, slice k+1 dispatched or None). A staged k+1 is
+    not dispatched ahead of a slice k that has to be re-run."""
+    staged = None
+    if nxt_batches is not None:
+        staged = tpu_stage(
+            chain, nxt_batches, metrics, start_offset, flow=nxt_flow
+        )
+    fetched = False
+    if pending is not None:
+        fetched = tpu_fetch(chain, pending, metrics, topic, partition)
+        inflight.remove(pending)  # settled either way
+    nxt = None
+    if staged is not None and (pending is None or fetched):
+        nxt = tpu_dispatch(chain, staged, metrics, topic, partition)
+        if nxt is not None:
+            inflight.append(nxt)
+            if pending is not None and pending.flow is not None:
+                pending.flow.interleaved = True
+    return fetched, nxt
+
+
 class StreamFetchHandler:
     """One push stream: select loop over data / acks / end.
 
@@ -620,11 +651,13 @@ class StreamFetchHandler:
 
         end_wait = asyncio.ensure_future(self.conn.end.wait())
         try:
-            if chain is not None and tpu_pipelinable(chain):
+            if getattr(chain, "tpu_chain", None) is not None:
                 await self._run_pipelined(
                     leader, chain, end_wait, current, first_flow
                 )
                 return
+            # plain consumes, and chains the chip does not serve (the
+            # interpreter and native backends): slice by slice
             flow = first_flow  # the current slice's causal flow record
             while not self.conn.end.is_set() and not self._ended:
                 bound = leader.read_bound(req.isolation)
@@ -698,22 +731,63 @@ class StreamFetchHandler:
     async def _run_pipelined(
         self, leader, chain, end_wait, current: int, first_flow=None
     ) -> None:
-        """Dispatch-ahead stream loop for stateless TPU chains.
+        """The stream loop of every chain the chip serves (stateless,
+        fan-out, stateful): one order of a slice's phases.
 
-        Slice k+1 is read, staged, and dispatched (JAX dispatch is async:
-        H2D + device compute proceed in the background) BEFORE slice k's
-        results are downloaded, encoded, and pushed — so the device works
-        under the socket send and the consumer's ack wait instead of
-        after them. Speculation is safe because `tpu_pipelinable` chains
-        carry no device state to roll back; a max_bytes truncation (the
-        consume point moved) just discards the speculative dispatch.
+        1. read, wire_decode, stage of slice k+1: host only;
+        2. fetch(k): the blocking half of slice k (`tpu_fetch`), with
+           nothing else queued on the chip, so its count-sized slice
+           programs run at once, a fan-out overflow retries alone, and
+           a stateful slice's carry is settled;
+        3. dispatch(k+1): JAX dispatch is async, H2D and device compute
+           proceed in the background;
+        4. materialize, encode, send, ack_wait of slice k, while the
+           chip works on k+1.
+
+        No slice is ever dispatched ahead of a fetch that can roll a
+        carry back. A slice that declines in step 4, or a ``max_bytes``
+        cut found there (the consume point moved), discards the
+        dispatched k+1; so does a stream that ends with one in flight.
+
+        The host halves run OFF the event loop (`_off_loop`), under the
+        chain's lock: steps 1-3 as one pass (the log read stays on the
+        loop, which owns the replica), step 4's join and encode as
+        another; send and ack_wait await on the loop.
         """
+        inflight: List[PendingSlice] = []
+        try:
+            await self._pipelined_loop(
+                leader, chain, end_wait, current, first_flow, inflight
+            )
+        finally:
+            # the stream ends (consumer gone, error, end of the
+            # connection) with a slice out on the device: nobody will
+            # fetch it, so its handles, gauges and carries go back here
+            for p in reversed(inflight):
+                p.discard(chain.tpu_chain)
+
+    async def _off_loop(self, chain, fn, *args):
+        """`_chain_off_loop` for a pass that holds device handles: a
+        cancel waits for the pass to settle before it goes on. The
+        worker thread cannot be interrupted, and the stream's clean-up
+        must not race it for the slice's handles."""
+        task = asyncio.ensure_future(_chain_off_loop(chain, fn, *args))
+        try:
+            return await asyncio.shield(task)
+        except asyncio.CancelledError:
+            await asyncio.wait([task])
+            raise
+
+    async def _pipelined_loop(
+        self, leader, chain, end_wait, current, first_flow, inflight
+    ) -> None:
         req = self.req
-        pending: Optional[PendingSlice] = None
         # the next slice's flow, born at arrival and carried across
         # shed-hold retries until it stages or serves; the stream's
         # first one comes with its `chain_acquire` phase on it
         held_flow = first_flow
+        # the slice out on the device (dispatched, not fetched), if any
+        pending: Optional[PendingSlice] = None
         while not self.conn.end.is_set() and not self._ended:
             planned = pending.planned_next if pending is not None else current
             nxt: Optional[PendingSlice] = None
@@ -726,7 +800,7 @@ class StreamFetchHandler:
                     held_flow = TELEMETRY.begin_flow(
                         self._lag_key, self._tenant
                     )
-                # admission front door for the speculative read: a shed
+                # admission front door for the next slice's read: a shed
                 # skips THIS slice's intake (the in-flight one still
                 # finishes below) and, when nothing is in flight,
                 # sleeps out the backpressure hint — offsets never
@@ -760,23 +834,30 @@ class StreamFetchHandler:
                         e.code, hw=info.hw, log_start=info.start_offset
                     )
                     return
-                if nxt_batches is not None:
-                    nxt = tpu_stage_dispatch(
-                        chain, nxt_batches, self.metrics, start_offset=planned,
-                        topic=req.topic, partition=req.partition,
-                        flow=nxt_flow,
-                    )
+
+            fetched = False
+            if pending is not None or nxt_batches is not None:
+                # steps 1-3, one pass off the loop
+                fetched, nxt = await self._off_loop(
+                    chain, _advance_slices, chain, pending, inflight,
+                    nxt_batches, self.metrics, planned, req.topic,
+                    req.partition, nxt_flow,
+                )
 
             if pending is not None:
-                result = tpu_finish(
-                    chain, pending, req.max_bytes, self.metrics,
-                    topic=req.topic, partition=req.partition,
-                )
-                if result is None:
+                # step 4, the chip busy with `nxt` meanwhile
+                result = None
+                if fetched:
+                    result = await self._off_loop(
+                        chain, tpu_materialize, chain, pending,
+                        req.max_bytes, self.metrics, nxt,
+                    )
+                declined = result is None
+                if declined:
                     # rare decline: rerun this slice on the per-record path
                     # (directly — re-entering process_batches would
                     # re-dispatch the failed slice and double-count)
-                    result = await _chain_off_loop(
+                    result = await self._off_loop(
                         chain, process_batches_per_record,
                         chain, pending.batches, req.max_bytes, self.metrics,
                         pending.flow,
@@ -791,11 +872,14 @@ class StreamFetchHandler:
                         return
                     truncated = sent_next != pending.planned_next
                     pending = None
-                    if truncated and nxt is not None:
-                        # the speculative slice read from the wrong offset
-                        # (its flow record dies with it — never served)
-                        nxt.discard(chain.tpu_chain)
+                    if nxt is not None and (truncated or declined):
+                        # `tpu_materialize` discarded it: it read from the
+                        # wrong offset, or ran ahead of a slice that is
+                        # re-run (its flow record dies with it — never
+                        # served)
+                        inflight.remove(nxt)
                         nxt = None
+                    if truncated:
                         nxt_batches = None
                     await self._wait_for_ack(
                         sent_next, end_wait, flow=served_flow
@@ -819,8 +903,10 @@ class StreamFetchHandler:
                 pending = nxt
                 continue
             if nxt_batches is not None:
-                # staging declined this slice: serial per-record path
-                result = await _chain_off_loop(
+                # not dispatched (staging or the dispatch declined it, or
+                # the slice before it was re-run): served now, one slice
+                # through all its phases, per record where that declines
+                result = await self._off_loop(
                     chain, _process_batches_from, chain, nxt_batches,
                     req.max_bytes, self.metrics, read_from,
                     req.topic, req.partition, nxt_flow,

@@ -379,24 +379,34 @@ class BatchProcessResult:
 
 @dataclass
 class PendingSlice:
-    """A read slice staged + dispatched to the device, results pending.
+    """A read slice on its way through the device: staged (`tpu_stage`:
+    ``bufs``), dispatched (`tpu_dispatch`: ``chunks``), fetched
+    (`tpu_fetch`: ``parts``), then materialized and encoded
+    (`tpu_materialize`).
 
     ``chunks`` holds (RecordBuffer, dispatch handle) pairs in slice
     order. Stateless chains split a large slice into several dispatches
-    (all in flight at once — see the chunking note in
-    `tpu_stage_dispatch`); stateful/fan-out chains always stage exactly
-    one chunk."""
+    (all in flight at once — see the chunking note in `tpu_stage`);
+    stateful/fan-out chains always stage exactly one chunk."""
 
     batches: List[Batch]
-    chunks: List[tuple]  # [(RecordBuffer, executor dispatch handle)]
     planned_next: int  # next offset assuming no max_bytes truncation
     total_raw: int
     base0: int
     ts0: int
     count: int  # staged input records across all chunks
     read_from: Optional[int] = None  # consume cursor (drop outputs below)
+    bufs: List = field(default_factory=list)  # staged chunk buffers
+    chunks: List[tuple] = field(default_factory=list)  # [(buf, handle)]
+    # per chunk, after the fetch: its output buffer, the Future of its
+    # split-back thunk on the fetch worker, or (the last chunk) the
+    # thunk itself. None until then: the chunks' handles are live and a
+    # discard has to drop them
+    parts: Optional[List] = None
+    # stateful chains: the carries the fetched slice left, as host values
+    carries: Optional[List] = None
     # chunks currently counted in the inflight_queue_depth gauge (set at
-    # dispatch; release is idempotent — finish and discard both call it)
+    # dispatch; release is idempotent — fetch and discard both call it)
     tracked_depth: int = 0
     # the slice's causal flow record (telemetry/flow.py), carried from
     # arrival through dispatch to the serve that closes it; None when
@@ -409,9 +419,21 @@ class PendingSlice:
             self.tracked_depth = 0
 
     def discard(self, tpu) -> None:
+        """Drop the slice wherever it stands (idempotent). Dispatched
+        and not fetched: every handle is discarded, newest first, so a
+        stateful chain is back at the carry this slice started from.
+        Staged only, or already fetched: nothing of it is on the device."""
         self.release_depth()
-        for _, handle in self.chunks:
-            tpu.discard_dispatch(handle)
+        if self.parts is None:
+            for _, handle in reversed(self.chunks):
+                tpu.discard_dispatch(handle)
+        self.chunks = []
+
+    def rollback(self, tpu) -> None:
+        """A FETCHED slice declines (`encode-failed`): its carries go
+        back to where the slice started, for the per-record rerun."""
+        if self.chunks:
+            tpu.rollback_finished(self.chunks[0][1])
 
 
 def _decline(metrics, reason: str):
@@ -554,26 +576,18 @@ def admission_require_warm(chain) -> None:
         ctl.require_warm(admission_chain_sig(chain))
 
 
-def tpu_pipelinable(chain) -> bool:
-    """Safe for speculative dispatch-ahead: stateless, row-preserving
-    chains only (no carries to roll back when a speculative slice is
-    discarded, no fan-out overflow retries)."""
-    tpu = getattr(chain, "tpu_chain", None)
-    return tpu is not None and not tpu.agg_configs and not tpu._fanout
-
-
-def tpu_stage_dispatch(
+def tpu_stage(
     chain: SmartModuleChainInstance,
     batches: List[Batch],
     metrics=None,
     start_offset: Optional[int] = None,
-    topic: Optional[str] = None,
-    partition: Optional[int] = None,
     flow=None,
 ) -> Optional[PendingSlice]:
-    """Phase 1 of the TPU fast path: stage a read slice into columnar
-    buffers through the native parser (no per-record Python objects),
-    coalesce it into ONE device dispatch, and return without blocking.
+    """The host half of a slice's way in: stage a read slice into
+    columnar chunk buffers through the native parser (no per-record
+    Python objects). Touches no device and no device state, so the
+    stream loop runs it for slice k+1 while slice k is still out on the
+    device.
 
     Returns None (counting the decline reason) when the chain has no TPU
     executor, the native library is unavailable, a batch's slab
@@ -583,7 +597,6 @@ def tpu_stage_dispatch(
     from fluvio_tpu.protocol.compression import Compression, decompress
     from fluvio_tpu.smartengine import native_backend
     from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
-    from fluvio_tpu.smartengine.tpu.executor import TpuSpill
 
     tpu = getattr(chain, "tpu_chain", None)
     if tpu is None or not batches:
@@ -739,6 +752,34 @@ def tpu_stage_dispatch(
         TELEMETRY.add_phase(
             "stage", time.perf_counter() - t_stage0 - glz_decode_s
         )
+    return PendingSlice(
+        batches=batches,
+        bufs=chunk_bufs,
+        planned_next=staged[-1][0].computed_last_offset(),
+        total_raw=total_raw,
+        base0=base0,
+        ts0=ts0,
+        count=n_total,
+        read_from=start_offset,
+        flow=flow,
+    )
+
+
+def tpu_dispatch(
+    chain: SmartModuleChainInstance,
+    pending: PendingSlice,
+    metrics=None,
+    topic: Optional[str] = None,
+    partition: Optional[int] = None,
+) -> Optional[PendingSlice]:
+    """The device half of a slice's way in: every staged chunk goes out
+    in ONE `dispatch_buffers` call, which returns without blocking on
+    results. Returns ``pending`` with its ``chunks`` in flight, or None
+    (counting the decline reason) when the dispatch failed for good."""
+    from fluvio_tpu.smartengine.tpu.executor import TpuSpill
+
+    tpu = chain.tpu_chain
+    flow = pending.flow
     # executor-owned dispatch: with compression on, the worker
     # glz-compresses chunk k+1 while chunk k dispatches (one-ahead);
     # with it off this is a plain dispatch loop. A dispatch failure that
@@ -755,8 +796,8 @@ def tpu_stage_dispatch(
         # the chunks' BatchSpans are this phase's children: each carries
         # the slice's flow id
         with timed(flow, "dispatch"):
-            chunks: List[tuple] = tpu.dispatch_buffers(
-                chunk_bufs, flow_id=flow.flow_id if flow is not None else 0
+            pending.chunks = tpu.dispatch_buffers(
+                pending.bufs, flow_id=flow.flow_id if flow is not None else 0
             )
     except TpuSpill:
         return _decline(metrics, "transform-error-spill")
@@ -773,23 +814,28 @@ def tpu_stage_dispatch(
     finally:
         if pscope is not None:
             pscope.__exit__(None, None, None)
-    pending = PendingSlice(
-        batches=batches,
-        chunks=chunks,
-        planned_next=staged[-1][0].computed_last_offset(),
-        total_raw=total_raw,
-        base0=base0,
-        ts0=ts0,
-        count=n_total,
-        read_from=start_offset,
-        flow=flow,
-    )
     # pipelined occupancy gauge: every dispatched chunk counts until its
-    # finish (tpu_finish) or the slice's discard retires it
+    # fetch (tpu_fetch) or the slice's discard retires it
     if TELEMETRY.enabled:
-        TELEMETRY.gauge_add("inflight_queue_depth", len(chunks))
-        pending.tracked_depth = len(chunks)
+        TELEMETRY.gauge_add("inflight_queue_depth", len(pending.chunks))
+        pending.tracked_depth = len(pending.chunks)
     return pending
+
+
+def tpu_stage_dispatch(
+    chain: SmartModuleChainInstance,
+    batches: List[Batch],
+    metrics=None,
+    start_offset: Optional[int] = None,
+    topic: Optional[str] = None,
+    partition: Optional[int] = None,
+    flow=None,
+) -> Optional[PendingSlice]:
+    """`tpu_stage` then `tpu_dispatch`: a slice's whole way in."""
+    pending = tpu_stage(chain, batches, metrics, start_offset, flow=flow)
+    if pending is None:
+        return None
+    return tpu_dispatch(chain, pending, metrics, topic, partition)
 
 
 class _MergedOut:
@@ -840,92 +886,85 @@ class _MergedOut:
         }
 
 
-def tpu_finish(
+def tpu_fetch(
     chain: SmartModuleChainInstance,
     pending: PendingSlice,
-    max_bytes: int,
     metrics=None,
     topic: Optional[str] = None,
     partition: Optional[int] = None,
-) -> Optional[BatchProcessResult]:
-    """Phase 2: block on the device results and re-assemble output
-    batches at the byte level with the native encoder.
+) -> bool:
+    """The blocking half of a slice's way out (flow phase ``finish``):
+    per chunk, the header sync, the count-sized slice programs, the
+    downloads and every failure ladder (`finish_buffer_deferred`). When
+    it returns, the slice's carries are settled: the stream loop
+    dispatches the next slice only now, so the slice programs found an
+    empty device queue and no slice is ever in flight ahead of a fetch
+    that can roll a carry back. A chunk's pure split-back thunk goes to
+    the shared fetch worker as it appears, the last chunk's stays with
+    the slice; `tpu_materialize` joins the former and runs the latter.
 
     With the partition gate armed and a partition identity supplied,
-    the whole finish runs in the partition's placement scope so the
-    fetch-side telemetry (down-* variants, enc-ratio declines) books
-    per partition, matching the dispatch side.
+    the fetch runs in the partition's placement scope so the fetch-side
+    telemetry (down-* variants, enc-ratio declines) books per
+    partition, matching the dispatch side.
 
-    Wire/offset semantics match `process_batches`: survivors keep their
-    stored offsets rebased to the slice's first batch. Aggregate chains
-    always deliver every processed batch — device carries have already
-    advanced, so dropping computed outputs would double-count on
-    refetch; stateless chains honor the max_bytes cutoff exactly like
-    the per-record path. Returns None (with carries restored by the
-    executor) when the device signalled a transform error — the
-    interpreter re-runs the slice for exact error semantics.
+    False (the decline counted, carries restored by the executor) when
+    the device signalled a transform error or the fetch failed for
+    good — the interpreter re-runs the slice for exact error semantics.
     """
     pscope = _enter_partition_scope(
         topic, partition, getattr(chain, "tpu_chain", None)
     )
     try:
-        return _tpu_finish_inner(chain, pending, max_bytes, metrics)
+        return _tpu_fetch_inner(chain, pending, metrics)
     finally:
         if pscope is not None:
             pscope.__exit__(None, None, None)
 
 
-def _tpu_finish_inner(
-    chain: SmartModuleChainInstance,
-    pending: PendingSlice,
-    max_bytes: int,
-    metrics=None,
-) -> Optional[BatchProcessResult]:
-    from fluvio_tpu.smartengine import native_backend
+def _tpu_fetch_inner(
+    chain: SmartModuleChainInstance, pending: PendingSlice, metrics=None
+) -> bool:
+    from fluvio_tpu.smartengine.tpu import executor as tpu_executor
     from fluvio_tpu.smartengine.tpu.executor import TpuSpill
 
-    from fluvio_tpu.smartengine.tpu import executor as tpu_executor
-
     tpu = chain.tpu_chain
-    base0, ts0 = pending.base0, pending.ts0
-    result = BatchProcessResult()
-    result.next_offset = pending.planned_next
     # whatever the outcome below (outputs, spill, fused-error decline),
     # this slice's chunks leave the pipelined queue now
     pending.release_depth()
-    # fetch/compute overlap across the slice's chunks: each chunk's
-    # blocking half (downloads + failure ladders) runs here in order,
-    # its PURE split-back thunk on the shared fetch worker — chunk k
-    # materializes while chunk k+1's results download. `finished`
-    # counts chunks whose handles were consumed (the discard slices
-    # below must skip them AND the one that raised).
-    overlap = (
-        tpu_executor.effective_fetch_overlap() and len(pending.chunks) > 1
-    )
-    outbufs = []
+    overlap = tpu_executor.effective_fetch_overlap()
+    # from here on the handles are the executor's to settle: a discard
+    # of this slice must not touch them again. `finished` counts chunks
+    # whose handles were consumed (the discard slices below must skip
+    # them AND the one that raised).
+    parts = pending.parts = []
     finished = 0
-    flow = pending.flow
-    # `finish`: the blocking result syncs and split-back of every chunk
-    # (each chunk's BatchSpan books its own wait/d2h/fetch inside it)
-    with timed(flow, "finish"):
+    last = len(pending.chunks) - 1
+    with timed(pending.flow, "finish"):
         try:
-            if overlap:
-                parts = []
-                for b, h in pending.chunks:
-                    out = tpu.finish_buffer_deferred(b, h)
-                    finished += 1
-                    parts.append(
-                        tpu_executor._fetch_mat_pool().submit(out)
-                        if callable(out)
-                        else out
-                    )
-                outbufs = [
-                    p.result() if hasattr(p, "result") else p for p in parts
-                ]
-            else:
-                for b, h in pending.chunks:
-                    outbufs.append(tpu.finish_buffer(b, h))
-                    finished += 1
+            for b, h in pending.chunks:
+                out = (
+                    tpu.finish_buffer_deferred(b, h)
+                    if overlap
+                    else tpu.finish_buffer(b, h)
+                )
+                # a chunk's split-back runs on the fetch worker under
+                # the downloads of the chunks after it. The last chunk's
+                # would run under the next slice's dispatch and slow it
+                # (both hold the interpreter lock): `tpu_materialize`
+                # runs that one itself, once the chip has its work
+                parts.append(
+                    tpu_executor._fetch_mat_pool().submit(out)
+                    if callable(out) and finished < last
+                    else out
+                )
+                finished += 1
+            if tpu.agg_configs:
+                # the carries this slice left, read before a later
+                # dispatch replaces them by its futures; the host mirror
+                # takes them once the slice has encoded
+                pending.carries = tpu._download_carries()
+            return True
         except TpuSpill:
             # later chunks' dispatch-time D2H copies still crossed the link;
             # discard them so the executor's byte accounting stays honest.
@@ -936,7 +975,8 @@ def _tpu_finish_inner(
             # decline counter below already records it once)
             for _, h in pending.chunks[finished + 1 :]:
                 tpu.discard_dispatch(h)
-            return _decline(metrics, "transform-error-spill")
+            _decline(metrics, "transform-error-spill")
+            return False
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
@@ -951,7 +991,75 @@ def _tpu_finish_inner(
                 "fused slice finish failed (%s: %s); per-record fallback",
                 type(e).__name__, e,
             )
-            return _decline(metrics, "fused-error")
+            _decline(metrics, "fused-error")
+            return False
+
+
+def _split_back_of(part):
+    """A fetched chunk's output buffer: joined from the fetch worker,
+    split back here (the last chunk's thunk), or there already."""
+    if hasattr(part, "result"):
+        return part.result()
+    return part() if callable(part) else part
+
+
+def _decline_fetched(tpu, pending, ahead, metrics, reason: str):
+    """A slice declines AFTER its fetch, with its carries advanced and
+    perhaps a slice dispatched ``ahead`` of the decline: that one is
+    discarded first (its handles restore the carries this slice left),
+    then this slice's carries go back to where it started, so the
+    per-record rerun counts nothing twice."""
+    if ahead is not None:
+        ahead.discard(tpu)
+    pending.rollback(tpu)
+    return _decline(metrics, reason)
+
+
+def tpu_materialize(
+    chain: SmartModuleChainInstance,
+    pending: PendingSlice,
+    max_bytes: int,
+    metrics=None,
+    ahead: Optional[PendingSlice] = None,
+) -> Optional[BatchProcessResult]:
+    """The host half of a fetched slice's way out: join the split-back
+    thunks and run the last one (flow phase ``materialize``), and
+    re-assemble output batches
+    at the byte level with the native encoder (``encode``). Nothing
+    here waits for the device, so the stream loop runs it while the
+    slice it dispatched ``ahead`` is out there; that slice is discarded
+    here when this one's outcome makes it wrong (a ``max_bytes`` cut, a
+    decline).
+
+    Wire/offset semantics match `process_batches`: survivors keep their
+    stored offsets rebased to the slice's first batch. Aggregate chains
+    always deliver every processed batch — device carries have already
+    advanced, so dropping computed outputs would double-count on
+    refetch; stateless chains honor the max_bytes cutoff exactly like
+    the per-record path. Returns None when the encoder refuses the
+    output: the carries are back where this slice started, for the
+    per-record rerun.
+    """
+    from fluvio_tpu.smartengine import native_backend
+
+    tpu = chain.tpu_chain
+    base0, ts0 = pending.base0, pending.ts0
+    result = BatchProcessResult()
+    result.next_offset = pending.planned_next
+    flow = pending.flow
+    try:
+        with timed(flow, "materialize"):
+            outbufs = [_split_back_of(p) for p in pending.parts]
+    except (KeyboardInterrupt, SystemExit):
+        raise
+    except Exception as e:
+        if is_program_fault(e):
+            raise
+        logging.getLogger(__name__).warning(
+            "fused slice split-back failed (%s: %s); per-record fallback",
+            type(e).__name__, e,
+        )
+        return _decline_fetched(tpu, pending, ahead, metrics, "fused-error")
     # `encode`: output merge, resume drop, max_bytes cut, to_columns, the
     # native record encode and the response Batch
     with timed(flow, "encode"):
@@ -988,6 +1096,10 @@ def _tpu_finish_inner(
             if keep < n_out:
                 n_out = max(keep, 1)
                 result.next_offset = base0 + int(out_deltas[n_out - 1]) + 1
+                if ahead is not None:
+                    # the consume point moved: the slice dispatched ahead
+                    # read from the wrong offset
+                    ahead.discard(tpu)
         if n_out:
             cols = outbuf.to_columns()
             vo = cols["val_off"]
@@ -1004,7 +1116,9 @@ def _tpu_finish_inner(
                 out_ts[:n_out],
             )
             if raw_out is None:
-                return _decline(metrics, "encode-failed")
+                return _decline_fetched(
+                    tpu, pending, ahead, metrics, "encode-failed"
+                )
             out_batch = Batch(
                 base_offset=base0,
                 raw_records=raw_out,
@@ -1024,8 +1138,8 @@ def _tpu_finish_inner(
         metrics.add_fuel_used(pending.count * max(len(tpu.stages), 1))
         metrics.add_records_out(n_out)
         metrics.add_fastpath()
-    if tpu.agg_configs:
-        tpu._ensure_host_state()
+    if pending.carries is not None:
+        tpu._ensure_host_state(pending.carries)
     # a clean fused slice counts toward the chain breaker's health —
     # half-open probes served through the slice path must be able to
     # re-promote the chain, not only per-record batches
@@ -1033,6 +1147,21 @@ def _tpu_finish_inner(
     if breaker is not None:
         breaker.record_success()
     return result
+
+
+def tpu_finish(
+    chain: SmartModuleChainInstance,
+    pending: PendingSlice,
+    max_bytes: int,
+    metrics=None,
+    topic: Optional[str] = None,
+    partition: Optional[int] = None,
+) -> Optional[BatchProcessResult]:
+    """`tpu_fetch` then `tpu_materialize`: a slice's whole way out.
+    None when either half declined."""
+    if not tpu_fetch(chain, pending, metrics, topic, partition):
+        return None
+    return tpu_materialize(chain, pending, max_bytes, metrics)
 
 
 def _tpu_process_batches(
@@ -1045,12 +1174,13 @@ def _tpu_process_batches(
     partition: Optional[int] = None,
     flow=None,
 ) -> Optional[BatchProcessResult]:
-    """Coalesced TPU fast path, serial form: stage+dispatch then finish.
+    """Coalesced TPU fast path, one slice at a time: the four halves in
+    a row (stage, dispatch, fetch, materialize).
 
-    The stream-fetch handler's pipelined loop uses the two phases
-    directly so slice k+1 dispatches while slice k downloads and hits
-    the socket. ``flow`` is the slice's flow record: both loops' slices
-    carry one, so both record the same phases.
+    The stream-fetch handler's loop calls the halves itself, so that
+    slice k+1 is out on the device while slice k is split back, encoded
+    and sent. ``flow`` is the slice's flow record: a slice served here
+    records the same phases.
     """
     pending = tpu_stage_dispatch(
         chain, batches, metrics, start_offset,

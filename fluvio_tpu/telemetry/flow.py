@@ -26,14 +26,19 @@ the root span of everything the serving task did for it —
 - ``read``        `leader.read_records` + the shallow batch decode
                   (both stream loops of `spu/public_service.py`),
 - ``wire_decode`` the native per-batch wire decode and its guards in
-                  `tpu_stage_dispatch`, net of stored-batch
+                  `tpu_stage`, net of stored-batch
                   decompression (that stays the ``glz_decode`` phase
                   histogram),
 - ``stage``       column merge, chunk bounds, chunk buffer builds,
 - ``dispatch``    wall of `dispatch_buffers`; the chunks' `BatchSpan`s
                   (each carrying this flow's id) are its children,
-- ``finish``      wall of `tpu_finish`'s chunk loop: the blocking
-                  result syncs and the split-back of every chunk,
+- ``finish``      wall of `tpu_fetch`: the blocking half of every
+                  chunk (header sync, count-sized slice programs,
+                  downloads, failure ladders). The stream loop
+                  dispatches the NEXT slice after it,
+- ``materialize`` the chunks' split-back: the join of the thunks that
+                  ran on the fetch worker since ``finish``, and the last
+                  chunk's (a single-chunk slice's only one), run here,
 - ``encode``      output merge, resume drop, ``max_bytes`` cut,
                   `to_columns`, the native record encode, `Batch` build,
 - ``send``        `sink.send_response`,
@@ -42,7 +47,12 @@ the root span of everything the serving task did for it —
 - ``interpret``   the per-record fallback pass, so a slice the fast
                   path declined is not a hole,
 
-— and closes AFTER its ack wait (`end_flow` follows `_wait_for_ack` in
+— one boolean, ``interleaved``: the stream loop dispatched the next
+slice between this slice's ``finish`` and its ``materialize``, so the
+device worked under this slice's host half (a stream's last slice has no
+next one) —
+
+and closes AFTER its ack wait (`end_flow` follows `_wait_for_ack` in
 both stream loops): ``ack_wait`` belongs to the slice it waits for, so
 ``t0..t_end`` covers everything up to the consumer's ack. Completed flows
 land in a bounded :class:`FlowRing` (capacity ``FLUVIO_SLICE_RING``)
@@ -68,8 +78,8 @@ from fluvio_tpu.telemetry.spans import _BoundedRing
 #: and the Prometheus ``slice_wait_seconds`` family key on it)
 SLICE_PHASES = (
     "queue_wait", "batcher", "hold", "serve",
-    "chain_acquire", "read", "wire_decode", "stage", "dispatch", "finish", "encode", "send",
-    "ack_wait", "interpret",
+    "chain_acquire", "read", "wire_decode", "stage", "dispatch", "finish",
+    "materialize", "encode", "send", "ack_wait", "interpret",
 )
 
 
@@ -87,7 +97,7 @@ class SliceFlow:
 
     __slots__ = (
         "flow_id", "chain", "tenant", "t0", "t_end", "records", "phases",
-        "decision", "holds", "cause", "sources", "batch_id",
+        "decision", "holds", "cause", "sources", "batch_id", "interleaved",
         "_q_t0", "_b_t0",
     )
 
@@ -114,6 +124,9 @@ class SliceFlow:
         #: into ONE dispatch — the lead slice's (the renderer and the
         #: benchmark's readers join spans to flows on it)
         self.batch_id = flow_id
+        #: the next slice went out to the device between this slice's
+        #: ``finish`` and its ``materialize``
+        self.interleaved = False
         self._q_t0: Optional[float] = None
         self._b_t0: Optional[float] = None
 
@@ -172,6 +185,7 @@ class SliceFlow:
             "records": self.records,
             "serve_ms": round(self.serve_seconds() * 1000, 3),
             "t0": round(self.t0, 6),
+            "interleaved": self.interleaved,
         }
         if self.chain:
             d["chain"] = self.chain

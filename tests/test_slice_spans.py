@@ -3,11 +3,13 @@
 CPU, counts and structure only (a time read here says nothing about the
 chip):
 
-- a served two-slice stream through a real `SpuServer`, on both stream
-  loops (the pipelined loop a stateless chain takes, the serial loop a
-  fan-out chain takes), yields slice flows whose phases include the
-  served path's eight names, in wall order and never overlapping on the
-  serving task, and together covering the stream's span; every
+- a served two-slice stream through a real `SpuServer`, for a stateless
+  and for a fan-out chain (one stream loop serves both since ISSUE-31),
+  yields slice flows whose phases include the served path's nine names,
+  in wall order and never overlapping (the loop's passes run on worker
+  threads, one after the other), and together covering the stream's
+  span; slice 2 is dispatched after slice 1's `finish` and before its
+  `materialize` ends; every
   `BatchSpan` of the stream names a flow of the ring; a span's `wait`
   fits inside the span,
 - the compiled text of each chain program (narrow, striped, sharded)
@@ -49,8 +51,8 @@ from fluvio_tpu.telemetry.spans import (  # noqa: E402
 )
 
 SERVED_PHASES = (
-    "read", "wire_decode", "stage", "dispatch", "finish", "encode", "send",
-    "ack_wait",
+    "read", "wire_decode", "stage", "dispatch", "finish", "materialize",
+    "encode", "send", "ack_wait",
 )
 PER_BATCH = 2048
 
@@ -104,12 +106,10 @@ def _serve_two_slices(tmp_path, config_name: str):
     return asyncio.run(run())
 
 
-@pytest.mark.parametrize("config_name,pipelined", [
-    ("fluvio-northstar-1p", True),
-    ("fluvio-array-explode-1p", False),
-], ids=["pipelined-loop", "serial-loop"])
-def test_served_stream_yields_one_span_tree_per_slice(
-        tmp_path, config_name, pipelined):
+@pytest.mark.parametrize("config_name", [
+    "fluvio-northstar-1p", "fluvio-array-explode-1p",
+], ids=["stateless-chain", "fanout-chain"])
+def test_served_stream_yields_one_span_tree_per_slice(tmp_path, config_name):
     responses, counts = _serve_two_slices(tmp_path, config_name)
     assert len(responses) == 2
     assert counts["fastpath_slices"] >= 2 and counts["fallback_slices"] == 0
@@ -119,7 +119,7 @@ def test_served_stream_yields_one_span_tree_per_slice(
     every = []
     for f in flows:
         names = [name for name, _s, _d in f.phases]
-        # all eight, each once, in the order the served path runs them
+        # all nine, each once, in the order the served path runs them
         assert [n for n in names if n in SERVED_PHASES] == list(SERVED_PHASES)
         starts = [s for _n, s, _d in f.phases]
         assert starts == sorted(starts)
@@ -131,7 +131,7 @@ def test_served_stream_yields_one_span_tree_per_slice(
         assert [p[0] for p in doc["phases"]] == names
         assert set(doc["phases_ms"]) == set(names)
         every += [(s, s + d, n, f.flow_id) for n, s, d in f.phases]
-    # one serving task: no phase of any flow overlaps another
+    # one pass at a time: no phase of any flow overlaps another
     every.sort()
     for (a0, a1, an, af), (b0, _b1, bn, bf) in zip(every, every[1:]):
         assert b0 >= a1 - 1e-6, ((an, af), (bn, bf))
@@ -139,13 +139,17 @@ def test_served_stream_yields_one_span_tree_per_slice(
     t0 = min(f.t0 for f in flows)
     t1 = max(f.t_end for f in flows)
     assert sum(e - s for s, e, _n, _f in every) >= 0.90 * (t1 - t0)
-    if pipelined:
-        # slice 2 was dispatched before slice 1 finished
-        d2 = next(s for s, _e, n, fid in every
-                  if n == "dispatch" and fid == flows[1].flow_id)
-        f1 = next(s for s, _e, n, fid in every
-                  if n == "finish" and fid == flows[0].flow_id)
-        assert d2 < f1
+    # slice 2 went out after slice 1's blocking half and before its
+    # host half was over: the device works under materialize and encode
+    d2 = next((s, e) for s, e, n, fid in every
+              if n == "dispatch" and fid == flows[1].flow_id)
+    f1 = next(e for _s, e, n, fid in every
+              if n == "finish" and fid == flows[0].flow_id)
+    m1 = next(e for _s, e, n, fid in every
+              if n == "materialize" and fid == flows[0].flow_id)
+    assert f1 <= d2[0] and d2[1] <= m1
+    assert [f.interleaved for f in flows] == [True, False]
+    assert [f.to_dict()["interleaved"] for f in flows] == [True, False]
 
     # every chunk names its slice; `wait` is exclusive and inside the span
     ids = {f.flow_id for f in TELEMETRY.flows.recent()}
