@@ -196,12 +196,8 @@ CONFIGS = {
         "corpus": gen_fat_70k,
         "divisor": 1024,
     },
-    # sharded striped: the one compressed-staging exclusion left (PR-8)
-    # — sharded wide batches ship raw with the per-batch
-    # `glz-wide-unsupported` decline. This config exists so the
-    # per-config `link` block carries that decline attribution (the
-    # compress-ahead-worker decision's missing evidence); it skips
-    # cleanly when the backend has fewer devices than the mesh.
+    # sharded striped: wide batches under shard_map; skips cleanly
+    # when the backend has fewer devices than the mesh.
     "8_sharded_fat": {
         "specs": [("regex-filter", {"regex": "fluvio"})],
         "corpus": gen_fat_70k,
@@ -284,10 +280,10 @@ def _compile_delta(a: dict, b: dict) -> dict:
 
 
 def _link_deltas(lv0: dict, dc0: dict) -> tuple:
-    """(H2D variant deltas, D2H ``down-*`` variant deltas, glz-decline
-    deltas) since the captured baselines — the bench's per-config link
-    attribution (which form the flat crossed UP in, which form the
-    results crossed DOWN in, and WHY batches shipped raw)."""
+    """(D2H ``down-*`` variant deltas, glz-decline deltas) since the
+    captured baselines — the bench's per-config link attribution
+    (which form the results crossed DOWN in, and WHY batches shipped
+    unencoded)."""
     from fluvio_tpu.telemetry import TELEMETRY
 
     moved = {
@@ -295,14 +291,13 @@ def _link_deltas(lv0: dict, dc0: dict) -> tuple:
         for k, v in TELEMETRY.link_variant_counts().items()
         if v - lv0.get(k, 0) > 0
     }
-    lv = {k: v for k, v in moved.items() if not k.startswith("down-")}
     dn = {k: v for k, v in moved.items() if k.startswith("down-")}
     dc = {
         k: v - dc0.get(k, 0)
         for k, v in dict(TELEMETRY.declines).items()
         if k.startswith("glz-") and v - dc0.get(k, 0) > 0
     }
-    return lv, dn, dc
+    return dn, dc
 
 
 def bench_tpu(chain, buf, runs: int, passes: int, deadline=None) -> tuple:
@@ -315,7 +310,7 @@ def bench_tpu(chain, buf, runs: int, passes: int, deadline=None) -> tuple:
     # the run so each config reports the path it ACTUALLY executed
     # (fused / striped / interpreter) instead of a static label
     pr0 = TELEMETRY.path_records()
-    # link attribution: which staging variant each dispatch used and
+    # link attribution: which down-link variant each fetch used and
     # which glz decline reasons fired (feeds the per-config `link`
     # record in BENCH_DETAIL.json)
     lv0 = TELEMETRY.link_variant_counts()
@@ -415,15 +410,13 @@ def bench_tpu(chain, buf, runs: int, passes: int, deadline=None) -> tuple:
         f"pc {compile_info['persistent_hits']}h/"
         f"{compile_info['persistent_misses']}m)"
     )
-    variants, down_variants, glz_declines = _link_deltas(lv0, dc0)
+    down_variants, glz_declines = _link_deltas(lv0, dc0)
     link_info = {
         "up_mb": round(link_mb[0], 2),
         "down_mb": round(link_mb[1], 2),
-        # majority engaged variant (mixed runs keep the full histogram)
-        "variant": max(variants, key=variants.get) if variants else "off",
-        "variants": variants,
         # D2H (result) side: which form the outputs crossed down in —
         # the ISSUE-12 compaction/encode ladder's per-config evidence
+        # (majority engaged variant; mixed runs keep the histogram)
         "down_variant": (
             max(down_variants, key=down_variants.get)
             if down_variants
@@ -535,13 +528,6 @@ def verify_outputs(specs, values, ts, check_n: int) -> None:
     got, ref = run("tpu"), run("python")
     assert got == ref, "TPU output diverged from reference engine"
     log(f"  verified {len(ref)} outputs byte-equal to reference")
-
-
-# headline staging A/B verdict, propagated to the rest of the suite:
-# "raw" means the decode rounds lost to this weather's raw link time at
-# the JSON corpus ratio (~0.48), so later configs ship raw too — EXCEPT
-# wide300, whose ~0.074 ratio is 6x better and re-checks on its own.
-_AB_VERDICT = None  # set to "raw" by the headline A/B
 
 
 def _run_partitioned_config(
@@ -1057,26 +1043,7 @@ def _dispatch_config(
     if cfg.get("windowed"):
         return _run_windowed_config(name, cfg, n, smoke, deadline)
     headline = name == "2_filter_map"
-    # wide300 re-checks a raw verdict at its own far-better ratio — but
-    # only with enough budget left for its re-check to actually run;
-    # otherwise it must FOLLOW the verdict, not ship compressed-only
-    # numbers the verdict already rejected
-    wide_ab = (
-        name == "6_wide300"
-        and _AB_VERDICT == "raw"
-        and (deadline is None or time.time() < deadline - 180)
-    )
-    if not wide_ab:
-        return _run_config(name, cfg, n, smoke, deadline, headline)
-    prior_env = os.environ.get("FLUVIO_LINK_COMPRESS")
-    os.environ["FLUVIO_LINK_COMPRESS"] = "on"
-    try:
-        return _run_config(name, cfg, n, smoke, deadline, headline, True)
-    finally:
-        if prior_env is None:
-            os.environ.pop("FLUVIO_LINK_COMPRESS", None)
-        else:
-            os.environ["FLUVIO_LINK_COMPRESS"] = prior_env
+    return _run_config(name, cfg, n, smoke, deadline, headline)
 
 
 def _run_config(
@@ -1086,10 +1053,7 @@ def _run_config(
     smoke: bool,
     deadline,
     headline: bool,
-    wide_ab: bool = False,
 ) -> dict:
-    global _AB_VERDICT
-    ab_eligible = headline or wide_ab
     runs = (3 if smoke else 5) if headline else (2 if smoke else 3)
     passes = 3 if headline else 2
     divisor = cfg.get("divisor", 1)
@@ -1201,70 +1165,9 @@ def _run_config(
         e.bench_partial = {
             "link": {
                 "up_mb": round(chain.tpu_chain.h2d_bytes_total / 1e6, 2),
-                "glz": "on" if chain.tpu_chain._link_compress else "off",
             }
         }
         raise
-    staging_ab = None
-    if ab_eligible:
-        # staging A/B: nobody re-runs this after the round, so the
-        # headline must self-select the faster flat staging for THIS
-        # weather. When glz engaged, measure the raw path too (one
-        # extra compile) and keep whichever sustains faster.
-        glz_cache = getattr(buf, "_glz_cache", None)
-        if (
-            chain.tpu_chain._link_compress
-            and glz_cache is not None
-            and glz_cache[1] is not None
-            # the re-measure pays a fresh compile (20-40s cold) plus
-            # passes: an imminent deadline must keep the budget for the
-            # REQUIRED configs, not this optional comparison
-            and (deadline is None or time.time() < deadline - 120)
-        ):
-            log("  staging A/B: re-measuring the raw (uncompressed) path")
-            prior_env = os.environ.get("FLUVIO_LINK_COMPRESS")
-            os.environ["FLUVIO_LINK_COMPRESS"] = "off"
-            try:
-                chain_b = build_chain("tpu", cfg["specs"])
-                (
-                    out_b, times_b, first_b, link_b, phases_b, path_b,
-                    compile_b, link_info_b,
-                ) = bench_tpu(chain_b, buf, runs, passes, deadline)
-            except Exception as e:  # noqa: BLE001 — optional re-measure
-                # must never destroy the headline measurement in hand
-                log(f"  staging A/B: raw re-measure failed ({e}); keeping glz")
-                staging_ab = {"chosen": "glz", "raw_error": str(e)[:200]}
-            else:
-                staging_ab = {
-                    "glz_ms": [round(t * 1000) for t in times],
-                    "raw_ms": [round(t * 1000) for t in times_b],
-                }
-                if statistics.median(times_b) < statistics.median(times):
-                    staging_ab["chosen"] = "raw"
-                    (
-                        out, times, first_call, link_mb, phases, path_info,
-                        compile_info, link_info,
-                    ) = (
-                        out_b, times_b, first_b, link_b, phases_b, path_b,
-                        compile_b, link_info_b,
-                    )
-                    chain = chain_b
-                else:
-                    staging_ab["chosen"] = "glz"
-                log(f"  staging A/B: chose {staging_ab['chosen']}")
-            finally:
-                if prior_env is None:
-                    os.environ.pop("FLUVIO_LINK_COMPRESS", None)
-                else:
-                    os.environ["FLUVIO_LINK_COMPRESS"] = prior_env
-            if headline and staging_ab.get("chosen") == "raw":
-                # policy, not restoration: later configs follow the
-                # headline's verdict for this weather (wide300 alone
-                # re-checks — see run_config)
-                _AB_VERDICT = "raw"
-                os.environ["FLUVIO_LINK_COMPRESS"] = "off"
-                log("  staging verdict: raw for subsequent configs")
-
     t_med = statistics.median(times)
     tpu_rps = n / t_med
     # payload throughput: the per-byte view is what makes record-width
@@ -1303,9 +1206,9 @@ def _run_config(
         # cache-direntry diff as the only compile evidence
         "compile": compile_info,
         "link_mb": [round(m, 2) for m in link_mb],
-        # per-config link breakdown (ISSUE-8): which staging variant
-        # the batches actually shipped under (telemetry link_variants
-        # deltas) and which glz decline reasons fired
+        # per-config link breakdown (ISSUE-8): link MB both ways, the
+        # down-link variant the results shipped under (telemetry
+        # link_variants deltas) and which glz decline reasons fired
         "link": link_info,
         # per-phase breakdown (telemetry subsystem): serial-pass wall +
         # phase attribution + pipelined p50/p99 end-to-end
@@ -1358,8 +1261,6 @@ def _run_config(
             else None
         )
         result["preflight"] = preflight
-    if staging_ab:
-        result["staging_ab"] = staging_ab
     # DFA table-shape evidence (ISSUE-16 class packing): per-pattern
     # packed state/class counts + table bytes for every regex param
     # this config compiled; the compact line carries one tiny
@@ -1367,16 +1268,6 @@ def _run_config(
     dfa_detail = _dfa_detail(cfg["specs"])
     if dfa_detail:
         result["dfa"] = dfa_detail
-    # glz link compression attribution: which form the flat crossed in
-    # (link_mb above already reflects the compressed byte count)
-    glz_cache = getattr(buf, "_glz_cache", None)
-    if chain.tpu_chain._link_compress and glz_cache is not None:
-        comp = glz_cache[1]
-        flat_raw, _ = buf.ragged_values()
-        result["glz_ratio"] = (
-            round(comp.nbytes / max(len(flat_raw), 1), 3)
-            if comp is not None else None  # None = shipped raw (bailed)
-        )
     if _LINK.get("h2d_mb_s") and _LINK.get("d2h_mb_s"):
         # what this batch's transfers alone cost on the measured link:
         # pass_ms at (or under) this floor means the pipeline is
@@ -2006,9 +1897,9 @@ def _compact_line(out: dict, limit: int = COMPACT_LINE_LIMIT) -> dict:
     headline_cfg = (out.get("configs") or {}).get(
         out.get("headline_config", "2_filter_map")
     )
-    # the tiny link:{up_mb, glz} key (ISSUE-8 hardening): the headline's
-    # measured upload MB and engaged variant ride the line even when
-    # other configs errored — byte evidence survives a degraded run
+    # the tiny link:{up_mb} key (ISSUE-8 hardening): the headline's
+    # measured upload MB rides the line even when other configs
+    # errored — byte evidence survives a degraded run
     if isinstance(headline_cfg, dict) and isinstance(
         headline_cfg.get("link"), dict
     ):
@@ -2016,12 +1907,6 @@ def _compact_line(out: dict, limit: int = COMPACT_LINE_LIMIT) -> dict:
         compact.setdefault("link", {})
         if "up_mb" in hl:
             compact["link"]["up_mb"] = hl["up_mb"]
-        # link.glz speaks on/off, never the variant names — those stay
-        # in BENCH_DETAIL.json
-        compact["link"].setdefault(
-            "glz",
-            "on" if str(hl.get("variant", "off")).startswith("glz") else "off",
-        )
     # the tiny down:{mb,variant} key (ISSUE-12): the headline's result-
     # side bytes + engaged down-link variant — the compaction/encode
     # acceptance evidence rides the line like up_mb does
@@ -2091,8 +1976,8 @@ def _compact_line(out: dict, limit: int = COMPACT_LINE_LIMIT) -> dict:
         if mm:
             compact["mem"] = mm
     compact["detail"] = "BENCH_DETAIL.json"
-    # "link" drops LAST: link.glz is emitted unconditionally by
-    # contract — the bulky sections go first
+    # "link" drops LAST (the link calibration is what makes a low
+    # headline interpretable) — the bulky sections go first
     for drop in (
         "configs", "dfa", "win", "mem", "soak", "lag",
         "rebal", "part", "adm", "slo", "preflight", "down", "compile",
@@ -2184,7 +2069,6 @@ def _calibrate_link() -> None:
     number: compare each config's pass_ms against its link_floor_ms."""
     import jax
 
-    pinned = "FLUVIO_LINK_COMPRESS" in os.environ
     try:
         dev = jax.devices()[0]
         tiny = np.zeros(8, np.uint8)
@@ -2221,30 +2105,8 @@ def _calibrate_link() -> None:
             f"link: rtt {_LINK['rtt_ms']}ms, "
             f"H2D {h2d:.0f} MB/s, D2H {d2h:.0f} MB/s"
         )
-        # weather-adaptive glz: compressed staging pays exactly when
-        # the link is slower than the compressor (~40-170 MB/s by
-        # corpus); on a fast link the raw path is already cheap and the
-        # device decode rounds are pure overhead. Respect an operator
-        # pin; otherwise decide from the measured H2D rate.
-        if "FLUVIO_LINK_COMPRESS" not in os.environ:
-            mode = "on" if h2d < 150 else "off"
-            os.environ["FLUVIO_LINK_COMPRESS"] = mode
-            log(f"link compression: {mode} (H2D {h2d:.0f} MB/s)")
     except Exception as e:  # noqa: BLE001 — calibration must never kill a run
         log(f"link calibration failed: {type(e).__name__}: {e}")
-    finally:
-        # the RESOLVED effective mode rides the JSON unconditionally
-        # (an operator-pinned run used to omit the field entirely)
-        _LINK["glz"] = _effective_link_compress()
-        _LINK["glz_pinned"] = pinned
-
-
-def _effective_link_compress() -> str:
-    """The link-compress mode the executors will actually run with
-    ("on"/"off") — the executor's own resolution, not a re-derivation."""
-    from fluvio_tpu.smartengine.tpu.executor import effective_link_compress
-
-    return "on" if effective_link_compress() else "off"
 
 
 def run_suite(results: dict, n: int, smoke: bool, budget: float, only) -> None:

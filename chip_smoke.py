@@ -223,8 +223,6 @@ def resolved_modes() -> dict:
         "backend": g["backend"],
         "pallas": pallas_kernels.pallas_active(),
         "pallas_interpret": pallas_kernels.interpret_mode(),
-        "link_compress_up": g["link_compress"] and g["glz_available"],
-        "up_variant": "glz-gather" if g["link_compress"] else "raw",
         "result_compact": g["result_compact"],
         "result_compress_down": g["result_compress"],
         "down_variant": "xla" if g["result_compress"] else "off",
@@ -569,7 +567,6 @@ def phase_truth(modes: dict) -> None:
     c = snap["counters"]
     comp = snap["compile"]
     paths = TELEMETRY.path_records()
-    up = {k: v for k, v in c["link_variants"].items() if not k.startswith("down-")}
     down = {k: v for k, v in c["link_variants"].items() if k.startswith("down-")}
     say(f"truth: modes={json.dumps(modes, sort_keys=True)}")
     say(f"truth: heals={c['heals']} stripe_fallbacks={c['stripe_fallbacks']} "
@@ -577,7 +574,7 @@ def phase_truth(modes: dict) -> None:
         f"breaker={c['breaker']['states']} declines={c['declines']}")
     interpreted = paths.get("interpreter", 0) - _REFERENCE_RECORDS[0]
     say(f"truth: path_records={paths} (of which python-reference "
-        f"{_REFERENCE_RECORDS[0]}) link_up={up} link_down={down}")
+        f"{_REFERENCE_RECORDS[0]}) link_down={down}")
     say(f"truth: compiles={sum(comp['by_kind'].values())} by_kind={comp['by_kind']} "
         f"compile_s={sum(comp['seconds_by_kind'].values()):.1f} "
         f"persistent_cache_hits={comp['persistent_cache_hits']} "
@@ -594,19 +591,8 @@ def phase_truth(modes: dict) -> None:
     assert c["breaker"]["short_circuits"] == 0, c["breaker"]
     assert interpreted == 0, f"{interpreted} records ran interpreted: {paths}"
     assert paths.get("fused", 0) > 0 and paths.get("striped", 0) > 0, paths
-    # variants USED == variants RESOLVED at build time: compressed
-    # staging crossed (or every raw ship carries its per-batch decline
-    # reason) iff link compression resolved on; encoded results shipped
-    # only under the resolved encoder
-    if modes["link_compress_up"]:
-        assert up.get("glz-gather", 0) > 0, up
-        raw_declines = sum(
-            v for k, v in c["declines"].items()
-            if k.startswith("glz-") and not k.startswith("glz-enc") and "@" not in k
-        )
-        assert up.get("raw", 0) <= raw_declines, (up, c["declines"])
-    else:
-        assert set(up) <= {"raw"}, up
+    # variants USED == variants RESOLVED at build time: encoded
+    # results shipped only under the resolved encoder
     if modes["result_compress_down"]:
         assert set(down) <= {"down-glz-xla", "down-packed"}, down
     else:
@@ -640,7 +626,7 @@ def phase_drill(seed: int) -> None:
     want = [v for _, v in north_star_host_reference(values)]
     assert out_values(out, 0, out.count) == want, "drill: healed output differs"
     assert clean == want[:SLICE]
-    # an encode-/glz-armed batch answers a fetch-side fault by latching
+    # an encode-armed batch answers a fetch-side fault by latching
     # that stage off and re-dispatching (a heal); a plain batch retries
     assert (r1 - r0) + (h1 - h0) == 1, (
         f"drill: expected one recovery, saw retries={r1 - r0} heals={h1 - h0}"

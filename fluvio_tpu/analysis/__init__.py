@@ -35,7 +35,7 @@ __all__ = [
     "ERROR", "INFO", "WARN",
     "ChainReport", "Hazard", "PathPrediction", "LintViolation",
     "analyze_entries", "analyze_named", "analyze_chain", "resolve_gates",
-    "analyze_partitioned", "predict_link_variant",
+    "analyze_partitioned",
     "lint_source", "lint_file", "lint_paths", "lint_repo",
     "preflight_for_specs",
     "ConcurrencyReport", "analyze_concurrency", "static_lock_graph",
@@ -50,7 +50,7 @@ __all__ = [
 _SPEC_EXPORTS = {
     "ERROR", "INFO", "WARN", "ChainReport", "Hazard", "PathPrediction",
     "analyze_entries", "analyze_named", "analyze_partitioned",
-    "resolve_gates", "predict_link_variant",
+    "resolve_gates",
 }
 _CONCURRENCY_EXPORTS = {
     "ConcurrencyReport": "ConcurrencyReport",
@@ -144,16 +144,13 @@ def preflight_for_specs(
     """Compact per-config preflight record for the bench: the predicted
     path + reason strings for one chain spec at one record width.
     ``specs`` is the bench-matrix format: ``[(model name, params)]``;
-    ``sharded`` predicts for the multi-device (shard_map) engine mode —
-    its striped configs additionally predict the raw link ship with the
-    ``glz-wide-unsupported`` decline."""
+    ``sharded`` predicts for the multi-device (shard_map) engine mode."""
     from fluvio_tpu.analysis.spec import analyze_named
 
     report = analyze_named(specs, widths=(width,), sharded=sharded)
     pred = report.predictions[0]
     out = {
         "path": pred.path,
-        "link_variant": pred.link_variant,
         "down_variant": pred.down_variant,
     }
     if pred.window_variant != "off":

@@ -69,7 +69,7 @@ ALLOWED_TELEMETRY_SEAMS = {
     "add_stripe_fallback",
     "add_retry", "add_quarantine", "add_compile", "add_jit_hit",
     "add_interp_instance", "add_breaker_short_circuit", "record_breaker",
-    "add_sharded_compress", "add_slo_breach", "add_admission",
+    "add_slo_breach", "add_admission",
     "add_windows_closed", "add_window_delta", "add_window_downlink",
     "gauge_add", "gauge_set",
     "mem_acquire", "mem_release",

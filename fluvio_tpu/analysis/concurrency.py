@@ -1,7 +1,7 @@
 """Level-4 preflight: whole-package lock-discipline analysis.
 
 The engine is genuinely concurrent — the pipelined dispatch/finish
-paths, the glz compress-ahead worker, metering watchdog threads, the
+paths, the fetch materialization worker, metering watchdog threads, the
 monitoring socket accept loop, and the native-build threads all share
 mutable state behind ``threading.Lock``s — and PR 6's linter only
 checks single-threaded kernel invariants. This pass makes the
@@ -99,7 +99,7 @@ IO_LOCK_SEGMENTS = ("io", "build")
 
 #: pipelined engine paths that behave as thread entry points even
 #: though no `threading.Thread(target=...)` names them: the broker's
-#: stream loop drives dispatch/finish concurrently with the glz
+#: stream loop drives dispatch/finish concurrently with the fetch
 #: worker, scrapes, and metering watchdogs
 EXTRA_THREAD_ROOTS = (
     "smartengine.tpu.executor.TpuChainExecutor.dispatch_buffer",

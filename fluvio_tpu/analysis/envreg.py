@@ -163,11 +163,7 @@ REGISTRY: Tuple[EnvFlag, ...] = (
        "per-slice causal flow tracing (arms with telemetry capture)"),
     _f("FLUVIO_GLZ_CHUNK", "int", "262144", "bytes",
        "smartengine/tpu/glz.py",
-       "glz compress_link chunk size (GLZ_CHUNK)"),
-    _f("FLUVIO_LINK_COMPRESS", "mode", "auto", "on|off|auto",
-       "smartengine/tpu/executor.py",
-       "compressed H2D staging: only `on` compresses; `auto` ships the "
-       "flat raw on every backend (the device inflate lost on the v5e)"),
+       "device result encoder's chunk size (GLZ_CHUNK)"),
     _f("FLUVIO_LOCKWATCH", "mode", "0", "0|1|record|assert",
        "analysis/lockwatch.py",
        "runtime lock-order watchdog (assert: raise on new edges)"),

@@ -243,7 +243,6 @@ def trace_chain_entry_points(
             has_offsets=has_offsets,
             ts_mode=ts_mode,
             fanout_cap=executor._fanout_cap(buf),
-            glz_bytes=0,
             enc=enc,
             pack=pack,
         )
@@ -273,44 +272,7 @@ def trace_chain_entry_points(
                 )
             )
         reports.extend(_pallas_reports(executor, buf))
-        reports.extend(_glz_reports(executor, buf))
     return reports
-
-
-def _glz_reports(executor, buf) -> List[JaxprReport]:
-    """Trace the glz link decode the compressed staging would emit for
-    this batch's flat bucket (the gather-round device decode) —
-    synthetic token shapes at the staged pow2/8 buckets, values never
-    read. The signature names the byte bucket: distinct compiled
-    programs the AOT warmup must cover when link compression is on."""
-    from fluvio_tpu.smartengine.tpu import glz
-
-    if not executor._link_compress or not glz.available():
-        return []
-    _flat, bucket = executor._flat_and_bucket(buf)
-    # token-array shape guesses at the staging's own buckets: a midband
-    # ratio (~0.5) corpus; the lint is shape-driven so the guess only
-    # picks which buckets get covered
-    seq_pad = executor._bucket_bytes(max(bucket // 24, 8), floor=256)
-    lit_pad = executor._bucket_bytes(max(bucket // 3, 8), floor=256)
-    seqs = (
-        np.zeros(seq_pad, np.uint8),
-        np.zeros(seq_pad, np.uint8),
-        np.zeros(seq_pad, np.int32),
-    )
-    return [
-        _trace_report(
-            "glz_decode",
-            f"glz_decode bytes={bucket}",
-            lambda: scan_function(
-                glz.decompress_device,
-                *seqs,
-                np.zeros(lit_pad, np.uint8),
-                np.int32(1),
-                out_len=bucket,
-            ),
-        )
-    ]
 
 
 def _pallas_reports(executor, buf) -> List[JaxprReport]:

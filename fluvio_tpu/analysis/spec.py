@@ -85,17 +85,10 @@ class PathPrediction:
     spill_reasons: Tuple[str, ...] = ()  # expected TELEMETRY.spills keys
     declines: Tuple[str, ...] = ()  # expected TELEMETRY.declines keys
     causes: Tuple[str, ...] = ()  # human explanations for the above
-    # predicted H2D staging form for batches in this bucket: "raw" |
-    # "glz-gather" (the TELEMETRY.link_variants keys).
-    # This is the CONFIGURED variant — corpus-dependent declines
-    # (glz-ratio, glz-below-min) resolve per batch at runtime and the
-    # executor then ships raw with the reason on the decline counter.
-    link_variant: str = "raw"
     # predicted D2H (result) form: "down-raw" | "down-packed" |
-    # "down-glz-xla" — the result side's own
-    # variant family. Same contract as link_variant: the CONFIGURED
-    # variant; per-batch ratio losses ship packed with `glz-enc-ratio`
-    # on the decline counter.
+    # "down-glz-xla" (the TELEMETRY.link_variants keys). This is the
+    # CONFIGURED variant; per-batch ratio losses ship packed with
+    # `glz-enc-ratio` on the decline counter.
     down_variant: str = "down-raw"
     # predicted windowed-state emission form for chains with a windowed
     # aggregate: "off" (no windowed stage) | "win-delta" (delta-only
@@ -112,7 +105,6 @@ class PathPrediction:
             "spill_reasons": list(self.spill_reasons),
             "declines": list(self.declines),
             "causes": list(self.causes),
-            "link_variant": self.link_variant,
             "down_variant": self.down_variant,
             "window_variant": self.window_variant,
         }
@@ -157,9 +149,8 @@ def resolve_gates() -> dict:
     the runtime resolves them (one vocabulary with the knobs' homes)."""
     import jax
 
-    from fluvio_tpu.smartengine.tpu import glz, kernels
+    from fluvio_tpu.smartengine.tpu import kernels
     from fluvio_tpu.smartengine.tpu.buffer import MAX_RECORD_WIDTH
-    from fluvio_tpu.smartengine.tpu.executor import effective_link_compress
     from fluvio_tpu.smartengine.tpu.lower import _depth_over_work
 
     return {
@@ -172,12 +163,6 @@ def resolve_gates() -> dict:
         "dfa_classes": regex_classes_enabled(),
         "stripe_threshold": int(env_int("FLUVIO_STRIPE_THRESHOLD")),
         "max_record_width": MAX_RECORD_WIDTH,
-        # link-staging gates the executor resolves at build time
-        # (FLUVIO_LINK_COMPRESS / the native compressor), mirrored here
-        # so the preflight can predict which form each batch's flat
-        # crosses in
-        "link_compress": effective_link_compress(),
-        "glz_available": glz.available(),
         # down-link gates: the result-side compaction + ENCODE path
         # (FLUVIO_RESULT_COMPACT / FLUVIO_RESULT_COMPRESS), mirrored
         # for the down_variant arm
@@ -835,27 +820,11 @@ def predict_down_variant(
     else:  # desc
         if path == "striped" and (sharded or not striped_span):
             # striped whole-record views ship the mask alone; sharded
-            # striped keeps the raw descriptor ship (the H2D glz-wide
-            # exclusion, mirrored)
+            # striped keeps the raw descriptor ship
             return "down-packed"
         if not gates.get("result_compress"):
             return "down-packed"
     return "down-glz-xla"
-
-
-def predict_link_variant(gates: dict, path: str, sharded: bool) -> str:
-    """Which form a batch's flat crosses the H2D link in on this path —
-    the mirror of the executor's build-time variant resolution plus the
-    sharded staging's wide-path exclusion (sharded striped batches ship
-    raw with the ``glz-wide-unsupported`` decline). Interpreter batches
-    never stage, so they report "raw"."""
-    if path == "interpreter":
-        return "raw"
-    if not gates.get("link_compress") or not gates.get("glz_available"):
-        return "raw"
-    if sharded and path == "striped":
-        return "raw"
-    return "glz-gather"
 
 
 def predict_window_variant(programs, gates: dict) -> str:
@@ -934,7 +903,6 @@ def analyze_entries(
             striped_ok, striped_declines, striped_causes,
             has_fanout, sharded=sharded,
         )
-        pred.link_variant = predict_link_variant(gates, pred.path, sharded)
         pred.window_variant = predict_window_variant(programs, gates)
         pred.down_variant = predict_down_variant(
             gates, pred.path, down_profile(programs), sharded,
@@ -944,8 +912,6 @@ def analyze_entries(
                 for p in programs
             ),
         )
-        if sharded and pred.path == "striped" and gates.get("link_compress"):
-            pred.declines = pred.declines + ("glz-wide-unsupported",)
         report.predictions.append(pred)
         if pred.path == "interpreter" and narrow_ok:
             report.hazards.append(

@@ -125,11 +125,6 @@ def render_metrics_table(data: dict) -> str:
         rows.append((f"decline[{reason}]", _fmt_count(n)))
     for point, n in sorted((counters.get("retries") or {}).items()):
         rows.append((f"retry[{point}]", _fmt_count(n)))
-    if counters.get("sharded_inline_compress_shards"):
-        rows.append(
-            ("sharded_inline_compress_shards",
-             _fmt_count(counters["sharded_inline_compress_shards"]))
-        )
     for key, n in sorted((counters.get("slo_breaches") or {}).items()):
         rows.append((f"slo_breach[{key}]", _fmt_count(n)))
     for reason, n in sorted((counters.get("rebalance_moves") or {}).items()):

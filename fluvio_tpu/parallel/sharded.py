@@ -95,7 +95,7 @@ class ShardedChainExecutor:
         per-shard compacted view descriptors; ``kmax`` bounds their
         cross-stripe carry's outer scan."""
         (_width, kwidth, has_keys, has_offsets, ts_mode,
-         _glz_bytes, _enc, _cap, srows, kmax) = cfg
+         _enc, _cap, srows, kmax) = cfg
         ex = self.executor
         s, v = ex._stripe_s, ex._stripe_v
         with jax.named_scope("repad"):
@@ -177,40 +177,18 @@ class ShardedChainExecutor:
             return header(jnp.max(compacted[1])), packed, carries
         return header(jnp.max(jnp.where(valid, lengths, 0))), packed, carries
 
-    @staticmethod
-    def _shard_flat_words(uploads: Dict, glz_bytes: int):
-        """This shard's flat i32 words: the raw upload, or the shard's
-        own glz stream inflated on device (traced inside the shard
-        body; each shard's token rows arrive as its block of the
-        row-sharded token matrices)."""
-        if not glz_bytes:
-            return uploads["flat_words"]
-        seqs = (
-            uploads["glz_ll"][0],
-            uploads["glz_ml"][0],
-            uploads["glz_srcs"][0],
-        )
-        return kernels_executor.TpuChainExecutor._link_decode(
-            seqs, uploads["glz_lits"][0], uploads["glz_depth"][0], glz_bytes
-        )
-
     def _local_step_ragged(
         self, uploads: Dict, count, base_ts, carries, *, cfg: tuple
     ):
         """Rebuild this shard's padded arrays from its ragged upload, then
         run the stage pipeline (same device-side re-pad as the single
         device `_chain_fn_ragged`: the host link carries sum(lengths)
-        bytes per shard, not rows x width). Compressed staging
-        (``glz_bytes > 0``): each shard's flat segment crossed the link
-        as its OWN glz stream (per-shard token rows) and inflates
-        shard-locally through the same gather-round decode the
-        single-device paths use."""
+        bytes per shard, not rows x width)."""
         (width, kwidth, has_keys, has_offsets, ts_mode,
-         glz_bytes, enc, fanout_cap) = cfg
-        flat_words = self._shard_flat_words(uploads, glz_bytes)
+         enc, fanout_cap) = cfg
         with jax.named_scope("repad"):
             values, lengths = kernels_executor.ragged_repad_words(
-                flat_words, uploads["lengths"], width
+                uploads["flat_words"], uploads["lengths"], width
             )
             n_local = lengths.shape[0]
             g0 = lax.axis_index(RECORD_AXIS) * n_local
@@ -337,7 +315,7 @@ class ShardedChainExecutor:
         )
 
     def _jitted(self, uploads: Dict, cfg: tuple):
-        striped = len(cfg) == 10  # (..., enc, fanout_cap, srows, kmax)
+        striped = len(cfg) == 9  # (..., enc, fanout_cap, srows, kmax)
         key = (
             tuple(sorted((k, v.shape, str(v.dtype)) for k, v in uploads.items())),
             cfg,
@@ -355,7 +333,7 @@ class ShardedChainExecutor:
             )
             out_specs = (
                 row,  # per-shard (1, 5) headers stack to (n, 5)
-                self._packed_specs(striped, cfg[6]),
+                self._packed_specs(striped, cfg[5]),
                 jax.tree_util.tree_map(lambda _: rep, self._carries()),
             )
 
@@ -456,16 +434,13 @@ class ShardedChainExecutor:
         need = max(step, ((rows + step - 1) // step) * step)
         return need, need // self.n
 
-    def _shard_segments(self, buf: RecordBuffer) -> tuple:
+    def _shard_segments(self, buf: RecordBuffer) -> np.ndarray:
         """Per-shard flat segments for the ragged staging: the aligned
         flat cut at shard row boundaries, each segment padded to one
         bucketed length (equal shapes keep one compiled program).
         Shards over the LIVE rows (bucketed), not the buffer's pow2 row
         padding — trailing all-padding shards would otherwise still
-        ship seg_len bytes each. Shared by `_stage_ragged` and the
-        executor's sharded compress-ahead worker (the cache key is
-        (n, seg_len); the two must never disagree). Returns
-        (segs uint8[n, seg_len], seg_len, cache key)."""
+        ship seg_len bytes each. Returns segs uint8[n, seg_len]."""
         ex = self.executor
         _need, shard_rows = self._row_blocks(min(buf.count, buf.rows))
         flat, starts = buf.ragged_values()
@@ -483,11 +458,9 @@ class ShardedChainExecutor:
         segs = np.zeros((self.n, seg_len), dtype=np.uint8)
         for s in range(self.n):
             segs[s, : seg_sizes[s]] = flat[cuts[s] : cuts[s + 1]]
-        return segs, seg_len, (self.n, seg_len)
+        return segs
 
-    def _stage_ragged(
-        self, buf: RecordBuffer, compress_ok: bool = False, span=None
-    ) -> tuple:
+    def _stage_ragged(self, buf: RecordBuffer) -> tuple:
         """Ragged H2D staging (the single-device link diet, per shard).
 
         The aligned flat is cut at shard row boundaries; every shard's
@@ -496,51 +469,10 @@ class ShardedChainExecutor:
         never cross the link: arange offsets and zero timestamps are
         synthesized on device, timestamps narrow to i32 when they fit,
         lengths ride the narrowest of u8/u16 the record width allows.
-
-        ``compress_ok``: attempt glz compressed staging — each shard's
-        padded segment compresses as its OWN chunked stream (uniform
-        decoded size = the bucketed segment length) and the token
-        arrays ship as row-sharded matrices padded to the worst shard's
-        bucketed counts. ALL shards must compress (shard_map needs
-        uniform shapes); any shard's decline ships the whole batch raw
-        with its reason on the telemetry decline counter.
         Returns (uploads dict, static cfg, H2D byte count).
         """
-        ex = self.executor
-        need, shard_rows = self._row_blocks(min(buf.count, buf.rows))
-        segs, seg_len, _key = self._shard_segments(buf)
-        glz_up, glz_bytes = None, 0
-        if compress_ok:
-            # per-buffer cache (the single-device `_glz_cache` precedent):
-            # heal/fanout-cap/transient-retry re-dispatches of the same
-            # buffer re-use the compressed form instead of paying the
-            # n-shard compressor again; the cached decline reason counts
-            # on EVERY dispatch that ships raw because of it
-            key = _key
-            cached = getattr(buf, "_glz_shard_cache", None)
-            if cached is not None and cached[0] == key:
-                glz_up, reason = cached[1], cached[2]
-            else:
-                # the inline n-shard compress is the cost the ROADMAP
-                # flagged (the compress-ahead worker only covers
-                # single-device buffers): book it as its own
-                # glz_compress phase + per-shard counter so the span
-                # profile can justify extending the worker
-                t_gc = time.perf_counter() if TELEMETRY.enabled else 0.0
-                glz_up, reason = self._compress_segments(segs, seg_len)
-                buf._glz_shard_cache = (key, glz_up, reason)
-                if TELEMETRY.enabled:
-                    dt = time.perf_counter() - t_gc
-                    if span is not None:
-                        span.add("glz_compress", dt)
-                    else:
-                        TELEMETRY.add_phase("glz_compress", dt)
-                    TELEMETRY.add_sharded_compress(self.n)
-            if reason is not None:
-                TELEMETRY.add_decline(reason)
-                ex.tag_decline(reason)
-            if glz_up is not None:
-                glz_bytes = seg_len
+        need, _shard_rows = self._row_blocks(min(buf.count, buf.rows))
+        segs = self._shard_segments(buf)
         flat_words = segs.reshape(-1).view(np.int32)
 
         def pad_rows(a, fill=0):
@@ -555,10 +487,7 @@ class ShardedChainExecutor:
         lengths_np, has_keys, has_offsets, ts_mode, ts_np = (
             kernels_executor.stage_link_columns(buf)
         )
-        if glz_up is not None:
-            uploads = dict(glz_up, lengths=pad_rows(lengths_np))
-        else:
-            uploads = {"flat_words": flat_words, "lengths": pad_rows(lengths_np)}
+        uploads = {"flat_words": flat_words, "lengths": pad_rows(lengths_np)}
         if has_keys:
             uploads["keys"] = pad_rows(buf.keys)
             uploads["key_lengths"] = pad_rows(buf.key_lengths, fill=-1)
@@ -566,53 +495,8 @@ class ShardedChainExecutor:
             uploads["offset_deltas"] = pad_rows(buf.offset_deltas)
         if ts_np is not None:
             uploads["timestamp_deltas"] = pad_rows(ts_np)
-        cfg = (
-            buf.width, buf.keys.shape[1], has_keys, has_offsets, ts_mode,
-            glz_bytes,
-        )
+        cfg = (buf.width, buf.keys.shape[1], has_keys, has_offsets, ts_mode)
         return uploads, cfg, sum(v.nbytes for v in uploads.values())
-
-    def _compress_segments(self, segs: np.ndarray, seg_len: int):
-        """(per-shard glz token matrices, None) for the compressed
-        staging, or (None, decline reason) when any shard declines or
-        the padded token bytes fail the ratio gate the single-device
-        staging applies. Every shard's stream decodes to exactly
-        ``seg_len`` bytes (the zero tail compresses to almost nothing),
-        so the decode output shapes stay uniform under shard_map."""
-        comps = []
-        for s in range(self.n):
-            comp, reason = glz.compress_link(segs[s])
-            if comp is None:
-                return None, reason
-            comps.append(comp)
-        ex = self.executor
-        # worst-shard buckets so every shard's token rows share one
-        # shape; the padding itself is the single-device staging's
-        # `pad_glz_tokens` (one implementation of the bucket rules)
-        seq_pad = ex._bucket_bytes(
-            max(max(len(c.lit_lens) for c in comps), 8), floor=256
-        )
-        lit_pad = ex._bucket_bytes(
-            max(max(c.lits.size for c in comps), 8), floor=256
-        )
-        token_bytes = self.n * (seq_pad * 6 + lit_pad)
-        if token_bytes > segs.nbytes * glz.MAX_RATIO:
-            # worst-shard padding can sink a ratio every shard passed
-            # individually — re-check at the shipped (padded) sizes
-            return None, glz.DECLINE_RATIO
-        padded = [
-            kernels_executor.TpuChainExecutor.pad_glz_tokens(
-                c, seq_pad=seq_pad, lit_pad=lit_pad
-            )
-            for c in comps
-        ]
-        return {
-            "glz_ll": np.stack([p[0] for p in padded]),
-            "glz_ml": np.stack([p[1] for p in padded]),
-            "glz_srcs": np.stack([p[2] for p in padded]),
-            "glz_lits": np.stack([p[3] for p in padded]),
-            "glz_depth": np.array([c.depth for c in comps], np.int32),
-        }, None
 
     def _shard_fanout_cap(self, buf: RecordBuffer, cap_total=None) -> int:
         """Per-shard explode capacity: the learned global capacity split
@@ -669,35 +553,18 @@ class ShardedChainExecutor:
         t_ph = time.perf_counter() if span is not None else 0.0
         faults.maybe_fire("stage")
         striped = ex._needs_stripes(buf)
-        # compressed staging covers the sharded NARROW layout; sharded
-        # striped batches ship raw — their per-shard stripe shapes
-        # already compile against the worst shard, and stacking the
-        # token-bucket axis on top would square that compile matrix
-        # (the one wide-path exclusion left; counted per batch below)
-        gc0 = span.phase("glz_compress") if span is not None else 0.0
-        uploads, cfg, nbytes = self._stage_ragged(
-            buf, compress_ok=ex._link_compress and not striped, span=span
-        )
-        glz_bytes = cfg[5]
+        uploads, cfg, nbytes = self._stage_ragged(buf)
         if span is not None:
             now = time.perf_counter()
-            # the inline n-shard compressor booked its own phase inside
-            # _stage_ragged; stage keeps the remainder so the two are
-            # separable in the span profile (the ROADMAP's evidence for
-            # extending the compress-ahead worker to sharded buffers)
-            span.add(
-                "stage",
-                max(now - t_ph - (span.phase("glz_compress") - gc0), 0.0),
-            )
+            span.add("stage", now - t_ph)
             t_ph = now
         if ex._fanout and cap_shard is None:
             cap_shard = self._shard_fanout_cap(buf)
         # sharded down-link encode: the shared arming rule, further
         # restricted to narrow viewable/fan-out chains (sharded striped
-        # keeps its raw descriptor ship, mirroring the H2D glz-wide
-        # exclusion — the per-shard token-bucket axis would square the
-        # worst-shard compile matrix; sharded byte-mode keeps the
-        # padded ship, so packing stays off here too)
+        # keeps its raw descriptor ship — the per-shard token-bucket
+        # axis would square the worst-shard compile matrix; sharded
+        # byte-mode keeps the padded ship, so packing stays off here too)
         enc_sh = ex._down_axes(striped)[0] if ex._viewable else "off"
         cfg = cfg + (enc_sh, cap_shard)
         if striped:
@@ -710,9 +577,6 @@ class ShardedChainExecutor:
                     "and the chain cannot stripe under shard_map",
                     reason="record-too-wide-unstripeable",
                 )
-            if ex._link_compress:
-                TELEMETRY.add_decline(glz.DECLINE_WIDE)
-                ex.tag_decline(glz.DECLINE_WIDE)
             cfg = cfg + (self._stripe_rows_shard(buf), ex._stripe_kmax(buf))
             if span is not None:
                 span.path = "striped"
@@ -750,27 +614,12 @@ class ShardedChainExecutor:
             if enc_sh != "off" and classify(e) != TRANSIENT:
                 # sync half of the sharded ENCODE ladder (runtime
                 # failures): latch encode off and re-dispatch the same
-                # batch (the encoder is output-side; the staged uploads
-                # re-ship from cache)
+                # batch (the encoder is output-side)
                 ex._enc_demote(e, where="sharded dispatch")
                 return self._dispatch_buffer_inner(buf, cap_shard, span)
-            if not glz_bytes:
-                raise
-            if classify(e) == TRANSIENT:
-                # a recoverable device hiccup, not a decode failure:
-                # re-raise so the executor's bounded dispatch retry
-                # re-ships the SAME compressed form (from the buffer's
-                # cache) — a transient fault must not cost this
-                # executor a ladder rung
-                raise
-            # the single-device decode heal, sharded: a deterministic
-            # runtime failure of a compressed batch latches compression
-            # off; the batch re-stages and re-dispatches raw (the
-            # compressed token arrays that already crossed are on the
-            # counter below).
-            ex.h2d_bytes_total += nbytes
-            ex._glz_demote(e, buf, where="sharded dispatch")
-            return self._dispatch_buffer_inner(buf, cap_shard, span)
+            # anything else (a transient hiccup included) is the
+            # executor's bounded dispatch retry's to answer
+            raise
         if span is not None:
             span.add("dispatch", time.perf_counter() - t_ph)
             span.mark_dispatched()
@@ -781,12 +630,8 @@ class ShardedChainExecutor:
             # carries chain through device futures at dispatch time so
             # streams pipeline; the host mirror commits at finish
             self._pending_carries = new_carries
-        TELEMETRY.add_link_variant(
-            "glz-gather" if glz_bytes else "raw"
-        )
         return (
             prev_carries, new_carries, header, packed, cap_shard, span,
-            "gather" if glz_bytes else None,
             enc_sh if enc_sh != "off" else None,
         )
 
@@ -896,8 +741,7 @@ class ShardedChainExecutor:
     def finish_buffer(self, buf: RecordBuffer, handle) -> RecordBuffer:
         from fluvio_tpu.smartengine.tpu.executor import TpuSpill
 
-        (_prev, new_carries, header, packed, cap_shard, span, _glz,
-         _enc) = handle
+        (_prev, new_carries, header, packed, cap_shard, span, _enc) = handle
         t_f0 = time.perf_counter() if span is not None else 0.0
         d2h0 = span.phase("d2h") if span is not None else 0.0
         ex = self.executor
@@ -944,7 +788,7 @@ class ShardedChainExecutor:
                     buf, cap_shard=retry_cap, reuse_span=span
                 )
                 (_prev, new_carries, header, packed, cap_shard, _,
-                 _glz, _enc) = handle
+                 _enc) = handle
                 with timed(span, "wait"):
                     down_meta = (
                         np.asarray(jax.device_get(packed["down_meta"]))

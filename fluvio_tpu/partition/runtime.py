@@ -465,14 +465,8 @@ class PartitionRuntime:
 
         Partition A's batch k+1 dispatches (H2D + device compute in the
         background, on A's group) while partition B's batch k downloads
-        — the multi-partition mirror of ``process_stream``. Per-
-        partition compress-ahead rides along: the shared glz worker
-        compresses the NEXT partition's buffer (its own independent
-        stream/cache) while the current one dispatches, settled before
-        that buffer stages.
+        — the multi-partition mirror of ``process_stream``.
         """
-        from fluvio_tpu.smartengine.tpu.executor import _compress_pool
-
         items = list(items)
         if self._stateful and self._executor._fanout:
             # same guard as process_stream: a fan-out overflow retry at
@@ -480,18 +474,9 @@ class PartitionRuntime:
             # same-partition batch dispatched against them — serialize
             depth = 0
         inflight: List[tuple] = []
-        fut = None
         try:
-            for i, (topic, part, buf) in enumerate(items):
-                if fut is not None:
-                    fut.result()
-                    fut = None
+            for topic, part, buf in items:
                 handle = self.dispatch(topic, part, buf)
-                if i + 1 < len(items):
-                    nxt = items[i + 1][2]
-                    job = self._executor._precompress_fn(nxt)
-                    if job is not None:
-                        fut = _compress_pool().submit(job, nxt)
                 inflight.append((topic, part, buf, handle))
                 while len(inflight) > max(depth, 0):
                     t, p, b, h = inflight.pop(0)
@@ -500,8 +485,6 @@ class PartitionRuntime:
                 t, p, b, h = inflight.pop(0)
                 yield (t, p, b, self.finish(t, p, b, h))
         except BaseException:
-            if fut is not None:
-                fut.cancel()
             for t, p, b, h in inflight:
                 if self._stateful:
                     # the discard's carry restore must land in THIS

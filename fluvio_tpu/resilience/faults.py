@@ -14,7 +14,6 @@ point           seam
 ``dispatch``    the jitted chain call (trace/compile/enqueue)
 ``device``      first blocking sync on device results (header fetch)
 ``fetch``       the D2H download of result columns
-``glz_decode``  the on-device link-decompression path (glz armed only)
 ``glz_encode``  the on-device result-encode path (down-link ladder armed)
 ``spill_rerun`` the interpreter re-run of a spilled batch
 ``socket_accept``  the SPU monitoring socket's per-client handler
@@ -60,7 +59,6 @@ FAULT_POINTS = (
     "dispatch",
     "device",
     "fetch",
-    "glz_decode",
     "glz_encode",
     "spill_rerun",
     "socket_accept",

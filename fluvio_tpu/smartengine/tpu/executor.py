@@ -460,18 +460,6 @@ def stage_link_columns(buf):
     return lengths_up, has_keys, has_offsets, ts_mode, ts_up
 
 
-def effective_link_compress() -> bool:
-    """Resolve ``FLUVIO_LINK_COMPRESS`` (on/off/auto) to the mode
-    executors actually run with: only "on" compresses; "auto" ships the
-    staged flat raw on every backend. On the v5e the device-side
-    inflate ran at about 9 MB/s (283 ms per 2.6 MB flat, 95 % of the
-    north star's device time: PERF.md §6, PR 27), so compression can
-    only pay across a link slower than that, which no locally attached
-    chip has. The ONE home for this policy (the bench records it next
-    to every capture)."""
-    return env_raw("FLUVIO_LINK_COMPRESS") == "on"
-
-
 def effective_result_compact() -> bool:
     """``FLUVIO_RESULT_COMPACT`` (on/off/auto): device-side result
     compaction — byte-mode outputs ship as ONE packed payload +
@@ -487,10 +475,9 @@ def effective_result_compact() -> bool:
 def effective_result_compress() -> bool:
     """``FLUVIO_RESULT_COMPRESS`` (on/off/auto): the device-side glz
     ENCODE ladder for result streams (descriptor blocks, packed
-    payloads) — the down-link mirror of ``FLUVIO_LINK_COMPRESS``.
-    "auto" enables off-CPU only (on CPU there is no link to save), and
-    only composes with compaction (the encoder runs over the packed
-    streams compaction builds)."""
+    payloads). "auto" enables off-CPU only (on CPU there is no link to
+    save), and only composes with compaction (the encoder runs over the
+    packed streams compaction builds)."""
     mode = env_raw("FLUVIO_RESULT_COMPRESS")
     if mode == "off":
         return False
@@ -500,11 +487,11 @@ def effective_result_compress() -> bool:
 
 
 def effective_donation() -> bool:
-    """``FLUVIO_DONATE`` (on/off/auto): donate the staged flat (and glz
-    token) buffers into the chain jits — the staged input is dead after
-    the device re-pad, so XLA may alias it for outputs instead of the
-    fetch paying a copy. "auto" is off on CPU (donation is
-    unimplemented there and warns). Every dispatch stages FRESH device
+    """``FLUVIO_DONATE`` (on/off/auto): donate the staged flat into the
+    chain jits — the staged input is dead after the device re-pad, so
+    XLA may alias it for outputs instead of the fetch paying a copy.
+    "auto" is off on CPU (donation is unimplemented
+    there and warns). Every dispatch stages FRESH device
     arrays (`jnp.asarray` per call), so heal/retry re-dispatches can
     never read a donated buffer — pinned in tests/test_glz_encode.py."""
     mode = env_raw("FLUVIO_DONATE")
@@ -575,31 +562,6 @@ def transfer_guard_fetch():
     return _NULL_CTX
 
 
-_GLZ_POOL = None
-_GLZ_POOL_LOCK = make_lock("executor.glz_pool")
-
-
-def _compress_pool():
-    """Process-wide single-worker pool for the stream loop's
-    compress-ahead. Shared across executors so a broker that builds a
-    chain per consumer session holds ONE idle thread, not one per
-    discarded executor; lazily created so non-streaming processes never
-    spawn it."""
-    global _GLZ_POOL
-    # double-checked lazy init: the unlocked fast-path read is a
-    # GIL-atomic reference load (a stale None just falls through to the
-    # locked re-check), so the per-dispatch cost is one attribute read
-    if _GLZ_POOL is None:  # noqa: FLV202 — double-checked lazy init
-        from concurrent.futures import ThreadPoolExecutor
-
-        with _GLZ_POOL_LOCK:
-            if _GLZ_POOL is None:
-                _GLZ_POOL = ThreadPoolExecutor(
-                    max_workers=1, thread_name_prefix="glz-compress"
-                )
-    return _GLZ_POOL  # noqa: FLV202 — published once, never rebound
-
-
 _FETCH_POOL = None
 _FETCH_POOL_LOCK = make_lock("executor.fetch_pool")
 
@@ -609,10 +571,12 @@ def _fetch_mat_pool():
     host materialization (`effective_fetch_overlap`): batch N's pure
     numpy split-back runs here while the main thread dispatches N+1 and
     blocks on N+1's downloads. One worker keeps completion in dispatch
-    order; shared across executors like the glz pool."""
+    order; shared across executors so a broker that builds a chain per
+    consumer session holds ONE idle thread, lazily created."""
     global _FETCH_POOL
-    # double-checked lazy init (same pattern as _compress_pool): the
-    # unlocked read is a GIL-atomic reference load
+    # double-checked lazy init: the unlocked fast-path read is a
+    # GIL-atomic reference load (a stale None just falls through to the
+    # locked re-check), so the per-dispatch cost is one attribute read
     if _FETCH_POOL is None:  # noqa: FLV202 — double-checked lazy init
         from concurrent.futures import ThreadPoolExecutor
 
@@ -716,12 +680,11 @@ class TpuChainExecutor:
             )
             or "empty"
         )
-        # buffer donation (effective_donation): the staged flat / glz
-        # token arrays are dead after the device re-pad, so the jits may
-        # alias them for outputs — fetch stops paying that copy. Args
-        # 0/9/10 are flat, glz_seqs, glz_lits; every dispatch stages
-        # fresh device arrays, so retries never touch a donated buffer.
-        donate = (0, 9, 10) if effective_donation() else ()
+        # buffer donation (effective_donation): the staged flat (arg 0)
+        # is dead after the device re-pad, so the jits may alias it for
+        # outputs — fetch stops paying that copy. Every dispatch stages
+        # a fresh device array, so retries never touch a donated buffer.
+        donate = (0,) if effective_donation() else ()
         # jit entry points wrapped for compile observability: every
         # trace-cache miss records {kind, chain signature + shape
         # bucket, wall seconds, persistent-cache outcome} (free when
@@ -731,7 +694,7 @@ class TpuChainExecutor:
                 scoped_program(self._chain_fn_ragged),
                 static_argnames=(
                     "width", "kwidth", "has_keys", "has_offsets", "ts_mode",
-                    "fanout_cap", "glz_bytes", "enc", "pack",
+                    "fanout_cap", "enc", "pack",
                 ),
                 donate_argnums=donate,
             ),
@@ -752,7 +715,7 @@ class TpuChainExecutor:
                 scoped_program(self._chain_fn_striped),
                 static_argnames=(
                     "srows", "kmax", "kwidth", "has_keys", "has_offsets",
-                    "ts_mode", "fanout_cap", "glz_bytes", "enc", "pack",
+                    "ts_mode", "fanout_cap", "enc", "pack",
                 ),
                 donate_argnums=donate,
             ),
@@ -793,13 +756,6 @@ class TpuChainExecutor:
         # failures retry against the handle's carry snapshot; budgets
         # come from the FLUVIO_RETRY_* env knobs at construction
         self._retry_policy = RetryPolicy()
-        # glz link compression (smartengine/tpu/glz.py): with
-        # FLUVIO_LINK_COMPRESS=on record bytes cross the H2D link
-        # compressed and inflate ON DEVICE in the same jit as the
-        # chain; unset, the flat ships raw
-        # (resolved ONCE here; a runtime decode failure latches it off
-        # for this executor and ships raw — `_glz_demote`)
-        self._link_compress = effective_link_compress()
         self._viewable = not agg_configs and all(
             isinstance(s, (_FilterStage, _ArrayMapStage))
             or (
@@ -1210,16 +1166,6 @@ class TpuChainExecutor:
         header = _header(jnp.max(packed["lengths"]), jnp.max(packed["key_lengths"]))
         return header, packed, carries
 
-    @staticmethod
-    def _link_decode(glz_seqs, glz_lits, glz_depth, glz_bytes: int):
-        """The flat that crossed the link compressed, inflated on device
-        to the i32 words the raw path ships (``link_decode`` scope)."""
-        with jax.named_scope("link_decode"):
-            raw = glz.decompress_device(
-                *glz_seqs, glz_lits, glz_depth, glz_bytes
-            )
-            return lax.bitcast_convert_type(raw.reshape(-1, 4), jnp.int32)
-
     def _chain_fn_ragged(
         self,
         flat,
@@ -1231,9 +1177,6 @@ class TpuChainExecutor:
         count,
         base_ts,
         carries,
-        glz_seqs=None,
-        glz_lits=None,
-        glz_depth=None,
         *,
         width: int,
         kwidth: int,
@@ -1241,7 +1184,6 @@ class TpuChainExecutor:
         has_offsets: bool,
         ts_mode: str,
         fanout_cap: Optional[int] = None,
-        glz_bytes: int = 0,
         enc: str = "off",
         pack: bool = False,
     ):
@@ -1256,15 +1198,7 @@ class TpuChainExecutor:
         lengths, arange offset deltas (``has_offsets=False``) and zero
         timestamp deltas (``ts_mode='zero'``) are synthesized, and
         narrowed timestamps (``ts_mode`` u16/i32) widen on device.
-
-        glz staging (``glz_bytes > 0``): the flat crossed the link
-        COMPRESSED — ``glz_seqs`` is (lit_lens u8, match_lens u8,
-        srcs i32) and ``glz_lits`` the literal stream; the gather-round
-        decode inflates to ``glz_bytes`` raw bytes on device, then
-        bitcasts to the same i32 words the raw path ships.
         """
-        if glz_bytes:
-            flat = self._link_decode(glz_seqs, glz_lits, glz_depth, glz_bytes)
         with jax.named_scope("repad"):
             values, lengths = ragged_repad_words(flat, lengths, width)
             n = lengths.shape[0]
@@ -1365,9 +1299,6 @@ class TpuChainExecutor:
         count,
         base_ts,
         carries,
-        glz_seqs=None,
-        glz_lits=None,
-        glz_depth=None,
         *,
         srows: int,
         kmax: int = 0,
@@ -1376,14 +1307,13 @@ class TpuChainExecutor:
         has_offsets: bool,
         ts_mode: str,
         fanout_cap: Optional[int] = None,
-        glz_bytes: int = 0,
         enc: str = "off",
         pack: bool = False,
     ):
         """Striped chain body: same ragged flat upload as the narrow
-        path (glz decode included), re-padded into ``srows`` stripe rows
-        of ``_stripe_s`` bytes with the segment sidecar derived on
-        device from the lengths. Filters reduce per segment, aggregates
+        path, re-padded into ``srows`` stripe rows of ``_stripe_s`` bytes
+        with the segment sidecar derived on device from the lengths.
+        Filters reduce per segment, aggregates
         run on the segment axis (the narrow scan stages, reused), and
         outputs ship as the segment survivor bitmask / aggregate ints /
         span view descriptors / fan-out descriptors — the narrow fetch
@@ -1391,8 +1321,6 @@ class TpuChainExecutor:
         stripe-count bound the JsonGet cross-stripe carry scans over
         (0 when the chain has no span stage).
         """
-        if glz_bytes:
-            flat = self._link_decode(glz_seqs, glz_lits, glz_depth, glz_bytes)
         with jax.named_scope("repad"):
             lengths = lengths.astype(jnp.int32)
             n = lengths.shape[0]
@@ -1515,7 +1443,7 @@ class TpuChainExecutor:
         static shape-bucket kwargs (never touches array values)."""
         return (
             f"{self._chain_sig} w={k.get('width')} "
-            f"glz={k.get('glz_bytes', 0)} cap={k.get('fanout_cap')}"
+            f"cap={k.get('fanout_cap')}"
             f"{self._down_sig(k)}"
         )
 
@@ -1533,29 +1461,25 @@ class TpuChainExecutor:
     def _describe_striped(self, *a, **k) -> str:
         return (
             f"{self._chain_sig} srows={k.get('srows')} "
-            f"kmax={k.get('kmax', 0)} glz={k.get('glz_bytes', 0)}"
+            f"kmax={k.get('kmax', 0)}"
             f"{self._down_sig(k)}"
         )
 
     # -- device-memory / in-flight gauges ------------------------------------
 
-    def _gauge_track(self, handle, nbytes: int, glz_nbytes: int = 0) -> None:
+    def _gauge_track(self, handle, nbytes: int) -> None:
         """A dispatch went up: its staged link bytes are HBM-resident
         until the fetch (or discard) releases them. Booked in the
         device-memory ledger under a typed owner — ``shard_staging``
-        on the sharded path, else ``staged_batch``, with compressed
-        token bytes split out under ``glz_tokens`` — and the old
+        on the sharded path, else ``staged_batch`` — and the old
         ``hbm_staged_bytes`` gauge republishes from the ledger as an
         alias, so finish/discard/dead-letter imbalance cannot drift
         the gauge from the balance the ledger proves."""
         if not TELEMETRY.enabled:
             return
         owner = "shard_staging" if self._sharded is not None else "staged_batch"
-        glz_nbytes = min(max(glz_nbytes, 0), nbytes)
         self._handle_gauge[id(handle)] = nbytes
-        TELEMETRY.mem_acquire(owner, ("batch", id(handle)), nbytes - glz_nbytes)
-        if glz_nbytes:
-            TELEMETRY.mem_acquire("glz_tokens", ("glz", id(handle)), glz_nbytes)
+        TELEMETRY.mem_acquire(owner, ("batch", id(handle)), nbytes)
         TELEMETRY.gauge_add("live_batch_handles", 1)
 
     def _gauge_release(self, handle) -> None:
@@ -1565,7 +1489,6 @@ class TpuChainExecutor:
         if nbytes is None:
             return
         TELEMETRY.mem_release(("batch", id(handle)))
-        TELEMETRY.mem_release(("glz", id(handle)))
         TELEMETRY.gauge_add("live_batch_handles", -1)
 
     def _dispatch(
@@ -1593,7 +1516,7 @@ class TpuChainExecutor:
         no keys. Remaining columns go as separate arrays — the host link
         runs per-array transfer streams concurrently. ``span`` (a
         telemetry BatchSpan, or None) collects the host-side phase
-        clock pairs: stage / glz_compress / h2d / dispatch.
+        clock pairs: stage / h2d / dispatch.
         """
         if self._device_carries is not None:
             carries = self._device_carries
@@ -1620,25 +1543,15 @@ class TpuChainExecutor:
         with timed(span, "stage"):
             faults.maybe_fire("stage")
             flat, bucket = self._flat_and_bucket(buf)
-        with timed(span, "h2d") as ph:
+        with timed(span, "h2d"):
             faults.maybe_fire("h2d")
-            (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
-             flat_h2d) = self._stage_flat(buf, flat, bucket)
-            if glz_bytes:
-                # the compressed form's staging IS the compressor (plus
-                # token padding); the raw form's is the pad + device
-                # enqueue. Which it was is known only now.
-                ph.rename("glz_compress")
+            flat_up, flat_h2d = self._stage_flat(flat, bucket)
         lengths_up, has_keys, has_offsets, ts_mode, ts_np = (
             stage_link_columns(buf)
         )
         ts_up = jnp.asarray(ts_np) if ts_np is not None else None
 
         def _call(enc, pack):
-            if glz_bytes:
-                # the device-decode seam: an InjectedFault here takes the
-                # same self-heal path a real decode failure would
-                faults.maybe_fire("glz_decode")
             if enc != "off":
                 # the device-ENCODE seam: the sync half of the encode
                 # ladder; async runtime failures surface at fetch and
@@ -1655,9 +1568,6 @@ class TpuChainExecutor:
                 jnp.int32(buf.count),
                 jnp.int64(buf.base_timestamp),
                 carries,
-                glz_seqs,
-                glz_lits,
-                glz_depth,
             )
             kwargs = dict(
                 kwidth=buf.keys.shape[1],
@@ -1665,7 +1575,6 @@ class TpuChainExecutor:
                 has_offsets=has_offsets,
                 ts_mode=ts_mode,
                 fanout_cap=fanout_cap,
-                glz_bytes=glz_bytes,
                 enc=enc,
                 pack=pack,
             )
@@ -1694,36 +1603,19 @@ class TpuChainExecutor:
                         # the PROGRAM, not device weather: no quieter rung
                         # answers it — the compiler's own error stops the run
                         raise
-                    if enc_now != "off":
-                        # sync half of the ENCODE heal (runtime failures
-                        # only): the encoder is output-side, so the batch
-                        # re-dispatches in the same link form with encode
-                        # latched off
-                        enc_now = self._enc_demote(e, where="dispatch")
-                    elif glz_bytes:
-                        # sync half of the decode heal (async failures heal
-                        # in finish_buffer): ship the batch raw and latch
-                        # compression off for this executor
-                        self._glz_demote(e, buf)
-                    else:
+                    if enc_now == "off":
                         raise
-                    # the failed attempt's arrays already crossed the link —
-                    # keep them on the counter — and may have been DONATED
-                    # into the failed call: a healed re-dispatch stages
-                    # fresh device arrays, never a consumed buffer
+                    # sync half of the ENCODE heal (runtime failures
+                    # only): the encoder is output-side, so the batch
+                    # re-dispatches with encode latched off
+                    enc_now = self._enc_demote(e, where="dispatch")
+                    # the failed attempt's flat already crossed the link —
+                    # keep it on the counter — and may have been DONATED
+                    # into the failed call: a healed re-dispatch stages a
+                    # fresh device array, never a consumed buffer
                     self.h2d_bytes_total += flat_h2d
-                    (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
-                     flat_h2d) = self._stage_flat(buf, flat, bucket)
-        self._glz_last = bool(glz_bytes)
-        # ledger attribution: how many of THIS dispatch's flat-link
-        # bytes were compressed token arrays (glz_tokens owner)
-        self._glz_last_h2d = flat_h2d if glz_bytes else 0
+                    flat_up, flat_h2d = self._stage_flat(flat, bucket)
         self._enc_last = enc_now if enc_now != "off" else None
-        # link-variant attribution (always-on counter, like declines):
-        # which form THIS batch's flat actually crossed the link in
-        TELEMETRY.add_link_variant(
-            "glz-gather" if glz_bytes else "raw"
-        )
         # keep aggregate state device-resident; host mirrors sync on demand
         self._device_carries = new_carries
         self._dispatch_seq += 1
@@ -1739,13 +1631,9 @@ class TpuChainExecutor:
     @staticmethod
     def _flat_and_bucket(buf: RecordBuffer):
         """The flat's link form: 4-aligned ragged bytes + the pow2/8
-        bucket it pads to — bounded compile count (<=8 per size decade)
-        without pow2's up-to-2x H2D blowup. Returned UNPADDED: the
-        warm-cache glz path never touches the bytes, so the pad copy is
-        paid only by the paths that ship them (`_padded`). One
-        implementation for the dispatch and the stream loop's
-        prefetch-compression worker (the cache key is the bucket; the
-        two must never disagree)."""
+        bucket it pads to — bounded compile count (four per size
+        doubling) without pow2's up-to-2x H2D blowup. Returned UNPADDED:
+        `_stage_flat` pads, under the ``h2d`` phase."""
         flat, _starts = buf.ragged_values()
         bucket = TpuChainExecutor._bucket_bytes(max(len(flat), 4))
         return flat, bucket
@@ -1755,66 +1643,6 @@ class TpuChainExecutor:
         if len(flat) < bucket:
             return np.pad(flat, (0, bucket - len(flat)))
         return flat
-
-    def _precompress_fn(self, buf: RecordBuffer):
-        """Which compress-ahead job covers ``buf`` on this executor's
-        engine mode: the single-device flat compressor, the sharded
-        per-shard segment compressor (PR-8/9 leftover — the inline
-        n-shard compress was the hot spot the
-        `sharded_inline_compress_shards_total` counter measured), or
-        None (compression off / sharded striped, which keeps its
-        explicit `glz-wide-unsupported` raw ship)."""
-        if not self._link_compress:
-            return None
-        if self._sharded is None:
-            return self._precompress
-        if self._needs_stripes(buf):
-            return None
-        return self._precompress_sharded
-
-    def _precompress_sharded(self, buf: RecordBuffer) -> None:
-        """Worker-thread sharded compress-ahead: fill the buffer's
-        per-shard glz cache so the NEXT sharded dispatch stages warm —
-        the inline n-shard compressor (and its glz_compress phase cost)
-        drops out of the dispatch path exactly like the single-device
-        worker did for flat buffers."""
-        sh = self._sharded
-        segs, seg_len, key = sh._shard_segments(buf)
-        cached = getattr(buf, "_glz_shard_cache", None)
-        if cached is not None and cached[0] == key:
-            return
-        up, reason = sh._compress_segments(segs, seg_len)
-        buf._glz_shard_cache = (key, up, reason)
-
-    def _precompress(self, buf: RecordBuffer) -> None:
-        """Worker-thread half of the stream loop's compress-ahead: fill
-        the buffer's glz cache so the NEXT dispatch finds it warm. The
-        compressor runs in C with the GIL released, so it overlaps the
-        consumer's processing of already-yielded batches instead of
-        serializing before the next dispatch."""
-        flat, bucket = self._flat_and_bucket(buf)
-        cached = getattr(buf, "_glz_cache", None)
-        if cached is not None and cached[0] == bucket:
-            return
-        comp, reason = glz.compress_link(self._padded(flat, bucket))
-        buf._glz_cache = (bucket, comp, reason)
-
-    def _glz_demote(self, e, buf=None, where: str = "dispatch") -> None:
-        """A RUNTIME failure of a compressed batch — the sync/async
-        halves of the glz self-heal (single-device dispatch + fetch,
-        sharded dispatch + finish) all route here so the seams cannot
-        diverge: compression latches off for this executor and the
-        buffer's cached compressed forms drop, so restaging ships raw.
-        Counts the heal."""
-        TELEMETRY.add_heal()
-        logging.getLogger(__name__).warning(
-            "glz decode failed at %s; link compression disabled: %s",
-            where, e,
-        )
-        self._link_compress = False
-        if buf is not None:
-            buf._glz_cache = None
-            buf._glz_shard_cache = None
 
     def _down_axes(self, striped: bool) -> Tuple[str, bool]:
         """The down-link STATIC jit axes for a batch on the given
@@ -1839,11 +1667,11 @@ class TpuChainExecutor:
         return enc, pack
 
     def _enc_demote(self, e, where: str = "dispatch") -> str:
-        """A RUNTIME failure of an encode-armed batch — the mirror of
-        `_glz_demote`, shared by the sync dispatch seam, the async
-        fetch seam, and both sharded seams: encode latches off for this
-        executor (the raw packed columns are still in every dispatch's
-        ``packed``, so nothing is lost mid-flight). Counts the heal;
+        """A RUNTIME failure of an encode-armed batch, shared by the
+        sync dispatch seam, the async fetch seam, and both sharded
+        seams: encode latches off for this executor (the raw packed
+        columns are still in every dispatch's ``packed``, so nothing is
+        lost mid-flight). Counts the heal;
         returns the new variant ("off")."""
         TELEMETRY.add_heal()
         logging.getLogger(__name__).warning(
@@ -1854,71 +1682,13 @@ class TpuChainExecutor:
         return "off"
 
     @staticmethod
-    def pad_glz_tokens(comp, seq_pad=None, lit_pad=None):
-        """Pad a compressed stream's token arrays to pow2/8 buckets
-        (bounded compile variants, like every other link array). One
-        implementation for the single-device staging and the per-shard
-        sharded staging — the sharded caller passes its worst-shard
-        buckets so every shard's rows share one shape. Returns
-        (ll, ml, srcs, lits) numpy arrays."""
-        n_seq = len(comp.lit_lens)
-        if seq_pad is None:
-            seq_pad = TpuChainExecutor._bucket_bytes(max(n_seq, 8), floor=256)
-        if lit_pad is None:
-            lit_pad = TpuChainExecutor._bucket_bytes(
-                max(comp.lits.size, 8), floor=256
-            )
-        ll = np.zeros(seq_pad, np.uint8)
-        ll[:n_seq] = comp.lit_lens
-        ml = np.zeros(seq_pad, np.uint8)
-        ml[:n_seq] = comp.match_lens
-        srcs = np.zeros(seq_pad, np.int32)
-        srcs[:n_seq] = comp.srcs
-        lits = np.zeros(lit_pad, np.uint8)
-        lits[: comp.lits.size] = comp.lits
-        return ll, ml, srcs, lits
-
-    def _stage_flat(self, buf: RecordBuffer, flat: np.ndarray, bucket: int):
-        """Pick the flat's link form: glz-compressed or raw i32 words.
-
-        Returns (flat_up, glz_seqs, glz_lits, glz_depth, glz_bytes,
-        h2d_bytes) — exactly one of flat_up / the glz arrays
-        is non-None. The compressed form is cached on the buffer (same
-        precedent as RecordBuffer.ragged_values caching the flat):
-        stream loops that re-dispatch one buffer pay the compressor
-        once; the cached decline REASON feeds the per-batch telemetry
-        decline counter on every dispatch that ships raw because of it.
-        Token arrays bucket at pow2/8 like every other link array so
-        compile variants stay bounded.
-        """
-        if self._link_compress:
-            cached = getattr(buf, "_glz_cache", None)
-            if cached is not None and cached[0] == bucket:
-                comp = cached[1]
-                reason = cached[2] if len(cached) > 2 else None
-            else:
-                comp, reason = glz.compress_link(self._padded(flat, bucket))
-                buf._glz_cache = (bucket, comp, reason)
-            if comp is not None:
-                ll, ml, srcs, lits = self.pad_glz_tokens(comp)
-                h2d = ll.nbytes + ml.nbytes + srcs.nbytes + lits.nbytes
-                return (
-                    None,
-                    (jnp.asarray(ll), jnp.asarray(ml), jnp.asarray(srcs)),
-                    jnp.asarray(lits),
-                    jnp.int32(comp.depth),
-                    bucket,
-                    h2d,
-                )
-            # per-batch decline attribution: WHY this batch ships raw
-            # (glz-ratio / glz-below-min / glz-unavailable)
-            if reason is not None:
-                TELEMETRY.add_decline(reason)
-                self.tag_decline(reason)
-        # ship the aligned flat as i32 words (see _chain_fn_ragged);
-        # derivable columns stay off the link (synthesized on device)
-        words = self._padded(flat, bucket).view(np.int32)
-        return jnp.asarray(words), None, None, None, 0, words.nbytes
+    def _stage_flat(flat: np.ndarray, bucket: int):
+        """The flat's one link form: padded to its bucket and viewed as
+        i32 words (see `_chain_fn_ragged`; derivable columns stay off
+        the link, synthesized on device). Returns (device array, bytes
+        that crossed the link)."""
+        words = TpuChainExecutor._padded(flat, bucket).view(np.int32)
+        return jnp.asarray(words), words.nbytes
 
     def _download_carries(self):
         """The device carries as host values; None where the host
@@ -1949,8 +1719,9 @@ class TpuChainExecutor:
 
     @staticmethod
     def _bucket_bytes(n: int, floor: int = 1024) -> int:
-        """pow2/8-granular bucket: <=12.5% padding, <=8 compiles per size
-        decade (each distinct bucket is a fresh XLA compile — persisted
+        """pow2/8-granular bucket: padding under an eighth of the enclosing
+        power of two (under a quarter of ``n``), four buckets per size
+        doubling (each distinct bucket is a fresh XLA compile — persisted
         across processes by the compilation cache, but still paid once)."""
         v = floor
         while v < n:
@@ -2147,15 +1918,14 @@ class TpuChainExecutor:
     def tag_decline(self, reason: str) -> None:
         """Per-partition decline attribution: when the partition layer
         tagged this executor, count the decline AGAIN under its
-        ``reason@topic/partition:group`` key (the sharded-striped
-        ``glz-wide-unsupported`` raw ship stays visible per group).
-        Zero work untagged — one attr read."""
+        ``reason@topic/partition:group`` key. Zero work untagged — one
+        attr read."""
         if self.partition_tag is not None:
             TELEMETRY.add_decline(f"{reason}@{self.partition_tag}")
 
     def _count_down_variant(self, variant: Optional[str]) -> None:
-        """Per-batch down-link attribution (the D2H mirror of the H2D
-        `link_variants` family, and the preflight's differential truth):
+        """Per-batch down-link attribution (the `link_variants` family,
+        and the preflight's differential truth):
         ``down-glz-xla`` when encoded tokens shipped,
         ``down-packed`` for mask/descriptor/delta-int/packed-payload
         downloads, ``down-raw`` only for the unpacked byte-mode matrix."""
@@ -2861,8 +2631,8 @@ class TpuChainExecutor:
 
     def _redispatch_refetch(self, buf: RecordBuffer, handle, span):
         """Roll device state back to the handle's pre-dispatch carry
-        snapshot and re-run the batch end to end (the glz self-heal's
-        re-dispatch, generalized to every fetch-side recovery).
+        snapshot and re-run the batch end to end (the re-dispatch of
+        every fetch-side recovery: the encode heal, the transient retry).
 
         The heal-epoch bump marks every OTHER in-flight aggregate
         dispatch stale — their carry lineage chained through the failed
@@ -2972,34 +2742,17 @@ class TpuChainExecutor:
                 if self.agg_configs and lineage_ok:
                     self._sharded._pending_carries = handle[0]
                 if not (lineage_ok and self._retry_policy.should_retry(e, attempt)):
-                    enc_form = handle[7] if len(handle) > 7 else None
-                    if enc_form is not None and lineage_ok:
+                    if handle[6] is not None and lineage_ok:
                         # async half of the sharded ENCODE ladder: a
                         # deterministic runtime failure of an
                         # encode-armed batch at the stacked-header sync
-                        # latches encode off and re-dispatches (the raw
-                        # re-dispatch has enc_form None, bounding the
-                        # loop exactly like the decode ladder below)
+                        # latches encode off and re-dispatches. Transient
+                        # faults never reach this branch (the bounded
+                        # retry below re-ships the same form), and the
+                        # raw re-dispatch's handle[6] (its encode form) is
+                        # None, so a repeat failure re-raises: the loop
+                        # is bounded.
                         self._enc_demote(e, where="sharded fetch")
-                        handle = self._sharded_dispatch(
-                            buf, reuse_span=handle[5]
-                        )
-                        continue
-                    glz_form = handle[6] if len(handle) > 6 else None
-                    if glz_form is not None and lineage_ok:
-                        # async half of the sharded glz ladder: a
-                        # DETERMINISTIC failure of a compressed batch
-                        # surfacing at the stacked-header sync makes the
-                        # decode the prime suspect — latch compression
-                        # off and re-dispatch the same batch raw.
-                        # Transient faults never reach this
-                        # branch: the bounded retry below re-ships the
-                        # SAME compressed form, so a recoverable fetch
-                        # hiccup cannot cost the executor its link
-                        # compression. The ladder bounds the loop: the
-                        # raw re-dispatch has glz_form None and a repeat
-                        # failure re-raises.
-                        self._glz_demote(e, buf, where="sharded fetch")
                         handle = self._sharded_dispatch(
                             buf, reuse_span=handle[5]
                         )
@@ -3064,51 +2817,30 @@ class TpuChainExecutor:
         if span is not None:
             span.mark_dispatched()
             spec["span"] = span
-        # finish-side self-heal markers: whether THIS dispatch shipped a
-        # glz-compressed flat (async runtime failures surface at fetch),
-        # and the heal epoch its carry lineage belongs to
-        spec["glz_used"] = getattr(self, "_glz_last", False)
+        # finish-side self-heal markers: whether THIS dispatch armed the
+        # result encoder (async runtime failures surface at fetch), and
+        # the heal epoch its carry lineage belongs to
         spec["enc_used"] = getattr(self, "_enc_last", None) is not None
         spec["epoch"] = self._heal_epoch
         handle = (prev_carries, header, packed, spec)
-        self._gauge_track(
-            handle,
-            self.h2d_bytes_total - h0,
-            glz_nbytes=getattr(self, "_glz_last_h2d", 0),
-        )
+        self._gauge_track(handle, self.h2d_bytes_total - h0)
         return handle
 
     def dispatch_buffers(
         self, bufs: List[RecordBuffer], flow_id: int = 0
     ) -> List[tuple]:
-        """Dispatch several buffers with ONE-AHEAD compress-ahead:
-        while buffer k stages and issues, the shared glz worker
-        compresses buffer k+1 (settle-before-dispatch, so staging never
-        races the worker on a cache). One-ahead bounds wasted work to a
-        single job if the self-heal disables compression mid-list, and
-        keeps the process-wide worker fair to other executors. Returns
-        [(buf, handle), ...] for `finish_buffer`. The SPU slice bridge
-        (spu/smart_chain.py) builds on this; the stream loop below
-        inlines the same pattern around its yields. ``flow_id``: the
-        slice flow every one of these chunks belongs to."""
+        """Dispatch several buffers in order. Returns
+        [(buf, handle), ...] for `finish_buffer`; the SPU slice bridge
+        (spu/smart_chain.py) builds on this. ``flow_id``: the slice
+        flow every one of these chunks belongs to."""
         out = []
-        fut = None
         try:
-            for i, buf in enumerate(bufs):
-                if fut is not None:
-                    fut.result()
-                    fut = None
-                if i + 1 < len(bufs):
-                    job = self._precompress_fn(bufs[i + 1])
-                    if job is not None:
-                        fut = _compress_pool().submit(job, bufs[i + 1])
+            for buf in bufs:
                 out.append((buf, self.dispatch_buffer(buf, flow_id)))
         except BaseException:
             # a mid-list dispatch failure (post-retries) must not leak
             # the earlier chunks' in-flight handles: discard them so
             # carries and byte accounting stay coherent for the rerun
-            if fut is not None:
-                fut.cancel()
             for _, h in reversed(out):
                 self.discard_dispatch(h)
             raise
@@ -3176,8 +2908,27 @@ class TpuChainExecutor:
             packed["mask"].copy_to_host_async()
         return spec
 
+    @staticmethod
+    def _settle_device_at_exit() -> None:
+        """Wait for the device before the interpreter tears the client
+        down. A discarded dispatch is device work nobody fetches: its
+        dispatch-time `copy_to_host_async` copies run when its results
+        are ready, and jax's own exit hook destroys the client without
+        waiting for them (SIGSEGV in `TpuRawBuffer::CopyToLiteralAsync`
+        at the exit of an `explode-drain` run whose consumer had left
+        with a slice on the chip: PERF.md §6, PR 33)."""
+        try:
+            jax.block_until_ready(jax.live_arrays())
+        except Exception:  # noqa: BLE001 — exiting: nothing left to tell
+            pass
+
     def discard_dispatch(self, handle) -> None:
         """Drop a speculative dispatch, restoring pre-dispatch carries."""
+        import atexit
+
+        # idempotent registration; runs before jax's exit hook (LIFO)
+        atexit.unregister(self._settle_device_at_exit)
+        atexit.register(self._settle_device_at_exit)
         self._gauge_release(handle)
         if self._sharded is not None:
             self._sharded.discard_dispatch(handle)
@@ -3189,7 +2940,7 @@ class TpuChainExecutor:
             and spec is not None
             and spec.get("epoch", self._heal_epoch) != self._heal_epoch
         ):
-            # a glz heal already superseded this handle's carry lineage;
+            # a heal already superseded this handle's carry lineage;
             # restoring its pre-dispatch futures would resurrect the
             # corrupt chain the heal rolled away from
             return
@@ -3241,8 +2992,8 @@ class TpuChainExecutor:
 
     def _fetch_or_recover(self, buf: RecordBuffer, handle, span, defer: bool):
         """The blocking half of a finish: the fetch, and on a failure
-        the ladder that answers it (fan-out capacity retry, encode and
-        glz heals, the bounded transient retry). Returns the output
+        the ladder that answers it (fan-out capacity retry, the encode
+        heal, the bounded transient retry). Returns the output
         buffer, or the deferred split-back thunk."""
         prev_carries, header, packed, spec = handle
         try:
@@ -3280,40 +3031,24 @@ class TpuChainExecutor:
                 # failure of an encode-armed batch surfaces when results
                 # are consumed — latch encode off and re-run the batch
                 # through the shared recovery re-dispatch (which owns
-                # the carry snapshot + heal-epoch bookkeeping, exactly
-                # like the decode heal below)
+                # the carry snapshot + heal-epoch bookkeeping). Gated on
+                # THIS batch's own enc_used, not the executor-wide latch:
+                # under a pipelined loop, batch k's heal latches encode
+                # off while batch k+1 (already dispatched encode-armed)
+                # is still in flight, and k+1 must heal too
                 self._enc_demote(e, where="fetch")
                 try:
                     out = self._redispatch_refetch(buf, handle, span)
                 except (TpuSpill, KeyboardInterrupt, SystemExit):
                     raise
                 except Exception as e2:
-                    out = self._finish_retry(buf, handle, span, e2)
-            elif heal and spec.get("glz_used"):
-                # async half of the glz self-heal (device RUNTIME
-                # failures surface here when results are consumed):
-                # disable compression,
-                # roll carries back, re-run the batch raw (the shared
-                # recovery re-dispatch — `_redispatch_refetch` — owns the
-                # carry snapshot + heal-epoch bookkeeping). Gated on THIS
-                # batch's own glz_used — not the executor-wide latch:
-                # under the pipelined loop, batch k's heal latches
-                # compression off while batch k+1 (already dispatched
-                # compressed) is still in flight, and k+1 must heal too
-                # instead of re-raising.
-                self._glz_demote(e, buf, where="fetch")
-                try:
-                    out = self._redispatch_refetch(buf, handle, span)
-                except (TpuSpill, KeyboardInterrupt, SystemExit):
-                    raise
-                except Exception as e2:
-                    # the raw rerun failed too: hand off to the bounded
+                    # the rerun failed too: hand off to the bounded
                     # transient retry (unrelated deterministic failures
                     # re-raise from there with carries restored)
                     out = self._finish_retry(buf, handle, span, e2)
             else:
-                # transient device/fetch failure outside glz: bounded
-                # retry against the handle's carry snapshot
+                # transient device/fetch failure of an unencoded batch:
+                # bounded retry against the handle's carry snapshot
                 out = self._finish_retry(buf, handle, span, e)
         return out
 
@@ -3371,7 +3106,7 @@ class TpuChainExecutor:
         return _complete(out)
 
     def _finish_stale_epoch(self, buf: RecordBuffer, handle) -> RecordBuffer:
-        """Finish an aggregate dispatch whose carry lineage a glz heal
+        """Finish an aggregate dispatch whose carry lineage a heal
         invalidated while it was in flight.
 
         When nothing else has consumed the carry chain since the heal
@@ -3396,7 +3131,7 @@ class TpuChainExecutor:
             self._device_carries = self._heal_carries
             self._heal_carries = None
         raise TpuSpill(
-            "glz heal invalidated in-flight aggregate carry lineage",
+            "heal invalidated in-flight aggregate carry lineage",
             reason="heal-lineage",
         )
 
@@ -3423,11 +3158,7 @@ class TpuChainExecutor:
         # back, and aggregate chains without fan-out cannot overflow.
         # Sharded aggregates pipeline too: carries chain through device
         # futures at dispatch time (ShardedChainExecutor._pending_carries)
-        # Compress-ahead: a worker thread glz-compresses batch k+1
-        # (ctypes releases the GIL) while finish_buffer blocks on batch
-        # k-1's device work and the consumer processes its results —
-        # the one ordering with a real overlap window. The cost is a
-        # one-batch lookahead: batch k dispatches immediately (the
+        # One-batch lookahead: batch k dispatches immediately (the
         # device never idles behind an arrival), but k-1's results
         # yield only after k+1 arrives — immaterial for eager sources
         # (the bench, sharded pipelining, queue drains), one batch of
@@ -3443,21 +3174,11 @@ class TpuChainExecutor:
         cur = next(it, None)
         pending = None
         handle = None
-        fut = None
         mat = None  # in-flight deferred materialization (Future)
         try:
             while cur is not None:
-                if fut is not None:
-                    # settle before cur dispatches: the staging must never
-                    # race the worker on the same buffer's cache
-                    fut.result()
-                    fut = None
                 handle = self.dispatch_buffer(cur)
                 nxt = next(it, None)
-                if nxt is not None:
-                    job = self._precompress_fn(nxt)
-                    if job is not None:
-                        fut = _compress_pool().submit(job, nxt)
                 if pending is not None:
                     if overlap:
                         out = self.finish_buffer_deferred(

@@ -1,15 +1,15 @@
-"""glz link compression: host compressor bindings + device decompressor.
+"""glz: the down-link's result codec — device encoder, host decoders.
 
-The H2D link is the first wall a byte-bound chain meets. glz keeps
-record bytes COMPRESSED across the link and inflates them on the
-device itself, inside the same jit program that re-pads and runs the
-chain — possible because the format (native/glz.cpp) is a list of
-LZ4-shaped sequences (literal run + match) whose matches never overlap
-their own output and whose match-chain depth is capped, turning
-decompression into a fixed number of vectorized gather rounds instead
-of a serial decode.
-
-Decode algorithm (all traced, static shapes):
+Result streams (descriptor blocks, packed payloads) may cross the D2H
+link COMPRESSED: the chain's own jit program encodes them on the device
+(`encode_result`, armed by ``FLUVIO_RESULT_COMPRESS``) and the fetch
+inflates them on the host (`decode_result_host`: the native
+`glz_decompress` of native/glz.cpp, which validates what the device
+sent, else the numpy mirror `decompress_numpy`). The format is a list
+of LZ4-shaped sequences (literal run + match) whose matches never
+overlap their own output and whose match-chain depth is capped at
+``MAX_DEPTH``, so a decode is a fixed number of vectorized gather
+rounds (`decompress_numpy` is that algorithm's executable spec):
   1. per-sequence dst offsets = exclusive cumsum of lit_len+match_len;
      literal-stream offsets = exclusive cumsum of lit_len
   2. sequence id per output byte = scatter(1 at dst offsets) + cumsum
@@ -18,15 +18,9 @@ Decode algorithm (all traced, static shapes):
      resolves every depth-k byte because its sources (depth < k)
      resolved in earlier rounds
 
-Parity: the reference inflates wire compression on the CPU before its
-engine sees bytes (fluvio-compression/src/lib.rs); a CPU-side engine
-has nothing to gain from device-side inflation. Nor has a locally
-attached chip: on the v5e the gather rounds inflate a 2.6 MB flat in
-about 283 ms (9 MB/s, 95 % of the north star's device time; PERF.md §6,
-PR 27), against 1.06 ms to ship it raw. So the up-link runs
-this decode only under ``FLUVIO_LINK_COMPRESS=on``
-(`executor.effective_link_compress`); the result ENCODER further down
-is the down-link's and is armed by its own flag.
+The up-link carries no glz: the staged flat ships raw. On the v5e the
+device-side inflate ran at about 9 MB/s (283 ms per 2.6 MB flat)
+against 1.06 ms to ship it raw (PERF.md §6, PRs 27 and 33).
 """
 
 from __future__ import annotations
@@ -37,7 +31,7 @@ import logging
 import os
 import subprocess
 from pathlib import Path
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple
 
 import numpy as np
 
@@ -54,45 +48,21 @@ _lock = make_lock("glz.build")
 _lib = None
 _lib_failed = False
 
-MAX_DEPTH = 6       # gather rounds the device decode runs at most
-MIN_MATCH = 8       # sequences are 6 B; shorter matches don't pay
-MIN_INPUT = 4096    # below this the link time is noise — ship raw
-# worthwhile threshold: compressed bytes (seqs*6 + lits) must come in
-# under this fraction of raw before the executor switches the jit to
-# the compressed staging variant
-MAX_RATIO = 0.75
-# link streams compress in independent CHUNKS of this many output
-# bytes: every match source stays inside its own chunk (the wire
-# format the device ENCODER's per-chunk hash tables also emit); the
-# whole-buffer gather rounds and the host oracle read the merged
-# stream — sources are absolute — and never need the sidecar
+MAX_DEPTH = 6       # gather rounds a decode runs at most
+# streams encode in independent CHUNKS of this many output bytes:
+# every match source stays inside its own chunk (the device encoder's
+# per-chunk hash tables); sources are absolute, so the host decoders
+# read the merged stream
 GLZ_CHUNK = 256 * 1024
-
-# decline-reason vocabulary (telemetry counter keys — the bench's
-# per-config link breakdown and the preflight analyzer must speak the
-# same strings)
-DECLINE_UNAVAILABLE = "glz-unavailable"
-DECLINE_BELOW_MIN = "glz-below-min"
-DECLINE_RATIO = "glz-ratio"
-DECLINE_WIDE = "glz-wide-unsupported"
 
 
 def chunk_bytes() -> int:
-    """Configured link-chunk size (``FLUVIO_GLZ_CHUNK``); must stay a
+    """Configured encode-chunk size (``FLUVIO_GLZ_CHUNK``); must stay a
     multiple of 1024 so chunk starts stay word- and group-aligned."""
     c = int(env_int("FLUVIO_GLZ_CHUNK"))
     if c < 4096 or c % 1024:
         raise ValueError(f"FLUVIO_GLZ_CHUNK={c}: need a multiple of 1024 >= 4096")
     return c
-
-
-class _GlzResult(ctypes.Structure):
-    _fields_ = [
-        ("n_seqs", ctypes.c_int64),
-        ("n_lits", ctypes.c_int64),
-        ("depth", ctypes.c_int32),
-        ("status", ctypes.c_int32),
-    ]
 
 
 def _load():
@@ -118,18 +88,11 @@ def _load():
                 os.replace(tmp, out)
             lib = ctypes.CDLL(str(out))
         except (OSError, subprocess.CalledProcessError) as e:
-            logger.warning("glz link compression unavailable: %s", e)
+            logger.warning("native glz decoder unavailable: %s", e)
             _lib_failed = True
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
-        lib.glz_compress.restype = _GlzResult
-        lib.glz_compress.argtypes = [
-            u8p, ctypes.c_int64,
-            u8p, u8p, i32p, ctypes.c_int64,
-            u8p, ctypes.c_int64,
-            ctypes.c_int32, ctypes.c_int32,
-        ]
         lib.glz_decompress.restype = ctypes.c_int32
         lib.glz_decompress.argtypes = [
             u8p, u8p, i32p, ctypes.c_int64,
@@ -150,131 +113,12 @@ class Compressed(NamedTuple):
     lits: np.ndarray        # uint8[n_lits]
     depth: int              # gather rounds needed (<= MAX_DEPTH)
     out_len: int            # decompressed size == len(raw)
-    # chunked-stream sidecar (compress_link): 0/None for a whole-buffer
-    # stream. chunk_seqs[c] is the first sequence of chunk c (host-side
-    # bookkeeping + test surface for the chunk-locality invariant; the
-    # device decode derives everything from positions, so the sidecar
-    # never crosses the link)
-    chunk_bytes: int = 0
-    chunk_seqs: Optional[np.ndarray] = None  # int32[n_chunks + 1]
-
-    @property
-    def nbytes(self) -> int:
-        return (self.lit_lens.nbytes + self.match_lens.nbytes
-                + self.srcs.nbytes + self.lits.nbytes)
-
-
-def compress(raw: np.ndarray, max_ratio: float = MAX_RATIO) -> Optional[Compressed]:
-    """Compress a uint8 array; None when raw is the better ship.
-
-    Returns None when the native library is unavailable, the input is
-    tiny, the compressor bailed (incompressible), or the achieved ratio
-    is worse than ``max_ratio`` — callers fall back to the raw staging
-    path in all those cases.
-    """
-    lib = _load()
-    n = int(raw.size)
-    if lib is None or n < MIN_INPUT:
-        return None
-    raw = np.ascontiguousarray(raw, dtype=np.uint8)
-    seq_cap = n // 4 + 64
-    lit_lens = np.empty(seq_cap, dtype=np.uint8)
-    match_lens = np.empty(seq_cap, dtype=np.uint8)
-    srcs = np.empty(seq_cap, dtype=np.int32)
-    lits = np.empty(n + 64, dtype=np.uint8)
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-    res = lib.glz_compress(
-        raw.ctypes.data_as(u8p), n,
-        lit_lens.ctypes.data_as(u8p), match_lens.ctypes.data_as(u8p),
-        srcs.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)), seq_cap,
-        lits.ctypes.data_as(u8p), lits.size,
-        MAX_DEPTH, MIN_MATCH,
-    )
-    if res.status != 0:
-        return None
-    ns, nl = int(res.n_seqs), int(res.n_lits)
-    if ns * 6 + nl > n * max_ratio:
-        return None
-    return Compressed(
-        lit_lens=lit_lens[:ns].copy(), match_lens=match_lens[:ns].copy(),
-        srcs=srcs[:ns].copy(), lits=lits[:nl].copy(),
-        depth=max(int(res.depth), 1), out_len=n,
-    )
-
-
-def compress_link(
-    raw: np.ndarray,
-    max_ratio: float = MAX_RATIO,
-    chunk: Optional[int] = None,
-) -> Tuple[Optional[Compressed], Optional[str]]:
-    """Chunked link compression: (stream, None) or (None, decline reason).
-
-    The input compresses in independent ``chunk``-byte windows so every
-    match source lands inside its own chunk. Sources are emitted ABSOLUTE (chunk
-    base added), so the merged stream is also a valid whole-buffer glz
-    stream for the gather-round decode and the host oracle. The decline
-    reason is one of the telemetry counter keys (`glz-unavailable`,
-    `glz-below-min`, `glz-ratio`) so staging sites can surface exactly
-    why a batch shipped raw.
-    """
-    lib = _load()
-    n = int(raw.size)
-    if lib is None:
-        return None, DECLINE_UNAVAILABLE
-    if n < MIN_INPUT:
-        return None, DECLINE_BELOW_MIN
-    chunk = chunk or chunk_bytes()
-    raw = np.ascontiguousarray(raw, dtype=np.uint8)
-    n_chunks = (n + chunk - 1) // chunk
-    seq_cap = n // 4 + 64 * n_chunks
-    lit_cap = n + 64 * n_chunks
-    lit_lens = np.empty(seq_cap, dtype=np.uint8)
-    match_lens = np.empty(seq_cap, dtype=np.uint8)
-    srcs = np.empty(seq_cap, dtype=np.int32)
-    lits = np.empty(lit_cap, dtype=np.uint8)
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-    i32p = ctypes.POINTER(ctypes.c_int32)
-    bounds = np.zeros(n_chunks + 1, dtype=np.int32)
-    n_seq = n_lit = 0
-    depth = 1
-    for c in range(n_chunks):
-        base = c * chunk
-        clen = min(chunk, n - base)
-        res = lib.glz_compress(
-            raw[base:].ctypes.data_as(u8p), clen,
-            lit_lens[n_seq:].ctypes.data_as(u8p),
-            match_lens[n_seq:].ctypes.data_as(u8p),
-            srcs[n_seq:].ctypes.data_as(i32p), seq_cap - n_seq,
-            lits[n_lit:].ctypes.data_as(u8p), lit_cap - n_lit,
-            MAX_DEPTH, MIN_MATCH,
-        )
-        if res.status != 0:
-            # one incompressible window sinks the stream: a mixed ship
-            # (some chunks raw) would fork the wire format for a corner
-            # the ratio gate already rejects
-            return None, DECLINE_RATIO
-        ns = int(res.n_seqs)
-        srcs[n_seq : n_seq + ns] += base  # chunk-local -> absolute
-        n_seq += ns
-        n_lit += int(res.n_lits)
-        depth = max(depth, int(res.depth), 1)
-        bounds[c + 1] = n_seq
-    if n_seq * 6 + n_lit > n * max_ratio:
-        return None, DECLINE_RATIO
-    return (
-        Compressed(
-            lit_lens=lit_lens[:n_seq].copy(),
-            match_lens=match_lens[:n_seq].copy(),
-            srcs=srcs[:n_seq].copy(), lits=lits[:n_lit].copy(),
-            depth=depth, out_len=n,
-            chunk_bytes=chunk, chunk_seqs=bounds,
-        ),
-        None,
-    )
 
 
 def decompress_host(comp: Compressed) -> np.ndarray:
-    """Native reference decompressor (tests / debugging oracle)."""
+    """Native decoder; fails closed (``ValueError``) on a stream that
+    overruns its output or literals, reads forward or overlapping
+    sources, decodes to another length, or carries an empty sequence."""
     lib = _load()
     assert lib is not None
     out = np.empty(comp.out_len, dtype=np.uint8)
@@ -295,13 +139,10 @@ def decompress_host(comp: Compressed) -> np.ndarray:
 
 
 def decompress_numpy(comp: Compressed) -> np.ndarray:
-    """Pure-numpy mirror of the DEVICE algorithm (same gather rounds).
-
-    Exists so tests can pin the traced program's semantics against an
-    executable spec without a jax dependency; must stay in lockstep
-    with ``byte_plan_device`` + ``decompress_device``: literal (and
-    pad) bytes carry ``midx == their own index``, so ``out = out[midx]``
-    is the decode's fixpoint iteration with no literal mask.
+    """Pure-numpy gather-round decode (the module docstring's
+    algorithm), for hosts without a toolchain: literal (and pad) bytes
+    carry ``midx == their own index``, so ``out = out[midx]`` is the
+    decode's fixpoint iteration with no literal mask.
     """
     out_len = comp.out_len
     ll = comp.lit_lens.astype(np.int64)
@@ -333,79 +174,18 @@ def decompress_numpy(comp: Compressed) -> np.ndarray:
     return out
 
 
-def byte_plan_device(lit_lens, match_lens, srcs, lits, out_len: int):
-    """Traced per-byte decode plan: (base, midx), both [out_len].
-
-    ``base`` is the literal-resolved output (literal bytes placed, match
-    bytes zero); ``midx`` the gather source per byte, with literal and
-    pad bytes pointing AT THEMSELVES — so ``out = out[midx]`` iterates
-    to the decoded buffer as its fixpoint (over-application past the
-    stream's real depth is a no-op). The gather-round decode runs
-    ``depth`` rounds of it through HBM.
-
-    Sequence arrays may be zero-padded past the real count (link
-    bucketing) — pad sequences have lit_len == match_len == 0, land at
-    dst == out_len, and drop out of the scatter.
-    """
-    import jax.numpy as jnp
-
-    ll = lit_lens.astype(jnp.int32)
-    ml = match_lens.astype(jnp.int32)
-    total = ll + ml
-    dst_start = jnp.cumsum(total) - total
-    lit_start = jnp.cumsum(ll) - ll
-    # pad sequences (total == 0) may share dst_start with a real
-    # sequence; scatter them out of range so only real sequences mark
-    marks_at = jnp.where(total > 0, dst_start, out_len)
-    marks = jnp.zeros((out_len,), jnp.int32).at[marks_at].add(1, mode="drop")
-    seq_id = jnp.cumsum(marks) - 1
-    idx = jnp.arange(out_len, dtype=jnp.int32)
-    within = idx - jnp.take(dst_start, seq_id)
-    seq_ll = jnp.take(ll, seq_id)
-    in_lit = within < seq_ll
-    lit_idx = jnp.clip(
-        jnp.take(lit_start, seq_id) + within, 0, lits.shape[0] - 1
-    )
-    base = jnp.where(in_lit, jnp.take(lits, lit_idx), 0).astype(jnp.uint8)
-    midx = jnp.where(
-        in_lit,
-        idx,
-        jnp.clip(jnp.take(srcs, seq_id) + (within - seq_ll), 0, out_len - 1),
-    )
-    return base, midx
-
-
-def decompress_device(lit_lens, match_lens, srcs, lits, depth, out_len: int):
-    """Traced gather-round decode: uint8[out_len] from sequence arrays.
-
-    ``depth`` is a traced scalar so batches with different chain depths
-    share one compiled program (fori_loop dynamic bound). Each round
-    materializes the full buffer through HBM.
-    """
-    import jax.numpy as jnp
-    from jax import lax
-
-    base, midx = byte_plan_device(lit_lens, match_lens, srcs, lits, out_len)
-
-    def round_(_, o):
-        return jnp.take(o, midx)
-
-    return lax.fori_loop(0, depth, round_, base)
-
-
 # ---------------------------------------------------------------------------
-# Device-side result ENCODER (the down-link mirror of the decode ladder)
+# Device-side result ENCODER
 # ---------------------------------------------------------------------------
 #
 # Result streams compress ON DEVICE before they ever
 # cross the link and inflate host-side with the existing decoders
-# (`decompress_host` native, `decompress_numpy` fallback) — the same
-# one-wire-format contract as `compress_link`: chunk-local matches,
-# absolute sources, lit/match lens <= 255, depth <= MAX_DEPTH.
+# (`decompress_host` native, `decompress_numpy` fallback). The wire
+# format: chunk-local matches, absolute sources, lit/match lens <= 255,
+# depth <= MAX_DEPTH.
 #
-# A TPU cannot run the host compressor's serial greedy parse, so the
-# device encoder is a data-parallel formulation over aligned 8-byte
-# GROUPS:
+# A TPU cannot run a serial greedy parse, so the device encoder is a
+# data-parallel formulation over aligned 8-byte GROUPS:
 #
 #   1. match detection — a group matches an EARLIER group of its own
 #      chunk with identical bytes: a scatter-built per-chunk
@@ -422,11 +202,11 @@ def decompress_device(lit_lens, match_lens, srcs, lits, depth, out_len: int):
 #      sequences, capped at ENC_MAX_RUN groups per half (248 <= u8),
 #      split at chunk boundaries; one scatter packs the literal stream.
 #
-# The stream is VALID, not canonical: the host compressor may pick
-# different matches (the differential tests pin round-trip equality,
-# not byte-identical tokens).
+# The stream is VALID, not canonical (the differential tests pin
+# round-trip equality, not byte-identical tokens).
 
-ENC_GROUP = 8        # bytes per match group (== MIN_MATCH)
+ENC_GROUP = 8        # bytes per match group (a sequence is 6 B: shorter
+                     # matches don't pay)
 ENC_MAX_RUN = 31     # groups per sequence half: 248 bytes <= the u8 field
 ENC_TABLE = 1 << 15  # first-occurrence hash slots per chunk
 

@@ -11,8 +11,7 @@ HBM-resident ``RecordBuffer``:
   of ``STRIPE_WIDTH`` bytes sharing a segment id, with a per-row
   ``(segment_id, stripe_idx, stripe_len)`` sidecar DERIVED ON DEVICE from
   the record lengths — the flat H2D copy stays the single contiguous
-  ragged transfer the narrow path ships (glz staging compresses it the
-  same way);
+  ragged transfer the narrow path ships;
 - consecutive stripes overlap by ``STRIPE_OVERLAP`` bytes, so any byte
   window up to the overlap length is wholly contained in some stripe:
   filter literals evaluate per stripe and reduce per segment

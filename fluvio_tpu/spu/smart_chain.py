@@ -780,10 +780,8 @@ def tpu_dispatch(
 
     tpu = chain.tpu_chain
     flow = pending.flow
-    # executor-owned dispatch: with compression on, the worker
-    # glz-compresses chunk k+1 while chunk k dispatches (one-ahead);
-    # with it off this is a plain dispatch loop. A dispatch failure that
-    # survived the executor's bounded retries (or a deterministic fault)
+    # executor-owned dispatch: a plain dispatch loop. A dispatch failure
+    # that survived the executor's bounded retries (or a deterministic fault)
     # must not crash the stream handler: the slice declines to the
     # per-record path, whose own fused/spill/quarantine ladder decides
     # per batch (dispatch_buffers discarded any partial handles).

@@ -2,7 +2,7 @@
 
 HBM is home to far more than staged batches — partition carry banks,
 window state banks and their pow2 emit buffers, per-shard staging,
-glz token ladders, the compiled-executable cache — yet before this
+the compiled-executable cache — yet before this
 module the only accounting was one gauge bumped at one executor seam.
 The :class:`MemoryLedger` is the join: every allocation seam books
 ``acquire(owner, key, nbytes)`` when bytes land on the device and
@@ -19,7 +19,7 @@ Three consumers sit on top:
 - **gauges**: every acquire/release republishes the flat gauges
   (``device_memory_bytes``, ``device_memory_peak_bytes``) plus the
   compatibility aliases ``hbm_staged_bytes`` (the staged-batch +
-  glz-token + shard-staging sum — the pre-ledger gauge folded in so it
+  shard-staging sum — the pre-ledger gauge folded in so it
   cannot drift from the ledger) and ``window_state_bytes`` (the
   ``window_bank`` owner). Per-owner byte totals export through the
   snapshot ``memory`` section and the Prometheus
@@ -61,7 +61,6 @@ OWNERS = (
     "carry_bank",     # partition runtimes' device-resident aggregate carries
     "window_bank",    # WindowStateBank device arrays (sums/counts/meta)
     "emit_buffer",    # pow2-bucketed window emit/resync fetch buffers
-    "glz_tokens",     # compressed-staging token ladders (ll/ml/srcs/lits)
     "shard_staging",  # sharded per-shard staged dispatch
     "compile_cache",  # resident compiled-executable estimates
 )
@@ -71,7 +70,7 @@ OWNERS = (
 #: carry/window banks and the compile cache legitimately persist
 #: across batches, so assert_drained() exempts them.
 TRANSIENT_OWNERS = (
-    "staged_batch", "emit_buffer", "glz_tokens", "shard_staging",
+    "staged_batch", "emit_buffer", "shard_staging",
 )
 
 #: the SLO rule family this ledger feeds (the memory CLI's breach gate
@@ -163,9 +162,7 @@ class MemoryLedger:
         with self._lock:
             by = self._by_owner
             total = sum(by.values())
-            staged = (
-                by["staged_batch"] + by["glz_tokens"] + by["shard_staging"]
-            )
+            staged = by["staged_batch"] + by["shard_staging"]
             window = by["window_bank"]
             peak = self._peak
         t.gauge_set("device_memory_bytes", float(total))

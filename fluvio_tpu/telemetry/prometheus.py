@@ -90,7 +90,6 @@ def render_prometheus(
         spills, declines = dict(t.spills), dict(t.declines)
         link_variants = dict(t.link_variants)
         retries, quarantined = dict(t.retries), t.quarantined
-        sharded_compress = t.sharded_compress_shards
         slo_breaches = dict(t.slo_breaches)
         admission = dict(t.admission)
         breaker_states = dict(t.breaker_states)
@@ -160,7 +159,7 @@ def render_prometheus(
 
     w.header(
         f"{_PREFIX}_glz_heals_total",
-        "Link-compression self-heal events (glz disabled + batch re-shipped raw).",
+        "Self-heal events (result encode latched off + batch re-dispatched).",
         "counter",
     )
     w.sample(f"{_PREFIX}_glz_heals_total", {}, heals)
@@ -190,8 +189,8 @@ def render_prometheus(
 
     w.header(
         f"{_PREFIX}_link_variants_total",
-        "Dispatched batches by H2D link staging form "
-        "(raw / glz-gather).",
+        "Fetched batches by D2H link form "
+        "(down-glz-xla / down-packed / down-raw / agg-*).",
         "counter",
     )
     for variant, n in sorted(link_variants.items()):
@@ -211,18 +210,6 @@ def render_prometheus(
         "counter",
     )
     w.sample(f"{_PREFIX}_quarantined_total", {}, quarantined)
-
-    w.header(
-        f"{_PREFIX}_sharded_inline_compress_shards_total",
-        "Shard segments glz-compressed inline on the sharded staging "
-        "path (not covered by the compress-ahead worker).",
-        "counter",
-    )
-    w.sample(
-        f"{_PREFIX}_sharded_inline_compress_shards_total",
-        {},
-        sharded_compress,
-    )
 
     w.header(
         f"{_PREFIX}_slo_breaches_total",
@@ -440,7 +427,7 @@ def render_prometheus(
         f"{_PREFIX}_device_memory_bytes",
         "Device-memory ledger bytes by owner class "
         "(staged_batch | carry_bank | window_bank | emit_buffer | "
-        "glz_tokens | shard_staging | compile_cache).",
+        "shard_staging | compile_cache).",
         "gauge",
     )
     for owner, v in sorted(memory_owners.items()):
@@ -466,7 +453,7 @@ def render_prometheus(
     for name, help_text in (
         ("hbm_staged_bytes",
          "Device-memory bytes currently staged by in-flight batches "
-         "(ledger alias: staged_batch + glz_tokens + shard_staging)."),
+         "(ledger alias: staged_batch + shard_staging)."),
         ("live_batch_handles",
          "Dispatched batches whose results have not been fetched."),
         ("inflight_queue_depth",

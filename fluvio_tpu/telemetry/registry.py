@@ -9,7 +9,7 @@ recording:
 - per-phase latency histograms + running time totals (the bench's
   per-phase breakdown reads the totals; histograms answer "is the
   d2h tail bimodal"),
-- event counters: glz heals, interpreter spills keyed by reason,
+- event counters: heals, interpreter spills keyed by reason,
   stripe fallbacks, fast-path declines keyed by reason,
 - JIT-compile telemetry: per-kind compile counts + wall seconds +
   a compile-latency histogram, persistent-`.xla_cache` hit/miss
@@ -103,10 +103,11 @@ class PipelineTelemetry:
         self.stripe_fallbacks = 0
         self.spills: Dict[str, int] = {}
         self.declines: Dict[str, int] = {}
-        # which form each dispatched batch's flat crossed the H2D link
-        # in: "raw" | "glz-gather" (the bench's per-config
-        # link breakdown and the preflight link-variant prediction both
-        # read this family)
+        # which form each fetched batch's result crossed the D2H link
+        # in (`down-*`; `agg-*` for an accumulator column): the bench's
+        # per-config link breakdown and the preflight's down-variant
+        # prediction both read this family. The up-link has one form
+        # (raw), so it books nothing here.
         self.link_variants: Dict[str, int] = {}
         self.batch_records: Dict[str, int] = {
             "fused": 0, "striped": 0, "interpreter": 0
@@ -117,13 +118,6 @@ class PipelineTelemetry:
         # breaker + transition counts + open-state short-circuits)
         self.retries: Dict[str, int] = {}
         self.quarantined = 0
-        # sharded inline-compress accounting (ROADMAP's noted gap: the
-        # compress-ahead worker covers only single-device buffers, so a
-        # sharded stream pays the n-shard compressor inline in stage):
-        # shard segments glz-compressed inline, so the "extend the
-        # worker to pre-fill _glz_shard_cache" call can be made from
-        # evidence instead of guesswork
-        self.sharded_compress_shards = 0
         # SLO breach transitions, keyed "chain/rule" (telemetry/slo.py)
         self.slo_breaches: Dict[str, int] = {}
         # admission-controller decisions keyed by outcome (admission/):
@@ -561,14 +555,6 @@ class PipelineTelemetry:
             self.quarantined += 1
         self._event("quarantine")
 
-    def add_sharded_compress(self, shards: int) -> None:
-        """Shard segments glz-compressed INLINE on the sharded staging
-        path (the compress-ahead worker does not cover sharded buffers
-        yet; this counter + the ``glz_compress`` phase span are the
-        evidence for extending it)."""
-        with self._lock:
-            self.sharded_compress_shards += shards
-
     def add_slo_breach(self, key: str, detail: str = "") -> None:
         """One SLO verdict transition into ``breach`` for ``key``
         ("chain/rule"). Emits the flight-recorder instant event so the
@@ -883,9 +869,6 @@ class PipelineTelemetry:
                     "link_variants": dict(self.link_variants),
                     "retries": dict(self.retries),
                     "quarantined": self.quarantined,
-                    "sharded_inline_compress_shards": (
-                        self.sharded_compress_shards
-                    ),
                     "slo_breaches": dict(self.slo_breaches),
                     "admission": dict(self.admission),
                     "rebalance_moves": dict(self.rebalance_moves),
@@ -1010,7 +993,6 @@ class PipelineTelemetry:
             self.link_variants = {}
             self.retries = {}
             self.quarantined = 0
-            self.sharded_compress_shards = 0
             self.slo_breaches = {}
             self.admission = {}
             self.breaker_states = {}

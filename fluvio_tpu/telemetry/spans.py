@@ -5,7 +5,6 @@ FIXED vocabulary (indexes into one flat float list — no per-phase dict
 allocation on the hot path):
 
 - ``stage``        host staging: ragged flat build, column merge/slice
-- ``glz_compress`` host glz compression of the H2D flat
 - ``h2d``          host-side link staging/enqueue (device array builds;
                    the physical transfer overlaps ``device``)
 - ``dispatch``     jit call: trace lookup + async dispatch enqueue
@@ -31,8 +30,7 @@ allocation on the hot path):
                    split-back where that runs on the fetch worker
 - ``d2h``          blocking device->host copy time
 - ``glz_decode``   host decompression of stored-batch compression on
-                   the staging side (device-side glz inflate is inside
-                   the jit and therefore part of ``device``)
+                   the staging side
 - ``spill``        interpreter re-run after a fused-path spill/decline
 
 Overhead contract: begin/end is two monotonic clock reads; each phase
@@ -59,7 +57,6 @@ from fluvio_tpu.analysis.lockwatch import make_lock
 
 PHASES = (
     "stage",
-    "glz_compress",
     "h2d",
     "dispatch",
     "device",
@@ -78,7 +75,10 @@ _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
 #: `stage.apply` is `stage<i>.<kind>` (`stage_scope`). Scopes nest
 #: (``compact/pack``); the innermost one names the operation.
 DEVICE_SCOPES = (
-    "link_decode",  # glz inflate of the flat + the bitcast to words
+    "link_decode",  # reader-only word: no program opens it since the
+                    # flat ships raw (PR 33); kept because the benchmark's
+                    # recorded fixture (tests/benchmark) is reduced
+                    # through this tuple
     "repad",        # ragged flat -> padded matrix, derived meta columns
     "stage",        # stage<i>.<kind>: one chain stage's apply
     "compact",      # survivor compaction, mask, header
@@ -165,9 +165,10 @@ class _TimedPhase:
 
     def rename(self, name: str) -> None:
         """Book under another phase than the one entered, where only
-        the work itself decides which it was (the link form of a
-        staged flat): the annotation keeps the name it was entered
-        with and gains ``phase=<name>``."""
+        the work itself decides which it was: the annotation keeps the
+        name it was entered with and gains ``phase=<name>``. No program
+        site needs it since the staged flat has one link form (PR 33);
+        `tests/benchmark/test_tracing_readers.py` holds its contract."""
         self.name = name
         set_metadata = getattr(self._ann, "set_metadata", None)
         if set_metadata is not None:

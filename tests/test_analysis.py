@@ -152,8 +152,7 @@ def test_bench_matrix_predicted_down_variant_matches_observed(
     the predicted D2H variant must be differential-exact against the
     telemetry ``down-*`` counters for every bench-matrix config — the
     one tolerated divergence is a per-batch ratio/size decline, which
-    must then show on the `glz-enc-ratio`/decline surface (the same
-    contract the H2D prediction has with `glz-ratio`)."""
+    must then show on the `glz-enc-ratio`/decline surface."""
     monkeypatch.setenv("FLUVIO_RESULT_COMPRESS", "on")
     b = _bench()
     cfg = b.CONFIGS[name]
@@ -186,19 +185,17 @@ def test_bench_matrix_predicted_down_variant_matches_observed(
 
 
 def test_bench_preflight_record_shape():
-    """The record bench.py embeds per config: path + link variant (both
-    directions) + optional reasons. On the CPU test backend link
-    compression AND the result-encode ladder resolve off (auto), so the
-    predicted H2D variant is raw and the D2H one is down-packed (the
-    headline chain is a descriptor-shipping span chain; compaction is
-    on everywhere)."""
+    """The record bench.py embeds per config: path + down-link variant
+    + optional reasons. On the CPU test backend the result-encode
+    ladder resolves off (auto), so the predicted D2H variant is
+    down-packed (the headline chain is a descriptor-shipping span
+    chain; compaction is on everywhere)."""
     b = _bench()
     pred = preflight_for_specs(
         b.CONFIGS["2_filter_map"]["specs"], 64
     )
     assert pred == {
         "path": "fused",
-        "link_variant": "raw",
         "down_variant": "down-packed",
     }
 
@@ -517,38 +514,6 @@ def test_hard_ceiling_record_too_wide(monkeypatch):
     _run(chain, [b"fluvio" + b"x" * width])
     assert _observed_path(pr0) == "interpreter"
     assert _spill_delta(s0).get("record-too-wide", 0) > 0
-
-
-def test_sharded_striped_predicts_glz_wide_unsupported(monkeypatch):
-    """ISSUE-11 satellite (PR-8 leftover): with link compression armed,
-    a sharded STRIPED config must predict the raw link ship with the
-    per-batch ``glz-wide-unsupported`` decline — the compact `link`
-    block's evidence for the compress-ahead-worker decision."""
-    from fluvio_tpu.smartengine.tpu import glz
-
-    monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
-    if not glz.available():
-        pytest.skip("native glz library unavailable")
-    report = analyze_named(
-        [("regex-filter", {"regex": "fluvio"})],
-        widths=(70 * 1024,),
-        sharded=True,
-    )
-    pred = report.predictions[0]
-    assert pred.path == "striped"
-    assert "glz-wide-unsupported" in pred.declines
-    assert pred.link_variant == "raw"
-    # the same prediction through the bench's entry point
-    pf = preflight_for_specs(
-        [("regex-filter", {"regex": "fluvio"})], 70 * 1024, sharded=True
-    )
-    assert pf["path"] == "striped"
-    assert "glz-wide-unsupported" in pf.get("declines", [])
-    # unsharded at the same width: striped ships COMPRESSED (no decline)
-    pf2 = preflight_for_specs(
-        [("regex-filter", {"regex": "fluvio"})], 70 * 1024
-    )
-    assert "glz-wide-unsupported" not in pf2.get("declines", [])
 
 
 def test_sharded_fanout_stays_narrow_in_prediction():

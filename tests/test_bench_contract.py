@@ -176,14 +176,11 @@ def _full_config(rps: int, x: float, path: str = "fused") -> dict:
         "link_mb": [34.62, 4.33],
         "link_floor_ms": 777,
         "link_saturation": 0.45,
-        "glz_ratio": 0.476,
-        # ISSUE-8: per-config link breakdown (engaged staging variant +
-        # glz decline attribution from the telemetry counters)
+        # ISSUE-8: per-config link breakdown (link MB both ways + glz
+        # decline attribution from the telemetry counters)
         "link": {
             "up_mb": 34.62,
             "down_mb": 4.33,
-            "variant": "glz-pallas",
-            "variants": {"glz-pallas": 7},
             # ISSUE-12: the result-side (D2H) variant family — which
             # form the outputs crossed down in
             "down_variant": "down-glz-pallas",
@@ -209,7 +206,7 @@ def _full_config(rps: int, x: float, path: str = "fused") -> dict:
             "wall_ms": 1693.4,
             "phase_sum_ms": 1650.2,
             "phase_ms": {
-                "stage": 201.5, "glz_compress": 144.2, "dispatch": 55.1,
+                "stage": 201.5, "h2d": 144.2, "dispatch": 55.1,
                 "device": 901.2, "fetch": 240.8, "d2h": 107.4,
             },
             "top": [["device", 0.55], ["fetch", 0.15], ["stage", 0.12]],
@@ -223,7 +220,6 @@ def _full_config(rps: int, x: float, path: str = "fused") -> dict:
         # executed path from the static analyzer, full detail file-only)
         "preflight": {
             "path": path, "actual": path, "agree": True,
-            "link_variant": "glz-pallas",
             "down_variant": "down-glz-pallas",
         },
         # SLO-PR satellite: per-config verdict block (targets, observed
@@ -260,11 +256,6 @@ def _full_results() -> dict:
             ("7_fat70k", 190253, 19.94, "striped"),
         ]
     }
-    results["2_filter_map"]["staging_ab"] = {
-        "glz_ms": [1139, 1731, 2049],
-        "raw_ms": [1400, 1390, 1410],
-        "chosen": "glz",
-    }
     results["broker_e2e"] = {
         "records_per_sec": 300392,
         "vs_engine_only": 0.52,
@@ -295,7 +286,7 @@ def test_compact_line_fits_driver_window():
     b = _bench()
     b._BACKEND_MODE = "tpu"
     b._LINK.update(
-        rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0, glz="on", glz_pinned=False
+        rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0
     )
     try:
         out, rc = b._build_output(_full_results())
@@ -314,10 +305,10 @@ def test_compact_line_fits_driver_window():
     assert parsed["configs"]["7_fat70k"]["path"] == "striped"
     assert "path" not in parsed["configs"]["1_filter"]
     assert "fallback" not in parsed["configs"]["7_fat70k"]  # static label is gone
-    assert parsed["link"]["glz"] == "on"
     # ISSUE-8: the tiny link key carries the headline's measured upload
-    # MB next to the resolved glz mode
+    # MB next to the link calibration
     assert parsed["link"]["up_mb"] == 34.62
+    assert parsed["link"]["h2d_mb_s"] == 49.0
     assert parsed["detail"] == "BENCH_DETAIL.json"
     # telemetry satellite: ONE compact phases key (the headline's p50/p99
     # + top-3 phase shares); the per-config phase tables stay in the file
@@ -346,7 +337,7 @@ def test_compact_line_trims_pathological_blowup_keeps_link():
 
     b = _bench()
     b._BACKEND_MODE = "tpu"
-    b._LINK.update(rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0, glz="on")
+    b._LINK.update(rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0)
     results = {
         f"cfg_{i:02d}": {"error": "boom " * 100} for i in range(40)
     }
@@ -359,9 +350,8 @@ def test_compact_line_trims_pathological_blowup_keeps_link():
     assert len(line) <= 1500
     parsed = json.loads(line)
     assert parsed["value"] == 1000
-    # link.glz survives trimming: the emit contract says it rides
-    # unconditionally
-    assert parsed["link"]["glz"] == "on"
+    # the link calibration survives trimming: `link` drops last
+    assert parsed["link"]["h2d_mb_s"] == 49.0
 
 
 def test_compact_line_fits_with_codecs_and_device_blocks():
@@ -412,12 +402,12 @@ def test_errored_config_keeps_link_evidence_on_the_line():
 
     b = _bench()
     b._BACKEND_MODE = "tpu"
-    b._LINK.update(rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0, glz="on")
+    b._LINK.update(rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0)
     results = {
         "2_filter_map": dict(GOOD),
         "6_wide300": {
             "error": "RuntimeError: device stalled mid-pass",
-            "link": {"up_mb": 12.4, "glz": "on"},
+            "link": {"up_mb": 12.4},
         },
     }
     try:
@@ -450,34 +440,20 @@ def test_compact_line_hard_trim_always_parseable():
     assert parsed["detail"] == "BENCH_DETAIL.json"
 
 
-def test_effective_link_compress_resolution(monkeypatch):
-    b = _bench()
-    monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
-    assert b._effective_link_compress() == "on"
-    monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "off")
-    assert b._effective_link_compress() == "off"
-    # unset -> "auto", which the executor resolves to off on every
-    # backend (only "on" compresses the up-link: ISSUE 27)
-    monkeypatch.delenv("FLUVIO_LINK_COMPRESS")
-    assert b._effective_link_compress() == "off"
-
-
-def test_staging_ab_and_glz_fields_survive_the_emit():
-    # round-5 additions: the headline's staging A/B record and per-config
-    # glz ratio must ride through _build_output untouched (the judge
-    # reads them to attribute the chosen staging to the run's weather)
+def test_link_floor_fields_survive_the_emit():
+    # the per-config link floor (what the batch's transfers alone cost
+    # on the calibrated link) must ride through _build_output untouched
+    # (the judge reads it to tell a link-bound pass from a chip-bound one)
     b = _bench()
     b._BACKEND_MODE = "tpu"
     cfg = dict(GOOD)
-    cfg["staging_ab"] = {
-        "glz_ms": [100, 101], "raw_ms": [140, 139], "chosen": "glz",
-    }
-    cfg["glz_ratio"] = 0.476
+    cfg["link_floor_ms"] = 777
+    cfg["link_saturation"] = 0.45
     out, rc = b._build_output({"2_filter_map": cfg})
     assert rc == 0
     got = out["configs"]["2_filter_map"]
-    assert got["staging_ab"]["chosen"] == "glz"
-    assert got["glz_ratio"] == 0.476
+    assert got["link_floor_ms"] == 777
+    assert got["link_saturation"] == 0.45
 
 
 
@@ -543,14 +519,14 @@ def test_adm_line_key_aggregates_shed_and_warm():
 
 def test_adm_key_fits_contract_and_trims_before_link():
     """The full seven-config line with the adm key stays ≤1500 chars,
-    and the blowup trim drops ``adm`` before ``link`` (link.glz is the
-    contract field)."""
+    and the blowup trim drops ``adm`` before ``link`` (the link calibration drops
+    last)."""
     import json
 
     b = _bench()
     b._BACKEND_MODE = "tpu"
     b._LINK.update(
-        rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0, glz="on", glz_pinned=False
+        rtt_ms=65.0, h2d_mb_s=49.0, d2h_mb_s=37.0
     )
     results = _full_results()
     for cfg in results.values():
@@ -581,7 +557,7 @@ def test_down_key_rides_compact_line_and_trims_before_link():
     """ISSUE-12: the headline's result-side evidence rides the line as
     the tiny ``down:{mb,variant}`` key, stays inside the 1500-char
     contract for a full run, and the blowup trim drops ``down`` BEFORE
-    ``link`` (link.glz is the unconditional contract field)."""
+    ``link`` (the link calibration drops last)."""
     import json
     import re
 
@@ -669,7 +645,7 @@ def test_preflight_counts_disagreement_and_unjudged():
 def test_preflight_survives_emit_and_line_trim_order():
     """The per-config preflight record rides BENCH_DETAIL.json through
     _build_output untouched, and the compact key drops BEFORE link in
-    the blowup trim ladder (link.glz is the contract field)."""
+    the blowup trim ladder (the link calibration drops last)."""
     import json
 
     b = _bench()
@@ -899,7 +875,7 @@ def test_soak_key_fits_contract_and_trims_before_lag():
 def test_dfa_key_fits_contract_and_trims_before_link():
     """The full-matrix line with the dfa key stays ≤1500 chars and the
     blowup trim ladder drops ``dfa`` BEFORE ``lag``/``part``/``link``
-    (link.glz is the unconditional contract field)."""
+    (the link calibration drops last)."""
     import json
     import re
 
@@ -1096,14 +1072,14 @@ def test_mem_key_fits_contract_and_trims_after_win_before_soak():
     for name, cfg in results.items():
         cfg["memory"] = {
             "peak_mb": 0.262,
-            "owners": {"staged_batch": 131072, "glz_tokens": 4096},
+            "owners": {"staged_batch": 131072, "carry_bank": 4096},
         }
     out, _ = b._build_output(results)
     line = json.dumps(b._compact_line(out))
     assert len(line) <= 1500, f"compact line is {len(line)} chars"
     parsed = json.loads(line)
     assert parsed["mem"] == {
-        "peak_mb": 0.262, "owners": ["glz_tokens", "staged_batch"],
+        "peak_mb": 0.262, "owners": ["carry_bank", "staged_batch"],
     }
     src = open(_BENCH_PATH).read()
     ladder = re.search(r"for drop in \(([^)]*)\)", src, re.S).group(1)

@@ -73,11 +73,10 @@ def _no_compile_cache():
 def as_tpu(monkeypatch):
     """Steer the repo's `auto` policies onto their TPU branch: Pallas
     kernels for real (no interpreter), the glz result encoder on the way
-    down, donation, the associative DFA, the fast JSON kernel. The
-    up-link's `auto` ships raw on every backend (PR 27)."""
+    down, donation, the associative DFA, the fast JSON kernel."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for flag in (
-        "FLUVIO_TPU_PALLAS", "FLUVIO_LINK_COMPRESS", "FLUVIO_RESULT_COMPRESS",
+        "FLUVIO_TPU_PALLAS", "FLUVIO_RESULT_COMPRESS",
         "FLUVIO_DONATE", "FLUVIO_DFA_ASSOC", "FLUVIO_TPU_FAST_JSON",
     ):
         monkeypatch.delenv(flag, raising=False)
@@ -166,7 +165,7 @@ def _chain(specs):
     return b.initialize().tpu_chain
 
 
-def _program_args(ex, probe, rows, flat_bytes, sharding, glz: bool):
+def _program_args(ex, probe, rows, flat_bytes, sharding):
     """The jit's argument list as `_dispatch` stages it, as shapes: the
     static axes come from a tiny probe buffer of the same record shape,
     the array extents from the real batch (rows, ragged flat bytes)."""
@@ -182,36 +181,20 @@ def _program_args(ex, probe, rows, flat_bytes, sharding, glz: bool):
     carries = tuple(
         (i64(), i64(), _sds((), jnp.bool_, sharding)) for _ in ex.carries
     )
-    flat = glz_seqs = glz_lits = glz_depth = None
-    if glz:
-        # token buckets of a ~0.5-ratio corpus (jaxpr_lint's guess)
-        seq = TpuChainExecutor._bucket_bytes(bucket // 24, floor=256)
-        lit = TpuChainExecutor._bucket_bytes(bucket // 3, floor=256)
-        glz_seqs = (
-            _sds((seq,), jnp.uint8, sharding),
-            _sds((seq,), jnp.uint8, sharding),
-            _sds((seq,), jnp.int32, sharding),
-        )
-        glz_lits = _sds((lit,), jnp.uint8, sharding)
-        glz_depth = _sds((), jnp.int32, sharding)
-    else:
-        flat = _sds((bucket // 4,), jnp.int32, sharding)
     args = (
-        flat,
+        _sds((bucket // 4,), jnp.int32, sharding),
         _sds((rows,), lengths_up.dtype, sharding),
         None, None, None,
         None,
         _sds((), jnp.int32, sharding),
         i64(),
         carries,
-        glz_seqs, glz_lits, glz_depth,
     )
     kwargs = dict(
         kwidth=probe.keys.shape[1],
         has_keys=False,
         has_offsets=False,
         ts_mode=ts_mode,
-        glz_bytes=bucket if glz else 0,
     )
     return args, kwargs
 
@@ -223,8 +206,8 @@ def _json_probe():
     return chip_smoke.pack(values)
 
 
-def _compile_ragged(ex, probe, sharding, *, glz):
-    args, kwargs = _program_args(ex, probe, ROWS, JSON_FLAT, sharding, glz)
+def _compile_ragged(ex, probe, sharding):
+    args, kwargs = _program_args(ex, probe, ROWS, JSON_FLAT, sharding)
     enc, pack = ex._down_axes(False)
     return _compile(
         ex._jit_ragged.__wrapped__, *args,
@@ -235,17 +218,13 @@ def _compile_ragged(ex, probe, sharding, *, glz):
 NORTH_STAR = [("regex-filter", {"regex": "fluvio"}), ("json-map", {"field": "name"})]
 
 
-@pytest.mark.parametrize("glz", [False, True], ids=["raw-link", "glz-link"])
-def test_ragged_north_star(one_chip, as_tpu, monkeypatch, glz):
+def test_ragged_north_star(one_chip, as_tpu):
     """2_filter_map at 1M records: Pallas DFA + Pallas JSON span inside
     the fused chain, the XLA result encoder on the way down, and the
-    raw flat on the way up (what `auto` serves) or, with
-    `FLUVIO_LINK_COMPRESS=on`, the gather-round link decode."""
-    if glz:
-        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
+    raw flat on the way up."""
     ex = _chain(NORTH_STAR)
-    assert ex._link_compress == glz and ex._enc_variant == "xla"
-    hlo = _compile_ragged(ex, _json_probe(), one_chip, glz=glz)
+    assert ex._enc_variant == "xla"
+    hlo = _compile_ragged(ex, _json_probe(), one_chip)
     assert "tpu_custom_call" in hlo
 
 
@@ -253,12 +232,12 @@ def test_ragged_filter(one_chip, as_tpu):
     """1_filter: a literal pattern lowers to the XLA window compare (no
     Pallas kernel expected), mask-only downlink."""
     ex = _chain([("regex-filter", {"regex": "fluvio"})])
-    _compile_ragged(ex, _json_probe(), one_chip, glz=ex._link_compress)
+    _compile_ragged(ex, _json_probe(), one_chip)
 
 
 def test_ragged_aggregate(one_chip, as_tpu):
     ex = _chain([("aggregate-field", {"field": "n", "combine": "add"})])
-    hlo = _compile_ragged(ex, _json_probe(), one_chip, glz=ex._link_compress)
+    hlo = _compile_ragged(ex, _json_probe(), one_chip)
     assert "tpu_custom_call" in hlo  # the Pallas JSON span feeds the sum
 
 
@@ -270,9 +249,7 @@ def _compile_striped(ex, n_records, sharding):
     rec = int(probe.lengths[0])
     flat_bytes = n_records * ((rec + 3) // 4 * 4)
     rows = 1024  # pack() pads 976 records to the next pow2
-    args, kwargs = _program_args(
-        ex, probe, rows, flat_bytes, sharding, glz=ex._link_compress
-    )
+    args, kwargs = _program_args(ex, probe, rows, flat_bytes, sharding)
     shape = type(
         "B", (), {"rows": rows, "count": n_records, "width": probe.width,
                   "lengths": np.full(rows, rec, np.int32)},

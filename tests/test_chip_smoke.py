@@ -5,7 +5,7 @@ The script proves the chip path, so un-steered it must FAIL here: the
 rehearsals steer it IN THE TEST (module constants by monkeypatch, the
 `auto` policies by env) — the program has no option that lets it pass
 without a TPU. The env steer mirrors what `auto` resolves to on a TPU:
-Pallas kernels (interpreted here), glz link compression both ways, the
+Pallas kernels (interpreted here), the glz result encoder, the
 associative DFA, the fast JSON kernel.
 """
 
@@ -22,7 +22,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TPU_LIKE_ENV = {
     "FLUVIO_TPU_PALLAS": "interpret",
-    "FLUVIO_LINK_COMPRESS": "on",
     "FLUVIO_RESULT_COMPRESS": "on",
     "FLUVIO_DFA_ASSOC": "1",
     "FLUVIO_TPU_FAST_JSON": "1",

@@ -275,6 +275,23 @@ def test_warn_unknown_env_warns_once_per_flag():
     assert len(caught) == 1 and "FLUVIO_TPYO" in str(caught[0].message)
 
 
+def test_deleted_link_compress_flag_warns_like_a_typo():
+    """The up-link has one form since PR 33: an operator who still sets
+    the deleted flag is told so at boot, like any other unread name."""
+    env = {"FLUVIO_LINK_COMPRESS": "on", "FLUVIO_RESULT_COMPRESS": "on"}
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        names = warn_unknown_env(env)
+    assert names == ["FLUVIO_LINK_COMPRESS"]
+    assert len(caught) == 1 and "FLUVIO_LINK_COMPRESS" in str(caught[0].message)
+
+
+def test_registry_has_77_flags_and_none_is_the_link_chooser():
+    assert len(REGISTRY) == 77
+    assert not any("LINK_COMPRESS" in f.name for f in REGISTRY)
+    assert "encoder" in BY_NAME["FLUVIO_GLZ_CHUNK"].note
+
+
 def test_server_start_invokes_the_hook():
     import inspect
 

@@ -4,8 +4,8 @@ Four surfaces:
 
 - differential fuzz of the device compressor (both rungs) against the
   host decoders across corpora x chunk sizes, plus wire-format legality
-  (the encoder must emit streams `compress_link` consumers accept:
-  chunk-local non-overlapping matches, u8 run lengths, bounded depth),
+  (the encoder must emit streams the host decoders accept: chunk-local
+  non-overlapping matches, u8 run lengths, bounded depth),
 - the encode demotion ladder from BOTH seams (sync dispatch, async
   fetch) including sharded, carry-lineage-exact through heal epochs,
 - donation safety (fresh staging per dispatch: heal/retry re-dispatches
@@ -56,6 +56,22 @@ def _corpora():
         "vocab": _pad8(
             np.tile(np.array([1, 0, 7, 0, 6, 0, 250, 199], np.uint8), 3000)
         ),
+        # the byte patterns only the deleted up-link compressor was
+        # tested on (PR 33): the encoder and both host decoders take them
+        "zeros": np.zeros(64 * 1024, np.uint8),
+        "run": _pad8(b"ab" * 40000),
+        "period28": _pad8(b'{"name":"fluvio-1","n":123}\n' * 3000),
+        "mixed": _pad8(np.concatenate([
+            np.frombuffer(b'{"name":"kafka-3","n":77}' * 1000, np.uint8),
+            rng.integers(0, 256, 8192).astype(np.uint8),
+            np.frombuffer(b'{"name":"fluvio-9","n":5}' * 1000, np.uint8),
+        ])),
+        # wide-record shape: few records of ~30 KB (long runs + a
+        # repeated header)
+        "wide": _pad8(b"".join(
+            (b'{"name":"fluvio-%d","body":"' % (i & 7)) + b"x" * 30000 + b'"}'
+            for i in range(8)
+        )),
     }
 
 
@@ -69,6 +85,7 @@ def _encode(raw, chunk):
 
 _CORPUS_NAMES = (
     "json", "periodic5", "const", "zeros_tail", "random", "tiny", "vocab",
+    "zeros", "run", "period28", "mixed", "wide",
 )
 
 
@@ -90,6 +107,33 @@ def test_encode_roundtrip_differential(name, chunk):
     )
     got2 = glz.decompress_numpy(comp)
     assert np.array_equal(got2, raw), (chunk, name, "numpy")
+
+
+def _stream(lit_lens, match_lens, srcs, lits, out_len):
+    return glz.Compressed(
+        lit_lens=np.array(lit_lens, np.uint8),
+        match_lens=np.array(match_lens, np.uint8),
+        srcs=np.array(srcs, np.int32),
+        lits=np.arange(lits, dtype=np.uint8), depth=1, out_len=out_len,
+    )
+
+
+@pytest.mark.skipif(not glz.available(), reason="native glz library unavailable")
+@pytest.mark.parametrize("rc,comp", [
+    (1, _stream([12, 8], [0, 0], [0, 0], 20, out_len=16)),
+    (2, _stream([12, 8], [0, 0], [0, 0], 16, out_len=20)),
+    (3, _stream([12], [8], [8], 12, out_len=20)),
+    (4, _stream([12], [4], [0], 12, out_len=20)),
+    (5, _stream([12, 0, 0], [0, 0, 8], [-1, 99, 4], 12, out_len=20)),
+], ids=["output-overrun", "literal-overrun", "overlapping-source",
+        "length-mismatch", "zero-total-sequence"])
+def test_native_decoder_fails_closed(rc, comp):
+    """What the device sent is validated, not trusted: every return
+    code of `glz_decompress` surfaces as a ValueError (interior (0,0)
+    sequences are invalid glz: the gather decode's labeling cannot
+    represent them)."""
+    with pytest.raises(ValueError, match=rf"corrupt glz stream \(rc={rc}\)"):
+        glz.decompress_host(comp)
 
 
 def test_encode_wire_legality():
@@ -278,19 +322,37 @@ def test_dispatch_seam_runtime_fault_latches_off(enc_on, monkeypatch):
     assert TELEMETRY.heals > heals0
 
 
-def test_dispatch_seam_lowering_error_propagates(enc_on, monkeypatch):
+@pytest.mark.parametrize("mesh", [0, 4], ids=["single", "sharded"])
+@pytest.mark.parametrize(
+    "exc",
+    [
+        NotImplementedError("Unimplemented primitive in lowering"),
+        TypeError("lowering rejected the operand type"),
+    ],
+    ids=["notimplemented", "typeerror"],
+)
+def test_dispatch_seam_lowering_error_propagates(enc_on, monkeypatch, mesh, exc):
     """A LOWERING error of the encoder is a program fault (ISSUE 22):
     it raises through `process()` under backend="tpu" — no heal, no
-    quieter rung, no interpreter re-run."""
+    rung demoted, no interpreter re-run — single-device and sharded."""
+    if mesh and len(jax.devices()) < mesh:
+        pytest.skip(f"needs {mesh} devices")
 
     def refuse(*a, **k):
-        raise NotImplementedError("Unimplemented primitive in lowering")
+        raise exc
 
     monkeypatch.setattr(glz, "enc_match_xla", refuse)
+    tc = _chain("tpu", *SPAN_MODS, mesh=mesh)
+    assert (tc.tpu_chain._sharded is not None) == bool(mesh)
     heals0 = TELEMETRY.heals
-    with pytest.raises(NotImplementedError):
-        _run_both(SPAN_MODS, _span_corpus(1000))
+    spills0 = dict(TELEMETRY.snapshot()["counters"]["spills"])
+    with pytest.raises(type(exc)):
+        tc.process(
+            SmartModuleInput.from_records(_records(_span_corpus(1000)), 0, 100)
+        )
+    assert tc.tpu_chain._enc_variant == "xla", "a program fault demotes nothing"
     assert TELEMETRY.heals == heals0
+    assert TELEMETRY.snapshot()["counters"]["spills"] == spills0
 
 
 def test_dispatch_seam_injected_fault_demotes(enc_on, monkeypatch):
@@ -389,24 +451,24 @@ def test_sharded_encode_and_fetch_demotion(enc_on, monkeypatch):
 
 def test_donation_safety_with_heal_redispatch(monkeypatch):
     """FLUVIO_DONATE=on: every dispatch stages fresh device arrays, so
-    the glz heal's re-dispatch after a fetch-time failure cannot read a
-    donated buffer (no use-after-donate), and outputs stay exact."""
+    the encode heal's re-dispatch after a fetch-time failure cannot read
+    a donated buffer (no use-after-donate), and outputs stay exact."""
     monkeypatch.setenv("FLUVIO_DONATE", "on")
-    monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
+    monkeypatch.setenv("FLUVIO_RESULT_COMPRESS", "on")
     real_fetch = TpuChainExecutor._fetch
     state = {"bombed": False}
 
     def fetch_bomb(self, buf, header, packed, spec=None, defer=False):
-        if spec and spec.get("glz_used") and not state["bombed"]:
+        if spec and spec.get("enc_used") and not state["bombed"]:
             state["bombed"] = True
             raise RuntimeError("simulated device runtime failure")
         return real_fetch(self, buf, header, packed, spec, defer)
 
     monkeypatch.setattr(TpuChainExecutor, "_fetch", fetch_bomb)
-    tc, tv = _run_both(
-        [("regex-filter", {"regex": "fluvio"})], _span_corpus(6000)
-    )
+    heals0 = TELEMETRY.heals
+    tc, tv = _run_both(SPAN_MODS, _span_corpus(6000))
     assert state["bombed"]
+    assert TELEMETRY.heals > heals0
     assert len(tv) == 6000
 
 

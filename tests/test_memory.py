@@ -204,10 +204,10 @@ class TestLedger:
         clk = {"t": 100.0}
         led = MemoryLedger(clock=lambda: clk["t"])
         led.acquire("staged_batch", ("b", 1), 1000)
-        led.acquire("glz_tokens", ("g", 1), 200)
+        led.acquire("emit_buffer", ("g", 1), 200)
         assert led.total_bytes() == 1200
         by = led.owner_bytes()
-        assert by["staged_batch"] == 1000 and by["glz_tokens"] == 200
+        assert by["staged_batch"] == 1000 and by["emit_buffer"] == 200
         led.release(("b", 1))
         led.release(("g", 1))
         assert led.total_bytes() == 0
@@ -251,8 +251,7 @@ class TestLedger:
     def test_gauge_aliases_republish_from_the_ledger(self):
         led = MemoryLedger(clock=lambda: 0.0)
         led.acquire("staged_batch", "b", 1000)
-        led.acquire("glz_tokens", "g", 200)
-        led.acquire("shard_staging", "s", 300)
+        led.acquire("shard_staging", "s", 500)
         led.acquire("window_bank", "w", 480)
         gauges = TELEMETRY.snapshot()["gauges"]
         assert gauges["device_memory_bytes"] == 1980

@@ -164,6 +164,32 @@ def test_missing_executor_module_raises_under_tpu(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# one form for the up-link (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+
+def test_no_symbol_of_the_deleted_link_compression_is_left():
+    """The glz up-link went whole in PR 33 (compressor, device inflate,
+    compress-ahead workers, sharded and preflight mirrors): a mirror
+    deleted by half fails here, in tier-1, and not on the chip."""
+    import pathlib
+    import re
+
+    import fluvio_tpu
+
+    gone = re.compile(r"\b(compress_link|decompress_device|_precompress\w*)\b")
+    root = pathlib.Path(fluvio_tpu.__file__).parent
+    left = [
+        f"{path.relative_to(root)}:{n}: {line.strip()}"
+        for path in sorted(root.rglob("*"))
+        if path.suffix in (".py", ".cpp")
+        for n, line in enumerate(path.read_text().splitlines(), 1)
+        if gone.search(line)
+    ]
+    assert not left, left
+
+
+# ---------------------------------------------------------------------------
 # one process per chip
 # ---------------------------------------------------------------------------
 

@@ -373,13 +373,6 @@ class TestShardedLinkDiet:
     flat upload, device re-pad, derived-column synthesis) — review round 3
     weak #3: the old dense upload was a rows x width blowup."""
 
-    @pytest.fixture(autouse=True)
-    def _raw_staging(self, monkeypatch):
-        # this class compares the RAGGED STAGING byte diet; a forced
-        # FLUVIO_LINK_COMPRESS=on would compress only the single-device
-        # side (the sharded staging ships raw) and skew the comparison
-        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "off")
-
     def _bytes_for(self, specs, values, timestamps=None):
         from fluvio_tpu.protocol.record import Record
         from fluvio_tpu.smartmodule import SmartModuleInput

@@ -38,8 +38,8 @@ from fluvio_tpu.smartmodule.types import SmartModuleInput
 from fluvio_tpu.telemetry import TELEMETRY, render_prometheus
 
 # the transient fault points the generic chaos smoke can arm on the
-# headline chain (glz_decode/spill_rerun/socket_accept have their own
-# dedicated tests — they need compression / a forced spill / a socket)
+# headline chain (glz_encode/spill_rerun/socket_accept have their own
+# dedicated tests — they need the result encoder / a forced spill / a socket)
 GENERIC_POINTS = ("stage", "h2d", "dispatch", "device", "fetch")
 
 
@@ -271,10 +271,12 @@ class TestCarrySafety:
         assert TELEMETRY.snapshot()["counters"]["retries"].get("device", 0) >= 1
 
     def test_carry_exact_across_heal_retry_interleaving(self, monkeypatch):
-        # glz heal (link compression latches off, batch re-ships raw)
-        # AND a transient fetch fault on the same stream: the carry
-        # chain must come out exact (repetitive corpus so glz engages)
-        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
+        # encode heal (the result encoder latches off, batch
+        # re-dispatches) AND a transient fetch fault on the same stream:
+        # the carry chain must come out exact. The aggregate is NOT last,
+        # so the chain ships byte-mode payloads the encoder arms on.
+        monkeypatch.setenv("FLUVIO_RESULT_COMPRESS", "on")
+        mods = self.AGG + (("regex-filter", {"regex": "[0-9]"}),)
         slabs = [
             SmartModuleInput.from_records(
                 [
@@ -284,19 +286,19 @@ class TestCarrySafety:
             )
             for k in range(3)
         ]
-        py = _build("python", self.AGG)
+        py = _build("python", mods)
         ref = _run(py, slabs)
-        chain = _build("tpu", self.AGG)
-        assert chain.tpu_chain._link_compress
-        faults.FAULTS.inject("glz_decode", first=1)
+        chain = _build("tpu", mods)
+        assert chain.tpu_chain._enc_variant == "xla"
+        faults.FAULTS.inject("glz_encode", first=1)
         faults.FAULTS.inject("fetch", first=1)
         got = _run(chain, slabs)
         faults.FAULTS.clear()
         assert got == ref
         assert str(self._acc(chain)).encode() == py.instances[0].accumulator
         counters = TELEMETRY.snapshot()["counters"]
-        assert counters["heals"] >= 1, "glz_decode fault should have healed"
-        assert not chain.tpu_chain._link_compress, "heal latches glz off"
+        assert counters["heals"] >= 1, "glz_encode fault should have healed"
+        assert chain.tpu_chain._enc_variant == "off", "heal latches encode off"
         assert counters["retries"].get("fetch", 0) >= 1
 
     def test_sharded_retry_zero_divergence(self):

@@ -253,8 +253,7 @@ def _compiled_text(ex, attr: str, buf) -> str:
 
 @pytest.fixture
 def both_links(monkeypatch):
-    """Link compression up and result encode down, as on the chip."""
-    monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
+    """Raw flat up and result encode down, as on the chip."""
     monkeypatch.setenv("FLUVIO_RESULT_COMPRESS", "on")
     monkeypatch.setenv("FLUVIO_RESULT_COMPACT", "on")
 
@@ -264,8 +263,9 @@ def test_north_star_program_carries_every_scope(both_links):
                 ("json-map", {"field": "name"}))
     hlo = _compiled_text(ex, "_jit_ragged", _buf(_json(4096)))
     found = _scopes_in(hlo)
-    assert found >= {"link_decode", "repad", "stage0.filter", "stage1.map",
+    assert found >= {"repad", "stage0.filter", "stage1.map",
                      "compact", "pack", "link_encode"}, found
+    assert "link_decode" not in found
     # the program's NAME carries the vocabulary's version: the compile
     # cache keys on it, never on a scope (debug info is stripped there)
     assert f"_chain_fn_ragged_{DEVICE_SCOPES_TAG}" in hlo.splitlines()[0]
@@ -275,7 +275,7 @@ def test_fanout_program_carries_every_scope(both_links):
     ex = _chain(("array-map-json", None))
     values = [f'["a{i & 255}","b{i}",{i},"x","y"]'.encode() for i in range(4096)]
     found = _scopes_in(_compiled_text(ex, "_jit_ragged", _buf(values)))
-    assert found >= {"link_decode", "repad", "stage0.array_map", "compact",
+    assert found >= {"repad", "stage0.array_map", "compact",
                      "pack", "link_encode"}, found
 
 
@@ -294,18 +294,12 @@ def test_byte_mode_program_packs_its_payload(both_links):
 @pytest.mark.parametrize("config_name", [
     "fluvio-northstar-1p", "fluvio-array-explode-1p",
 ], ids=["pipelined-loop", "serial-loop"])
-@pytest.mark.parametrize("flag", [None, "on"], ids=["unset", "on"])
-def test_served_slice_inflates_only_when_pinned_on(
-        tmp_path, monkeypatch, config_name, flag):
-    """ISSUE-27: the program a served slice runs takes its flat raw
-    unless `FLUVIO_LINK_COMPRESS=on`: no `link_decode` scope in it, and
-    every dispatched chunk books the `raw` link variant."""
+def test_served_slice_ships_its_flat_raw(tmp_path, monkeypatch, config_name):
+    """The program a served slice runs takes its flat raw: the staged
+    i32 words are its first operand, no `link_decode` scope is in it,
+    and the up-link books no link variant (it has one form)."""
     from fluvio_tpu.smartengine.tpu.executor import TpuChainExecutor
 
-    if flag is None:
-        monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)
-    else:
-        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", flag)
     calls = []
     init = TpuChainExecutor.__init__
 
@@ -325,22 +319,20 @@ def test_served_slice_inflates_only_when_pinned_on(
     assert len(responses) == 2
     assert counts["fastpath_slices"] >= 2 and counts["fallback_slices"] == 0
     lv = TELEMETRY.link_variant_counts()
-    # the family also counts the down-link's forms; the chain's
-    # 2-record warm-up ships raw under either setting (`glz-below-min`),
-    # and a fan-out slice that outgrows its capacity dispatches again
-    up = {k: lv.get(k, 0) - lv0.get(k, 0) for k in ("raw", "glz-gather")}
-    if flag == "on":
-        assert up["glz-gather"] >= 2, lv
-    else:
-        assert up["glz-gather"] == 0 and up["raw"] >= 2, lv
+    # the family counts the down-link's forms only
+    grown = {k for k in lv if lv[k] > lv0.get(k, 0)}
+    assert grown and all(k.startswith(("down-", "agg-")) for k in grown), lv
 
     served = [c for c in calls if c[1][1].shape[0] >= PER_BATCH]
     assert served
     jit, args, kwargs = served[-1]
-    assert bool(kwargs["glz_bytes"]) == (flag == "on")
+    assert args[0].dtype == np.int32 and args[0].ndim == 1
+    assert len(args) == 9 and set(kwargs) == {
+        "width", "kwidth", "has_keys", "has_offsets", "ts_mode",
+        "fanout_cap", "enc", "pack",
+    }
     found = _scopes_in(jit.__wrapped__.lower(*args, **kwargs).compile().as_text())
-    assert "repad" in found
-    assert ("link_decode" in found) == (flag == "on"), found
+    assert "repad" in found and "link_decode" not in found, found
 
 
 def test_striped_program_carries_its_scopes(monkeypatch):

@@ -588,44 +588,6 @@ class TestHealthSurfaces:
         assert rc == 1
         assert "--watch" in capsys.readouterr().err
 
-    @pytest.mark.skipif(
-        len(__import__("jax").devices()) < 8,
-        reason="needs 8 virtual devices",
-    )
-    def test_sharded_inline_compress_records_span_and_counter(
-        self, monkeypatch
-    ):
-        """ROADMAP satellite: the sharded inline-compress path (not
-        covered by the compress-ahead worker) books a ``glz_compress``
-        phase on the batch span and counts shard segments, so the
-        "extend the worker to pre-fill _glz_shard_cache" decision can
-        be made from the span profile."""
-        monkeypatch.setenv("FLUVIO_LINK_COMPRESS", "on")
-        chain = build_chain([("regex-filter", {"regex": "fluvio"})])
-        ex = chain.tpu_chain
-        assert ex._link_compress
-        ex.enable_sharded(8)
-        # highly compressible values so every shard's stream engages
-        buf = make_buf(
-            [b'{"name":"fluvio-' + b"ab" * 90 + b'"}' for _ in range(256)]
-        )
-        out = ex.process_buffer(buf)
-        assert out.count == 256
-        snap = TELEMETRY.snapshot()
-        # one inline compress, n=8 shard segments
-        assert snap["counters"]["sharded_inline_compress_shards"] == 8
-        span = TELEMETRY.spans.recent()[-1]
-        d = span.to_dict()
-        assert d["chain"] == "filter"
-        assert d["phases_ms"].get("glz_compress", 0) > 0
-        # stage excludes the compress time (the two phases separate)
-        assert d["phases_ms"].get("stage", 0) > 0
-        # a re-dispatch of the SAME buffer reuses the per-buffer cache:
-        # the counter must not move again
-        ex.process_buffer(buf)
-        snap = TELEMETRY.snapshot()
-        assert snap["counters"]["sharded_inline_compress_shards"] == 8
-
     def test_chain_identity_rides_spans_and_snapshot(self):
         """End-to-end: a real fused chain labels its spans with the
         executor signature and the snapshot grows the per-chain family

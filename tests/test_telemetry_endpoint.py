@@ -74,7 +74,6 @@ DECLARED_SERIES = [
     "fluvio_tpu_batch_latency_seconds",
     "fluvio_tpu_phase_seconds",
     "fluvio_tpu_chain_e2e_latency_seconds",
-    "fluvio_tpu_sharded_inline_compress_shards_total",
     "fluvio_tpu_slo_verdict",
     "fluvio_tpu_batch_records_total",
     "fluvio_tpu_glz_heals_total",

@@ -243,34 +243,6 @@ def test_lockwatch_seam_zero_cost_when_disabled(monkeypatch):
         assert type(TELEMETRY._lock) is type(threading.Lock())
 
 
-def test_glz_chooser_zero_cost_when_disabled(monkeypatch):
-    """ISSUE-8 CI satellite: with link compression off (the default
-    on every backend), the staging-variant chooser must be ZERO work
-    per dispatch — the variant resolves once at executor build, and the
-    raw staging path never touches the glz module, the compressor, or
-    the pallas gate. Tripwires on every glz entry point prove it over
-    a full pipelined pass."""
-    from fluvio_tpu.smartengine.tpu import glz
-
-    monkeypatch.delenv("FLUVIO_LINK_COMPRESS", raising=False)
-
-    def tripwire(*a, **k):
-        raise AssertionError("glz seam touched with link compression off")
-
-    chain = _headline_chain()
-    executor = chain.tpu_chain
-    assert not executor._link_compress
-    buf = _corpus_buf()
-    for out in executor.process_stream(iter([buf] * 2)):
-        pass
-    for mod, name in (
-        (glz, "compress"), (glz, "compress_link"),
-        (glz, "decompress_device"), (glz, "byte_plan_device"),
-    ):
-        monkeypatch.setattr(mod, name, tripwire)
-    _one_pass(executor, buf)  # any glz touch raises
-
-
 def test_result_encode_zero_cost_when_disabled(monkeypatch):
     """ISSUE-12 CI satellite: with the result-ENCODE ladder off (the
     CPU auto default), the down-link seams must be ZERO work per

@@ -367,6 +367,33 @@ class TestDispatchPrefetch:
             )
             assert vals == [r.value for r in out.successes]
 
+    def test_discarded_dispatch_is_settled_before_exit(self, monkeypatch):
+        """A discarded dispatch's prefetch copies have no fetch to wait
+        for them: the discard registers ONE exit hook (idempotent), and
+        the hook leaves nothing of the dispatch in flight (on the chip a
+        process that exited under them died with SIGSEGV, PR 33)."""
+        import atexit
+
+        calls = []
+        monkeypatch.setattr(atexit, "register", lambda f: calls.append(("reg", f)))
+        monkeypatch.setattr(atexit, "unregister", lambda f: calls.append(("un", f)))
+        tpu = self._chain("tpu").tpu_chain
+        (b40,) = self._bufs([40])
+        hook = tpu._settle_device_at_exit
+        for _ in range(2):
+            h = tpu.dispatch_buffer(b40)
+            tpu.discard_dispatch(h)
+        assert calls == [("un", hook), ("reg", hook)] * 2
+        hook()
+        arrays = [
+            a for a in jax.tree_util.tree_leaves((h[1], h[2]))
+            if isinstance(a, jax.Array)
+        ]
+        assert arrays and all(a.is_ready() for a in arrays)
+        # the stream goes on: the discard left the executor usable
+        out = tpu.finish_buffer(b40, tpu.dispatch_buffer(b40))
+        assert out.count == 40
+
     def test_spec_arms_hits_and_charges_misses(self):
         tpu = self._chain("tpu").tpu_chain
         b40, b200 = self._bufs([40, 200])

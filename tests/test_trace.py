@@ -41,7 +41,7 @@ from fluvio_tpu.telemetry import (
     render_prometheus,
     render_trace,
 )
-from fluvio_tpu.telemetry.spans import BatchSpan, InstantEvent, SpanRing
+from fluvio_tpu.telemetry.spans import PHASES, BatchSpan, InstantEvent, SpanRing
 from fluvio_tpu.telemetry import memory as memory_mod
 from fluvio_tpu.telemetry import trace as trace_mod
 
@@ -99,10 +99,11 @@ class TestTraceDocument:
     def test_round_trip_parity_and_overlap_tracks(self):
         # two overlapping fused batches (the pipelined shape) + one after
         a = _span(100.0, 0.010)
-        a.phase_s[0] = 0.002  # stage
-        a.phase_t0[0] = 100.0
-        a.phase_s[4] = 0.006  # device
-        a.phase_t0[4] = 100.003
+        stage, device = PHASES.index("stage"), PHASES.index("device")
+        a.phase_s[stage] = 0.002
+        a.phase_t0[stage] = 100.0
+        a.phase_s[device] = 0.006
+        a.phase_t0[device] = 100.003
         b = _span(100.005, 0.010)
         c = _span(100.020, 0.005, path="striped")
         for s in (a, b, c):
