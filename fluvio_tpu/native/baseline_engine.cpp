@@ -957,53 +957,201 @@ struct EncodedRecords {
     int64_t len;
 };
 
-EncodedRecords* encode_record_columns(
-    const uint8_t* val_flat, const int64_t* val_off,
-    const uint8_t* key_flat, const int64_t* key_off,
-    const uint8_t* key_present,
-    const int64_t* off_delta, const int64_t* ts_delta, int64_t n) {
-    int64_t total = 0;
-    std::vector<int64_t> inner_sizes((size_t)n);
-    for (int64_t i = 0; i < n; i++) {
-        int64_t vlen = val_off[i + 1] - val_off[i];
-        int64_t inner = 1;  // attributes
-        inner += varint_encoded_size(ts_delta ? ts_delta[i] : 0);
-        inner += varint_encoded_size(off_delta ? off_delta[i] : i);
-        inner += 1;  // key tag
-        if (key_present && key_present[i]) {
-            int64_t klen = key_off[i + 1] - key_off[i];
-            inner += varint_encoded_size(klen) + klen;
+}  // extern "C"
+
+namespace {
+
+// Readers of a record's value bytes (`len`, then `write` of those `n`
+// bytes; `ok` bounds-checks what the caller handed over) and of its key
+// (`len` < 0 = null key).
+// ONE record writer below serves them all, so a wire-format fix cannot
+// land in one form and miss another.
+
+// exact-packed columns: RecordBuffer.to_columns (the general form)
+struct ColumnValues {
+    const uint8_t* flat;
+    const int64_t* off;
+    bool ok(int64_t) const { return true; }
+    int64_t len(int64_t i) const { return off[i + 1] - off[i]; }
+    void write(uint8_t*& p, int64_t i, int64_t n) const {
+        std::memcpy(p, flat + off[i], (size_t)n);
+        p += n;
+    }
+};
+
+// the 4-aligned flat a fetch already holds (flat-backed RecordBuffer)
+struct FlatValues {
+    const uint8_t* flat;
+    int64_t flat_len;
+    const int32_t* starts;
+    const int32_t* lengths;
+    bool ok(int64_t i) const {
+        return starts[i] >= 0 && lengths[i] >= 0
+            && (int64_t)starts[i] + lengths[i] <= flat_len;
+    }
+    int64_t len(int64_t i) const { return lengths[i]; }
+    void write(uint8_t*& p, int64_t i, int64_t n) const {
+        std::memcpy(p, flat + starts[i], (size_t)n);
+        p += n;
+    }
+};
+
+// an int64 column rendered as decimals into the record being written
+// (int-backed RecordBuffer; byte-equal to kernels.int_to_ascii)
+struct IntValues {
+    const int64_t* ints;
+    static uint64_t magnitude(int64_t v) {
+        return v < 0 ? ~(uint64_t)v + 1 : (uint64_t)v;  // exact at INT64_MIN
+    }
+    bool ok(int64_t) const { return true; }
+    int64_t len(int64_t i) const {
+        int64_t n = ints[i] < 0 ? 2 : 1;
+        for (uint64_t m = magnitude(ints[i]);; m /= 10000, n += 4) {
+            if (m < 10) return n;
+            if (m < 100) return n + 1;
+            if (m < 1000) return n + 2;
+            if (m < 10000) return n + 3;
         }
+    }
+    void write(uint8_t*& p, int64_t i, int64_t n) const {
+        static const char pairs[] =
+            "00010203040506070809101112131415161718192021222324"
+            "25262728293031323334353637383940414243444546474849"
+            "50515253545556575859606162636465666768697071727374"
+            "75767778798081828384858687888990919293949596979899";
+        uint64_t m = magnitude(ints[i]);
+        uint8_t* q = p + n;
+        for (; m >= 100; m /= 100) {  // two digits a division
+            q -= 2;
+            std::memcpy(q, pairs + 2 * (m % 100), 2);
+        }
+        if (m >= 10) {
+            q -= 2;
+            std::memcpy(q, pairs + 2 * m, 2);
+        } else {
+            *--q = (uint8_t)('0' + m);
+        }
+        if (ints[i] < 0) *p = '-';
+        p += n;
+    }
+};
+
+struct ColumnKeys {
+    const uint8_t* flat;
+    const int64_t* off;
+    const uint8_t* present;
+    bool ok(int64_t) const { return true; }
+    int64_t len(int64_t i) const { return present[i] ? off[i + 1] - off[i] : -1; }
+    const uint8_t* at(int64_t i) const { return flat + off[i]; }
+};
+
+// a (rows, width) key matrix with per-row lengths, -1 = null
+struct MatrixKeys {
+    const uint8_t* keys;
+    int64_t width;
+    const int32_t* lengths;
+    bool ok(int64_t i) const { return lengths[i] <= width; }
+    int64_t len(int64_t i) const { return lengths[i]; }
+    const uint8_t* at(int64_t i) const { return keys + i * width; }
+};
+
+// Append rows [first, end) to the slab as wire records (parity:
+// protocol.record.Record.encode). With `max_bytes` > 0 the rows stop
+// after the first one at which the SLAB (what earlier calls appended
+// included) reaches `max_bytes`: a row is kept while the bytes before
+// it are under the budget, so the first row of an empty slab always
+// is. Returns the rows kept, -1 out of memory, -2 a row out of bounds.
+template <class Values, class Keys, class Off>
+int64_t append_records(EncodedRecords* e, const Values& vals, const Keys& keys,
+                       const Off* off_delta, const int64_t* ts_delta,
+                       int64_t first, int64_t end, int64_t max_bytes) {
+    std::vector<int64_t> inner_sizes;
+    inner_sizes.reserve((size_t)(end > first ? end - first : 0));
+    int64_t total = 0;
+    for (int64_t i = first; i < end; i++) {
+        if (max_bytes > 0 && e->len + total >= max_bytes) break;
+        if (!vals.ok(i) || !keys.ok(i)) return -2;
+        int64_t vlen = vals.len(i), klen = keys.len(i);
+        int64_t inner = 1;  // attributes
+        inner += varint_encoded_size(ts_delta[i]);
+        inner += varint_encoded_size((int64_t)off_delta[i]);
+        inner += 1;  // key tag
+        if (klen >= 0) inner += varint_encoded_size(klen) + klen;
         inner += varint_encoded_size(vlen) + vlen;
         inner += varint_encoded_size(0);  // header count
-        inner_sizes[(size_t)i] = inner;
+        inner_sizes.push_back(inner);
         total += varint_encoded_size(inner) + inner;
     }
-    auto* e = new EncodedRecords();
-    e->data = (uint8_t*)std::malloc(total ? total : 1);
-    e->len = total;
-    uint8_t* p = e->data;
-    for (int64_t i = 0; i < n; i++) {
-        int64_t vlen = val_off[i + 1] - val_off[i];
-        write_varint(p, inner_sizes[(size_t)i]);
+    int64_t kept = (int64_t)inner_sizes.size();
+    if (!total) return kept;
+    uint8_t* grown = (uint8_t*)std::realloc(e->data, (size_t)(e->len + total));
+    if (!grown) return -1;
+    e->data = grown;
+    uint8_t* p = grown + e->len;
+    for (int64_t i = first; i < first + kept; i++) {
+        write_varint(p, inner_sizes[(size_t)(i - first)]);
         *p++ = 0;  // attributes
-        write_varint(p, ts_delta ? ts_delta[i] : 0);
-        write_varint(p, off_delta ? off_delta[i] : i);
-        if (key_present && key_present[i]) {
-            int64_t klen = key_off[i + 1] - key_off[i];
+        write_varint(p, ts_delta[i]);
+        write_varint(p, (int64_t)off_delta[i]);
+        int64_t klen = keys.len(i);
+        if (klen >= 0) {
             *p++ = 1;
             write_varint(p, klen);
-            std::memcpy(p, key_flat + key_off[i], (size_t)klen);
+            std::memcpy(p, keys.at(i), (size_t)klen);
             p += klen;
         } else {
             *p++ = 0;
         }
+        int64_t vlen = vals.len(i);
         write_varint(p, vlen);
-        std::memcpy(p, val_flat + val_off[i], (size_t)vlen);
-        p += vlen;
+        vals.write(p, i, vlen);
         write_varint(p, 0);  // no record headers
     }
+    e->len += total;
+    return kept;
+}
+
+}  // namespace
+
+extern "C" {
+
+EncodedRecords* encoded_records_new() {
+    auto* e = new EncodedRecords();
+    e->data = nullptr;
+    e->len = 0;
     return e;
+}
+
+int64_t encode_append_columns(
+    EncodedRecords* e, const uint8_t* val_flat, const int64_t* val_off,
+    const uint8_t* key_flat, const int64_t* key_off,
+    const uint8_t* key_present,
+    const int64_t* off_delta, const int64_t* ts_delta,
+    int64_t first, int64_t end, int64_t max_bytes) {
+    return append_records(e, ColumnValues{val_flat, val_off},
+                          ColumnKeys{key_flat, key_off, key_present},
+                          off_delta, ts_delta, first, end, max_bytes);
+}
+
+int64_t encode_append_flat(
+    EncodedRecords* e, const uint8_t* flat, int64_t flat_len,
+    const int32_t* starts, const int32_t* lengths,
+    const uint8_t* keys, int64_t key_width, const int32_t* key_lengths,
+    const int32_t* off_delta, const int64_t* ts_delta,
+    int64_t first, int64_t end, int64_t max_bytes) {
+    return append_records(e, FlatValues{flat, flat_len, starts, lengths},
+                          MatrixKeys{keys, key_width, key_lengths},
+                          off_delta, ts_delta, first, end, max_bytes);
+}
+
+int64_t encode_append_ints(
+    EncodedRecords* e, const int64_t* ints,
+    const uint8_t* keys, int64_t key_width, const int32_t* key_lengths,
+    const int32_t* off_delta, const int64_t* ts_delta,
+    int64_t first, int64_t end, int64_t max_bytes) {
+    return append_records(e, IntValues{ints},
+                          MatrixKeys{keys, key_width, key_lengths},
+                          off_delta, ts_delta, first, end, max_bytes);
 }
 
 void encoded_records_free(EncodedRecords* e) {

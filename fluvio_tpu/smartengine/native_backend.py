@@ -171,16 +171,26 @@ def load_library():
             ctypes.c_int64,
         ]
         lib.record_columns_v2_free.argtypes = [ctypes.POINTER(RecordColumnsV2)]
-        lib.encode_record_columns.restype = ctypes.POINTER(EncodedRecords)
-        lib.encode_record_columns.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64,
+        lib.encoded_records_new.restype = ctypes.POINTER(EncodedRecords)
+        lib.encoded_records_new.argtypes = []
+        # numpy arrays go in as they are: ctypes checks dtype and layout
+        slab = ctypes.POINTER(EncodedRecords)
+        p8, p32, p64 = (
+            np.ctypeslib.ndpointer(dtype=t, flags="C_CONTIGUOUS")
+            for t in (np.uint8, np.int32, np.int64)
+        )
+        i64 = ctypes.c_int64
+        lib.encode_append_columns.restype = i64
+        lib.encode_append_columns.argtypes = [
+            slab, p8, p64, p8, p64, p8, p64, p64, i64, i64, i64,
+        ]
+        lib.encode_append_flat.restype = i64
+        lib.encode_append_flat.argtypes = [
+            slab, p8, i64, p32, p32, p8, i64, p32, p32, p64, i64, i64, i64,
+        ]
+        lib.encode_append_ints.restype = i64
+        lib.encode_append_ints.argtypes = [
+            slab, p64, p8, i64, p32, p32, p64, i64, i64, i64,
         ]
         lib.encoded_records_free.argtypes = [ctypes.POINTER(EncodedRecords)]
         _lib = lib
@@ -265,6 +275,130 @@ def decode_record_columns_aligned(raw: bytes):
         lib.record_columns_v2_free(c2)
 
 
+class RecordSlab:
+    """One response's wire-format record slab, appended to chunk after
+    chunk by the native record writer (`baseline_engine.cpp:
+    append_records`): one sizing-and-writing pass per call, reading the
+    caller's arrays as they are.
+
+    ``max_bytes`` > 0 cuts the slab at the longest record prefix that
+    fits, the slab's own length carrying the budget across calls: a row
+    is kept while the bytes before it are under ``max_bytes`` (numpy's
+    ``searchsorted(cumsum(sizes), max_bytes, side="left") + 1``, so at
+    least one record). Every ``append_*`` returns the rows it kept of
+    [first, end); fewer than asked for is the cut.
+    """
+
+    def __init__(self, lib, max_bytes: int = 0):
+        self._lib = lib
+        self._slab = lib.encoded_records_new()
+        self._max_bytes = int(max_bytes)
+
+    @staticmethod
+    def _arg(a, dtype):
+        """The array as the native entry takes it (no copy when it
+        already is); an empty one is padded so its pointer is real."""
+        a = np.ascontiguousarray(a, dtype=dtype)
+        return a if len(a) else np.zeros(1, dtype)
+
+    @staticmethod
+    def _check_rows(first: int, end: int, rows: int) -> None:
+        if not 0 <= first <= end <= rows:
+            raise ValueError(f"rows [{first}, {end}) outside 0..{rows}")
+
+    @staticmethod
+    def _kept(kept: int) -> int:
+        if kept == -1:
+            raise MemoryError("record slab: out of memory")
+        if kept < 0:
+            raise ValueError("record slab: a row lies outside its column")
+        return int(kept)
+
+    def _key_matrix(self, keys, key_lengths, end: int):
+        keys = np.ascontiguousarray(keys, dtype=np.uint8)
+        if keys.ndim != 2 or len(keys) < end or len(key_lengths) < end:
+            raise ValueError("key matrix shorter than the rows to encode")
+        return (self._arg(keys.reshape(-1), np.uint8), keys.shape[1],
+                self._arg(key_lengths, np.int32))
+
+    def append_columns(
+        self, val_flat, val_off, key_flat, key_off, key_present,
+        off_delta, ts_delta, first: int = 0,
+    ) -> int:
+        """Exact-packed columns (`RecordBuffer.to_columns`): the general
+        form."""
+        end = len(val_off) - 1
+        rows = min(end, len(key_off) - 1, len(key_present),
+                   len(off_delta), len(ts_delta))
+        self._check_rows(first, end, rows)
+        arg = self._arg
+        return self._kept(self._lib.encode_append_columns(
+            self._slab, arg(val_flat, np.uint8), arg(val_off, np.int64),
+            arg(key_flat, np.uint8), arg(key_off, np.int64),
+            arg(key_present, np.uint8),
+            arg(off_delta, np.int64), arg(ts_delta, np.int64),
+            first, end, self._max_bytes,
+        ))
+
+    def append_flat(
+        self, flat, starts, lengths, keys, key_lengths, off_delta, ts_delta,
+        first: int, end: int,
+    ) -> int:
+        """A flat-backed buffer as its fetch left it: the 4-aligned
+        value flat with per-row starts and lengths, the key matrix with
+        per-row lengths (-1 = null), int32 offset deltas."""
+        rows = min(len(starts), len(lengths), len(off_delta), len(ts_delta))
+        self._check_rows(first, end, rows)
+        arg = self._arg
+        return self._kept(self._lib.encode_append_flat(
+            self._slab, arg(flat, np.uint8), len(flat),
+            arg(starts, np.int32), arg(lengths, np.int32),
+            *self._key_matrix(keys, key_lengths, end),
+            arg(off_delta, np.int32), arg(ts_delta, np.int64),
+            first, end, self._max_bytes,
+        ))
+
+    def append_ints(
+        self, ints, keys, key_lengths, off_delta, ts_delta,
+        first: int, end: int,
+    ) -> int:
+        """An int-backed buffer: each int64 rendered as its decimal
+        (byte-equal to `kernels.int_to_ascii`) straight into its
+        record."""
+        rows = min(len(ints), len(off_delta), len(ts_delta))
+        self._check_rows(first, end, rows)
+        arg = self._arg
+        return self._kept(self._lib.encode_append_ints(
+            self._slab, arg(ints, np.int64),
+            *self._key_matrix(keys, key_lengths, end),
+            arg(off_delta, np.int32), arg(ts_delta, np.int64),
+            first, end, self._max_bytes,
+        ))
+
+    def take(self) -> bytes:
+        """The slab's bytes; the slab is freed and may not be used
+        again."""
+        slab, self._slab = self._slab, None
+        try:
+            n = int(slab.contents.len)
+            return ctypes.string_at(slab.contents.data, n) if n else b""
+        finally:
+            self._lib.encoded_records_free(slab)
+
+    def __del__(self):
+        slab = getattr(self, "_slab", None)  # unset if the constructor failed
+        if slab is not None:
+            self._slab = None
+            self._lib.encoded_records_free(slab)
+
+
+def record_slab(max_bytes: int = 0) -> "RecordSlab | None":
+    """An empty `RecordSlab`; ``None`` when the native library is
+    unavailable."""
+    lib = load_library()
+    return None if lib is None else RecordSlab(lib, max_bytes)
+
+
 def encode_record_columns(
     val_flat: np.ndarray,
     val_off: np.ndarray,
@@ -278,36 +412,13 @@ def encode_record_columns(
 
     Returns ``None`` when the native library is unavailable.
     """
-    lib = load_library()
-    if lib is None:
+    slab = record_slab()
+    if slab is None:
         return None
-    n = len(val_off) - 1
-
-    def p8(a):
-        a = np.ascontiguousarray(a, dtype=np.uint8)
-        return a.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), a
-
-    def p64(a):
-        a = np.ascontiguousarray(a, dtype=np.int64)
-        return a.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), a
-
-    # keep the arrays alive across the call
-    vf, _vf = p8(val_flat if len(val_flat) else np.zeros(1, np.uint8))
-    vo, _vo = p64(val_off)
-    kf, _kf = p8(key_flat if len(key_flat) else np.zeros(1, np.uint8))
-    ko, _ko = p64(key_off)
-    kp, _kp = p8(key_present)
-    od, _od = p64(off_delta)
-    td, _td = p64(ts_delta)
-    e = lib.encode_record_columns(vf, vo, kf, ko, kp, od, td, n)
-    try:
-        ee = e.contents
-        ln = int(ee.len)
-        if ln == 0:
-            return b""
-        return bytes(np.ctypeslib.as_array(ee.data, shape=(ln,)))
-    finally:
-        lib.encoded_records_free(e)
+    slab.append_columns(
+        val_flat, val_off, key_flat, key_off, key_present, off_delta, ts_delta
+    )
+    return slab.take()
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ XLA compiles one program per bucket, not per batch.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
@@ -159,10 +159,21 @@ class RecordBuffer:
     _starts: Optional[np.ndarray] = None
     _width: int = 0
     _rows: int = 0
+    # An INT-BACKED buffer (`values is None`, `_flat is None`) holds the
+    # live rows' values as ONE int64 column: an int-output fetch keeps
+    # what crossed the link and renders nothing. The served encode
+    # writes the decimals straight into the wire records
+    # (`encode_into`); every other consumer gets the padded matrix AND
+    # `lengths` (None until then) from `dense_values()`, rendered
+    # through ``_render(ints, rows, count) -> (values, lengths)``.
+    _ints: Optional[np.ndarray] = None
+    _render: Optional[Callable] = None
 
     @property
     def width(self) -> int:
-        """Bucketed value-matrix width (valid in both backing modes)."""
+        """Bucketed value-matrix width (valid in every backing mode)."""
+        if self.values is None and self._flat is None:
+            return self.dense_values().shape[1]
         return self.values.shape[1] if self.values is not None else self._width
 
     @property
@@ -171,8 +182,12 @@ class RecordBuffer:
 
     def dense_values(self) -> np.ndarray:
         """The padded matrix; materialized on demand for flat-backed
-        buffers (slow-path consumers only — the TPU hot path never calls
-        this)."""
+        and int-backed buffers (slow-path consumers only — the TPU hot
+        path never calls this)."""
+        if self.values is None and self._flat is None:
+            self.values, self.lengths = self._render(
+                self._ints, self._rows, self.count
+            )
         if self.values is None:
             rows, width = self._rows, self._width
             values = np.zeros((rows, width), dtype=np.uint8)
@@ -205,12 +220,15 @@ class RecordBuffer:
         emits the 4-aligned flat directly).
         """
         if self._flat is None:
-            width = self.values.shape[1]
+            values = self.values
+            if values is None:  # int-backed: render first
+                values = self.dense_values()
+            width = values.shape[1]
             check_flat_addressing(self.lengths)
             lengths4 = (self.lengths.astype(np.int64) + 3) & ~3
             # rows' padding bytes are already zero in `values`
             mask = np.arange(width, dtype=np.int64)[None, :] < lengths4[:, None]
-            self._flat = np.ascontiguousarray(self.values[mask])
+            self._flat = np.ascontiguousarray(values[mask])
             starts = np.zeros(len(self.lengths), dtype=np.int64)
             starts[1:] = np.cumsum(lengths4[:-1])
             # check_flat_addressing above: every start fits i32
@@ -456,6 +474,8 @@ class RecordBuffer:
         form, so a fused slice goes packed-payload -> wire bytes without
         ever densifying."""
         n = self.count
+        if self._flat is None:
+            self.dense_values()  # an int-backed buffer renders here
         lengths = self.lengths[:n].astype(np.int64)
         val_off = np.zeros(n + 1, dtype=np.int64)
         np.cumsum(lengths, out=val_off[1:])
@@ -506,11 +526,35 @@ class RecordBuffer:
             flat, self._starts[:n].astype(np.int64), lengths
         )
 
+    def encode_into(self, slab, first: int = 0) -> Tuple[int, str]:
+        """Append live rows [first, count) to a response slab
+        (`native_backend.RecordSlab`) in ONE native pass that reads the
+        form this buffer already holds: the int64 column, the 4-aligned
+        flat, or (a dense matrix) the exact-packed columns of
+        `to_columns`. Returns (rows kept — fewer than asked for is the
+        slab's ``max_bytes`` cut —, the encode form taken)."""
+        n = self.count
+        meta = (self.keys, self.key_lengths, self.offset_deltas,
+                self.timestamp_deltas, first, n)
+        if self.values is not None:
+            c = self.to_columns()
+            return slab.append_columns(
+                c["val_flat"], c["val_off"], c["key_flat"], c["key_off"],
+                c["key_present"], c["off_delta"], c["ts_delta"], first,
+            ), "enc-columns"
+        if self._flat is not None:
+            return slab.append_flat(
+                self._flat, self._starts, self.lengths, *meta
+            ), "enc-direct-bytes"
+        return slab.append_ints(self._ints, *meta), "enc-direct-int"
+
     # -- materialization ----------------------------------------------------
 
     def to_records(self) -> List[Record]:
         out: List[Record] = []
         keys = self.keys
+        if self._flat is None:
+            self.dense_values()  # an int-backed buffer renders here
         if self.values is None:
             # flat-backed: slice each record straight out of the flat
             flat, starts = self._flat, self._starts

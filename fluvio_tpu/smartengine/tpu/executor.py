@@ -2437,11 +2437,10 @@ class TpuChainExecutor:
         lens = (mat != 0).sum(axis=1).astype(np.int32)  # digits have no NULs
         return mat, lens
 
-    def _int_output_columns(self, buf, ints, wins, src, rows: int, count: int):
-        """Shared host assembly for int-output chains (single-device AND
-        sharded): render decimals, window keys (``wins``; None when
-        unwindowed), or pass input keys through — one implementation so
-        both engine modes stay bit-identical by construction."""
+    def _int_value_columns(self, ints, rows: int, count: int):
+        """An int column's decimals as the padded value matrix + lengths:
+        what an int-backed `RecordBuffer` renders on demand, and the
+        value half of `_int_output_columns`."""
         mat, lens = self._ints_to_ascii_host(ints)
         vw = min(self._pad_slice(max(int(lens.max()) if count else 1, 1)), 32)
         out_values = np.zeros((rows, vw), dtype=np.uint8)
@@ -2450,6 +2449,15 @@ class TpuChainExecutor:
             w = min(vw, mat.shape[1])
             out_values[:count, :w] = mat[:, :w]
             out_lengths[:count] = lens
+        return out_values, out_lengths
+
+    def _int_output_columns(self, buf, ints, wins, src, rows: int, count: int):
+        """Shared host assembly for int-output chains (the sharded
+        executor's, and the single-device one's windowed outputs): render
+        decimals, window keys (``wins``; None when unwindowed), or pass
+        input keys through — one implementation so both engine modes
+        stay bit-identical by construction."""
+        out_values, out_lengths = self._int_value_columns(ints, rows, count)
         if wins is not None:
             kmat, klens = self._ints_to_ascii_host(wins)
             kw = min(self._pad_slice(max(int(klens.max()) if count else 1, 1)), 32)
@@ -2474,8 +2482,12 @@ class TpuChainExecutor:
         self, buf: RecordBuffer, count: int, packed, probe, span=None,
         defer: bool = False,
     ):
-        """Int-output D2H: survivor mask + accumulator column(s); the host
-        renders decimals (and window keys) itself.
+        """Int-output D2H: survivor mask + accumulator column(s). The
+        output buffer is INT-BACKED: it carries the reconstructed int64
+        column, and the decimals are rendered where they are wanted (by
+        the native encoder straight into the served records, or by
+        `dense_values()` through `_int_value_columns`). Window-key
+        outputs are rendered here (no served path takes them yet).
 
         Running-aggregate outputs are the one mode whose D2H would be a
         full 8 B/row int64 column, and consecutive accumulator values
@@ -2483,9 +2495,9 @@ class TpuChainExecutor:
         int16/int32 deltas plus a scalar base whenever the batch's max
         |delta| fits (decided per batch by a tiny scalar sync), and the
         host reconstructs with one cumsum. Window ids are non-decreasing
-        and delta-compress the same way. ``defer``: the delta decode, the
-        int -> ASCII render and the assembly are numpy over the
-        downloaded arrays and are returned as a thunk."""
+        and delta-compress the same way. ``defer``: the delta decode and
+        the assembly are numpy over the downloaded arrays and are
+        returned as a thunk."""
         windowed = bool(self.stages[-1].window_ms)
         n_c = packed["agg_int"].shape[0]
         rows = min(self._bucket_bytes(max(count, 1), 8), n_c)
@@ -2517,13 +2529,17 @@ class TpuChainExecutor:
                 if a_is_delta
                 else np.asarray(host[1][:count]).astype(np.int64)
             )
-            wins = None
-            if windowed:
-                wins = (
-                    self._delta_decode(host[2], scal[3], count)
-                    if w_is_delta
-                    else np.asarray(host[2][:count]).astype(np.int64)
+            if not windowed:
+                out_keys, out_klens = self._view_keys(
+                    buf, count, rows, src[:count]
                 )
+                return self._assemble(buf, count, rows, None, None,
+                                      out_keys, out_klens, src, ints=ints)
+            wins = (
+                self._delta_decode(host[2], scal[3], count)
+                if w_is_delta
+                else np.asarray(host[2][:count]).astype(np.int64)
+            )
             out_values, out_lengths, out_keys, out_klens = self._int_output_columns(
                 buf, ints, wins, src, rows, count
             )
@@ -2534,7 +2550,7 @@ class TpuChainExecutor:
 
     def _assemble(self, buf, count, rows, out_values, out_lengths, out_keys,
                   out_klens, src, flat=None, starts=None,
-                  vw: int = 0) -> RecordBuffer:
+                  vw: int = 0, ints=None) -> RecordBuffer:
         """Rebuild offset/timestamp columns from survivor source rows.
 
         Row-preserving chains pass the source deltas through; fan-out
@@ -2543,7 +2559,9 @@ class TpuChainExecutor:
         at the engine surface, matching the interpreter's fresh
         Records). With ``flat``/``starts`` set (result compaction) the
         output buffer is FLAT-BACKED: ``out_values`` is None and the
-        padded matrix is never built."""
+        padded matrix is never built. With ``ints`` set (an int-output
+        fetch) it is INT-BACKED: ``out_values`` and ``out_lengths`` are
+        None until a consumer asks for the rendered form."""
         src_c = np.clip(
             src[:count] if len(src) >= count else np.zeros(count, np.int64),
             0,
@@ -2572,7 +2590,9 @@ class TpuChainExecutor:
             _flat=flat,
             _starts=starts,
             _width=vw if flat is not None else 0,
-            _rows=rows if flat is not None else 0,
+            _rows=rows if out_values is None else 0,
+            _ints=ints,
+            _render=self._int_value_columns if ints is not None else None,
         )
 
     def _fanout_cap(self, buf: RecordBuffer) -> Optional[int]:

@@ -51,38 +51,6 @@ def _slice_columns(cols: dict, lo: int, hi: int) -> dict:
     }
 
 
-def _varint_sizes(x: np.ndarray) -> np.ndarray:
-    """Exact zigzag-varint encoded sizes, vectorized."""
-    xi = x.astype(np.int64)
-    u = ((xi << 1) ^ (xi >> 63)).view(np.uint64)
-    nb = np.ones(len(u), dtype=np.int64)
-    for k in range(1, 10):
-        nb += (u >= np.uint64(1 << (7 * k))).astype(np.int64)
-    return nb
-
-
-def _encoded_record_sizes_at(
-    outbuf, drop: int, deltas: np.ndarray, ts: np.ndarray
-) -> np.ndarray:
-    """Per-record wire sizes (parity: protocol.record.Record.write_size)
-    for output rows [drop, drop+len(deltas))."""
-    n = len(deltas)
-    vlens = outbuf.lengths[drop : drop + n].astype(np.int64)
-    klens_raw = outbuf.key_lengths[drop : drop + n].astype(np.int64)
-    has_key = klens_raw >= 0
-    klens = np.maximum(klens_raw, 0)
-    inner = (
-        1  # attributes
-        + _varint_sizes(ts)
-        + _varint_sizes(deltas)
-        + 1  # key tag
-        + np.where(has_key, _varint_sizes(klens) + klens, 0)
-        + _varint_sizes(vlens)
-        + vlens
-        + 1  # varint(0) header count
-    )
-    return _varint_sizes(inner) + inner
-
 from fluvio_tpu.protocol.error import ErrorCode
 from fluvio_tpu.resilience.policy import is_program_fault
 from fluvio_tpu.protocol.record import Batch, RecordSet
@@ -836,54 +804,6 @@ def tpu_stage_dispatch(
     return tpu_dispatch(chain, pending, metrics, topic, partition)
 
 
-class _MergedOut:
-    """Concatenated live-row view over per-chunk output buffers.
-
-    Exposes exactly the surface `tpu_finish` touches (count, the live
-    offset/timestamp/length columns, `to_columns`); chunk outputs stay
-    separate until the single native encode."""
-
-    def __init__(self, outbufs: List):
-        ns = [b.count for b in outbufs]
-        self.count = sum(ns)
-        self._outbufs = outbufs
-        self.offset_deltas = np.concatenate(
-            [b.offset_deltas[:n] for b, n in zip(outbufs, ns)]
-        )
-        self.timestamp_deltas = np.concatenate(
-            [b.timestamp_deltas[:n] for b, n in zip(outbufs, ns)]
-        )
-        self.lengths = np.concatenate(
-            [b.lengths[:n] for b, n in zip(outbufs, ns)]
-        )
-        self.key_lengths = np.concatenate(
-            [b.key_lengths[:n] for b, n in zip(outbufs, ns)]
-        )
-
-    def to_columns(self) -> dict:
-        parts = [b.to_columns() for b in self._outbufs]
-        val_off = np.zeros(self.count + 1, dtype=np.int64)
-        key_off = np.zeros(self.count + 1, dtype=np.int64)
-        pos = v = k = 0
-        for c in parts:
-            n = c["count"]
-            val_off[pos : pos + n + 1] = c["val_off"] + v
-            key_off[pos : pos + n + 1] = c["key_off"] + k
-            pos += n
-            v += int(c["val_off"][-1])
-            k += int(c["key_off"][-1])
-        return {
-            "count": self.count,
-            "val_flat": np.concatenate([c["val_flat"] for c in parts]),
-            "val_off": val_off,
-            "key_flat": np.concatenate([c["key_flat"] for c in parts]),
-            "key_off": key_off,
-            "key_present": np.concatenate([c["key_present"] for c in parts]),
-            "off_delta": self.offset_deltas.astype(np.int64),
-            "ts_delta": self.timestamp_deltas.astype(np.int64),
-        }
-
-
 def tpu_fetch(
     chain: SmartModuleChainInstance,
     pending: PendingSlice,
@@ -1013,6 +933,47 @@ def _decline_fetched(tpu, pending, ahead, metrics, reason: str):
     return _decline(metrics, reason)
 
 
+def _encode_outputs(outbufs: List, max_bytes: int, resume: Optional[int]):
+    """A fetched slice's output buffers, chunk after chunk, into ONE
+    wire-format slab: per chunk the resume drop (rows whose offset delta
+    is under ``resume``; survivor deltas are ascending and already
+    rebased to the slice's base offset, so a consumer resuming mid-slice
+    filters correctly) and one native pass that reads the form the
+    buffer holds (`RecordBuffer.encode_into`). The slab carries the
+    ``max_bytes`` cut (0 = none) from chunk to chunk.
+
+    Returns (slab bytes, rows kept, the last kept row's offset delta
+    when the cut fell — else None —, the encode forms taken), or None
+    when there is something to encode and no native encoder."""
+    from fluvio_tpu.smartengine import native_backend
+
+    slab = None
+    n_out, last_delta, cut_at = 0, 0, None
+    forms = set()
+    for outbuf in outbufs:
+        n = outbuf.count
+        deltas = outbuf.offset_deltas[:n]
+        first = (
+            0 if resume is None
+            else int(np.searchsorted(deltas, resume, side="left"))
+        )
+        if first >= n:
+            continue
+        if slab is None:
+            slab = native_backend.record_slab(max_bytes)
+            if slab is None:
+                return None
+        kept, form = outbuf.encode_into(slab, first)
+        forms.add(form)
+        if kept:
+            n_out += kept
+            last_delta = int(deltas[first + kept - 1])
+        if kept < n - first:
+            cut_at = last_delta
+            break
+    return (slab.take() if n_out else b""), n_out, cut_at, forms
+
+
 def tpu_materialize(
     chain: SmartModuleChainInstance,
     pending: PendingSlice,
@@ -1038,8 +999,6 @@ def tpu_materialize(
     output: the carries are back where this slice started, for the
     per-record rerun.
     """
-    from fluvio_tpu.smartengine import native_backend
-
     tpu = chain.tpu_chain
     base0, ts0 = pending.base0, pending.ts0
     result = BatchProcessResult()
@@ -1058,65 +1017,38 @@ def tpu_materialize(
             type(e).__name__, e,
         )
         return _decline_fetched(tpu, pending, ahead, metrics, "fused-error")
-    # `encode`: output merge, resume drop, max_bytes cut, to_columns, the
-    # native record encode and the response Batch
+    # `encode`: the chunks' one native pass each into the response slab,
+    # then the response Batch
     with timed(flow, "encode"):
-        outbuf = outbufs[0] if len(outbufs) == 1 else _MergedOut(outbufs)
-        n_out = outbuf.count
-        # survivors keep their stored offsets (deltas are already rebased to
-        # base0), so a consumer resuming mid-slice filters correctly
-        out_deltas = outbuf.offset_deltas[:n_out].astype(np.int64)
-        out_ts = outbuf.timestamp_deltas[:n_out].astype(np.int64)
-        drop = 0
         stateless = not tpu.agg_configs and not tpu._fanout
+        resume = None
         if (
             stateless
-            and n_out
             and pending.read_from is not None
             and pending.read_from > base0
         ):
             # resuming mid-batch: outputs below the consume cursor were
             # already served in a previous (truncated) response — drop them
-            # so the stream always advances (survivor deltas are ascending)
-            drop = int(
-                np.searchsorted(out_deltas, pending.read_from - base0, side="left")
+            # so the stream always advances
+            resume = pending.read_from - base0
+        # stateless chains honor max_bytes: keep the longest record prefix
+        # whose encoded size fits (>= semantics: always keep one batch's
+        # worth of progress by including at least the first record)
+        encoded = _encode_outputs(
+            outbufs, max_bytes if stateless else 0, resume
+        )
+        if encoded is None:
+            return _decline_fetched(
+                tpu, pending, ahead, metrics, "encode-failed"
             )
-            out_deltas = out_deltas[drop:]
-            out_ts = out_ts[drop:]
-            n_out -= drop
-        if n_out and stateless and max_bytes > 0:
-            # stateless chains honor max_bytes: keep the longest record prefix
-            # whose encoded size fits (>= semantics: always keep one batch's
-            # worth of progress by including at least the first record)
-            sizes = _encoded_record_sizes_at(outbuf, drop, out_deltas, out_ts)
-            cum = np.cumsum(sizes)
-            keep = int(np.searchsorted(cum, max_bytes, side="left")) + 1
-            if keep < n_out:
-                n_out = max(keep, 1)
-                result.next_offset = base0 + int(out_deltas[n_out - 1]) + 1
-                if ahead is not None:
-                    # the consume point moved: the slice dispatched ahead
-                    # read from the wrong offset
-                    ahead.discard(tpu)
+        raw_out, n_out, cut_at, forms = encoded
+        if cut_at is not None:
+            result.next_offset = base0 + cut_at + 1
+            if ahead is not None:
+                # the consume point moved: the slice dispatched ahead
+                # read from the wrong offset
+                ahead.discard(tpu)
         if n_out:
-            cols = outbuf.to_columns()
-            vo = cols["val_off"]
-            ko = cols["key_off"]
-            v0 = int(vo[drop])
-            k0 = int(ko[drop])
-            raw_out = native_backend.encode_record_columns(
-                cols["val_flat"][v0 : int(vo[drop + n_out])],
-                vo[drop : drop + n_out + 1] - v0,
-                cols["key_flat"][k0 : int(ko[drop + n_out])],
-                ko[drop : drop + n_out + 1] - k0,
-                cols["key_present"][drop : drop + n_out],
-                out_deltas[:n_out],
-                out_ts[:n_out],
-            )
-            if raw_out is None:
-                return _decline_fetched(
-                    tpu, pending, ahead, metrics, "encode-failed"
-                )
             out_batch = Batch(
                 base_offset=base0,
                 raw_records=raw_out,
@@ -1136,6 +1068,10 @@ def tpu_materialize(
         metrics.add_fuel_used(pending.count * max(len(tpu.stages), 1))
         metrics.add_records_out(n_out)
         metrics.add_fastpath()
+    # which form the slice's output took into the encoder: `enc-direct-*`
+    # (the one native pass) or `enc-columns` (the general form)
+    for form in sorted(forms):
+        TELEMETRY.add_link_variant(form)
     if pending.carries is not None:
         tpu._ensure_host_state(pending.carries)
     # a clean fused slice counts toward the chain breaker's health —

@@ -39,8 +39,9 @@ the root span of everything the serving task did for it —
 - ``materialize`` the chunks' split-back: the join of the thunks that
                   ran on the fetch worker since ``finish``, and the last
                   chunk's (a single-chunk slice's only one), run here,
-- ``encode``      output merge, resume drop, ``max_bytes`` cut,
-                  `to_columns`, the native record encode, `Batch` build,
+- ``encode``      per chunk the resume drop and one native pass from
+                  the buffer's own form to the response slab (the
+                  ``max_bytes`` cut inside it), then the `Batch` build,
 - ``send``        `sink.send_response`,
 - ``ack_wait``    until the consumer's ack reaches the pushed offset
                   (the consumer's own decode is inside it),

@@ -190,7 +190,9 @@ def render_prometheus(
     w.header(
         f"{_PREFIX}_link_variants_total",
         "Fetched batches by D2H link form "
-        "(down-glz-xla / down-packed / down-raw / agg-*).",
+        "(down-glz-xla / down-packed / down-raw / agg-*) and served "
+        "slices by encode form (enc-direct-bytes / enc-direct-int / "
+        "enc-columns).",
         "counter",
     )
     for variant, n in sorted(link_variants.items()):

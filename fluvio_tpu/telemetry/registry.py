@@ -107,7 +107,9 @@ class PipelineTelemetry:
         # in (`down-*`; `agg-*` for an accumulator column): the bench's
         # per-config link breakdown and the preflight's down-variant
         # prediction both read this family. The up-link has one form
-        # (raw), so it books nothing here.
+        # (raw), so it books nothing here. `enc-*`: per served slice,
+        # the form its output took into the wire encoder
+        # (`smart_chain.tpu_materialize`).
         self.link_variants: Dict[str, int] = {}
         self.batch_records: Dict[str, int] = {
             "fused": 0, "striped": 0, "interpreter": 0

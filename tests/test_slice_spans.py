@@ -319,9 +319,11 @@ def test_served_slice_ships_its_flat_raw(tmp_path, monkeypatch, config_name):
     assert len(responses) == 2
     assert counts["fastpath_slices"] >= 2 and counts["fallback_slices"] == 0
     lv = TELEMETRY.link_variant_counts()
-    # the family counts the down-link's forms only
+    # the family counts the down-link's forms and the encode's: no up-link
     grown = {k for k in lv if lv[k] > lv0.get(k, 0)}
-    assert grown and all(k.startswith(("down-", "agg-")) for k in grown), lv
+    assert grown and all(
+        k.startswith(("down-", "agg-", "enc-")) for k in grown
+    ), lv
 
     served = [c for c in calls if c[1][1].shape[0] >= PER_BATCH]
     assert served

@@ -287,16 +287,17 @@ def test_stateful_decline_after_fetch_restores_both_carries(
     flat, off = _corpus(cfg)
     values = to_values(flat, off)
     events = []
-    real_encode = native_backend.encode_record_columns
+    real_slab = native_backend.record_slab
     calls = {"n": 0, "armed": False}
 
-    def encode(*a, **k):
+    def slab(*a, **k):
+        # the entry a served slice's encode opens (`tpu_materialize`)
         if calls["armed"]:
             calls["n"] += 1
             if calls["n"] == 2:     # the second served slice, once
                 events.append("encode-refused")
                 return None
-        return real_encode(*a, **k)
+        return real_slab(*a, **k)
 
     discard = smart_chain.PendingSlice.discard
     rollback = smart_chain.PendingSlice.rollback
@@ -315,7 +316,7 @@ def test_stateful_decline_after_fetch_restores_both_carries(
         events.append("rerun")
         return rerun(*a, **k)
 
-    monkeypatch.setattr(native_backend, "encode_record_columns", encode)
+    monkeypatch.setattr(native_backend, "record_slab", slab)
     monkeypatch.setattr(smart_chain.PendingSlice, "discard", spy_discard)
     monkeypatch.setattr(smart_chain.PendingSlice, "rollback", spy_rollback)
     monkeypatch.setattr(smart_chain, "_process_batches_per_record", spy_rerun)
@@ -423,3 +424,97 @@ def test_another_connection_is_answered_while_a_slice_materializes(
     assert answered_while_held and info.leo == N
     assert where and all(t is not loop_thread for t in where)
     assert len(responses) == N // PER_BATCH
+
+
+# -- ISSUE-34: a served slice's way out is one native pass ---------------------
+
+
+def _per_record_slices(broker, cfg, max_bytes, n=N):
+    """What the per-record path gives for the same slices, on a stream of
+    its own: (next offset, [(base, last delta, count, record bytes)])."""
+    chain = smart_chain.acquire_stream_chain(
+        invocations(cfg["chain"]), broker.server.ctx
+    )
+    out, offset = [], 0
+    while offset < n:
+        rslice = broker.leader.read_records(
+            offset, max_bytes, Isolation.READ_UNCOMMITTED
+        )
+        result = smart_chain.process_batches_per_record(
+            chain, rslice.decode_batches(parse_records=False), max_bytes
+        )
+        out.append((result.next_offset, [
+            (b.base_offset, b.header.last_offset_delta, b.records_len(),
+             b._encode_record_section())
+            for b in result.records.batches
+        ]))
+        offset = result.next_offset
+    return out
+
+
+@pytest.mark.parametrize("config_name,compact,form", [
+    (STATELESS, "auto", "enc-direct-bytes"),
+    (FANOUT, "auto", "enc-direct-bytes"),
+    (STATEFUL, "auto", "enc-direct-int"),
+    (STATELESS, "off", "enc-columns"),
+], ids=["stateless", "fanout", "stateful", "stateless-dense"])
+def test_served_slice_goes_out_in_one_native_pass(
+        tmp_path, monkeypatch, config_name, compact, form):
+    from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
+
+    monkeypatch.setenv("FLUVIO_RESULT_COMPACT", compact)
+    cfg = _config(config_name)
+    flat, off = _corpus(cfg)
+    one_batch = int(off[PER_BATCH]) + 64
+    armed, hits = {"on": False}, []
+
+    def tripwire(owner, name):
+        real = getattr(owner, name)
+
+        def wired(*a, **k):
+            if armed["on"]:
+                hits.append(name)
+            return real(*a, **k)
+
+        plain = isinstance(owner.__dict__[name], staticmethod)
+        monkeypatch.setattr(owner, name, staticmethod(wired) if plain else wired)
+
+    tripwire(RecordBuffer, "to_columns")
+    tripwire(RecordBuffer, "dense_values")
+    tripwire(TpuChainExecutor, "_ints_to_ascii_host")
+
+    async def body(broker):
+        lv0 = TELEMETRY.link_variant_counts()
+        armed["on"] = True
+        try:
+            responses = await _drain(broker, one_batch)
+        finally:
+            armed["on"] = False
+        lv = TELEMETRY.link_variant_counts()
+        booked = {
+            k: lv[k] - lv0.get(k, 0) for k in lv
+            if k.startswith("enc-") and lv[k] > lv0.get(k, 0)
+        }
+        return (responses, booked, broker.slice_counts(),
+                _per_record_slices(broker, cfg, one_batch))
+
+    responses, booked, counts, want = _serve(tmp_path, cfg, flat, off, body)
+    assert counts["fallback_slices"] == 0
+    served = counts["fastpath_slices"]
+    assert served == len(responses) == N // PER_BATCH
+    # the consumer's bytes are the per-record path's, slice by slice
+    assert [
+        (r.next_offset, [
+            (b.base_offset, b.header.last_offset_delta, b.records_len(),
+             bytes(b.raw_records))
+            for b in r.batches
+        ])
+        for r in responses
+    ] == want
+    # every served slice booked ONE encode form: the direct pass for the
+    # forms the cells serve, the general form for a dense buffer
+    assert booked == {form: served}
+    if form == "enc-columns":
+        assert "to_columns" in hits
+    else:
+        assert hits == []
