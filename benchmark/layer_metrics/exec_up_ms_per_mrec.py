@@ -1,6 +1,6 @@
 """Host time of the executor's phases BEFORE the device has the batch
-(stage, glz_compress, h2d, dispatch; `TELEMETRY.phase_totals()` window
-delta, host clock), per million input records."""
+(stage, h2d, dispatch; `TELEMETRY.phase_totals()` window delta, host
+clock), per million input records."""
 
 from spubench.window import UP_PHASES
 

@@ -9,10 +9,9 @@ from __future__ import annotations
 
 import time
 
-# the executor's phase spans, split at the dispatch: host work before the
-# device has the batch, and host time blocked on / after the device
-UP_PHASES = ("stage", "glz_compress", "h2d", "dispatch")
-DOWN_PHASES = ("device", "fetch", "d2h")
+# the executor's phase spans up to the dispatch: host work before the
+# device has the batch
+UP_PHASES = ("stage", "h2d", "dispatch")
 
 
 def snapshot(broker) -> dict:

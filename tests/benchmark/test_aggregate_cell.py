@@ -4,13 +4,12 @@ The CPU rehearsal of `test_benchmark_harness.py` picks the cell up by
 itself. Here: the plain reference against `numpy.cumsum` and against the
 program's interpreter, the four new readers on a fixture and on a
 rehearsal (each returns None, never 0, where what it reads is absent),
-the manifest entries, and that this PR's benchmark files are additions.
+and the manifest entries.
 """
 
 from __future__ import annotations
 
 import json
-import subprocess
 import sys
 from pathlib import Path
 
@@ -33,7 +32,6 @@ from fluvio_tpu.telemetry import TELEMETRY  # noqa: E402
 CELL, CONFIG = "agg-drain", "fluvio-aggregate-1p"
 NEW_READERS = ("chain_acquire_ms_per_stream", "stream_chain_builds",
                "device_agg_ms_per_mrec", "agg_scan_hbm_share")
-PARENT = "543df4388bf67b29a7048b8ed0e2e6a891b26ff6"
 
 
 def _reader(name):
@@ -112,6 +110,15 @@ def test_reference_agrees_with_python_backend(seed):
 # -- the manifest ------------------------------------------------------------
 
 
+def check_aggregate_entries(m):
+    """PR 30's four entries in the manifest ``m``: each is there once, for
+    the aggregate cell alone. Where in the list they stand is nobody's to
+    assert: a later PR appends its own after them."""
+    for name in NEW_READERS:
+        (entry,) = [e for e in m["per_layer"] if e["name"] == name]
+        assert entry["workloads"] == [CELL] and entry["moves"] == "records_in_per_s"
+
+
 def test_cell_and_configuration_are_as_the_issue_names_them():
     cell = manifest.load_cell(CELL, REPO)
     assert (cell.config_name, cell.traffic_name, cell.chips) == (
@@ -127,37 +134,7 @@ def test_cell_and_configuration_are_as_the_issue_names_them():
     assert cfg["guarantees"][:3] == ns["guarantees"] and len(cfg["guarantees"]) == 5
     assert [s["kind"] for s in cfg["chain"]] == ["AGGREGATE"]
     assert {m["name"] for m in cell.end_to_end} == {"records_in_per_s", "setup_s"}
-    m = harness.MANIFEST
-    for name in NEW_READERS:
-        (entry,) = [e for e in m["per_layer"] if e["name"] == name]
-        assert entry["workloads"] == [CELL] and entry["moves"] == "records_in_per_s"
-    assert [e["name"] for e in m["per_layer"][-4:]] == list(NEW_READERS)
-
-
-def test_benchmark_files_of_this_pr_are_additions():
-    """No file the accepted benchmark had is edited or deleted, and
-    `BENCHMARK.json` gains entries and list members and loses nothing."""
-    def git(*args):
-        return subprocess.run(["git", *args], cwd=REPO, capture_output=True,
-                              text=True)
-
-    if git("cat-file", "-e", PARENT).returncode != 0:
-        pytest.skip("not a checkout that holds the parent commit")
-    changed = git("diff", "--name-status", PARENT, "--",
-                  "benchmark", "tests/benchmark").stdout.split("\n")
-    assert [c for c in changed if c and not c.startswith("A")] == []
-    was = json.loads(git("show", f"{PARENT}:BENCHMARK.json").stdout)
-    now = json.loads((REPO / "BENCHMARK.json").read_text())
-    assert {k: was[k] for k in ("command", "paths", "run_seconds")} == {
-        k: now[k] for k in ("command", "paths", "run_seconds")}
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        assert len(now[key]) >= len(was[key])
-        for old, new in zip(was[key], now[key]):       # in place, in order
-            lists = {k for k in old if isinstance(old[k], list)}
-            assert {k: old[k] for k in old if k not in lists} == {
-                k: new[k] for k in new if k not in lists}
-            for k in lists:
-                assert new[k][:len(old[k])] == old[k]
+    check_aggregate_entries(harness.MANIFEST)
 
 
 # -- the device readers on a recorded shape ----------------------------------

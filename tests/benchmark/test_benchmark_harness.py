@@ -123,10 +123,27 @@ def _check_metrics(m, root):
         assert any(cell in cells_of(x) for x in m["per_layer"])
 
 
+def _check_readers(m, root):
+    """Every metric entry names a reader file that loads and has `read`;
+    every file under `layer_metrics/` is named by an entry, or is a paced
+    cell's (`*.paced.py`: kept for `ns-paced`, PERF.md Open questions).
+    So no reader sits in the tree that no manifest entry reads."""
+    bench = root / m["paths"][0]
+    for kind, entries in (("end_to_end", m["end_to_end"]),
+                          ("layer_metrics", m["per_layer"])):
+        for x in entries:       # no such file: `ManifestError`
+            reader = manifest.load_plugin(bench, kind, x["name"])
+            assert callable(getattr(reader, "read", None)), x["name"]
+    named = {x["name"] for x in m["per_layer"]}
+    unread = [p.name for p in sorted((bench / "layer_metrics").glob("*.py"))
+              if p.stem not in named and not p.name.endswith(".paced.py")]
+    assert unread == []
+
+
 # every check takes (manifest, the root it lies in): the accepted manifest
 # here, a copy with a cell added in `test_added_cell_joins_every_list`
 MANIFEST_CHECKS = (_check_keys_and_limits, _check_configs, _check_workloads,
-                   _check_metrics)
+                   _check_metrics, _check_readers)
 
 
 def test_manifest_keys_and_limits():
@@ -143,6 +160,39 @@ def test_manifest_workloads():
 
 def test_manifest_metrics():
     _check_metrics(MANIFEST, REPO)
+
+
+def test_manifest_readers():
+    _check_readers(MANIFEST, REPO)
+
+
+def _unread_file(bench, m):
+    (bench / "layer_metrics" / "orphan_ms.py").write_text(
+        "def read(obs):\n    return 1.0\n")
+
+
+def _entry_without_file(bench, m):
+    template = next(iter(m["per_layer"]))       # any entry; only the name matters
+    m["per_layer"].append(dict(template, name="no_such_reader"))
+
+
+def _file_without_read(bench, m):
+    _entry_without_file(bench, m)
+    (bench / "layer_metrics" / "no_such_reader.py").write_text("VALUE = 1.0\n")
+
+
+@pytest.mark.parametrize("fault", [_unread_file, _entry_without_file,
+                                   _file_without_read])
+def test_check_readers_refuses(tmp_path, fault):
+    """What let PR 31's two readers sit unread for five PRs: a reader file
+    no entry names; and its mirror images."""
+    shutil.copytree(BENCH / "layer_metrics", tmp_path / "benchmark" / "layer_metrics")
+    shutil.copytree(BENCH / "end_to_end", tmp_path / "benchmark" / "end_to_end")
+    m = json.loads(json.dumps(MANIFEST))
+    _check_readers(m, tmp_path)
+    fault(tmp_path / "benchmark", m)
+    with pytest.raises((AssertionError, manifest.ManifestError)):
+        _check_readers(m, tmp_path)
 
 
 def test_files_under_paths_are_legally_named():
@@ -318,6 +368,7 @@ def test_peaks_and_shapes():
 
 
 REHEARSAL_BATCH = 512     # records to a stored batch in a rehearsal, at most
+REHEARSAL_WARM_MAX = 4    # batches in a paced rehearsal's largest warm-up burst
 
 
 def _tiny_config(cfg: dict, backlog: int) -> dict:
@@ -345,8 +396,12 @@ def _tiny_root(tmp_path, backlog=2048, extra=None) -> Path:
     t = root / "benchmark" / "traffic" / "paced-16k.json"
     if t.exists():
         tr = json.loads(t.read_text())
-        tr |= {"rate_batches_per_s": 10, "warm_single_batches": 2,
-               "warm_max_batches": 2, "grace_s": 30}
+        # a slow pace and warm-up bursts up to `REHEARSAL_WARM_MAX`: the
+        # event loop has to stall for most of a second (a loaded CPU, the
+        # profiler's start) before a window's slice coalesces more
+        # batches than a burst had, which is a shape that compiles
+        tr |= {"rate_batches_per_s": 5, "warm_single_batches": 2,
+               "warm_max_batches": REHEARSAL_WARM_MAX, "grace_s": 30}
         t.write_text(json.dumps(tr))
     if extra:
         extra(root, m)
@@ -403,11 +458,18 @@ def test_cell_rehearsal_on_cpu(monkeypatch, tmp_path, cell):
 
 
 def test_paced_layer_metrics_on_cpu(monkeypatch, tmp_path):
+    """Counts, not times: 20 batches at 5 a second are written, each gives
+    one sample however late it comes (`grace_s` 30), and a compile in the
+    window is held against the run only where every slice was a shape the
+    warm-up had: a stall that coalesces more is this CPU's, not a fault."""
     root = _tiny_root(tmp_path, extra=_add_paced_cell)
-    r = _rehearse(monkeypatch, root, "ns-paced", trace=True, seconds=2.0)
+    r = _rehearse(monkeypatch, root, "ns-paced", trace=True, seconds=4.0)
     assert r["correct"] is True and r["counts"]["samples"] == r["attempted"] == 20
     assert set(r["metrics"]) == set(PACED_LAYER)
-    assert r["metrics"]["compiles_in_window.paced"]["value"] == 0.0
+    compiles = r["metrics"]["compiles_in_window.paced"]["value"]
+    assert compiles == r["counts"]["compiles"]      # the window's own delta
+    if r["counts"]["max_slice_batches"] <= REHEARSAL_WARM_MAX:
+        assert compiles == 0.0
     assert 1 <= r["metrics"]["slice_records_mean.paced"]["value"] <= 20 * 400
 
 
@@ -442,7 +504,7 @@ def test_traced_rehearsal_reports_no_device_number(monkeypatch, tmp_path):
                  "hbm_roofline_share"):
         assert name not in r["metrics"]
     for name in ("wire_out_mb_per_s", "fastpath_share", "spill_records",
-                 "exec_up_ms_per_mrec", "exec_down_ms_per_mrec"):
+                 "exec_up_ms_per_mrec", "exec_wait_ms_per_mrec"):
         assert name in r["metrics"]
     assert r["metrics"]["fastpath_share"]["value"] == 100.0
     assert r["metrics"]["spill_records"]["value"] == 0.0
@@ -533,6 +595,62 @@ def test_added_cell_joins_every_list(monkeypatch, tmp_path):
     assert set(readers.NEW_HOST) | {"dummy_responses", "fastpath_share",
                                     "exec_up_ms_per_mrec"} <= set(r["metrics"])
     assert not set(readers.NEW_DEVICE) & set(r["metrics"])
+
+
+def _add_model_config(root, m):
+    """What a `model_config` PR brings, by new files and by APPENDING to
+    the manifest's lists alone: a configuration, a mix and a cell
+    (`_add_dummy_cell`), the cell's name at the end of `records_in_per_s`
+    and of every GENERAL reader's `workloads` (the readers that list every
+    accepted cell; a reader of one chain's scopes stays that chain's), and
+    two readers of its own at the end of `per_layer`."""
+    accepted = {w["name"] for w in m["workloads"]}
+    _add_dummy_cell(root, m)
+    for e in m["per_layer"]:
+        if accepted <= set(e.get("workloads", ())):
+            e["workloads"].append("dummy-cell")
+    (root / "benchmark" / "layer_metrics" / "dummy_slices.py").write_text(
+        "def read(obs):\n    return obs['delta']['fastpath_slices']\n")
+    m["per_layer"].append({
+        "name": "dummy_slices", "unit": "slices", "better": "higher",
+        "source": "program_counter", "layer": "slice path",
+        "moves": "records_in_per_s", "workloads": ["dummy-cell"]})
+
+
+def test_a_model_config_prs_additions_are_taken_by_appending(monkeypatch, tmp_path):
+    """Every manifest-level assertion of `tests/benchmark` holds on a copy
+    to which a `model_config` PR's entries were appended, and the new
+    cell's rehearsal reports the readers it brought. An assertion that
+    pins an entry's place in a list (PR 30's `per_layer[-4:]`, refused
+    PR 35's wall) fails here, before the PR that meets it."""
+    import test_aggregate_cell as aggregate
+    import test_loop_order_readers as loop_readers
+    import test_tracing_readers as readers
+
+    root = _tiny_root(tmp_path, extra=_add_model_config)
+    m = json.loads((root / "BENCHMARK.json").read_text())
+    own = [e["name"] for e in m["per_layer"] if e["workloads"] == ["dummy-cell"]]
+    assert own == ["dummy_responses", "dummy_slices"]
+    assert len(m["per_layer"]) == len(MANIFEST["per_layer"]) + 2
+    for check_manifest in MANIFEST_CHECKS:
+        check_manifest(m, root)
+    for name in readers.NEW_HOST + readers.NEW_DEVICE:
+        readers.check_new_entry(m, name)
+    aggregate.check_aggregate_entries(m)
+    for name in loop_readers.LOOP_READERS:
+        loop_readers.check_loop_reader_entry(m, name)
+    # the accepted cells report what they reported
+    for cell in CELLS:
+        assert ([e["name"] for e in manifest.load_cell(cell, root).per_layer]
+                == [e["name"] for e in manifest.load_cell(cell).per_layer])
+    r = _rehearse(monkeypatch, root, "dummy-cell", trace=True, seconds=0.5)
+    assert r["correct"] is True
+    assert r["metrics"]["dummy_responses"]["value"] == r["counts"]["responses"]
+    assert r["metrics"]["dummy_slices"]["value"] == r["counts"]["fastpath_slices"] > 0
+    # ... beside the general readers it joined, the loop's three among
+    # them; a reader of the aggregate chain alone is not asked
+    assert set(loop_readers.LOOP_READERS) | set(readers.NEW_HOST) <= set(r["metrics"])
+    assert not set(aggregate.NEW_READERS) & set(r["metrics"])
 
 
 @pytest.mark.parametrize("own,backlog,want", [

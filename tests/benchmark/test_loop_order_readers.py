@@ -1,12 +1,12 @@
-"""ISSUE-31: the two readers of the stream loop's order, on a seeded flow
-ring; none needs the chip. Each returns None, never 0, where the phase
-or the field it reads is absent (a program without it). And what this PR
-brings to the benchmark is additions."""
+"""The readers of the stream loop's order (ISSUE 31's two, and ISSUE 36's
+of the `materialize` phase), on a seeded flow ring; none needs the chip.
+Each returns None, never 0, where the phase or the field it reads is
+absent (a program without it). And their manifest entries, wherever in
+the list they stand."""
 
 from __future__ import annotations
 
 import json
-import subprocess
 import sys
 from pathlib import Path
 
@@ -18,13 +18,16 @@ for _p in (str(REPO), str(BENCH), str(Path(__file__).resolve().parent)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from test_tracing_readers import _seed_flow  # noqa: E402
+from test_tracing_readers import _seed_flow, check_workloads_floor  # noqa: E402
 from spubench import manifest  # noqa: E402
 
 from fluvio_tpu.telemetry import TELEMETRY  # noqa: E402
 from fluvio_tpu.telemetry.flow import SLICE_PHASES, SliceFlow  # noqa: E402
 
-PARENT = "07cf184a05c3e80f87b82b84df34c115ba4b54ba"
+# the entries ISSUE 36 appended: name -> (unit, better)
+LOOP_READERS = {"finish_blocked_ms_per_mrec": ("ms/Mrec", "lower"),
+                "interleave_share": ("%", "higher"),
+                "materialize_ms_per_mrec": ("ms/Mrec", "lower")}
 OBS = {"t_open": 100.0, "t_close": 110.0, "records_in": 4000}
 # a served slice in the loop's order, and in the order before it
 SLICE = (("read", 0.01), ("wire_decode", 0.03), ("stage", 0.02),
@@ -73,8 +76,10 @@ def test_finish_blocked_reads_the_finish_phase_alone():
     assert "materialize" in SLICE_PHASES and "finish" in SLICE_PHASES
 
 
-def test_finish_blocked_is_silent_without_phases(monkeypatch):
-    read = _reader("finish_blocked_ms_per_mrec")
+@pytest.mark.parametrize("name", ["finish_blocked_ms_per_mrec",
+                                  "materialize_ms_per_mrec"])
+def test_phase_readers_are_silent_without_phases(monkeypatch, name):
+    read = _reader(name)
     to_dict = SliceFlow.to_dict
     monkeypatch.setattr(
         SliceFlow, "to_dict",
@@ -111,62 +116,62 @@ def test_interleave_share_is_silent_without_the_field(monkeypatch):
     assert read(OBS) is None      # a parent commit: nothing, no raise
 
 
-def test_benchmark_files_of_this_pr_are_additions():
-    """No file the accepted benchmark had is edited or deleted, and
-    `BENCHMARK.json` loses nothing: entries stay in place and in order,
-    lists only grow at their end."""
-    def git(*args):
-        return subprocess.run(["git", *args], cwd=REPO, capture_output=True,
-                              text=True)
-
-    if git("cat-file", "-e", PARENT).returncode != 0:
-        pytest.skip("not a checkout that holds the parent commit")
-    changed = git("diff", "--name-status", PARENT, "--",
-                  "benchmark", "tests/benchmark").stdout.split("\n")
-    assert [c for c in changed if c and not c.startswith("A")] == []
-    added = {c.split("\t")[1] for c in changed if c}
-    assert {"benchmark/layer_metrics/finish_blocked_ms_per_mrec.py",
-            "benchmark/layer_metrics/interleave_share.py"} <= added
-    was = json.loads(git("show", f"{PARENT}:BENCHMARK.json").stdout)
-    now = json.loads((REPO / "BENCHMARK.json").read_text())
-    assert {k: was[k] for k in ("command", "paths", "run_seconds")} == {
-        k: now[k] for k in ("command", "paths", "run_seconds")}
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        assert len(now[key]) >= len(was[key])
-        for old, new in zip(was[key], now[key]):       # in place, in order
-            lists = {k for k in old if isinstance(old[k], list)}
-            assert {k: old[k] for k in old if k not in lists} == {
-                k: new[k] for k in new if k not in lists}
-            for k in lists:
-                assert new[k][:len(old[k])] == old[k]
+def test_materialize_reads_the_materialize_phase_alone():
+    read = _reader("materialize_ms_per_mrec")
+    assert read(OBS) is None                                  # no flow at all
+    _seed_flow(98.0, SLICE)                                   # before the window
+    _seed_flow(101.0, [("chain_acquire", 0.5)], records=0)    # an open alone
+    assert read(OBS) == 0.0   # flows with phases, none of them `materialize`
+    _seed_flow(102.0, SLICE)
+    _seed_flow(103.0, SLICE)
+    _seed_flow(104.0, OLD_SLICE)          # the order before it: `finish` held it
+    # 2 x 300 ms over 4,000 records: 150,000 ms a million; `finish` (3 x 50
+    # ms) and `encode` are other readers'
+    assert read(OBS) == pytest.approx(150_000.0)
+    assert _reader("finish_blocked_ms_per_mrec")(OBS) == pytest.approx(37_500.0)
+    assert read(OBS | {"records_in": 0}) is None
 
 
-# -- a rehearsal that reads both ---------------------------------------------
+# -- the manifest entries ----------------------------------------------------
 
 
-def _with_the_two_readers(root, m):
-    """The manifest entries a later `benchmark` PR adds (PERF.md §7), and
-    a mix whose ``max_bytes`` makes a pass several slices: the tiny
+def check_loop_reader_entry(m, name):
+    """The entry ``name`` (one of `LOOP_READERS`) in the manifest ``m``:
+    there once, for the slice path, with the three drain cells in its
+    `workloads` (a FLOOR: a later PR appends its cell) and a reader that
+    loads. No word on where in `per_layer` it stands."""
+    (entry,) = [e for e in m["per_layer"] if e["name"] == name]
+    check_workloads_floor(m, entry, {"ns-drain", "explode-drain", "agg-drain"})
+    assert (entry["unit"], entry["better"]) == LOOP_READERS[name]
+    assert (entry["source"], entry["layer"], entry["moves"]) == (
+        "program_span", "slice path", "records_in_per_s")
+    assert callable(_reader(name))
+
+
+@pytest.mark.parametrize("name", LOOP_READERS)
+def test_loop_reader_entries_list_the_three_drain_cells(name):
+    check_loop_reader_entry(json.loads((REPO / "BENCHMARK.json").read_text()), name)
+
+
+# -- a rehearsal that reads all three ----------------------------------------
+
+
+def _several_slices_a_pass(root, m):
+    """A mix whose ``max_bytes`` makes a pass several slices: the tiny
     backlog is one slice at 16 MiB, and one slice has no next one."""
-    for name, unit, better in (("finish_blocked_ms_per_mrec", "ms/Mrec", "lower"),
-                               ("interleave_share", "%", "higher")):
-        m["per_layer"].append({
-            "name": name, "unit": unit, "better": better,
-            "source": "program_span", "layer": "slice path",
-            "moves": "records_in_per_s",
-            "workloads": [w["name"] for w in m["workloads"]]})
     (root / "benchmark" / "traffic" / "drain-16m.json").write_text(
         json.dumps({"mode": "drain", "max_bytes": 20_000}))
 
 
 @pytest.mark.parametrize("cell", ["ns-drain", "explode-drain", "agg-drain"])
-def test_rehearsal_reports_both_readers_in_every_cell(monkeypatch, tmp_path, cell):
+def test_rehearsal_reports_the_readers_in_every_cell(monkeypatch, tmp_path, cell):
     import test_benchmark_harness as harness
 
-    root = harness._tiny_root(tmp_path, extra=_with_the_two_readers)
+    root = harness._tiny_root(tmp_path, extra=_several_slices_a_pass)
     r = harness._rehearse(monkeypatch, root, cell, trace=True, seconds=1.0)
     assert r["correct"] is True and r["faults"] == []
     assert r["metrics"]["fastpath_share"]["value"] == 100.0
     assert r["metrics"]["finish_blocked_ms_per_mrec"]["value"] > 0.0
+    assert r["metrics"]["materialize_ms_per_mrec"]["value"] > 0.0
     # every pass is several slices and its last has no next one
     assert 0.0 < r["metrics"]["interleave_share"]["value"] < 100.0

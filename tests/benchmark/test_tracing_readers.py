@@ -64,21 +64,24 @@ def _fresh_registry():
 # -- the manifest entries ----------------------------------------------------
 
 
-def check_new_entry(m, name):
-    """PR 26's entry ``name`` in the manifest ``m``. Its `workloads` is
-    held to a FLOOR: both drain cells are in it, and whatever else is in
-    it is a cell of the manifest, once. A later PR appends its own."""
-    (entry,) = [e for e in m["per_layer"] if e["name"] == name]
-    cells = [w["name"] for w in m["workloads"]]
-    assert {"ns-drain", "explode-drain"} <= set(entry["workloads"]) <= set(cells)
+def check_workloads_floor(m, entry, floor):
+    """An entry's `workloads` is held to a FLOOR: the cells it was accepted
+    with are in it, and whatever else is in it is a cell of the manifest
+    ``m``, once. A later PR appends its own."""
+    cells = {w["name"] for w in m["workloads"]}
+    assert floor <= set(entry["workloads"]) <= cells
     assert len(set(entry["workloads"])) == len(entry["workloads"])
+
+
+def check_new_entry(m, name):
+    """PR 26's entry ``name`` in the manifest ``m``, wherever in the list
+    it stands."""
+    (entry,) = [e for e in m["per_layer"] if e["name"] == name]
+    check_workloads_floor(m, entry, {"ns-drain", "explode-drain"})
     assert entry["moves"] == "records_in_per_s"
     assert entry["source"] == (
         "device_trace" if name in NEW_DEVICE else "program_span")
     assert callable(_reader(name))
-    # added at the end of the list, after what PR 24 accepted
-    names = [e["name"] for e in m["per_layer"]]
-    assert names.index(name) > names.index("device_idle_share")
 
 
 @pytest.mark.parametrize("name", NEW_HOST + NEW_DEVICE)
