@@ -213,6 +213,8 @@ def _warm_probes(executor, probes, report: WarmupReport) -> None:
     t0 = time.perf_counter()
     carries0 = [tuple(c) for c in executor.carries]
     device_carries0 = executor._device_carries
+    # a window chain's probes fold into a bank of their own
+    bank0, executor._window_bank = executor._window_bank, None
     buckets = []
     for label, buf in probes:
         try:
@@ -225,6 +227,7 @@ def _warm_probes(executor, probes, report: WarmupReport) -> None:
     if executor.agg_configs:
         executor.carries = [tuple(c) for c in carries0]
         executor._device_carries = device_carries0
+    executor._window_bank = bank0
     report.buckets = tuple(dict.fromkeys(buckets))
     report.wall_s = time.perf_counter() - t0
     c1 = TELEMETRY.compile_totals()

@@ -217,6 +217,8 @@ def trace_chain_entry_points(
             (jnp.int64(acc), jnp.int64(win), jnp.asarray(has))
             for acc, win, has in executor.carries
         )
+        if executor._window is not None:
+            carries = executor._stream_bank().arrays()
         flat, bucket = executor._flat_and_bucket(buf)
         words = executor._padded(flat, bucket).view(np.int32)
         lengths_up, has_keys, has_offsets, ts_mode, ts_np = (

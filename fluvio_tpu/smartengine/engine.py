@@ -408,16 +408,14 @@ class SmartModuleChainInstance:
         """The interpreter rerun ladder every fused-path demotion takes
         (spill, non-spill fused failure, open breaker): rerun with
         bounded transient retry — a one-off host failure must not
-        condemn the batch as poison — then quarantine. Instance state is
-        exactly (accumulator, window_start) per module, so a snapshot
-        makes every attempt start from the same aggregates, and a
-        quarantined batch contributes nothing to them."""
+        condemn the batch as poison — then quarantine. A snapshot of
+        every instance's state (`PythonInstance.state_snapshot`) makes
+        every attempt start from the same aggregates, and a quarantined
+        batch contributes nothing to them."""
         from fluvio_tpu.telemetry import TELEMETRY
 
         policy = self._spill_retry
-        snapshot = [
-            (i.accumulator, i._window_start) for i in self.instances
-        ]
+        snapshot = [i.state_snapshot() for i in self.instances]
         attempt = 0
         while True:
             try:
@@ -441,11 +439,9 @@ class SmartModuleChainInstance:
                 return self._quarantine(inp, fused_error, interp_error)
 
     def _restore_instances(self, snapshot) -> None:
-        """Roll per-instance aggregate state — exactly (accumulator,
-        window_start) — back to a pre-rerun snapshot."""
-        for inst, (acc, win) in zip(self.instances, snapshot):
-            inst.accumulator = acc
-            inst._window_start = win
+        """Roll every instance's state back to a pre-rerun snapshot."""
+        for inst, snap in zip(self.instances, snapshot):
+            inst.state_restore(snap)
 
     def _quarantine(
         self,

@@ -65,6 +65,14 @@ class PythonInstance:
         }
         # windowed aggregate state
         self._window_start: Optional[int] = None
+        # `dsl.WindowProgram` state: open windows {window index: {key:
+        # [aggregate, count]}} and the largest event time seen (the
+        # watermark is that less the program's lateness). A fused chain
+        # that served slices since leaves its device bank in
+        # ``window_source`` instead: loaded on the next use
+        self._window_open: dict = {}
+        self._window_max_ts = dsl.INT64_MIN + 1
+        self.window_source = None
 
     # -- init / look_back ---------------------------------------------------
 
@@ -82,6 +90,47 @@ class PythonInstance:
                 hook(rec)
             except Exception as e:  # noqa: BLE001 — user code boundary
                 raise SmartModuleLookbackError(str(e), rec.offset) from e
+
+    # -- state the engine snapshots around a spill rerun ----------------------
+
+    def state_snapshot(self) -> tuple:
+        self._load_window_source()
+        return (
+            self.accumulator, self._window_start, self._window_max_ts,
+            {w: {k: list(v) for k, v in row.items()}
+             for w, row in self._window_open.items()},
+        )
+
+    def state_restore(self, snapshot: tuple) -> None:
+        (self.accumulator, self._window_start, self._window_max_ts,
+         window_open) = snapshot
+        self._window_open = {
+            w: {k: list(v) for k, v in row.items()}
+            for w, row in window_open.items()
+        }
+
+    def _load_window_source(self) -> None:
+        """Take over the device bank a fused chain left behind."""
+        bank, self.window_source = self.window_source, None
+        if bank is None:
+            return
+        entries, self._window_max_ts = bank.snapshot()
+        self._window_open = {}
+        for composite, acc, cnt in entries:
+            key, w = divmod(composite, dsl.WINDOW_KEY_LIMIT)
+            self._window_open.setdefault(w, {})[key] = [acc, cnt]
+
+    def window_state(self) -> tuple:
+        """(open entries [(key * WINDOW_KEY_LIMIT + window index,
+        aggregate, count)] in that order, largest event time): what a
+        device bank is restored from."""
+        self._load_window_source()
+        entries = sorted(
+            (key * dsl.WINDOW_KEY_LIMIT + w, acc, cnt)
+            for w, row in self._window_open.items()
+            for key, (acc, cnt) in row.items()
+        )
+        return entries, self._window_max_ts
 
     # -- transform ----------------------------------------------------------
 
@@ -236,6 +285,8 @@ class PythonInstance:
                     out.successes.append(Record(value=el, key=rec.key))
         elif isinstance(program, dsl.AggregateProgram):
             self._run_dsl_aggregate(program, sm_records, out)
+        elif isinstance(program, dsl.WindowProgram):
+            self._run_dsl_window(program, sm_records, out)
         else:
             raise TypeError(f"unknown DSL program {type(program).__name__}")
         return out
@@ -300,3 +351,58 @@ class PythonInstance:
             rec.record.value = str(acc).encode("ascii")
             out.successes.append(rec.record)
         self.accumulator = str(acc).encode("ascii")
+
+    def _run_dsl_window(
+        self,
+        program: dsl.WindowProgram,
+        sm_records: List[SmartModuleRecord],
+        out: SmartModuleOutput,
+    ) -> None:
+        """`dsl.WindowProgram` record by record: fold the record into
+        the windows that hold its event time, then emit every window
+        the watermark has reached, in order of its end. A contribution
+        to a window already emitted is late: dropped and counted."""
+        if program.combine not in dsl.AGGREGATE_COMBINES:
+            raise ValueError(f"unknown window combine {program.combine!r}")
+        if program.emit not in dsl.WINDOW_EMITS:
+            raise ValueError(f"unknown window emit {program.emit!r}")
+        comb = {"add": lambda a, x: a + x, "max": max, "min": min}[
+            program.combine
+        ]
+        neutral = dsl.AGGREGATE_COMBINE_NEUTRAL[program.combine]
+        window = program.window_ms
+        slide = program.slide_ms or window
+        reach = window + program.lateness_ms  # start -> emitted
+        self._load_window_source()
+        open_ = self._window_open
+        ev = dsl.eval_expr
+        closed = late = invalid = 0
+        for rec in sm_records:
+            key = int(ev(program.key, rec.value, rec.key))
+            t = int(ev(program.event_time, rec.value, rec.key))
+            x = int(ev(program.contribution, rec.value, rec.key))
+            if not 0 <= key < dsl.WINDOW_KEY_LIMIT:
+                invalid += 1
+                continue
+            for w in range(t // slide, t // slide - window // slide, -1):
+                if w < 0:
+                    break
+                if w * slide + reach <= self._window_max_ts:
+                    late += 1
+                    continue
+                cell = open_.setdefault(w, {}).setdefault(key, [neutral, 0])
+                cell[0] = comb(cell[0], x)
+                cell[1] += 1
+            if t <= self._window_max_ts:
+                continue
+            self._window_max_ts = t
+            for w in sorted(w for w in open_ if w * slide + reach <= t):
+                row = open_.pop(w)
+                closed += len(row)
+                top = max(acc for acc, _ in row.values())
+                for k in sorted(row):
+                    if program.emit == "all" or row[k][0] == top:
+                        out.successes.append(Record(value=dsl.window_row_bytes(
+                            program, w * slide + window, k, row[k][0]
+                        )))
+        TELEMETRY.add_window_slice(closed, late, invalid)

@@ -49,7 +49,7 @@ from fluvio_tpu.smartmodule.types import (
 )
 from fluvio_tpu.smartengine.config import SmartModuleConfig
 from fluvio_tpu.smartengine.metrics import SmartModuleChainMetrics
-from fluvio_tpu.smartengine.tpu import glz, kernels, stripes
+from fluvio_tpu.smartengine.tpu import glz, kernels, stripes, window_stage
 from fluvio_tpu.smartengine.tpu.buffer import (
     MAX_RECORD_WIDTH,
     RecordBuffer,
@@ -606,12 +606,12 @@ class StreamState:
       sequence tells a stale finish whether the healed carry tip is
       still current (safe to re-dispatch from) or already consumed by
       later dispatches,
+    - ``window_bank``: a window chain's bank, which advances at a
+      slice's FETCH and not at its dispatch (`window_stage.py`),
     - the partition identity (``span_chain``, ``partition_tag``) the
-      partition layer installs around a slice: the span chain label
-      gains the chain@partition suffix (SLO and admission key on it)
-      and down-link/decline telemetry a per-partition:group label;
-      None (the default) costs one attribute read on the seams that
-      check it.
+      partition layer installs around a slice: a chain@partition span
+      label (SLO and admission key on it) and a per-partition:group
+      label on down-link/decline telemetry; None by default.
 
     A state starts from the chain spec's seed
     (`TpuChainExecutor.initial_carries`)."""
@@ -619,7 +619,7 @@ class StreamState:
     __slots__ = (
         "carries", "device_carries", "instances", "heal_epoch",
         "heal_carries", "heal_dispatch_seq", "dispatch_seq",
-        "span_chain", "partition_tag",
+        "span_chain", "partition_tag", "window_bank",
     )
 
     def __init__(self, carries: List[Tuple[int, int, bool]]) -> None:
@@ -632,6 +632,7 @@ class StreamState:
         self.dispatch_seq = 0
         self.span_chain: Optional[str] = None
         self.partition_tag: Optional[str] = None
+        self.window_bank = None
 
 
 def _stream_field(name: str) -> property:
@@ -642,7 +643,7 @@ def _stream_field(name: str) -> property:
     )
 
 
-class TpuChainExecutor:
+class TpuChainExecutor(window_stage.WindowChainMixin):
     """A compiled chain, and the state of the one stream that owns it.
 
     The compiled half (stages, the instrumented jits and their
@@ -740,12 +741,9 @@ class TpuChainExecutor:
         # the viewable fetch (speculation arms only when they agree)
         self._spec_rows: Optional[int] = None
         self._spec_prev: Optional[int] = None
-        # CUMULATIVE link-byte totals since executor creation
-        # (observability + bench attribution; read deltas around a batch
-        # for per-batch numbers — totals stay correct under the pipelined
-        # stream loop where dispatch k+1 interleaves with fetch k). Byte
-        # counts are hardware-independent: the same arrays cross the link
-        # on CPU and on the real chip.
+        # CUMULATIVE link-byte totals since executor creation (read deltas
+        # around a batch: they stay correct when dispatch k+1 interleaves
+        # with fetch k); the same arrays cross the link on CPU and chip
         self.h2d_bytes_total = 0
         self.d2h_bytes_total = 0
         # gauge bookkeeping: staged link bytes per in-flight handle, so
@@ -839,6 +837,8 @@ class TpuChainExecutor:
             return None
         try:
             for module, config in entries:
+                if stages and stages[-1].kind == "window":
+                    raise Unlowerable("a window's rows are the chain's output")
                 kind = module.transform_kind()
                 prog = module.dsl_program(kind)
                 if prog is None:
@@ -911,8 +911,8 @@ class TpuChainExecutor:
                     if any(isinstance(s, _ArrayMapStage) for s in stages):
                         raise Unlowerable("one array_map per fused chain")
                     stages.append(_ArrayMapStage(mode=prog.mode, sep=prog.sep))
-                else:
-                    return None
+                else:  # a `dsl.WindowProgram`, or Unlowerable
+                    stages.append(window_stage.WindowStage.lower(prog, stages))
         except (Unlowerable, KeyError):
             return None
         ex = cls(stages, agg_configs)
@@ -1063,11 +1063,11 @@ class TpuChainExecutor:
 
     def _chain_outputs(self, arrays: Dict, state: Dict, carries,
                        enc: str, pack: bool):
-        """The chain body's tail, traced under the ``compact`` device
-        scope (`_desc_stream`/`_packed_payload` and `_down_encode` open
-        their own ``pack`` / ``link_encode`` scopes inside it; the
-        innermost scope names an operation): survivor compaction, the
-        mask, the header and the down-link form of the result."""
+        """The chain body's tail under the ``compact`` device scope
+        (``pack`` / ``link_encode`` open inside it; the innermost names an
+        operation): compaction, mask, header, the result's down-link form."""
+        if self._window is not None:
+            return window_stage.chain_outputs(state, carries)
         valid = state["valid"]
         out_count = jnp.sum(valid.astype(jnp.int32))
         fan_err = state.get("fan_err", jnp.asarray(False))
@@ -1443,7 +1443,7 @@ class TpuChainExecutor:
         static shape-bucket kwargs (never touches array values)."""
         return (
             f"{self._chain_sig} w={k.get('width')} "
-            f"cap={k.get('fanout_cap')}"
+            f"cap={k.get('fanout_cap')}{self._bank_sig(a)}"
             f"{self._down_sig(k)}"
         )
 
@@ -1518,13 +1518,13 @@ class TpuChainExecutor:
         telemetry BatchSpan, or None) collects the host-side phase
         clock pairs: stage / h2d / dispatch.
         """
-        if self._device_carries is not None:
+        if self._window is not None:
+            carries = self._stream_bank().arrays()
+        elif self._device_carries is not None:
             carries = self._device_carries
         else:
-            carries = tuple(
-                (jnp.int64(acc), jnp.int64(win), jnp.asarray(has))
-                for acc, win, has in self.carries
-            )
+            carries = tuple((jnp.int64(acc), jnp.int64(win), jnp.asarray(has))
+                            for acc, win, has in self.carries)
         striped = self._needs_stripes(buf)
         if striped and self._striped_chain() is None:
             # the one structural fallback left: a wide batch whose chain
@@ -1654,7 +1654,10 @@ class TpuChainExecutor:
         encode ladder applies to descriptor/payload streams only
         (striped: span chains ship descriptors, mask-only chains have
         nothing to encode); byte-mode packing never applies striped
-        (there is no striped byte mode)."""
+        (there is no striped byte mode). A window chain's few answer
+        rows take neither."""
+        if self._window is not None:
+            return "off", False
         enc = self._enc_variant if self._enc_eligible else "off"
         if striped and not self._striped_has_span():
             enc = "off"
@@ -1948,7 +1951,12 @@ class TpuChainExecutor:
         """The intentional D2H seam: `_fetch_inner` under the explicit
         transfer-guard allow scope (see `transfer_guard_fetch`)."""
         with transfer_guard_fetch():
-            return self._fetch_inner(buf, header, packed, spec, defer)
+            try:
+                return self._fetch_inner(buf, header, packed, spec, defer)
+            except window_stage.WindowOverflow as o:
+                # here, under every caller (first fetch, a retry's
+                # refetch), so a replayed slice grows alike
+                return self._refetch_grown(buf, o, spec, defer)
 
     def _fetch_inner(
         self, buf: RecordBuffer, header, packed, spec: Optional[Dict] = None,
@@ -1972,6 +1980,8 @@ class TpuChainExecutor:
         # device-side failures surface at the first blocking sync on this
         # batch's results — the seam an armed "device" fault models
         faults.maybe_fire("device")
+        if self._window is not None:
+            return self._fetch_window(buf, header, packed, span, defer)
         # fan-out source rows are non-decreasing after compaction, so they
         # ship as uint8 deltas + a scalar base whenever the max delta fits
         # (the probe scalars ride the header sync the fetch pays anyway) —
@@ -2598,7 +2608,10 @@ class TpuChainExecutor:
     def _fanout_cap(self, buf: RecordBuffer) -> Optional[int]:
         """Capacity for this batch: learned elements-per-source-row ratio
         scaled by the batch's rows (an outlier batch raises the ratio,
-        not an absolute row count, so small batches stay small)."""
+        not an absolute row count, so small batches stay small). A
+        window chain's is its learned emit capacity."""
+        if self._window is not None:
+            return self._window_emit_cap(buf)
         if not self._fanout:
             return None
         rows = buf.rows
@@ -2618,6 +2631,11 @@ class TpuChainExecutor:
         that is fatal)."""
         from fluvio_tpu.parallel.sharded import ShardedChainExecutor
 
+        if self._window is not None:
+            raise ValueError(
+                "a window chain cannot be sharded: a stream's window bank "
+                "lives on one device"
+            )
         self._sharded = ShardedChainExecutor(self, n_devices, devices)
 
     # -- recovery (resilience/policy.py) -------------------------------------
@@ -2842,6 +2860,9 @@ class TpuChainExecutor:
         # the heal epoch its carry lineage belongs to
         spec["enc_used"] = getattr(self, "_enc_last", None) is not None
         spec["epoch"] = self._heal_epoch
+        if self._window is not None:
+            # where `rollback_finished` puts the bank back to
+            spec["bank0"] = self._window_bank.checkpoint()
         handle = (prev_carries, header, packed, spec)
         self._gauge_track(handle, self.h2d_bytes_total - h0)
         return handle
@@ -2972,6 +2993,8 @@ class TpuChainExecutor:
         refused the output) and is re-run per record, which must start
         where the slice did. Nothing of the stream may be in flight: a
         caller that dispatched ahead discards that first."""
+        if self._window is not None:
+            self._window_bank.commit(*handle[3]["bank0"])
         if not self.agg_configs:
             return
         if self._sharded is not None:
@@ -3165,9 +3188,10 @@ class TpuChainExecutor:
         The broker's consume loop shape: sustained throughput is bounded by
         max(compute, transfer), not their sum.
         """
-        if self.agg_configs and self._fanout:
+        if (self.agg_configs and self._fanout) or self._window is not None:
             # serialized: fan-out overflow retry must roll carries back,
-            # impossible once the next batch dispatched
+            # impossible once the next batch dispatched; a window bank
+            # is committed at the fetch, which the next dispatch reads
             for buf in bufs:
                 yield self.process_buffer(buf)
             return
@@ -3285,6 +3309,8 @@ class TpuChainExecutor:
         self._device_carries = None  # host state becomes authoritative
         if self._sharded is not None:
             self._sharded._pending_carries = None
+        if self._window is not None:
+            self._restore_window(instances[-1])
         slot = 0
         for inst in instances:
             if inst.kind != SmartModuleKind.AGGREGATE:

@@ -43,6 +43,13 @@ Programs (one per transform kind):
                                          view (accumulator resets per
                                          timestamp window; record key set to
                                          the window start)
+    WindowProgram(key, event_time, contribution, combine, window_ms,
+                  slide_ms, lateness_ms, emit="top"|"all")
+                                         keyed sliding EVENT-TIME windows (an
+                                         aggregate-kind program): one output
+                                         row per closed (window, key), or per
+                                         window only the key(s) whose aggregate
+                                         is the window's maximum
 """
 
 from __future__ import annotations
@@ -438,6 +445,59 @@ class AggregateProgram(Expr):
     combine: Optional[str] = None  # one of AGGREGATE_COMBINES
 
 
+WINDOW_EMITS = ("top", "all")
+# a window key is an int in [0, WINDOW_KEY_LIMIT): (key, window index)
+# packs into one sortable int64 on the device (`windows/spec.py:
+# KEY_STRIDE`, the same number); a key outside is dropped and counted
+WINDOW_KEY_LIMIT = 1 << 31
+INT64_MIN = -(2**63)
+
+
+@_node
+@dataclass
+class WindowProgram(Expr):
+    """Keyed sliding event-time windows, an AGGREGATE-kind program
+    (NEXmark Q5 "hot items" is the model case).
+
+    Every record yields a ``key`` (int in [0, 2**31)), an ``event_time``
+    (epoch ms, int) and an int ``contribution``; it counts in every
+    window ``[k*slide_ms, k*slide_ms + window_ms)`` that holds its event
+    time, folded per (window, key) by the ``combine`` monoid (a record
+    whose key is outside the range is dropped and counted invalid). The
+    stream's watermark is ``max(event_time seen) - lateness_ms``; a
+    window is emitted, once and complete, when the watermark reaches
+    its end, and a contribution to a window already emitted is dropped
+    and counted late. ``emit="all"`` emits every (window, key)
+    aggregate, ``"top"`` only the key(s) whose aggregate equals the
+    window's maximum. Output rows, ordered by (window end, key), are
+    fresh records whose value is
+    ``{"window_end":<ms>,"<key_field>":<key>,"<value_field>":<agg>}``.
+    Windows still open when the stream ends are not emitted. The state
+    (open windows, watermark) belongs to the consumer stream."""
+
+    key: Expr = None
+    event_time: Expr = None
+    contribution: Expr = None
+    combine: str = "add"
+    window_ms: int = 0
+    slide_ms: int = 0  # 0 -> tumbling (slide == window)
+    lateness_ms: int = 0
+    emit: str = "top"
+    key_field: str = "key"
+    value_field: str = "value"
+
+
+def window_row_bytes(program: WindowProgram, window_end: int, key: int,
+                     value: int) -> bytes:
+    """The value of one `WindowProgram` output record: the ONE
+    rendering both executors use."""
+    return (
+        b'{"window_end":%d,"%s":%d,"%s":%d}'
+        % (window_end, program.key_field.encode(), key,
+           program.value_field.encode(), value)
+    )
+
+
 # ---------------------------------------------------------------------------
 # Build-time resolution & interpretation (reference semantics)
 # ---------------------------------------------------------------------------
@@ -478,6 +538,9 @@ def resolve_params(expr: Expr, params: Dict[str, str]) -> Expr:
     # typed post-fixups for non-string fields configured via @param
     if isinstance(resolved, AggregateProgram) and isinstance(resolved.window_ms, str):
         resolved.window_ms = int(resolved.window_ms)
+    if isinstance(resolved, WindowProgram):
+        for name in ("window_ms", "slide_ms", "lateness_ms"):
+            setattr(resolved, name, int(getattr(resolved, name)))
     return resolved
 
 

@@ -23,7 +23,10 @@ engine records a transform runtime error at that record and short-circuits.
 
 A transform may also attach a declarative DSL program (``dsl=``) describing
 the same computation; the TPU engine backend requires it to lower the module
-to JAX kernels, and tests assert DSL-vs-Python equivalence.
+to JAX kernels, and tests assert DSL-vs-Python equivalence. A transform
+stated by its program alone, which every backend then interprets or lowers
+(a `dsl.WindowProgram` has no per-record hook form: its outputs are its
+closed windows'), decorates ``None``: ``smartmodule.aggregate(dsl=...)(None)``.
 """
 
 from __future__ import annotations
@@ -110,7 +113,10 @@ class _SmartModuleNamespace:
         m = _current()
         if kind in m.hooks or (dsl is not None and kind in m.dsl):
             raise ValueError(f"duplicate #[smartmodule({kind.value})] export")
-        m.hooks[kind] = fn
+        if fn is not None:
+            m.hooks[kind] = fn
+        elif dsl is None:
+            raise ValueError(f"#[smartmodule({kind.value})] exports nothing")
         if dsl is not None:
             m.dsl[kind] = dsl
         return fn

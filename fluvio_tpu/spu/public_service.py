@@ -391,7 +391,7 @@ def _schedule_chain_warmup(chain) -> None:
 
     tpu = getattr(chain, "tpu_chain", None)
     aot = adm_warmup.warmup_enabled()
-    if tpu is None or (tpu.agg_configs and not aot) or chain in _warmed_chains:
+    if tpu is None or (tpu.stateful and not aot) or chain in _warmed_chains:
         return
     _warmed_chains.add(chain)
     if aot:

@@ -246,7 +246,7 @@ def acquire_stream_chain(
         cacheable
         and tpu is not None
         and chain.backend_in_use == "tpu"
-        and not (tpu.agg_configs and tpu._sharded is not None)
+        and not (tpu.stateful and tpu._sharded is not None)
     ):
         ctx.stream_chains[key] = chain
         while len(ctx.stream_chains) > _STREAM_CHAIN_CACHE_MAX:
@@ -258,7 +258,7 @@ def acquire_stream_chain(
 def _stream_of(cached: SmartModuleChainInstance) -> SmartModuleChainInstance:
     """What a stream gets of a cached chain: the chain itself when it
     holds no state, else a stream of it with a state of its own."""
-    return cached.open_stream() if cached.tpu_chain.agg_configs else cached
+    return cached.open_stream() if cached.tpu_chain.stateful else cached
 
 
 async def ensure_dedup_chain(ctx: GlobalContext, leader: LeaderReplicaState) -> None:
@@ -660,7 +660,7 @@ def tpu_stage(
         # keep those single-chunk.
         n_total = merged["count"]
         chunk_rows = _DISPATCH_CHUNK_ROWS
-        stateless = not tpu.agg_configs and not tpu._fanout
+        stateless = not tpu.stateful and not tpu._fanout
         if stateless and n_total > chunk_rows * 3 // 2:
             bounds = list(range(0, n_total, chunk_rows)) + [n_total]
             if bounds[-1] == bounds[-2]:
@@ -1020,7 +1020,7 @@ def tpu_materialize(
     # `encode`: the chunks' one native pass each into the response slab,
     # then the response Batch
     with timed(flow, "encode"):
-        stateless = not tpu.agg_configs and not tpu._fanout
+        stateless = not tpu.stateful and not tpu._fanout
         resume = None
         if (
             stateless

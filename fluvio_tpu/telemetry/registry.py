@@ -632,6 +632,26 @@ class PipelineTelemetry:
             self.window_deltas[kind] = (
                 self.window_deltas.get(kind, 0) + rows
             )
+        if kind in ("late", "invalid"):
+            # dropped rows are rare and each batch of them is worth a
+            # date: the event ring lets a reader count a time window's
+            self._event("window-drop", f"{kind}:{rows}")
+
+    def add_window_slice(self, closed: int, late: int, invalid: int) -> None:
+        """What one served (or interpreted) window slice counted:
+        (key, window) entries closed, contributions dropped late, rows
+        dropped for a key out of range."""
+        self.add_windows_closed(closed)
+        for kind, rows in (("close", closed), ("late", late),
+                           ("invalid", invalid)):
+            self.add_window_delta(kind, rows)
+
+    def add_window_grow(self, detail: str) -> None:
+        """A served stream's window bank or emit columns outgrew their
+        shape: the slice is re-run under a doubled one, which compiles.
+        An instant event, beside the compile it causes (as
+        `add_chain_build`)."""
+        self._event("window-grow", detail)
 
     def add_window_downlink(self, delta_bytes: int, full_bytes: int) -> None:
         """One windowed batch's downlink split: bytes the delta

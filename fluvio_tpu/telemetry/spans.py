@@ -80,7 +80,7 @@ DEVICE_SCOPES = (
                     # recorded fixture (tests/benchmark) is reduced
                     # through this tuple
     "repad",        # ragged flat -> padded matrix, derived meta columns
-    "stage",        # stage<i>.<kind>: one chain stage's apply
+    "stage",        # stage<i>.<kind>, inner .aggregate_scan/.window_merge/.window_top
     "compact",      # survivor compaction, mask, header
     "pack",         # byte-mode payload / descriptor stream packing
     "link_encode",  # down-link glz encode of the packed stream
@@ -119,7 +119,11 @@ def stage_scope(index: int, kind: str) -> str:
     around the carry chain and the scan, so its contribution (the
     field extraction and parse) keeps `stage<i>.aggregate`; the inner
     name has the stage form because a reader names an operation by the
-    innermost path component that is one of these scopes."""
+    innermost path component that is one of these scopes. A window
+    stage (`stage<i>.window`: field spans, parses, window assignment)
+    opens `stage<i>.window_merge` (concat with the bank, one sort that
+    carries the columns, prefix sums, compaction, close, new bank) and
+    `stage<i>.window_top` (the per-window maximum) the same way."""
     return f"stage{index}.{kind}"
 
 

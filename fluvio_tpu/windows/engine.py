@@ -15,13 +15,13 @@ stage/dispatch/device/fetch seams, transient faults retried ONCE
 against the untouched carry (the bank commits only after the fetch
 succeeded), then re-raised. Every batch books a `BatchSpan` on the
 "windowed" path so BENCH_DETAIL's phase split shows where the wall
-went.
+went; its host phases go through `telemetry/spans.py:timed` like the
+executor's, and the span names the slice flow that caused it.
 """
 
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
@@ -29,6 +29,7 @@ import numpy as np
 
 from fluvio_tpu.resilience import faults
 from fluvio_tpu.telemetry import TELEMETRY
+from fluvio_tpu.telemetry.spans import timed
 from fluvio_tpu.windows.kernels import WindowJits
 from fluvio_tpu.windows.spec import WindowCapacityError, WindowSpec
 from fluvio_tpu.windows.state import ENTRY_BYTES, WindowStateBank
@@ -66,6 +67,17 @@ class WindowDelta:
         return int(self.ids.shape[0])
 
 
+def _fetch_bucket(n: int, cap: int) -> int:
+    """Rows a fetch slices for ``n`` live ones: a power of two from 8
+    (the executor's bucketed-jit discipline: XLA compiles each slice
+    shape ONCE, a per-batch ``n`` would pay a tiny-op compile a batch),
+    at most the column's length."""
+    rows = 8
+    while rows < n:
+        rows *= 2
+    return min(rows, cap)
+
+
 def _full_state_bytes(records: int) -> int:
     """What the classic per-record emission ships for the same batch:
     one i64 result + i64 window id per record, a validity bitmap, and
@@ -99,35 +111,40 @@ class WindowedRuntime:
 
     # -- ingest --------------------------------------------------------------
 
-    def process_buffer(self, buf) -> WindowDelta:
+    def process_buffer(self, buf, flow_id: int = 0) -> WindowDelta:
         """Fold one RecordBuffer; returns the batch's delta. Transient
         injected faults retry once against the identical carry (the
-        bank is untouched until the fetch lands)."""
+        bank is untouched until the fetch lands). ``flow_id`` names the
+        slice flow that caused the batch on its span (0 = none; a
+        buffer tagged with its flow names it itself)."""
+        if not flow_id:
+            flow = getattr(buf, "_flow", None)
+            if flow is not None:
+                flow_id = flow.batch_id
         for attempt in (0, 1):
             try:
-                return self._process_once(buf)
+                return self._process_once(buf, flow_id)
             except faults.InjectedFault as exc:
                 if not exc.transient or attempt:
                     raise
                 TELEMETRY.add_retry(exc.point)
 
-    def _process_once(self, buf) -> WindowDelta:
-        import jax
+    def _process_once(self, buf, flow_id: int = 0) -> WindowDelta:
         import jax.numpy as jnp
 
-        span = TELEMETRY.begin_batch("windowed", chain=self.spec.mode)
-        t_ph = time.perf_counter()
-        faults.maybe_fire("stage")
-        values = buf.dense_values()
-        n = values.shape[0]
-        count = int(buf.count)
-        # base_timestamp -1 is the buffer's "unset" sentinel
-        base = max(int(buf.base_timestamp), 0)
-        ts = np.asarray(buf.timestamp_deltas, dtype=np.int64) + base
-        valid = np.arange(n, dtype=np.int64) < count
-        lengths = np.asarray(buf.lengths, dtype=np.int32)
-        if span is not None:
-            span.add("stage", time.perf_counter() - t_ph)
+        span = TELEMETRY.begin_batch(
+            "windowed", chain=self.spec.mode, flow_id=flow_id
+        )
+        with timed(span, "stage"):
+            faults.maybe_fire("stage")
+            values = buf.dense_values()
+            n = values.shape[0]
+            count = int(buf.count)
+            # base_timestamp -1 is the buffer's "unset" sentinel
+            base = max(int(buf.base_timestamp), 0)
+            ts = np.asarray(buf.timestamp_deltas, dtype=np.int64) + base
+            valid = np.arange(n, dtype=np.int64) < count
+            lengths = np.asarray(buf.lengths, dtype=np.int32)
         return self._run(
             self.jits.update_values,
             (jnp.asarray(values), jnp.asarray(lengths),
@@ -157,17 +174,17 @@ class WindowedRuntime:
     def _run(self, update, batch_args, count: int, span) -> WindowDelta:
         import jax
 
-        t_ph = time.perf_counter()
-        faults.maybe_fire("dispatch")
-        outs = update(*self.bank.arrays(), *batch_args)
+        with timed(span, "dispatch"):
+            faults.maybe_fire("dispatch")
+            outs = update(*self.bank.arrays(), *batch_args)
         if span is not None:
-            span.add("dispatch", time.perf_counter() - t_ph)
             span.mark_dispatched()
         faults.maybe_fire("device")
         (header, nb_ids, nb_accs, nb_cnts,
          em_ids, em_accs, em_cnts, em_closed) = outs
         # first blocking sync: the scalar header (8 i64 = 64 bytes)
-        h = jax.device_get(header)
+        with timed(span, "wait"):
+            h = jax.device_get(header)
         if span is not None:
             span.mark_device_ready()
         faults.maybe_fire("fetch")
@@ -197,86 +214,56 @@ class WindowedRuntime:
         self.bank.commit(
             nb_ids, nb_accs, nb_cnts, header[4], n_open, new_wm
         )
+        # ONE bucketed device_get per batch: the emit columns' live
+        # prefix (a resync ships only its closed rows, which the kernel
+        # packs first and the guard above pinned within the columns)
+        # and, on a resync, the open-state image of the bank just
+        # committed — more changed rows than the emit columns hold, or
+        # the FLUVIO_WINDOW_DELTA=0 escape hatch: correct, just not
+        # delta-sized; the view folds the closes and replaces its open
+        # table from the image
+        n_cols = n_closed if resync else n_emit
+        fetch_rows = (
+            _fetch_bucket(n_cols, emit_cols) if n_cols or not resync else 0
+        )
+        parts = [em_ids[:fetch_rows], em_accs[:fetch_rows],
+                 em_cnts[:fetch_rows]]
         if resync:
-            # more changed rows than the emit columns hold — or the
-            # FLUVIO_WINDOW_DELTA=0 escape hatch: ship the batch's
-            # CLOSED rows (the compacted emit prefix — the kernel packs
-            # closes first, and the guard above pinned n_closed within
-            # the columns) plus ONE full open-state image (correct,
-            # just not delta-sized); the view folds the closes and
-            # replaces its open table from the image
-            t_ph = time.perf_counter()
-            if n_closed:
-                fetch_rows = 8
-                while fetch_rows < n_closed:
-                    fetch_rows *= 2
-                fetch_rows = min(fetch_rows, emit_cols)
-                # emit-buffer ledger window: the sliced device rows are
-                # live HBM until the host copy below materializes
-                TELEMETRY.mem_acquire(
-                    "emit_buffer", ("emit", id(self)),
-                    fetch_rows * ENTRY_BYTES,
-                )
-                try:
-                    cl_ids, cl_accs, cl_cnts = (
-                        np.asarray(a)[:n_closed]
-                        for a in jax.device_get(
-                            (em_ids[:fetch_rows], em_accs[:fetch_rows],
-                             em_cnts[:fetch_rows])
-                        )
-                    )
-                finally:
-                    TELEMETRY.mem_release(("emit", id(self)))
-                closed_bytes = fetch_rows * ENTRY_BYTES
-            else:
-                cl_ids = cl_accs = cl_cnts = np.zeros((0,), dtype=np.int64)
-                closed_bytes = 0
-            rows = self.bank.full_rows()
-            if span is not None:
-                span.add("d2h", time.perf_counter() - t_ph)
-            ids = np.concatenate([cl_ids, rows[:, 0]])
-            accs = np.concatenate([cl_accs, rows[:, 1]])
-            cnts = np.concatenate([cl_cnts, rows[:, 2]])
+            image_rows = _fetch_bucket(n_open, self.spec.capacity)
+            parts += [nb_ids[:image_rows], nb_accs[:image_rows],
+                      nb_cnts[:image_rows]]
+        else:
+            parts.append(em_closed[:fetch_rows])
+        # emit-buffer ledger window: the sliced device rows (3 i64 + 1
+        # i32 verdict column a row) are live HBM until the host copy
+        # below materializes
+        row_bytes = ENTRY_BYTES if resync else ENTRY_BYTES + 4
+        with timed(span, "d2h"):
+            TELEMETRY.mem_acquire(
+                "emit_buffer", ("emit", id(self)), fetch_rows * row_bytes
+            )
+            try:
+                host = [np.asarray(a) for a in jax.device_get(parts)]
+            finally:
+                TELEMETRY.mem_release(("emit", id(self)))
+        ids, accs, cnts = (a[:n_cols] for a in host[:3])
+        if resync:
+            ids, accs, cnts = (
+                np.concatenate([c, image[:n_open]])
+                for c, image in zip((ids, accs, cnts), host[3:])
+            )
             closed = np.zeros((ids.shape[0],), dtype=np.int32)
             closed[:n_closed] = 1
             kind = "rows-resync"
             delta_bytes = (
-                closed_bytes
-                + rows.shape[0] * ENTRY_BYTES
+                fetch_rows * ENTRY_BYTES
+                + n_open * ENTRY_BYTES
                 + DELTA_FRAME_BYTES
             )
         else:
-            # bucketed emit fetch: slice lengths quantize to powers of
-            # two (the executor's bucketed-jit discipline) so XLA
-            # compiles each slice shape ONCE — a per-batch n_emit slice
-            # would pay a fresh tiny-op compile every batch. The wire
-            # ships bucket rows; the host trims to n_emit.
-            fetch_rows = 8
-            while fetch_rows < n_emit:
-                fetch_rows *= 2
-            fetch_rows = min(fetch_rows, self.spec.emit_capacity)
-            t_ph = time.perf_counter()
-            # emit-buffer ledger window: 3 i64 + 1 i32 columns per
-            # bucket row stay device-live until this copy lands
-            TELEMETRY.mem_acquire(
-                "emit_buffer", ("emit", id(self)), fetch_rows * 28
-            )
-            try:
-                ids, accs, cnts, closed = jax.device_get(
-                    (em_ids[:fetch_rows], em_accs[:fetch_rows],
-                     em_cnts[:fetch_rows], em_closed[:fetch_rows])
-                )
-            finally:
-                TELEMETRY.mem_release(("emit", id(self)))
-            if span is not None:
-                span.add("d2h", time.perf_counter() - t_ph)
-            ids = np.asarray(ids)[:n_emit]
-            accs = np.asarray(accs)[:n_emit]
-            cnts = np.asarray(cnts)[:n_emit]
-            closed = np.asarray(closed)[:n_emit]
+            closed = host[3][:n_emit]
             kind = "rows"
-            # 3 i64 columns + 1 i32 verdict column per shipped row
-            delta_bytes = fetch_rows * 28 + DELTA_FRAME_BYTES
+            delta_bytes = fetch_rows * row_bytes + DELTA_FRAME_BYTES
         full_bytes = _full_state_bytes(count)
         self.batches += 1
         self.d2h_bytes_total += delta_bytes

@@ -68,35 +68,219 @@ def parse_two_ints(values, lengths) -> Tuple:
 # ---------------------------------------------------------------------------
 
 
-def _segment_merge(ids, accs, cnts, touched, op: str):
-    """Combine rows sharing a composite id: one argsort + segmented
-    scans; returns (n_entries, entry columns, live mask), entries
-    compacted to the front with empty slots re-marked EMPTY_ID."""
+_SCAN_LANE = 128
+
+
+def prefix_sum(x):
+    """Inclusive prefix sum of a vector, exact (integer adds), as a
+    blocked two-level scan: rows of 128 are scanned by a reduce-window
+    no wider than a row, the rows' totals by the same scan one level
+    up. It is the tree the chip's compiler makes of `jnp.cumsum`'s one
+    long reduce-window itself, stated here so that its operations keep
+    the caller's `jax.named_scope`: the compiler's rewritten ones carry
+    no `op_name`, and `jnp.cumsum`'s lowering on a TPU drops the name
+    stack (22 ms a million bids of the merge's device time booked to no
+    scope; PERF.md section 6, PR 35)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def row_scan(rows):
+        w = rows.shape[1]
+        return lax.reduce_window(
+            rows, np.zeros((), rows.dtype), lax.add,
+            (1, w), (1, 1), [(0, 0), (w - 1, 0)],
+        )
+
+    n = x.shape[0]
+    if n <= _SCAN_LANE:
+        return row_scan(x[None, :])[0] if n else x
+    rows = jnp.pad(x, (0, -n % _SCAN_LANE)).reshape(-1, _SCAN_LANE)
+    inner = row_scan(rows)
+    through = prefix_sum(inner[:, -1])
+    before = jnp.concatenate([jnp.zeros((1,), x.dtype), through[:-1]])
+    return (inner + before[:, None]).reshape(-1)[:n]
+
+
+def compact_front(mask, cap: int, *arrays):
+    """The first ``cap`` rows of ``arrays`` where ``mask`` holds, in
+    order: (rows kept by the mask — MAY exceed ``cap`` —, the packed
+    columns, zeros past the count). By GATHER: a row's rank is a prefix
+    sum of the mask, output slot k binary-searches the row of rank k + 1,
+    so the cost is ``cap`` lookups and not one scattered write a row
+    (`smartengine.tpu.kernels.compact_rows`: on the chip a scatter of
+    1.4 M int64 rows took 82 ms, eight of them three quarters of a
+    window slice; PERF.md section 6, PR 35)."""
     import jax.numpy as jnp
 
-    from fluvio_tpu.smartengine.tpu.kernels import compact_rows, segmented_scan
-
-    m = ids.shape[0]
-    order = jnp.argsort(ids)
-    sid = jnp.take(ids, order)
-    sacc = jnp.take(accs, order)
-    scnt = jnp.take(cnts, order)
-    stb = jnp.take(touched, order)
-    change = sid[1:] != sid[:-1]
-    head = jnp.concatenate([jnp.ones((1,), bool), change])
-    tail = jnp.concatenate([change, jnp.ones((1,), bool)])
-    acc_run = segmented_scan(sacc, head, op)
-    cnt_run = segmented_scan(scnt, head, "add")
-    tb_run = segmented_scan(stb, head, "add")
-    is_entry = tail & (sid != EMPTY_ID)
-    n_entries, (e_ids, e_accs, e_cnts, e_tb) = compact_rows(
-        is_entry, sid, acc_run, cnt_run, tb_run
+    cap = min(cap, mask.shape[0])
+    rank = prefix_sum(mask.astype(jnp.int32))
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    src = jnp.minimum(
+        jnp.searchsorted(rank, slot + 1, side="left"), mask.shape[0] - 1
     )
-    # compact_rows zero-fills dropped slots; a zero id is a REAL
-    # composite id (key 0, window 0), so dead slots must be re-marked
-    live = jnp.arange(m, dtype=jnp.int32) < n_entries
+    live = slot < rank[-1]
+    return rank[-1], tuple(
+        jnp.where(
+            live,
+            # `src` is in bounds already; the default fill mode lowers
+            # through a function that drops the caller's scope
+            jnp.take(arr, src, mode="clip"),
+            jnp.zeros((), arr.dtype),
+        )
+        for arr in arrays
+    )
+
+
+def _segment_merge(ids, accs, cnts, touched, op: str, entry_cap: int):
+    """Combine rows sharing a composite id: ONE sort that carries the
+    columns along, prefix sums, and the segments' totals read at their
+    last rows. Returns (sorted ids and the is-entry mask over all rows,
+    for exact counts; then the first ``entry_cap`` entries, compacted to
+    the front: ids — empty slots re-marked EMPTY_ID —, accs, counts,
+    touched-or-None, live mask). A caller whose merge may hold more
+    entries than ``entry_cap`` reads the overflow from the counts.
+    ``touched`` None (nobody reads which entries this batch touched:
+    the bank merge, the served top-of-window update) drops that column."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from fluvio_tpu.smartengine.tpu.kernels import segmented_scan
+
+    cols = [ids, accs, cnts] + ([touched] if touched is not None else [])
+    # the monoids commute, so ties need no order: an unstable sort
+    sid, sacc, *counted = lax.sort(cols, num_keys=1, is_stable=False)
+    change = sid[1:] != sid[:-1]
+    tail = jnp.concatenate([change, jnp.ones((1,), bool)])
+    is_entry = tail & (sid != EMPTY_ID)
+    if op == "add":
+        acc_col = prefix_sum(sacc)
+    else:
+        head = jnp.concatenate([jnp.ones((1,), bool), change])
+        acc_col = segmented_scan(sacc, head, op)
+    n_kept, (e_ids, e_accs, *e_sums) = compact_front(
+        is_entry, entry_cap, sid, acc_col, *map(prefix_sum, counted)
+    )
+    live = jnp.arange(e_ids.shape[0], dtype=jnp.int32) < n_kept
+    # a zero id is a REAL composite id (key 0, window 0): dead slots
+    # must be re-marked
     e_ids = jnp.where(live, e_ids, EMPTY_ID)
-    return n_entries, e_ids, e_accs, e_cnts, e_tb, live
+
+    def total(prefix):
+        # a segment's total is the prefix sum at its last row less the
+        # one at the entry before (empties sort last: no entry follows one)
+        return prefix - jnp.concatenate(
+            [jnp.zeros((1,), prefix.dtype), prefix[:-1]]
+        )
+
+    if op == "add":
+        e_accs = total(e_accs)
+    e_cnts, *e_tb = map(total, e_sums)
+    return sid, is_entry, e_ids, e_accs, e_cnts, (e_tb or [None])[0], live
+
+
+def _assign_windows(
+    window_ms: int, slide_ms: int, fanout: int, lateness_ms: int,
+    neutral: int, watermark, contribs, keys, ts, valid,
+):
+    """Window assignment of one batch: every record replicated over the
+    ``fanout`` window phases that hold its event time (tumbling is
+    fanout == 1). Returns (ids, accs, counts — the flat ``n * fanout``
+    rows the merge folds into the bank —, the batch's largest valid
+    event time, late rows, invalid rows)."""
+    import jax.numpy as jnp
+
+    # composite-id packing only holds for keys in [0, KEY_STRIDE): an
+    # out-of-range key would silently alias into another key's window-id
+    # space (or overflow int64). Such rows are invalid — counted in the
+    # header and dropped entirely (no fold, no watermark advance), the
+    # same drop-not-corrupt rule as late rows; reference.py mirrors it.
+    key_ok = (keys >= 0) & (keys < KEY_STRIDE)
+    invalid = valid & ~key_ok
+    valid = valid & key_ok
+    base_idx = jnp.where(valid, ts // slide_ms, 0)
+    j = jnp.arange(fanout, dtype=jnp.int64)
+    win_idx = base_idx[:, None] - j[None, :]
+    rep_valid = valid[:, None] & (win_idx >= 0)
+    win_end = win_idx * slide_ms + window_ms
+    # late vs the PRE-batch watermark: the window already closed in an
+    # earlier batch, so folding this row in would re-open it — count
+    # and drop instead (the host reference applies the same rule)
+    late = rep_valid & (win_end + lateness_ms <= watermark)
+    rep_valid = rep_valid & ~late
+    ids = jnp.where(
+        rep_valid, keys[:, None] * KEY_STRIDE + win_idx, EMPTY_ID
+    )
+    rep_acc = jnp.where(rep_valid, contribs[:, None], neutral)
+    batch_max = jnp.max(
+        jnp.where(valid, ts, jnp.int64(INT64_MIN + 1)), initial=INT64_MIN + 1
+    )
+    return (
+        ids.reshape(-1), rep_acc.reshape(-1),
+        rep_valid.reshape(-1).astype(jnp.int64), batch_max,
+        jnp.sum(late).astype(jnp.int64), jnp.sum(invalid).astype(jnp.int64),
+    )
+
+
+def _merge_and_close(
+    window_ms: int, slide_ms: int, lateness_ms: int, op: str,
+    track_touched: bool, entry_cap: int, bank_ids, bank_accs, bank_cnts,
+    watermark, ids, accs, cnts, batch_max,
+):
+    """Fold a batch's assigned rows into the bank, advance the
+    watermark, and say which entries close. Returns (entry ids, accs,
+    counts, touched-or-None, closed mask, open mask — over the first
+    ``entry_cap`` entries —, new watermark, open entries, closed
+    entries: both counted over ALL rows, so exact whatever
+    ``entry_cap`` kept). A row's count doubles as its touched flag."""
+    import jax.numpy as jnp
+
+    touched = None
+    if track_touched:
+        touched = jnp.concatenate(
+            [jnp.zeros(bank_ids.shape, dtype=jnp.int64), cnts]
+        )
+    sid, is_entry, e_ids, e_accs, e_cnts, e_tb, live = _segment_merge(
+        jnp.concatenate([bank_ids, ids]),
+        jnp.concatenate([bank_accs, accs]),
+        jnp.concatenate([bank_cnts, cnts]),
+        touched,
+        op,
+        entry_cap,
+    )
+    new_wm = jnp.maximum(watermark, batch_max)
+
+    def closes(entry_ids, entry):
+        win_end = jnp.where(entry, entry_ids % KEY_STRIDE, 0) * slide_ms + window_ms
+        return entry & (win_end + lateness_ms <= new_wm)
+
+    closed_rows = closes(sid, is_entry)
+    n_closed = jnp.sum(closed_rows).astype(jnp.int64)
+    n_open = jnp.sum(is_entry).astype(jnp.int64) - n_closed
+    closed = closes(e_ids, live)
+    return (e_ids, e_accs, e_cnts, e_tb, closed, live & ~closed, new_wm,
+            n_open, n_closed)
+
+
+def _new_bank(open_m, e_ids, e_accs, e_cnts, capacity: int, neutral: int):
+    """The open entries, compacted to ``capacity`` bank rows (ids,
+    accs, counts)."""
+    import jax.numpy as jnp
+
+    n_open, (o_ids, o_accs, o_cnts) = compact_front(
+        open_m, capacity, e_ids, e_accs, e_cnts
+    )
+    pad = capacity - o_ids.shape[0]  # fewer entries than bank rows
+    if pad:
+        o_ids, o_accs, o_cnts = (
+            jnp.concatenate([c, jnp.zeros((pad,), c.dtype)])
+            for c in (o_ids, o_accs, o_cnts)
+        )
+    in_bank = jnp.arange(capacity, dtype=jnp.int32) < n_open
+    return (
+        jnp.where(in_bank, o_ids, EMPTY_ID),
+        jnp.where(in_bank, o_accs, jnp.int64(neutral)),
+        jnp.where(in_bank, o_cnts, jnp.int64(0)),
+    )
 
 
 def _update_core(
@@ -123,58 +307,18 @@ def _update_core(
     retries against the identical carry — exactness under chaos comes
     for free instead of from an undo path."""
     import jax.numpy as jnp
-    from jax import lax
 
-    from fluvio_tpu.smartengine.tpu.kernels import compact_rows
-
-    n = contribs.shape[0]
-    # composite-id packing only holds for keys in [0, KEY_STRIDE): an
-    # out-of-range key would silently alias into another key's window-id
-    # space (or overflow int64). Such rows are invalid — counted in the
-    # header and dropped entirely (no fold, no watermark advance), the
-    # same drop-not-corrupt rule as late rows; reference.py mirrors it.
-    key_ok = (keys >= 0) & (keys < KEY_STRIDE)
-    invalid = valid & ~key_ok
-    valid = valid & key_ok
-    # -- window assignment (sliding replicates each record over the
-    # fanout window phases; tumbling is fanout == 1) -------------------------
-    base_idx = jnp.where(valid, ts // slide_ms, 0)
-    j = jnp.arange(fanout, dtype=jnp.int64)
-    win_idx = base_idx[:, None] - j[None, :]
-    rep_valid = valid[:, None] & (win_idx >= 0)
-    win_end = win_idx * slide_ms + window_ms
-    # late vs the PRE-batch watermark: the window already closed in an
-    # earlier batch, so folding this row in would re-open it — count
-    # and drop instead (the host reference applies the same rule)
-    late = rep_valid & (win_end + lateness_ms <= watermark)
-    rep_valid = rep_valid & ~late
-    ids = jnp.where(
-        rep_valid, keys[:, None] * KEY_STRIDE + win_idx, EMPTY_ID
+    ids, accs, cnts, batch_max, n_late, n_invalid = _assign_windows(
+        window_ms, slide_ms, fanout, lateness_ms, neutral, watermark,
+        contribs, keys, ts, valid,
     )
-    rep_acc = jnp.where(rep_valid, contribs[:, None], neutral)
-    rep_cnt = rep_valid.astype(jnp.int64)
-    # -- merge into the bank -------------------------------------------------
-    all_ids = jnp.concatenate([bank_ids, ids.reshape(-1)])
-    all_accs = jnp.concatenate([bank_accs, rep_acc.reshape(-1)])
-    all_cnts = jnp.concatenate([bank_cnts, rep_cnt.reshape(-1)])
-    all_tb = jnp.concatenate(
-        [
-            jnp.zeros((capacity,), dtype=jnp.int64),
-            rep_valid.reshape(-1).astype(jnp.int64),
-        ]
+    # more entries than bank and emit rows together is an overflow of
+    # one of them, which the header reports from the exact counts
+    (e_ids, e_accs, e_cnts, e_tb, closed, open_m, new_wm, n_open,
+     n_closed) = _merge_and_close(
+        window_ms, slide_ms, lateness_ms, op, True, capacity + emit_cap,
+        bank_ids, bank_accs, bank_cnts, watermark, ids, accs, cnts, batch_max,
     )
-    n_entries, e_ids, e_accs, e_cnts, e_tb, live = _segment_merge(
-        all_ids, all_accs, all_cnts, all_tb, op
-    )
-    # -- watermark + closing -------------------------------------------------
-    batch_max = jnp.max(
-        jnp.where(valid, ts, jnp.int64(INT64_MIN + 1)), initial=INT64_MIN + 1
-    )
-    new_wm = jnp.maximum(watermark, batch_max)
-    e_win_idx = jnp.where(live, e_ids % KEY_STRIDE, 0)
-    e_win_end = e_win_idx * slide_ms + window_ms
-    closed = live & (e_win_end + lateness_ms <= new_wm)
-    open_m = live & ~closed
     # -- delta emission: closed windows always ship; open entries ship
     # only when this batch touched them (delta_only off = full state).
     # Closed rows compact FIRST (the two-block concat keeps them ahead
@@ -185,8 +329,10 @@ def _update_core(
     # closed windows live nowhere else.
     emit_open = (open_m & (e_tb > 0)) if delta_only else open_m
     m = e_ids.shape[0]
-    n_emit, (m_ids, m_accs, m_cnts, m_closed) = compact_rows(
+    e_slice = min(emit_cap, 2 * m)
+    n_emit, (em_ids, em_accs, em_cnts, em_closed) = compact_front(
         jnp.concatenate([closed, emit_open]),
+        e_slice,
         jnp.concatenate([e_ids, e_ids]),
         jnp.concatenate([e_accs, e_accs]),
         jnp.concatenate([e_cnts, e_cnts]),
@@ -195,34 +341,19 @@ def _update_core(
         ),
     )
     # -- new bank: open entries only, compacted to capacity ------------------
-    n_open, (o_ids, o_accs, o_cnts, _o_tb) = compact_rows(
-        open_m, e_ids, e_accs, e_cnts, e_tb
+    nb_ids, nb_accs, nb_cnts = _new_bank(
+        open_m, e_ids, e_accs, e_cnts, capacity, neutral
     )
-    slot = jnp.arange(capacity, dtype=jnp.int32)
-    in_bank = slot < n_open
-    nb_ids = jnp.where(in_bank, lax.slice(o_ids, (0,), (capacity,)), EMPTY_ID)
-    nb_accs = jnp.where(
-        in_bank, lax.slice(o_accs, (0,), (capacity,)), jnp.int64(neutral)
-    )
-    nb_cnts = jnp.where(
-        in_bank, lax.slice(o_cnts, (0,), (capacity,)), jnp.int64(0)
-    )
-    # -- bounded emit columns + scalar header --------------------------------
-    e_slice = min(emit_cap, m_ids.shape[0])
-    em_ids = lax.slice(m_ids, (0,), (e_slice,))
-    em_accs = lax.slice(m_accs, (0,), (e_slice,))
-    em_cnts = lax.slice(m_cnts, (0,), (e_slice,))
-    em_closed = lax.slice(m_closed, (0,), (e_slice,))
     header = jnp.stack(
         [
             n_emit.astype(jnp.int64),
-            n_open.astype(jnp.int64),
-            jnp.sum(closed).astype(jnp.int64),
-            jnp.sum(late).astype(jnp.int64),
+            n_open,
+            n_closed,
+            n_late,
             new_wm,
             (n_open > capacity).astype(jnp.int64),
             (n_emit > e_slice).astype(jnp.int64),
-            jnp.sum(invalid).astype(jnp.int64),
+            n_invalid,
         ]
     )
     return (
@@ -237,36 +368,136 @@ def _update_core(
     )
 
 
+# header slots of `update_top` (all int64)
+TOP_HEADER = (
+    "n_rows", "n_open", "n_closed", "n_late", "watermark", "n_invalid",
+)
+
+
+def update_top(
+    window_ms: int,
+    slide_ms: int,
+    lateness_ms: int,
+    op: str,
+    emit_cap: int,
+    emit_all: bool,
+    bank,
+    contribs,
+    keys,
+    ts,
+    valid,
+    merge_scope: str = "window_merge",
+    top_scope: str = "window_top",
+):
+    """The SERVED window update (traced inside a chain's one program
+    for a slice; `smartengine/tpu/executor.py:_WindowStage`): the same
+    assignment, fold and close as `_update_core` (the assignment under
+    the caller's scope, the rest under ``merge_scope``), and in place of the materialized
+    view's delta the ANSWER of the closed windows, so that what crosses
+    the down-link is rows of (window end, key, aggregate), not every
+    (key, window) aggregate.
+
+    ``bank`` is (ids, accs, counts, watermark); its capacity is the
+    arrays' length. The slice's closed rows (at most ``emit_cap``; more
+    is the caller's overflow: it re-runs the slice under a larger
+    shape) are re-sorted by (window, key); with ``emit_all`` off only
+    the key(s) whose aggregate equals their window's maximum stay.
+    Returns (header [`TOP_HEADER`], new bank arrays, rows i64[emit_cap,
+    3] ordered by (window end, key), the first ``n_rows`` live). The
+    caller compares ``n_open`` with the capacity and ``n_closed`` with
+    ``emit_cap``; past either the other outputs are not to be read."""
+    import jax
+    import jax.numpy as jnp
+
+    from fluvio_tpu.smartengine.tpu.kernels import segmented_scan
+    from fluvio_tpu.windows.spec import OP_NEUTRAL
+
+    bank_ids, bank_accs, bank_cnts, watermark = bank
+    capacity = bank_ids.shape[0]
+    neutral = OP_NEUTRAL[op]
+    ids, accs, cnts, batch_max, n_late, n_invalid = _assign_windows(
+        window_ms, slide_ms, window_ms // slide_ms, lateness_ms, neutral,
+        watermark, contribs, keys, ts, valid,
+    )
+    with jax.named_scope(merge_scope):
+        (e_ids, e_accs, e_cnts, _tb, closed, open_m, new_wm, n_open,
+         n_closed) = _merge_and_close(
+            window_ms, slide_ms, lateness_ms, op, False, capacity + emit_cap,
+            bank_ids, bank_accs, bank_cnts, watermark,
+            ids, accs, cnts, batch_max,
+        )
+        nb_ids, nb_accs, nb_cnts = _new_bank(
+            open_m, e_ids, e_accs, e_cnts, capacity, neutral
+        )
+        _n, (c_ids, c_accs) = compact_front(closed, emit_cap, e_ids, e_accs)
+        cap = c_ids.shape[0]
+    with jax.named_scope(top_scope):
+        # (key, window) order -> (window, key) order: the closed rows
+        # only, so this sort is over the emit capacity, not the merge's
+        c_live = jnp.arange(cap, dtype=jnp.int32) < n_closed
+        by_win = jnp.where(
+            c_live,
+            (c_ids % KEY_STRIDE) * KEY_STRIDE + c_ids // KEY_STRIDE,
+            EMPTY_ID,
+        )
+        order = jnp.argsort(by_win)
+        s_id = jnp.take(by_win, order, mode="clip")
+        s_acc = jnp.take(c_accs, order, mode="clip")
+        keep = s_id != EMPTY_ID
+        if not emit_all:
+            s_win = s_id // KEY_STRIDE
+            change = s_win[1:] != s_win[:-1]
+            head = jnp.concatenate([jnp.ones((1,), bool), change])
+            tail = jnp.concatenate([change, jnp.ones((1,), bool)])
+            # a window's maximum: the running maximum at its last row,
+            # carried back over the window by the same scan reversed
+            run = segmented_scan(
+                jnp.where(keep, s_acc, jnp.int64(INT64_MIN)), head, "max"
+            )
+            win_max = segmented_scan(run[::-1], tail[::-1], "max")[::-1]
+            keep = keep & (s_acc == win_max)
+        n_rows, (t_id, t_acc) = compact_front(keep, cap, s_id, s_acc)
+        rows = jnp.stack(
+            [
+                (t_id // KEY_STRIDE) * slide_ms + window_ms,
+                t_id % KEY_STRIDE,
+                t_acc,
+            ],
+            axis=1,
+        )
+    header = jnp.stack(
+        [
+            n_rows.astype(jnp.int64),
+            n_open,
+            n_closed,
+            n_late,
+            new_wm,
+            n_invalid,
+        ]
+    )
+    return header, (nb_ids, nb_accs, nb_cnts, new_wm), rows
+
+
 def _merge_core(op: str, neutral: int, capacity: int, a, b):
     """Associative bank combine for striped/sharded ingest: two banks'
     entries merge into one (watermark = max). No closing and no
     emission here — those happen at the next `update` against the
     merged bank, so split ingest stays bit-equal to serial ingest."""
     import jax.numpy as jnp
-    from jax import lax
-
-    from fluvio_tpu.smartengine.tpu.kernels import compact_rows
 
     a_ids, a_accs, a_cnts, a_wm = a
     b_ids, b_accs, b_cnts, b_wm = b
-    ids = jnp.concatenate([a_ids, b_ids])
-    accs = jnp.concatenate([a_accs, b_accs])
-    cnts = jnp.concatenate([a_cnts, b_cnts])
-    tb = jnp.zeros_like(cnts)
-    _n, e_ids, e_accs, e_cnts, _tb, live = _segment_merge(
-        ids, accs, cnts, tb, op
+    _sid, is_entry, e_ids, e_accs, e_cnts, _tb, live = _segment_merge(
+        jnp.concatenate([a_ids, b_ids]),
+        jnp.concatenate([a_accs, b_accs]),
+        jnp.concatenate([a_cnts, b_cnts]),
+        None,
+        op,
+        a_ids.shape[0] + b_ids.shape[0],
     )
-    n_open, (o_ids, o_accs, o_cnts, _o) = compact_rows(
-        live, e_ids, e_accs, e_cnts, e_cnts
-    )
-    slot = jnp.arange(capacity, dtype=jnp.int32)
-    in_bank = slot < n_open
-    nb_ids = jnp.where(in_bank, lax.slice(o_ids, (0,), (capacity,)), EMPTY_ID)
-    nb_accs = jnp.where(
-        in_bank, lax.slice(o_accs, (0,), (capacity,)), jnp.int64(neutral)
-    )
-    nb_cnts = jnp.where(
-        in_bank, lax.slice(o_cnts, (0,), (capacity,)), jnp.int64(0)
+    n_open = jnp.sum(is_entry)
+    nb_ids, nb_accs, nb_cnts = _new_bank(
+        live, e_ids, e_accs, e_cnts, capacity, neutral
     )
     header = jnp.stack(
         [

@@ -64,6 +64,42 @@ class WindowStateBank:
         self.watermark = int(watermark)
         self._note_ledger()
 
+    @property
+    def capacity(self) -> int:
+        """Entries the device arrays hold (the spec's, until `grow`)."""
+        return int(self.ids.shape[0])
+
+    def grow(self, capacity: int) -> None:
+        """Pad the device arrays to ``capacity`` entries (never
+        shrinks): a served stream's bank starts small and doubles when
+        a slice's header reports more open entries than it holds. The
+        live entries stay compacted at the front."""
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        pad = capacity - self.capacity
+        if pad <= 0:
+            return
+        self.ids = jnp.concatenate(
+            [self.ids, jnp.full((pad,), EMPTY_ID, dtype=jnp.int64)]
+        )
+        self.accs = jnp.concatenate(
+            [self.accs, jnp.full((pad,), self.spec.neutral, dtype=jnp.int64)]
+        )
+        self.counts = jnp.concatenate(
+            [self.counts, jnp.zeros((pad,), dtype=jnp.int64)]
+        )
+        self.spec = dataclasses.replace(self.spec, capacity=capacity)
+
+    def checkpoint(self) -> tuple:
+        """`commit`'s arguments for putting the bank back where it
+        stands (a served slice that declines after its commit is re-run
+        from where it started): references only, device arrays are
+        immutable."""
+        return (self.ids, self.accs, self.counts, self.wm,
+                self.occupancy, self.watermark)
+
     def state_bytes(self) -> int:
         """Live device bytes (the `window_state_bytes` gauge)."""
         return self.occupancy * ENTRY_BYTES + 8

@@ -181,6 +181,9 @@ def _program_args(ex, probe, rows, flat_bytes, sharding):
     carries = tuple(
         (i64(), i64(), _sds((), jnp.bool_, sharding)) for _ in ex.carries
     )
+    if ex._window is not None:   # a window chain's carry is its bank
+        col = _sds((ex._window.capacity,), jnp.int64, sharding)
+        carries = (col, col, col, i64())
     args = (
         _sds((bucket // 4,), jnp.int32, sharding),
         _sds((rows,), lengths_up.dtype, sharding),
@@ -239,6 +242,61 @@ def test_ragged_aggregate(one_chip, as_tpu):
     ex = _chain([("aggregate-field", {"field": "n", "combine": "add"})])
     hlo = _compile_ragged(ex, _json_probe(), one_chip)
     assert "tpu_custom_call" in hlo  # the Pallas JSON span feeds the sum
+
+
+def test_ragged_window_q5(one_chip, as_tpu):
+    """`q5-drain`'s slice: 147,456 bids of at most 120 B (262,144 rows x
+    128), five window phases a bid sort-merged into a bank grown to
+    65,536 entries, the per-window maximum over 65,536 emit rows."""
+    import json
+    from pathlib import Path
+
+    from fluvio_tpu.smartengine import SmartEngine, SmartModuleConfig
+    from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
+    from fluvio_tpu.protocol.record import Record
+
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmark" /
+                      "configs" / "fluvio-nexmark-q5-1p.json").read_text())
+    b = SmartEngine(backend="tpu").builder()
+    b.add_smart_module(SmartModuleConfig(), cfg["chain"][0]["adhoc"])
+    ex = b.initialize().tpu_chain
+    ex._window.capacity = ex._window.emit = 1 << 16
+    records = [Record(value=b"x" * 120, offset_delta=i) for i in range(8)]
+    probe = RecordBuffer.from_records(records)
+    args, kwargs = _program_args(ex, probe, 1 << 18, 15_000_000, one_chip)
+    enc, pack = ex._down_axes(False)
+    assert (enc, pack) == ("off", False)
+    hlo = _compile(
+        ex._jit_ragged.__wrapped__, *args, width=probe.width,
+        fanout_cap=ex._fanout_cap(probe), enc=enc, pack=pack, **kwargs,
+    )
+    assert probe.width == 128 and "sort" in hlo
+
+
+def test_window_scans_and_gathers_keep_their_scope(one_chip, as_tpu):
+    """`device_named_share` books an operation by the scope in its
+    `op_name`. `jnp.cumsum` (on a TPU) and `jnp.take` (fill mode) lower
+    through functions that drop the name stack, and the compiler's
+    rewrite of one long reduce-window keeps no metadata at all: the
+    merge's prefix sums were 2.2 % of `q5-drain`'s device time under no
+    scope (PR 35). `prefix_sum` and `compact_front` keep theirs."""
+    from fluvio_tpu.windows.kernels import compact_front, prefix_sum
+
+    def fn(mask, x):
+        with jax.named_scope("stage0.window_merge"):
+            n, (packed,) = compact_front(mask, 4096, prefix_sum(x))
+            return n, packed
+
+    hlo = _compile(
+        jax.jit(fn),
+        _sds((1 << 16,), jnp.bool_, one_chip),
+        _sds((1 << 16,), jnp.int64, one_chip),
+    )
+    ops = [ln for ln in hlo.splitlines()
+           if " reduce-window(" in ln or " gather(" in ln]
+    assert len(ops) >= 4
+    for ln in ops:
+        assert "stage0.window_merge" in ln, ln[:200]
 
 
 def _compile_striped(ex, n_records, sharding):
