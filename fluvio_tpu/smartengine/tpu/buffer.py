@@ -30,7 +30,8 @@ MAX_WIDTH = 1 << 16
 MAX_RECORD_WIDTH = 1 << 20
 # int32 addressing ceiling for one staged batch: every flat byte
 # offset downstream of here is i32 — host `starts`, the device cumsum
-# of aligned lengths (`ragged_repad_words`, `striped_repad_words`),
+# of aligned lengths (`ragged_repad_words`, `striped_repad_words`) and
+# the block and shift arithmetic on it (`kernels.rows_from_word_starts`),
 # and the packed-payload destination indices. A batch past this must
 # be refused loudly (shard it / smaller slices), never wrapped; the
 # valueflow analyzer's FLV302/FLV303 noqas at those sites cite THIS

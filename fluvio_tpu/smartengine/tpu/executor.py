@@ -364,30 +364,23 @@ class _AggregateStage:
 def ragged_repad_words(flat, lengths, width: int):
     """Device-side re-pad of a 4-aligned ragged upload (traced).
 
-    One gather rebuilds the padded value matrix; the host link only
-    carried sum(lengths) bytes. The flat is i32 words — 4x fewer gather
-    elements than per-byte, which is what the TPU's gather throughput is
-    sensitive to. Shared by the single-device ragged dispatch and the
-    per-shard rebuild in `parallel/sharded.py` (one implementation: a
-    re-pad fix cannot land in one path and miss the other). Returns
+    The host link only carried sum(lengths) bytes; the padded value
+    matrix is rebuilt here. Row ``r`` is the ``width // 4`` consecutive
+    i32 words of the flat that start at the record's word offset, so the
+    rebuild fetches whole aligned blocks a row and shifts them into
+    place (`kernels.rows_from_word_starts`) instead of gathering one
+    index a word, which is what the TPU's gather throughput is sensitive
+    to. Shared by the single-device ragged dispatch and the per-shard
+    rebuild in `parallel/sharded.py` (one implementation: a re-pad fix
+    cannot land in one path and miss the other). Returns
     (values uint8[n, width], lengths int32[n])."""
     lengths = lengths.astype(jnp.int32)
-    n = lengths.shape[0]
     lengths4 = (lengths + 3) & ~3
     # i32 accumulator is safe: buffer.check_flat_addressing refused any
     # batch whose 4-aligned flat exceeds i32 before it staged
     word_starts = (jnp.cumsum(lengths4) - lengths4) >> 2  # noqa: FLV303
-    wwidth = width // 4
-    jw = jnp.arange(wwidth, dtype=jnp.int32)[None, :]
-    widx = word_starts[:, None] + jw
-    words = jnp.take(flat, jnp.clip(widx, 0, flat.shape[0] - 1), axis=0)
-    # unpack LE bytes from words: byte k of word w = (w >> 8k) & 0xFF
-    shifts = jnp.arange(4, dtype=jnp.int32)[None, None, :] * 8
-    unpacked = (words[:, :, None] >> shifts) & 0xFF
-    gathered = unpacked.reshape(n, width)
-    jidx = jnp.arange(width, dtype=jnp.int32)[None, :]
-    mask = jidx < lengths[:, None]
-    return jnp.where(mask, gathered, 0).astype(jnp.uint8), lengths
+    words = kernels.rows_from_word_starts(flat, word_starts, width // 4)
+    return kernels.unpack_row_bytes(words, lengths), lengths
 
 
 def derived_meta_columns(
@@ -1189,11 +1182,13 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
     ):
         """Reconstruct the padded matrix on device from the flat upload.
 
-        One gather re-pads; the host link only carried sum(lengths) bytes
-        (plus bucketing) instead of rows x width. The flat staging is
-        4-byte aligned per record, so the gather moves i32 words — 4x
-        fewer gather elements than per-byte, which is what the TPU's
-        gather throughput is sensitive to. Derivable columns never cross
+        The host link only carried sum(lengths) bytes (plus bucketing)
+        instead of rows x width. The flat staging is 4-byte aligned per
+        record, so a row is consecutive i32 words of the flat and the
+        re-pad fetches aligned blocks and shifts them into place
+        (`ragged_repad_words`): no gather index per byte or per word,
+        which is what the TPU's gather throughput is sensitive to.
+        Derivable columns never cross
         the link: row starts come from a device cumsum of the aligned
         lengths, arange offset deltas (``has_offsets=False``) and zero
         timestamp deltas (``ts_mode='zero'``) are synthesized, and

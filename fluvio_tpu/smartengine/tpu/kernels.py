@@ -993,6 +993,72 @@ def global_any(flag, axis_name=None):
     return jnp.any(lax.all_gather(local, axis_name))
 
 
+# ---------------------------------------------------------------------------
+# Ragged flat -> padded rows
+# ---------------------------------------------------------------------------
+
+# words of one aligned block of the flat: one full lane row of the chip,
+# so the block fetch is a ROW gather of the [blocks, 128] view
+ROW_BLOCK_WORDS = 128
+
+
+def _shift_rows_left(win: jnp.ndarray, shift: jnp.ndarray, keep: int, bits: int):
+    """``win[r, shift[r] : shift[r] + keep]`` for ``shift < 2**bits``: a
+    barrel shifter along the lane axis, one static slice pair a bit of
+    the shift, highest bit first so the window narrows as it goes (after
+    bit ``b`` the shift left over is under ``2**b``). Element-wise work,
+    no index per word. ``win`` is at least ``keep + 2**bits - 1`` wide."""
+    for b in reversed(range(bits)):
+        step = 1 << b
+        width = keep + step - 1
+        moved = ((shift >> b) & 1).astype(bool)[:, None]
+        win = jnp.where(moved, win[:, step:step + width], win[:, :width])
+    return win
+
+
+def rows_from_word_starts(flat: jnp.ndarray, word_starts: jnp.ndarray, wwidth: int):
+    """int32[rows, wwidth] with ``out[r, j] = flat[word_starts[r] + j]``
+    wherever that word lies inside the flat (a start is clipped into the
+    flat; a word past its end reads some word of the flat's last blocks:
+    callers mask by length). Row ``r`` is ``wwidth`` CONSECUTIVE words,
+    so the rebuild addresses blocks, not words: the flat is viewed as
+    aligned 128-word blocks, a row gather fetches the
+    ``ceil(wwidth / 128) + 1`` blocks a row can touch (that many indices
+    a row, each a whole lane row, where a per-word gather hands XLA
+    ``rows x wwidth``), and `_shift_rows_left` moves each row left by
+    ``start % 128`` words. Plain XLA: shards under GSPMD and adds no
+    Pallas call site to a chain program's cache key."""
+    lb = ROW_BLOCK_WORDS.bit_length() - 1
+    n_words = flat.shape[0]
+    pad = -n_words % ROW_BLOCK_WORDS
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    blocks = flat.reshape(-1, ROW_BLOCK_WORDS)
+    starts = jnp.clip(word_starts.astype(jnp.int32), 0, n_words - 1)
+    per_row = (wwidth + ROW_BLOCK_WORDS - 2) // ROW_BLOCK_WORDS + 1
+    idx = jnp.minimum(
+        (starts >> lb)[:, None] + jnp.arange(per_row, dtype=jnp.int32),
+        blocks.shape[0] - 1,
+    )
+    # `idx` is in bounds already; the default fill mode lowers through a
+    # function that drops the caller's scope
+    win = jnp.take(blocks, idx, axis=0, mode="clip")
+    win = win.reshape(starts.shape[0], per_row * ROW_BLOCK_WORDS)
+    return _shift_rows_left(win, starts & (ROW_BLOCK_WORDS - 1), wwidth, lb)
+
+
+def unpack_row_bytes(words: jnp.ndarray, row_lengths: jnp.ndarray):
+    """uint8[rows, 4 * wwidth] from rebuilt int32 words: every word's LE
+    bytes, zero at and past each row's length (the flat's 0-3 pad bytes
+    and whatever follows the last record never leak)."""
+    n, wwidth = words.shape
+    # byte k of word w = (w >> 8k) & 0xFF
+    shifts = jnp.arange(4, dtype=jnp.int32)[None, None, :] * 8
+    unpacked = ((words[:, :, None] >> shifts) & 0xFF).reshape(n, wwidth * 4)
+    jidx = jnp.arange(wwidth * 4, dtype=jnp.int32)[None, :]
+    return jnp.where(jidx < row_lengths[:, None], unpacked, 0).astype(jnp.uint8)
+
+
 def compact_rows(mask: jnp.ndarray, *arrays: jnp.ndarray):
     """Scatter surviving rows to the front; returns (count, packed arrays).
 

@@ -148,26 +148,20 @@ def plan_device(lengths, live, r: int, s: int, v: int) -> dict:
 
 def striped_repad_words(flat, lengths, plan, s: int):
     """Build the striped byte matrix [r, s] from the 4-aligned ragged
-    flat upload (same i32-word gather diet as the narrow
-    ``ragged_repad_words``; stripe starts are word-aligned because the
-    stripe step is 4-aligned). Overlap bytes are gathered twice from the
-    same flat — HBM cost only, never link bytes."""
+    flat upload (the narrow ``ragged_repad_words``'s rebuild: a stripe
+    row is ``s // 4`` consecutive words of the flat, fetched as aligned
+    blocks and shifted into place by `kernels.rows_from_word_starts`;
+    stripe starts are word-aligned because the stripe step is
+    4-aligned). Overlap bytes are read twice from the same flat: HBM
+    cost only, never link bytes."""
     lengths = lengths.astype(jnp.int32)
     lengths4 = (lengths + 3) & ~3
     # i32 accumulator is safe: buffer.check_flat_addressing refused any
     # batch whose 4-aligned flat exceeds i32 before it staged
     word_starts = (jnp.cumsum(lengths4) - lengths4) >> 2  # noqa: FLV303
     ws = jnp.take(word_starts, plan["seg"]) + (plan["abs_start"] >> 2)
-    wwidth = s // 4
-    jw = jnp.arange(wwidth, dtype=jnp.int32)[None, :]
-    widx = ws[:, None] + jw
-    words = jnp.take(flat, jnp.clip(widx, 0, flat.shape[0] - 1), axis=0)
-    shifts = jnp.arange(4, dtype=jnp.int32)[None, None, :] * 8
-    unpacked = (words[:, :, None] >> shifts) & 0xFF
-    gathered = unpacked.reshape(words.shape[0], s)
-    jidx = jnp.arange(s, dtype=jnp.int32)[None, :]
-    mask = jidx < plan["stripe_len"][:, None]
-    return jnp.where(mask, gathered, 0).astype(jnp.uint8)
+    words = kernels.rows_from_word_starts(flat, ws, s // 4)
+    return kernels.unpack_row_bytes(words, plan["stripe_len"])
 
 
 def owned_lengths(plan):

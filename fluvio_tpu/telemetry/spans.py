@@ -79,7 +79,8 @@ DEVICE_SCOPES = (
                     # flat ships raw (PR 33); kept because the benchmark's
                     # recorded fixture (tests/benchmark) is reduced
                     # through this tuple
-    "repad",        # ragged flat -> padded matrix, derived meta columns
+    "repad",        # ragged flat -> padded matrix (block fetch, row
+                    # shift, byte unpack), derived meta columns
     "stage",        # stage<i>.<kind>, inner .aggregate_scan/.window_merge/.window_top
     "compact",      # survivor compaction, mask, header
     "pack",         # byte-mode payload / descriptor stream packing

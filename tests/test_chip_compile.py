@@ -22,7 +22,9 @@ A compile that passes is not a chip run and is never reported as one.
 
 from __future__ import annotations
 
+import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -221,14 +223,20 @@ def _compile_ragged(ex, probe, sharding):
 NORTH_STAR = [("regex-filter", {"regex": "fluvio"}), ("json-map", {"field": "name"})]
 
 
+# `test_repad_addresses_blocks` reads the two chain programs' compiled
+# text again: cached, so each is paid for once, whichever test asks first
+@functools.cache
+def _north_star_hlo(one_chip):
+    ex = _chain(NORTH_STAR)
+    assert ex._enc_variant == "xla"
+    return _compile_ragged(ex, _json_probe(), one_chip)
+
+
 def test_ragged_north_star(one_chip, as_tpu):
     """2_filter_map at 1M records: Pallas DFA + Pallas JSON span inside
     the fused chain, the XLA result encoder on the way down, and the
     raw flat on the way up."""
-    ex = _chain(NORTH_STAR)
-    assert ex._enc_variant == "xla"
-    hlo = _compile_ragged(ex, _json_probe(), one_chip)
-    assert "tpu_custom_call" in hlo
+    assert "tpu_custom_call" in _north_star_hlo(one_chip)
 
 
 def test_ragged_filter(one_chip, as_tpu):
@@ -244,10 +252,11 @@ def test_ragged_aggregate(one_chip, as_tpu):
     assert "tpu_custom_call" in hlo  # the Pallas JSON span feeds the sum
 
 
-def test_ragged_window_q5(one_chip, as_tpu):
-    """`q5-drain`'s slice: 147,456 bids of at most 120 B (262,144 rows x
-    128), five window phases a bid sort-merged into a bank grown to
-    65,536 entries, the per-window maximum over 65,536 emit rows."""
+Q5_ROWS = 1 << 18
+
+
+@functools.cache
+def _q5_hlo(one_chip):
     import json
     from pathlib import Path
 
@@ -263,14 +272,57 @@ def test_ragged_window_q5(one_chip, as_tpu):
     ex._window.capacity = ex._window.emit = 1 << 16
     records = [Record(value=b"x" * 120, offset_delta=i) for i in range(8)]
     probe = RecordBuffer.from_records(records)
-    args, kwargs = _program_args(ex, probe, 1 << 18, 15_000_000, one_chip)
+    args, kwargs = _program_args(ex, probe, Q5_ROWS, 15_000_000, one_chip)
     enc, pack = ex._down_axes(False)
-    assert (enc, pack) == ("off", False)
-    hlo = _compile(
+    assert (enc, pack) == ("off", False) and probe.width == 128
+    return _compile(
         ex._jit_ragged.__wrapped__, *args, width=probe.width,
         fanout_cap=ex._fanout_cap(probe), enc=enc, pack=pack, **kwargs,
     )
-    assert probe.width == 128 and "sort" in hlo
+
+
+def test_ragged_window_q5(one_chip, as_tpu):
+    """`q5-drain`'s slice: 147,456 bids of at most 120 B (262,144 rows x
+    128), five window phases a bid sort-merged into a bank grown to
+    65,536 entries, the per-window maximum over 65,536 emit rows."""
+    assert "sort" in _q5_hlo(one_chip)
+
+
+@pytest.mark.parametrize(
+    "program,rows,wwidth",
+    [(_north_star_hlo, ROWS, 16), (_q5_hlo, Q5_ROWS, 32)],
+    ids=["north_star", "q5"],
+)
+def test_repad_addresses_blocks(one_chip, as_tpu, program, rows, wwidth):
+    """The re-pad hands the compiler a ROW gather of aligned 128-word
+    blocks (`kernels.rows_from_word_starts`), never one index a word
+    (rows x wwidth of them ran at 155 M words/s and were the largest
+    device operation of three cells: PERF.md section 6, PR 38), and every
+    operation of the rebuild keeps the `repad` scope, by which
+    `device_link_ms_per_mrec` and `device_named_share` book it."""
+    from fluvio_tpu.smartengine.tpu.kernels import ROW_BLOCK_WORDS as B
+
+    hlo = program(one_chip)
+    per_row = (wwidth + B - 2) // B + 1
+    lines = hlo.splitlines()
+    gathers = [ln for ln in lines if " gather(" in ln and "/repad/" in ln]
+    assert gathers, "the rebuild's block fetch is a gather under `repad`"
+    for ln in gathers:
+        out = [int(d) for d in re.search(r"= s32\[([\d,]+)\]", ln).group(1).split(",")]
+        sizes = [int(d) for d in re.search(r"slice_sizes=\{([\d,]+)\}", ln).group(1).split(",")]
+        indices = int(np.prod(out)) // int(np.prod(sizes))
+        assert int(np.prod(sizes)) == B and indices <= rows * per_row, ln[:300]
+    # the shifter's windows are shapes nothing else in the program has:
+    # s32[rows, wwidth + 2**b - 1] and the fetched s32[rows, per_row * 128]
+    widths = {wwidth + (1 << b) - 1 for b in range(1, B.bit_length() - 1)}
+    widths.add(per_row * B)
+    shaped = [
+        ln for ln in lines
+        if "op_name=" in ln and any(f"= s32[{rows},{w}]" in ln for w in widths)
+    ]
+    assert len(shaped) >= len(widths)
+    for ln in shaped:
+        assert "/repad/" in ln, ln[:300]
 
 
 def test_window_scans_and_gathers_keep_their_scope(one_chip, as_tpu):
