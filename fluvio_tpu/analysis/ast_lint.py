@@ -72,6 +72,7 @@ ALLOWED_TELEMETRY_SEAMS = {
     "add_slo_breach", "add_admission",
     "add_windows_closed", "add_window_delta", "add_window_downlink",
     "add_window_slice", "add_window_grow",
+    "add_group_slice", "add_group_grow",
     "gauge_add", "gauge_set",
     "mem_acquire", "mem_release",
 }

@@ -996,43 +996,133 @@ struct FlatValues {
     }
 };
 
+// the decimal of an int64 (byte-equal to kernels.int_to_ascii): its
+// length, and its digits written into the `n` bytes at `p`
+inline uint64_t decimal_magnitude(int64_t v) {
+    return v < 0 ? ~(uint64_t)v + 1 : (uint64_t)v;  // exact at INT64_MIN
+}
+
+inline int64_t decimal_len(int64_t v) {
+    int64_t n = v < 0 ? 2 : 1;
+    for (uint64_t m = decimal_magnitude(v);; m /= 10000, n += 4) {
+        if (m < 10) return n;
+        if (m < 100) return n + 1;
+        if (m < 1000) return n + 2;
+        if (m < 10000) return n + 3;
+    }
+}
+
+inline void decimal_write(uint8_t*& p, int64_t v, int64_t n) {
+    static const char pairs[] =
+        "00010203040506070809101112131415161718192021222324"
+        "25262728293031323334353637383940414243444546474849"
+        "50515253545556575859606162636465666768697071727374"
+        "75767778798081828384858687888990919293949596979899";
+    uint64_t m = decimal_magnitude(v);
+    uint8_t* q = p + n;
+    for (; m >= 100; m /= 100) {  // two digits a division
+        q -= 2;
+        std::memcpy(q, pairs + 2 * (m % 100), 2);
+    }
+    if (m >= 10) {
+        q -= 2;
+        std::memcpy(q, pairs + 2 * m, 2);
+    } else {
+        *--q = (uint8_t)('0' + m);
+    }
+    if (v < 0) *p = '-';
+    p += n;
+}
+
 // an int64 column rendered as decimals into the record being written
-// (int-backed RecordBuffer; byte-equal to kernels.int_to_ascii)
+// (int-backed RecordBuffer)
 struct IntValues {
     const int64_t* ints;
-    static uint64_t magnitude(int64_t v) {
-        return v < 0 ? ~(uint64_t)v + 1 : (uint64_t)v;  // exact at INT64_MIN
-    }
     bool ok(int64_t) const { return true; }
-    int64_t len(int64_t i) const {
-        int64_t n = ints[i] < 0 ? 2 : 1;
-        for (uint64_t m = magnitude(ints[i]);; m /= 10000, n += 4) {
-            if (m < 10) return n;
-            if (m < 100) return n + 1;
-            if (m < 1000) return n + 2;
-            if (m < 10000) return n + 3;
-        }
-    }
+    int64_t len(int64_t i) const { return decimal_len(ints[i]); }
     void write(uint8_t*& p, int64_t i, int64_t n) const {
-        static const char pairs[] =
-            "00010203040506070809101112131415161718192021222324"
-            "25262728293031323334353637383940414243444546474849"
-            "50515253545556575859606162636465666768697071727374"
-            "75767778798081828384858687888990919293949596979899";
-        uint64_t m = magnitude(ints[i]);
-        uint8_t* q = p + n;
-        for (; m >= 100; m /= 100) {  // two digits a division
-            q -= 2;
-            std::memcpy(q, pairs + 2 * (m % 100), 2);
+        decimal_write(p, ints[i], n);
+    }
+};
+
+// one value a row rendered from int64 columns (a keyed table's answer
+// rows; buffer.py:RowFormat): literal pieces around slots, a slot the
+// decimal of a column, of a column shifted right, of the floor quotient
+// of two columns (0 where the divisor is 0), or a text of a table
+// indexed by a column's low bits (the rendered days)
+struct RowValues {
+    const int64_t* ints;   // [columns, stride], a column contiguous
+    int64_t stride;
+    int64_t columns;
+    const uint8_t* lit;    // the pieces, one more than slots
+    const int64_t* lit_off;
+    const int64_t* slots;  // (kind, a, b) a slot
+    int64_t n_slots;
+    const uint8_t* tab;
+    const int64_t* tab_off;
+    int64_t tab_n;
+    int64_t tab_base;
+    enum { INT = 0, SHIFTED = 1, DIV = 2, TABLE = 3 };
+
+    int64_t at(int64_t col, int64_t i) const { return ints[col * stride + i]; }
+    int64_t table_index(const int64_t* s, int64_t i) const {
+        return (at(s[1], i) & (((int64_t)1 << s[2]) - 1)) - tab_base;
+    }
+    int64_t number(const int64_t* s, int64_t i) const {
+        int64_t a = at(s[1], i);
+        if (s[0] == SHIFTED) return a >> s[2];
+        if (s[0] != DIV) return a;
+        int64_t b = at(s[2], i);
+        if (b == 0) return 0;
+        if (b == -1) return (int64_t)(0 - (uint64_t)a);  // no INT64_MIN / -1
+        int64_t q = a / b;  // floor, as Python's //
+        return (a % b != 0 && ((a < 0) != (b < 0))) ? q - 1 : q;
+    }
+    bool ok(int64_t i) const {
+        for (int64_t k = 0; k < n_slots; k++) {
+            const int64_t* s = slots + 3 * k;
+            if (s[1] < 0 || s[1] >= columns) return false;
+            if (s[0] == DIV && (s[2] < 0 || s[2] >= columns)) return false;
+            if (s[0] == TABLE) {
+                if (s[2] < 0 || s[2] > 62) return false;
+                int64_t t = table_index(s, i);
+                if (t < 0 || t >= tab_n) return false;
+            } else if (s[0] == SHIFTED && (s[2] < 0 || s[2] > 63)) {
+                return false;
+            }
         }
-        if (m >= 10) {
-            q -= 2;
-            std::memcpy(q, pairs + 2 * m, 2);
-        } else {
-            *--q = (uint8_t)('0' + m);
+        return true;
+    }
+    int64_t len(int64_t i) const {
+        int64_t n = lit_off[n_slots + 1] - lit_off[0];
+        for (int64_t k = 0; k < n_slots; k++) {
+            const int64_t* s = slots + 3 * k;
+            if (s[0] == TABLE) {
+                int64_t t = table_index(s, i);
+                n += tab_off[t + 1] - tab_off[t];
+            } else {
+                n += decimal_len(number(s, i));
+            }
         }
-        if (ints[i] < 0) *p = '-';
-        p += n;
+        return n;
+    }
+    void write(uint8_t*& p, int64_t i, int64_t) const {
+        for (int64_t k = 0;; k++) {
+            int64_t n = lit_off[k + 1] - lit_off[k];
+            std::memcpy(p, lit + lit_off[k], (size_t)n);
+            p += n;
+            if (k == n_slots) return;
+            const int64_t* s = slots + 3 * k;
+            if (s[0] == TABLE) {
+                int64_t t = table_index(s, i);
+                n = tab_off[t + 1] - tab_off[t];
+                std::memcpy(p, tab + tab_off[t], (size_t)n);
+                p += n;
+            } else {
+                int64_t v = number(s, i);
+                decimal_write(p, v, decimal_len(v));
+            }
+        }
     }
 };
 
@@ -1065,8 +1155,11 @@ template <class Values, class Keys, class Off>
 int64_t append_records(EncodedRecords* e, const Values& vals, const Keys& keys,
                        const Off* off_delta, const int64_t* ts_delta,
                        int64_t first, int64_t end, int64_t max_bytes) {
-    std::vector<int64_t> inner_sizes;
+    // per kept row: the record's inner size, and its value's length (a
+    // rendered value's length is not free to ask for twice)
+    std::vector<int64_t> inner_sizes, value_lens;
     inner_sizes.reserve((size_t)(end > first ? end - first : 0));
+    value_lens.reserve(inner_sizes.capacity());
     int64_t total = 0;
     for (int64_t i = first; i < end; i++) {
         if (max_bytes > 0 && e->len + total >= max_bytes) break;
@@ -1080,6 +1173,7 @@ int64_t append_records(EncodedRecords* e, const Values& vals, const Keys& keys,
         inner += varint_encoded_size(vlen) + vlen;
         inner += varint_encoded_size(0);  // header count
         inner_sizes.push_back(inner);
+        value_lens.push_back(vlen);
         total += varint_encoded_size(inner) + inner;
     }
     int64_t kept = (int64_t)inner_sizes.size();
@@ -1102,7 +1196,7 @@ int64_t append_records(EncodedRecords* e, const Values& vals, const Keys& keys,
         } else {
             *p++ = 0;
         }
-        int64_t vlen = vals.len(i);
+        int64_t vlen = value_lens[(size_t)(i - first)];
         write_varint(p, vlen);
         vals.write(p, i, vlen);
         write_varint(p, 0);  // no record headers
@@ -1152,6 +1246,23 @@ int64_t encode_append_ints(
     return append_records(e, IntValues{ints},
                           MatrixKeys{keys, key_width, key_lengths},
                           off_delta, ts_delta, first, end, max_bytes);
+}
+
+int64_t encode_append_rows(
+    EncodedRecords* e, const int64_t* ints, int64_t stride, int64_t columns,
+    const uint8_t* lit, const int64_t* lit_off,
+    const int64_t* slots, int64_t n_slots,
+    const uint8_t* tab, const int64_t* tab_off, int64_t tab_n,
+    int64_t tab_base,
+    const uint8_t* keys, int64_t key_width, const int32_t* key_lengths,
+    const int32_t* off_delta, const int64_t* ts_delta,
+    int64_t first, int64_t end, int64_t max_bytes) {
+    return append_records(
+        e,
+        RowValues{ints, stride, columns, lit, lit_off, slots, n_slots,
+                  tab, tab_off, tab_n, tab_base},
+        MatrixKeys{keys, key_width, key_lengths},
+        off_delta, ts_delta, first, end, max_bytes);
 }
 
 void encoded_records_free(EncodedRecords* e) {

@@ -122,11 +122,18 @@ class ResponseMessage:
         return w.bytes()
 
     def to_frame(self, version: Version) -> bytes:
-        payload = self.encode_payload(version)
+        return bytes(self.frame_buffer(version))
+
+    def frame_buffer(self, version: Version) -> bytearray:
+        """The length-prefixed frame as the ONE buffer it was encoded
+        into (a transport takes a bytearray as it is): a served slice's
+        response is tens of MB, and every `bytes()` of it is a copy."""
         w = ByteWriter()
-        w.write_i32(len(payload))
-        w.write_raw(payload)
-        return w.bytes()
+        w.write_i32(0)
+        w.write_i32(self.correlation_id)
+        self.response.encode(w, version)
+        w.patch_i32(0, len(w) - 4)
+        return w.buf
 
 
 def decode_request_header(payload: bytes) -> tuple[RequestHeader, ByteReader]:

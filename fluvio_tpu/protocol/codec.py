@@ -96,6 +96,14 @@ class ByteWriter:
     def write_raw(self, data: bytes) -> None:
         self.buf += data
 
+    def patch_i32(self, at: int, v: int) -> None:
+        """Overwrite the i32 slot written at offset ``at`` (a length
+        known only once what follows it is written)."""
+        _S_I32.pack_into(self.buf, at, v)
+
+    def patch_u32(self, at: int, v: int) -> None:
+        _S_U32.pack_into(self.buf, at, v)
+
     # -- composites ---------------------------------------------------------
 
     def write_string(self, s: str) -> None:

@@ -263,31 +263,32 @@ class Batch:
         return compress(self.header.compression(), body.bytes())
 
     def encode(self, w: ByteWriter, version: Version = 0) -> None:
+        """Written straight into ``w``: the record section (tens of MB
+        in a served keyed-table slice) is copied once, the CRC and the
+        length are patched over their slots afterwards."""
         record_section = self._encode_record_section()
-        count = self.records_len()
-
-        after_crc = ByteWriter()
-        after_crc.write_i16(self.header.attributes)
-        after_crc.write_i32(self.header.last_offset_delta)
-        after_crc.write_i64(self.header.first_timestamp)
-        after_crc.write_i64(self.header.max_time_stamp)
-        after_crc.write_i64(self.header.producer_id)
-        after_crc.write_i16(self.header.producer_epoch)
-        after_crc.write_i32(self.header.first_sequence)
-        if self.header.attributes & ATTR_SCHEMA_PRESENT:
-            after_crc.write_u32(self.header.schema_id)
-        after_crc.write_i32(count)
-        after_crc.write_raw(record_section)
-
-        crc = zlib.crc32(after_crc.buf) & 0xFFFFFFFF
-
-        batch_len = 4 + 1 + 4 + len(after_crc)  # epoch + magic + crc + rest
         w.write_i64(self.base_offset)
-        w.write_i32(batch_len)
+        len_at = len(w)
+        w.write_i32(0)  # batch_len: epoch + magic + crc + rest
         w.write_i32(self.header.partition_leader_epoch)
         w.write_i8(self.header.magic)
-        w.write_u32(crc)
-        w.write_raw(after_crc.buf)
+        crc_at = len(w)
+        w.write_u32(0)
+        w.write_i16(self.header.attributes)
+        w.write_i32(self.header.last_offset_delta)
+        w.write_i64(self.header.first_timestamp)
+        w.write_i64(self.header.max_time_stamp)
+        w.write_i64(self.header.producer_id)
+        w.write_i16(self.header.producer_epoch)
+        w.write_i32(self.header.first_sequence)
+        if self.header.attributes & ATTR_SCHEMA_PRESENT:
+            w.write_u32(self.header.schema_id)
+        w.write_i32(self.records_len())
+        w.write_raw(record_section)
+        with memoryview(w.buf) as view:
+            crc = zlib.crc32(view[crc_at + 4:]) & 0xFFFFFFFF
+        w.patch_u32(crc_at, crc)
+        w.patch_i32(len_at, len(w) - len_at - 4)
 
     @classmethod
     def decode(
@@ -366,11 +367,11 @@ class RecordSet:
         return self.batches[-1].computed_last_offset()
 
     def encode(self, w: ByteWriter, version: Version = 0) -> None:
-        body = ByteWriter()
+        len_at = len(w)
+        w.write_i32(0)
         for batch in self.batches:
-            batch.encode(body, version)
-        w.write_i32(len(body))
-        w.write_raw(body.bytes())
+            batch.encode(w, version)
+        w.patch_i32(len_at, len(w) - len_at - 4)
 
     @classmethod
     def decode(

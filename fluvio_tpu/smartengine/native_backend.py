@@ -192,6 +192,11 @@ def load_library():
         lib.encode_append_ints.argtypes = [
             slab, p64, p8, i64, p32, p32, p64, i64, i64, i64,
         ]
+        lib.encode_append_rows.restype = i64
+        lib.encode_append_rows.argtypes = [
+            slab, p64, i64, i64, p8, p64, p64, i64, p8, p64, i64, i64,
+            p8, i64, p32, p32, p64, i64, i64, i64,
+        ]
         lib.encoded_records_free.argtypes = [ctypes.POINTER(EncodedRecords)]
         _lib = lib
         return _lib
@@ -370,6 +375,38 @@ class RecordSlab:
         arg = self._arg
         return self._kept(self._lib.encode_append_ints(
             self._slab, arg(ints, np.int64),
+            *self._key_matrix(keys, key_lengths, end),
+            arg(off_delta, np.int32), arg(ts_delta, np.int64),
+            first, end, self._max_bytes,
+        ))
+
+    def append_rows(
+        self, ints, fmt, keys, key_lengths, off_delta, ts_delta,
+        first: int, end: int,
+    ) -> int:
+        """An int-backed buffer of ``[columns, rows]`` int64 with a
+        `buffer.RowFormat`: each row's value rendered from its ints
+        (literal pieces, decimals, a table of texts) straight into its
+        record."""
+        ints = np.ascontiguousarray(ints, dtype=np.int64)
+        columns, stride = ints.shape
+        self._check_rows(first, end, min(stride, len(off_delta), len(ts_delta)))
+        arg = self._arg
+
+        def packed(texts):
+            off = np.zeros(len(texts) + 1, dtype=np.int64)
+            np.cumsum([len(t) for t in texts], out=off[1:])
+            return arg(np.frombuffer(b"".join(texts), np.uint8), np.uint8), off
+
+        slots = np.ascontiguousarray(fmt.slots, dtype=np.int64).reshape(-1, 3)
+        if len(fmt.pieces) != len(slots) + 1:
+            raise ValueError("a row format has one more piece than slots")
+        lit, lit_off = packed(fmt.pieces)
+        tab, tab_off = packed(fmt.table)
+        return self._kept(self._lib.encode_append_rows(
+            self._slab, arg(ints.reshape(-1), np.int64), stride, columns,
+            lit, lit_off, arg(slots.reshape(-1), np.int64), len(slots),
+            tab, tab_off, len(fmt.table), int(fmt.table_base),
             *self._key_matrix(keys, key_lengths, end),
             arg(off_delta, np.int32), arg(ts_delta, np.int64),
             first, end, self._max_bytes,

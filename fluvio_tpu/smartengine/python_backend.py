@@ -43,6 +43,10 @@ from fluvio_tpu.smartengine.metrics import SmartModuleChainMetrics
 from fluvio_tpu.telemetry import TELEMETRY
 
 
+# the associative monoids of `dsl.AGGREGATE_COMBINES`, over Python ints
+_COMBINE = {"add": lambda a, x: a + x, "max": max, "min": min}
+
+
 def _normalize_map_result(result, record: Record) -> Tuple[Optional[bytes], bytes]:
     """User map result -> (key, value). Bare bytes preserves the input key."""
     if isinstance(result, tuple):
@@ -73,6 +77,10 @@ class PythonInstance:
         self._window_open: dict = {}
         self._window_max_ts = dsl.INT64_MIN + 1
         self.window_source = None
+        # `dsl.GroupProgram` state: the table {key * WINDOW_KEY_LIMIT +
+        # time bucket: [accumulator per lane]}; ``window_source`` is a
+        # fused chain's device table the same way
+        self._group_table: dict = {}
 
     # -- init / look_back ---------------------------------------------------
 
@@ -99,15 +107,20 @@ class PythonInstance:
             self.accumulator, self._window_start, self._window_max_ts,
             {w: {k: list(v) for k, v in row.items()}
              for w, row in self._window_open.items()},
+            {g: list(v) for g, v in self._group_table.items()},
         )
 
     def state_restore(self, snapshot: tuple) -> None:
         (self.accumulator, self._window_start, self._window_max_ts,
-         window_open) = snapshot
+         window_open, group_table) = snapshot
         self._window_open = {
             w: {k: list(v) for k, v in row.items()}
             for w, row in window_open.items()
         }
+        self._group_table = {g: list(v) for g, v in group_table.items()}
+
+    def _keeps_a_table(self) -> bool:
+        return isinstance(self._dsl_programs.get(self.kind), dsl.GroupProgram)
 
     def _load_window_source(self) -> None:
         """Take over the device bank a fused chain left behind."""
@@ -115,6 +128,9 @@ class PythonInstance:
         if bank is None:
             return
         entries, self._window_max_ts = bank.snapshot()
+        if self._keeps_a_table():
+            self._group_table = {g: list(accs) for g, accs, _ in entries}
+            return
         self._window_open = {}
         for composite, acc, cnt in entries:
             key, w = divmod(composite, dsl.WINDOW_KEY_LIMIT)
@@ -125,6 +141,10 @@ class PythonInstance:
         aggregate, count)] in that order, largest event time): what a
         device bank is restored from."""
         self._load_window_source()
+        if self._keeps_a_table():
+            return sorted(
+                (g, tuple(accs), None) for g, accs in self._group_table.items()
+            ), self._window_max_ts
         entries = sorted(
             (key * dsl.WINDOW_KEY_LIMIT + w, acc, cnt)
             for w, row in self._window_open.items()
@@ -287,6 +307,8 @@ class PythonInstance:
             self._run_dsl_aggregate(program, sm_records, out)
         elif isinstance(program, dsl.WindowProgram):
             self._run_dsl_window(program, sm_records, out)
+        elif isinstance(program, dsl.GroupProgram):
+            self._run_dsl_group(program, sm_records, out)
         else:
             raise TypeError(f"unknown DSL program {type(program).__name__}")
         return out
@@ -304,8 +326,7 @@ class PythonInstance:
             if combine not in dsl.AGGREGATE_COMBINES:
                 raise ValueError(f"unknown aggregate combine {combine!r}")
             neutral = dsl.AGGREGATE_COMBINE_NEUTRAL[combine]
-            ops = {"add": lambda a, x: a + x, "max": max, "min": min}
-            comb = ops[combine]
+            comb = _COMBINE[combine]
 
             def init_acc() -> int:
                 return neutral
@@ -366,9 +387,7 @@ class PythonInstance:
             raise ValueError(f"unknown window combine {program.combine!r}")
         if program.emit not in dsl.WINDOW_EMITS:
             raise ValueError(f"unknown window emit {program.emit!r}")
-        comb = {"add": lambda a, x: a + x, "max": max, "min": min}[
-            program.combine
-        ]
+        comb = _COMBINE[program.combine]
         neutral = dsl.AGGREGATE_COMBINE_NEUTRAL[program.combine]
         window = program.window_ms
         slide = program.slide_ms or window
@@ -406,3 +425,42 @@ class PythonInstance:
                             program, w * slide + window, k, row[k][0]
                         )))
         TELEMETRY.add_window_slice(closed, late, invalid)
+
+    def _run_dsl_group(
+        self,
+        program: dsl.GroupProgram,
+        sm_records: List[SmartModuleRecord],
+        out: SmartModuleOutput,
+    ) -> None:
+        """`dsl.GroupProgram` record by record: fold the record into its
+        (key, time bucket) group; the group's row after the fold takes
+        the record's place. A record without a key is dropped and
+        counted invalid."""
+        lanes = dsl.group_accumulators(program)
+        for c in lanes:
+            if c.combine not in dsl.AGGREGATE_COMBINES:
+                raise ValueError(f"unknown group combine {c.combine!r}")
+        self._load_window_source()
+        table = self._group_table
+        ev = dsl.eval_expr
+        invalid = 0
+        for rec in sm_records:
+            group = dsl.group_key(program, rec.value, rec.key)
+            if group is None:
+                invalid += 1
+                continue
+            gid = group[0] * dsl.WINDOW_KEY_LIMIT + group[1]
+            accs = table.get(gid)
+            if accs is None:
+                accs = table[gid] = [
+                    dsl.AGGREGATE_COMBINE_NEUTRAL[c.combine] for c in lanes
+                ]
+            for i, c in enumerate(lanes):
+                if c.where is None or ev(c.where, rec.value, rec.key):
+                    x = int(ev(c.contribution, rec.value, rec.key))
+                    accs[i] = _COMBINE[c.combine](accs[i], x)
+            rec.record.value = dsl.group_row_bytes(program, *group, accs)
+            out.successes.append(rec.record)
+        TELEMETRY.add_group_slice(
+            len(sm_records) - invalid, len(table), invalid
+        )

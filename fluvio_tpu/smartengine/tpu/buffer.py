@@ -127,6 +127,25 @@ def bucket_width(max_v: int) -> int:
 
 
 @dataclass
+class RowFormat:
+    """How the native record writer renders one value per row from
+    int64 columns (`baseline_engine.cpp:RowValues`): ``pieces`` are the
+    literal bytes around the slots (one more than slots), ``slots`` is
+    int64[k, 3] of (kind, a, b) over the columns of a ``[columns,
+    rows]`` matrix: the decimal of column ``a`` (`INT`), of ``a >> b``
+    (`SHIFTED`), of ``a // b``-th columns' floor quotient, 0 where the
+    divisor is 0 (`DIV`), or text ``(a & (2**b - 1)) - table_base`` of
+    ``table`` (`TABLE`)."""
+
+    INT, SHIFTED, DIV, TABLE = range(4)
+
+    pieces: list
+    slots: np.ndarray
+    table: list
+    table_base: int = 0
+
+
+@dataclass
 class RecordBuffer:
     """Padded columnar record batch (numpy on host; device puts are cheap).
 
@@ -167,8 +186,13 @@ class RecordBuffer:
     # (`encode_into`); every other consumer gets the padded matrix AND
     # `lengths` (None until then) from `dense_values()`, rendered
     # through ``_render(ints, rows, count) -> (values, lengths)``.
+    # ``_ints`` may also be a ``[columns, rows]`` matrix with the
+    # ``_row_format`` that says how one value is rendered from a row's
+    # ints (a keyed table's answer rows); without a format (a slice the
+    # encoder's table of texts cannot cover) `encode_into` renders first.
     _ints: Optional[np.ndarray] = None
     _render: Optional[Callable] = None
+    _row_format: Optional[RowFormat] = None
 
     @property
     def width(self) -> int:
@@ -537,6 +561,12 @@ class RecordBuffer:
         n = self.count
         meta = (self.keys, self.key_lengths, self.offset_deltas,
                 self.timestamp_deltas, first, n)
+        if self._row_format is not None:
+            return slab.append_rows(
+                self._ints, self._row_format, *meta
+            ), "enc-direct-rows"
+        if self._flat is None and self._ints is not None and self._ints.ndim > 1:
+            self.dense_values()
         if self.values is not None:
             c = self.to_columns()
             return slab.append_columns(

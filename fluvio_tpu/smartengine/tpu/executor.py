@@ -830,8 +830,8 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             return None
         try:
             for module, config in entries:
-                if stages and stages[-1].kind == "window":
-                    raise Unlowerable("a window's rows are the chain's output")
+                if stages and stages[-1].kind in window_stage.LAST_KINDS:
+                    raise Unlowerable("a banked stage's rows are the output")
                 kind = module.transform_kind()
                 prog = module.dsl_program(kind)
                 if prog is None:
@@ -904,8 +904,8 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                     if any(isinstance(s, _ArrayMapStage) for s in stages):
                         raise Unlowerable("one array_map per fused chain")
                     stages.append(_ArrayMapStage(mode=prog.mode, sep=prog.sep))
-                else:  # a `dsl.WindowProgram`, or Unlowerable
-                    stages.append(window_stage.WindowStage.lower(prog, stages))
+                else:  # a `dsl.WindowProgram` / `GroupProgram`, or Unlowerable
+                    stages.append(window_stage.lower_banked(prog, stages))
         except (Unlowerable, KeyError):
             return None
         ex = cls(stages, agg_configs)
@@ -2555,7 +2555,8 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
 
     def _assemble(self, buf, count, rows, out_values, out_lengths, out_keys,
                   out_klens, src, flat=None, starts=None,
-                  vw: int = 0, ints=None) -> RecordBuffer:
+                  vw: int = 0, ints=None, render=None,
+                  row_format=None) -> RecordBuffer:
         """Rebuild offset/timestamp columns from survivor source rows.
 
         Row-preserving chains pass the source deltas through; fan-out
@@ -2566,7 +2567,9 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         output buffer is FLAT-BACKED: ``out_values`` is None and the
         padded matrix is never built. With ``ints`` set (an int-output
         fetch) it is INT-BACKED: ``out_values`` and ``out_lengths`` are
-        None until a consumer asks for the rendered form."""
+        None until a consumer asks for the rendered form (``render``,
+        by default one column's decimals; ``row_format``: a keyed
+        table's rows, `RecordBuffer._row_format`)."""
         src_c = np.clip(
             src[:count] if len(src) >= count else np.zeros(count, np.int64),
             0,
@@ -2597,7 +2600,9 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             _width=vw if flat is not None else 0,
             _rows=rows if out_values is None else 0,
             _ints=ints,
-            _render=self._int_value_columns if ints is not None else None,
+            _render=(render or self._int_value_columns)
+            if ints is not None else None,
+            _row_format=row_format,
         )
 
     def _fanout_cap(self, buf: RecordBuffer) -> Optional[int]:

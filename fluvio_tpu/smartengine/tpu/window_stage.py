@@ -9,7 +9,8 @@ lowering (`lower.lower_expr`); the device work is
 for a slice. There is no second windowed implementation here.
 
 The rest of this module is `WindowChainMixin`, the methods
-`executor.TpuChainExecutor` inherits for such a chain:
+`executor.TpuChainExecutor` inherits for such a chain, and for one that
+ends in a keyed table (`group_stage.GroupStage`, whose bank has lanes):
 
 - a stream's carry is a `WindowStateBank` (`StreamState.window_bank`):
   empty at the stream's first dispatch, on the device across the
@@ -23,12 +24,11 @@ The rest of this module is `WindowChainMixin`, the methods
   stage, so a later stream starts at them, as the fan-out chain's
   learned output capacity does.
 
-It lives beside `executor.py` and not in it because a Pallas kernel's
-serialised body holds the line numbers of its Python call sites inside
-the compile-cache key: in `executor.py` the stages' `apply`, `_chain_fn`,
-`_chain_fn_ragged` and the jit calls of `_dispatch_inner` (PERF.md
-section 6, PRs 33 and 37). A line added above them costs every chain
-with a kernel a cold set-up, so `executor.py` keeps its lines there.
+It lives beside `executor.py` because a Pallas kernel's serialised
+body holds the line numbers of its Python call sites inside the
+compile-cache key (PERF.md section 6, PRs 33 and 37): `executor.py`
+keeps its lines down to the jit calls of `_dispatch_inner`, and this
+file down to `WindowStage.apply`, or such a chain pays a cold set-up.
 """
 
 from __future__ import annotations
@@ -36,7 +36,7 @@ from __future__ import annotations
 import functools
 import weakref
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -142,16 +142,95 @@ class WindowStage:
         )
         return {"window_header": header, "window_rows": rows}, bank
 
+    # -- what `WindowChainMixin` asks of a banked stage -----------------------
+
+    def bank_spec(self):
+        from fluvio_tpu.windows.spec import WindowSpec
+
+        p = self.program
+        return WindowSpec(
+            window_ms=p.window_ms, slide_ms=p.slide_ms, op=p.combine,
+            keyed=True, lateness_ms=p.lateness_ms,
+            capacity=self.capacity, emit_capacity=self.emit,
+        )
+
+    def counts(self, hdr):
+        """(bank entries the slice needs, emit rows it needs, the
+        watermark to commit) of a synced header."""
+        return int(hdr[1]), int(hdr[2]), int(hdr[4])
+
+    def emit_rows(self, packed) -> int:
+        """Answer rows the slice's program had room for."""
+        return packed["window_rows"].shape[0]
+
+    def raise_floor(self, rows: int) -> None:
+        """Neither capacity starts under a quarter of the slice's padded
+        rows: a large slice's first program is then not one whose only
+        use is to report that 1,024 entries were too few."""
+        self.capacity = max(self.capacity, rows // 4)
+        self.emit = max(self.emit, rows // 4)
+
+    note_growth = staticmethod(TELEMETRY.add_window_grow)
+
+    def fetch(self, ex, buf, hdr, packed, span):
+        """A window slice's D2H after the header sync: ONE bucketed
+        download of the answer rows (none when nothing closed).
+        Returns the thunk that renders them."""
+        n_rows, _n_open, n_closed, n_late, _wm, n_invalid = (
+            int(x) for x in hdr
+        )
+        rows_dev = packed["window_rows"]
+        rows = np.zeros((0, 3), dtype=np.int64)
+        if n_rows:
+            bucket = min(ex._pad_slice(n_rows), rows_dev.shape[0])
+            rows = ex._download(
+                [lax.slice(rows_dev, (0, 0), (bucket, 3))], span
+            )[0][:n_rows]
+        TELEMETRY.add_link_variant("win-top")
+        TELEMETRY.add_window_slice(n_closed, n_late, n_invalid)
+        return functools.partial(self.render_rows, buf, rows)
+
+    def render_rows(self, buf, rows: np.ndarray):
+        """Answer rows (window end, key, aggregate) as fresh output
+        records at the slice's base offset (delta 0, like a fan-out's:
+        never before the slice's first input, never past the record
+        whose arrival closed the window). A few rows a slice: the
+        general record form does."""
+        from fluvio_tpu.protocol.record import Record
+        from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
+
+        return RecordBuffer.from_records(
+            [Record(value=dsl.window_row_bytes(self.program, *r))
+             for r in rows.tolist()],
+            base_offset=buf.base_offset,
+            base_timestamp=buf.base_timestamp,
+        )
+
+
+# the kinds of a BANKED stage: the last of its chain, its carry a
+# stream's `WindowStateBank`, served through `WindowChainMixin`
+LAST_KINDS = ("window", "group")
+
+
+def lower_banked(prog, stages: List):
+    """`try_build`'s last branch: the banked stage of a `WindowProgram`
+    or a `GroupProgram` that may follow ``stages``; any other program
+    is one the fused path does not lower."""
+    if isinstance(prog, dsl.GroupProgram):
+        from fluvio_tpu.smartengine.tpu.group_stage import GroupStage
+
+        return GroupStage.lower(prog, stages)
+    return WindowStage.lower(prog, stages)
+
 
 def chain_outputs(state: Dict, bank):
-    """A window chain's (header, packed, carries): the stage's own
-    header and rows ARE the result; the new bank rides in ``packed``
+    """A banked chain's (header, packed, carries): the stage's own
+    header and outputs ARE the result; the new bank rides in ``packed``
     because the fetch commits it, not the dispatch."""
-    return (
-        state["window_header"],
-        {"window_rows": state["window_rows"], "window_bank": bank},
-        (),
-    )
+    packed = {k: v for k, v in state.items()
+              if k.startswith(("window_rows", "group_"))}
+    packed["window_bank"] = bank
+    return state["window_header"], packed, ()
 
 
 class WindowChainMixin:
@@ -161,9 +240,11 @@ class WindowChainMixin:
     the compiled chain's."""
 
     @property
-    def _window(self) -> Optional[WindowStage]:
+    def _window(self):
+        """The chain's banked stage (`WindowStage`, `GroupStage`), or
+        None."""
         stages = self.stages
-        if stages and isinstance(stages[-1], WindowStage):
+        if stages and stages[-1].kind in LAST_KINDS:
             return stages[-1]
         return None
 
@@ -200,29 +281,20 @@ class WindowChainMixin:
         bank = self._window_bank
         if bank is None:
             from fluvio_tpu.telemetry import memory as memory_mod
-            from fluvio_tpu.windows.spec import WindowSpec
             from fluvio_tpu.windows.state import WindowStateBank
 
-            p = stage.program
-            bank = self._window_bank = WindowStateBank(WindowSpec(
-                window_ms=p.window_ms, slide_ms=p.slide_ms, op=p.combine,
-                keyed=True, lateness_ms=p.lateness_ms,
-                capacity=stage.capacity, emit_capacity=stage.emit,
-            ))
+            bank = self._window_bank = WindowStateBank(stage.bank_spec())
             # a stream's bank dies with the stream: its ledger entry too
             weakref.finalize(bank, memory_mod.release_window_bank, id(bank))
         bank.grow(stage.capacity)
         return bank
 
     def _window_emit_cap(self, buf) -> int:
-        """The slice program's emit capacity (its ``fanout_cap``).
-        Neither it nor the bank's starts under a quarter of the slice's
-        padded rows: a large slice's first program is then not one whose
-        only use is to report that 1,024 entries were too few."""
+        """The slice program's emit capacity (its ``fanout_cap``), after
+        the stage raised its capacities to the floor this slice's rows
+        set (`raise_floor`)."""
         stage = self._window
-        floor = buf.rows // 4
-        stage.capacity = max(stage.capacity, floor)
-        stage.emit = max(stage.emit, floor)
+        stage.raise_floor(buf.rows)
         return stage.emit
 
     def _grow_window(self, o: WindowOverflow) -> None:
@@ -240,7 +312,7 @@ class WindowChainMixin:
                 f"{o.n_open} open entries / {o.n_closed} closed rows in one "
                 f"slice exceed the ceiling of {WINDOW_CAPACITY_MAX}"
             )
-        TELEMETRY.add_window_grow(
+        stage.note_growth(
             f"bank {stage.capacity}->{capacity} emit {stage.emit}->{emit}"
         )
         stage.capacity, stage.emit = capacity, emit
@@ -261,51 +333,26 @@ class WindowChainMixin:
         )
 
     def _fetch_window(self, buf, header, packed, span, defer):
-        """A window slice's D2H: the 48-byte header sync, then ONE
-        bucketed download of the answer rows (none when nothing
-        closed). The stream's bank is committed here, after the header
-        read clean: an overflow, a fault or a discard before this point
-        leaves the bank the slice started from."""
+        """A banked slice's D2H: the header sync, then what the stage
+        downloads of its answer (`WindowStage.fetch`: the closed
+        windows' rows; `GroupStage.fetch`: a row per record). The
+        stream's bank is committed here, after the header read clean and
+        the download ended: an overflow, a fault or a discard before
+        this point leaves the bank the slice started from."""
         with timed(span, "wait"):
             hdr = jax.device_get(header)
         if span is not None:
             span.mark_device_ready()
-        n_rows, n_open, n_closed, n_late, watermark, n_invalid = (
-            int(x) for x in hdr
-        )
-        rows_dev, bank = packed["window_rows"], packed["window_bank"]
-        if n_open > bank[0].shape[0] or n_closed > rows_dev.shape[0]:
+        stage = self._window
+        n_open, n_closed, watermark = stage.counts(hdr)
+        bank = packed["window_bank"]
+        if n_open > bank[0].shape[0] or n_closed > stage.emit_rows(packed):
             raise WindowOverflow(n_open, n_closed)
-        rows = np.zeros((0, 3), dtype=np.int64)
-        if n_rows:
-            bucket = min(self._pad_slice(n_rows), rows_dev.shape[0])
-            rows = self._download(
-                [lax.slice(rows_dev, (0, 0), (bucket, 3))], span
-            )[0][:n_rows]
+        thunk = stage.fetch(self, buf, hdr, packed, span)
         self._window_bank.commit(*bank, n_open, watermark)
         for inst in self._instances[-1:]:
             inst.window_source = self._window_bank  # stale until it loads
-        TELEMETRY.add_link_variant("win-top")
-        TELEMETRY.add_window_slice(n_closed, n_late, n_invalid)
-        thunk = functools.partial(self._render_window, buf, rows)
         return thunk if defer else thunk()
-
-    def _render_window(self, buf, rows: np.ndarray):
-        """Answer rows (window end, key, aggregate) as fresh output
-        records at the slice's base offset (delta 0, like a fan-out's:
-        never before the slice's first input, never past the record
-        whose arrival closed the window). A few rows a slice: the
-        general record form does."""
-        from fluvio_tpu.protocol.record import Record
-        from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
-
-        program = self._window.program
-        return RecordBuffer.from_records(
-            [Record(value=dsl.window_row_bytes(program, *r))
-             for r in rows.tolist()],
-            base_offset=buf.base_offset,
-            base_timestamp=buf.base_timestamp,
-        )
 
     def _restore_window(self, instance) -> None:
         """`sync_state_from`: the interpreter's window state becomes

@@ -50,6 +50,12 @@ Programs (one per transform kind):
                                          row per closed (window, key), or per
                                          window only the key(s) whose aggregate
                                          is the window's maximum
+    GroupProgram(key, event_time, bucket_ms, columns=[GroupColumn...])
+                                         a keyed RUNNING aggregate (an
+                                         aggregate-kind program; an unbounded
+                                         GROUP BY): every record yields the
+                                         row of its (key, time bucket) group
+                                         as it stands after that record
 """
 
 from __future__ import annotations
@@ -498,6 +504,107 @@ def window_row_bytes(program: WindowProgram, window_end: int, key: int,
     )
 
 
+GROUP_COLUMN_COMBINES = AGGREGATE_COMBINES + ("div",)
+# a group's time bucket renders as the UTC date of its start, which the
+# calendar (`datetime`) holds up to the year 9999
+GROUP_TIME_LIMIT_MS = 253_402_300_800_000
+
+
+@_node
+@dataclass
+class GroupColumn(Expr):
+    """One named column of a `GroupProgram`'s row. ``combine`` add / max
+    / min folds the record's int ``contribution`` into the group's
+    accumulator, for records where ``where`` (a bool expression; None =
+    every record) holds; a column that no record of the group has
+    contributed to reads the monoid's neutral. ``combine="div"`` is a
+    DERIVED column: ``num // den`` of two accumulator columns of the
+    same program, named (0 where ``den`` is 0)."""
+
+    name: str = ""
+    combine: str = "add"
+    contribution: Optional[Expr] = None
+    where: Optional[Expr] = None
+    num: str = ""
+    den: str = ""
+
+
+@_node
+@dataclass
+class GroupProgram(Expr):
+    """A keyed running aggregate, an AGGREGATE-kind program: an
+    unbounded ``GROUP BY key, event_time // bucket_ms`` answered as a
+    changelog (NEXmark Q17 "auction statistics" is the model case).
+
+    Every record yields a ``key`` (int in [0, 2**31)) and an
+    ``event_time`` (epoch ms in [0, `GROUP_TIME_LIMIT_MS`)); its group
+    is (key, event_time // bucket_ms). The record is folded into each
+    accumulator column of its group, and ONE output record takes its
+    place, at its own offset, whose value is the group's row after the
+    fold (`group_row_bytes`). A record whose key cannot be formed (key
+    or time out of range, or the bytes of a ``ParseInt`` key / time
+    empty: a field missing) yields no output and is counted invalid.
+    No watermark and nothing late: disorder changes which row a record
+    sees, never whether it is answered. The table (one entry a group,
+    never closed) belongs to the consumer stream and starts empty."""
+
+    key: Expr = None
+    event_time: Expr = None
+    bucket_ms: int = 86_400_000
+    columns: List[GroupColumn] = field(default_factory=list)
+    key_field: str = "key"
+    bucket_field: str = "day"
+
+
+def group_accumulators(program: GroupProgram) -> List[GroupColumn]:
+    """The program's accumulator columns, in row order (the table's
+    lanes; a derived column has none)."""
+    return [c for c in program.columns if c.combine != "div"]
+
+
+def group_key(program: GroupProgram, value: bytes, key: Optional[bytes]):
+    """(key, time bucket) of one record, or None where it has none: the
+    ONE statement of which records a `GroupProgram` answers."""
+    parts = []
+    for expr in (program.key, program.event_time):
+        if isinstance(expr, ParseInt) and not eval_expr(expr.arg, value, key):
+            return None
+        parts.append(int(eval_expr(expr, value, key)))
+    k, t = parts
+    if not (0 <= k < WINDOW_KEY_LIMIT and 0 <= t < GROUP_TIME_LIMIT_MS):
+        return None
+    return k, t // program.bucket_ms
+
+
+def group_bucket_text(program: GroupProgram, bucket: int) -> bytes:
+    """A time bucket as its row says it: the UTC date of its start."""
+    import datetime
+
+    day = datetime.datetime(1970, 1, 1) + datetime.timedelta(
+        milliseconds=bucket * program.bucket_ms
+    )
+    return day.strftime("%Y-%m-%d").encode("ascii")
+
+
+def group_row_bytes(program: GroupProgram, key: int, bucket: int,
+                    accumulators) -> bytes:
+    """The value of one `GroupProgram` output record: the ONE rendering
+    both executors use. ``accumulators`` are the group's, in
+    `group_accumulators` order."""
+    acc = dict(zip((c.name for c in group_accumulators(program)), accumulators))
+    parts = [b'{"%s":%d,"%s":"%s"' % (
+        program.key_field.encode(), key, program.bucket_field.encode(),
+        group_bucket_text(program, bucket),
+    )]
+    for c in program.columns:
+        if c.combine == "div":
+            v = acc[c.num] // acc[c.den] if acc[c.den] else 0
+        else:
+            v = acc[c.name]
+        parts.append(b',"%s":%d' % (c.name.encode(), v))
+    return b"".join(parts) + b"}"
+
+
 # ---------------------------------------------------------------------------
 # Build-time resolution & interpretation (reference semantics)
 # ---------------------------------------------------------------------------
@@ -541,6 +648,8 @@ def resolve_params(expr: Expr, params: Dict[str, str]) -> Expr:
     if isinstance(resolved, WindowProgram):
         for name in ("window_ms", "slide_ms", "lateness_ms"):
             setattr(resolved, name, int(getattr(resolved, name)))
+    if isinstance(resolved, GroupProgram):
+        resolved.bucket_ms = int(resolved.bucket_ms)
     return resolved
 
 

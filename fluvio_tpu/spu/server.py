@@ -23,6 +23,35 @@ from fluvio_tpu.transport.service import FluvioApiServer
 from fluvio_tpu.transport.tls import server_ssl
 
 
+def keep_large_buffers_on_heap() -> bool:
+    """Tell glibc's allocator to keep freed blocks of up to 32 MiB on
+    ONE heap. A served slice's way out allocates its response several
+    times over (the native slab, its `bytes`, the frame; the consumer's
+    reader and decode on the other side), tens of MB each for a chain
+    that answers every record. By default every block over 128 KiB is a
+    fresh `mmap` handed back on `free`, and a worker thread's arena
+    gives its heaps back the same way: each buffer is then page-faulted
+    in anew, which costs three to five times its memcpy (three copies
+    of 28.6 MB: 50 ms on the main thread and 25 on a worker, against 9
+    with these settings; PERF.md section 6, PR 39) and swings with the
+    host's neighbours. One arena, because the thresholds reach only the
+    main one; the process's allocations are made under the GIL or are
+    few. Best effort: False where libc has no `mallopt` (not glibc),
+    and nothing is changed."""
+    import ctypes
+
+    m_trim_threshold, m_mmap_threshold, m_arena_max = -1, -3, -8  # malloc.h
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    # 32 MiB is the largest mmap threshold glibc takes; a heap that is
+    # never trimmed below 1 GiB of free space keeps the blocks mapped
+    return bool(mallopt(m_mmap_threshold, 32 << 20)
+                and mallopt(m_trim_threshold, 1 << 30)
+                and mallopt(m_arena_max, 1))
+
+
 class SpuServer:
     def __init__(self, config: SpuConfig):
         self.config = config
@@ -67,6 +96,7 @@ class SpuServer:
         from fluvio_tpu.analysis.envreg import warn_unknown_env
 
         warn_unknown_env()
+        keep_large_buffers_on_heap()
         if self.config.smart_engine.backend == "tpu":
             # one process per chip: an SPU asked to serve from the device
             # opens it NOW, so a chip another process holds (or one that

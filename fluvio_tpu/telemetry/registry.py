@@ -201,6 +201,7 @@ class PipelineTelemetry:
         # byte split whose ratio is the d2h-win evidence
         self.windows_closed = 0
         self.window_deltas: Dict[str, int] = {}
+        self.group_totals: Dict[str, int] = {}
         self.window_delta_bytes = 0
         self.window_full_bytes = 0
         # device-memory plane (ISSUE-20): leak-detector counter by
@@ -653,6 +654,30 @@ class PipelineTelemetry:
         `add_chain_build`)."""
         self._event("window-grow", detail)
 
+    def add_group_slice(self, rows: int, keys: int, invalid: int) -> None:
+        """What one served (or interpreted) slice of a keyed running
+        aggregate (`dsl.GroupProgram`) counted: rows answered, the
+        table's entries after it, records dropped for want of a key.
+        Always-on, and dropped rows date an instant event, as a
+        window's do."""
+        with self._lock:
+            for kind, n in (("rows", rows), ("keys", keys),
+                            ("invalid", invalid)):
+                self.group_totals[kind] = self.group_totals.get(kind, 0) + n
+        if invalid:
+            self._event("group-drop", f"invalid:{invalid}")
+
+    def add_group_grow(self, detail: str) -> None:
+        """A served stream's group table outgrew its capacity: the slice
+        is re-run under a doubled one, which compiles (as
+        `add_window_grow`)."""
+        self._event("group-grow", detail)
+
+    def group_counts(self) -> Dict[str, int]:
+        """Running totals of `add_group_slice` (rows, keys, invalid)."""
+        with self._lock:
+            return dict(self.group_totals)
+
     def add_window_downlink(self, delta_bytes: int, full_bytes: int) -> None:
         """One windowed batch's downlink split: bytes the delta
         actually shipped vs what full-state per-record emission would
@@ -1047,6 +1072,7 @@ class PipelineTelemetry:
             self.migration_hist = LatencyHistogram()
             self.windows_closed = 0
             self.window_deltas = {}
+            self.group_totals = {}
             self.window_delta_bytes = 0
             self.window_full_bytes = 0
             self.memory_leaks = {}

@@ -81,7 +81,7 @@ DEVICE_SCOPES = (
                     # through this tuple
     "repad",        # ragged flat -> padded matrix (block fetch, row
                     # shift, byte unpack), derived meta columns
-    "stage",        # stage<i>.<kind>, inner .aggregate_scan/.window_merge/.window_top
+    "stage",        # stage<i>.<kind>, inner .aggregate_scan/.window_*/.group_*
     "compact",      # survivor compaction, mask, header
     "pack",         # byte-mode payload / descriptor stream packing
     "link_encode",  # down-link glz encode of the packed stream
@@ -124,7 +124,11 @@ def stage_scope(index: int, kind: str) -> str:
     stage (`stage<i>.window`: field spans, parses, window assignment)
     opens `stage<i>.window_merge` (concat with the bank, one sort that
     carries the columns, prefix sums, compaction, close, new bank) and
-    `stage<i>.window_top` (the per-window maximum) the same way."""
+    `stage<i>.window_top` (the per-window maximum) the same way, and a
+    group stage (`stage<i>.group`: field spans, parses, the key)
+    `stage<i>.group_merge` (concat with the table, the stable sort, the
+    segmented scans, the new table) and `stage<i>.group_emit` (rows
+    back in offset order, the output columns)."""
     return f"stage{index}.{kind}"
 
 

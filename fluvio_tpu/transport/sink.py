@@ -34,7 +34,8 @@ class FluvioSink:
         await self.write_frame(msg.encode_payload())
 
     async def send_response(self, msg: ResponseMessage, version: Version) -> None:
-        await self.write_frame(msg.encode_payload(version))
+        self.writer.write(msg.frame_buffer(version))
+        await self.writer.drain()
 
     async def send_response_with_file_slices(
         self,

@@ -104,7 +104,8 @@ def prefix_sum(x):
 def compact_front(mask, cap: int, *arrays):
     """The first ``cap`` rows of ``arrays`` where ``mask`` holds, in
     order: (rows kept by the mask — MAY exceed ``cap`` —, the packed
-    columns, zeros past the count). By GATHER: a row's rank is a prefix
+    columns, zeros past the count; a 2-D array is a stack of columns,
+    packed along its last axis). By GATHER: a row's rank is a prefix
     sum of the mask, output slot k binary-searches the row of rank k + 1,
     so the cost is ``cap`` lookups and not one scattered write a row
     (`smartengine.tpu.kernels.compact_rows`: on the chip a scatter of
@@ -124,11 +125,85 @@ def compact_front(mask, cap: int, *arrays):
             live,
             # `src` is in bounds already; the default fill mode lowers
             # through a function that drops the caller's scope
-            jnp.take(arr, src, mode="clip"),
+            jnp.take(arr, src, axis=arr.ndim - 1, mode="clip"),
             jnp.zeros((), arr.dtype),
         )
         for arr in arrays
     )
+
+
+def _sort_segments(cols, num_keys: int = 1):
+    """The ONE sort of a banked stage: by the first column (composite
+    ids), carrying the others along. Returns (sorted ids, the other
+    columns sorted, ``change``: row i + 1 starts another id). The
+    monoids commute, so a merge that reads only segment totals needs no
+    order among ties; a stage that answers every ROW in arrival order
+    makes its position column the second key (a stable sort by another
+    name: positions are distinct; it compiles in two thirds of the
+    time)."""
+    from jax import lax
+
+    sid, *rest = lax.sort(cols, num_keys=num_keys, is_stable=False)
+    return sid, rest, sid[1:] != sid[:-1]
+
+
+_SCAN_BLOCK = 1024
+
+
+def segment_scans(head, lanes, ops):
+    """Inclusive scans along the rows of ``lanes`` (int64[lanes, n], one
+    monoid of ``ops`` each) that restart at every position where
+    ``head`` holds, as a BLOCKED two-level associative scan of (flag,
+    the add lanes, the max lanes, the min lanes): within blocks of
+    1,024, across the blocks' last values, then each block's carry
+    folded into its positions ahead of the block's first head. No
+    gather (on the chip a gathered word costs what forty scanned ones
+    do), and no int64 reduce-window or `jnp.cumsum` (either compiles
+    for two minutes at a slice's size on the chip's compiler, one long
+    associative scan of a [lanes, n] matrix for ten; the blocked form
+    for half a minute)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from fluvio_tpu.windows.spec import OP_NEUTRAL
+
+    fns = {"add": jnp.add, "max": jnp.maximum, "min": jnp.minimum}
+    groups = [(op, [i for i, o in enumerate(ops) if o == op]) for op in fns]
+    groups = [(fns[op], OP_NEUTRAL[op], rows) for op, rows in groups if rows]
+
+    def combine(a, b):
+        return (a[0] | b[0],) + tuple(
+            jnp.where(b[0], y, fn(x, y))
+            for (fn, _, _), x, y in zip(groups, a[1:], b[1:])
+        )
+
+    n = head.shape[0]
+    block = min(_SCAN_BLOCK, n)
+    pad = -n % block
+    # padding starts segments of its own: it carries nothing forward
+    flags = jnp.pad(head, (0, pad), constant_values=True)
+    flags = flags.reshape(1, -1, block)
+    blocks = tuple(
+        jnp.pad(
+            jnp.stack([lanes[i] for i in rows]), ((0, 0), (0, pad))
+        ).reshape(len(rows), -1, block)
+        for _, _, rows in groups
+    )
+    seen, *inner = lax.associative_scan(combine, (flags,) + blocks, axis=2)
+    _, *through = lax.associative_scan(
+        combine, (seen[:, :, -1],) + tuple(b[:, :, -1] for b in inner), axis=1
+    )
+    out = [None] * len(ops)
+    for (fn, neutral, rows), part, ends in zip(groups, inner, through):
+        carry = jnp.concatenate(
+            [jnp.full((len(rows), 1), neutral, ends.dtype), ends[:, :-1]],
+            axis=1,
+        )
+        whole = jnp.where(seen, part, fn(carry[:, :, None], part))
+        whole = whole.reshape(len(rows), -1)[:, :n]
+        for j, i in enumerate(rows):
+            out[i] = whole[j]
+    return jnp.stack(out)
 
 
 def _segment_merge(ids, accs, cnts, touched, op: str, entry_cap: int):
@@ -142,14 +217,11 @@ def _segment_merge(ids, accs, cnts, touched, op: str, entry_cap: int):
     ``touched`` None (nobody reads which entries this batch touched:
     the bank merge, the served top-of-window update) drops that column."""
     import jax.numpy as jnp
-    from jax import lax
 
     from fluvio_tpu.smartengine.tpu.kernels import segmented_scan
 
     cols = [ids, accs, cnts] + ([touched] if touched is not None else [])
-    # the monoids commute, so ties need no order: an unstable sort
-    sid, sacc, *counted = lax.sort(cols, num_keys=1, is_stable=False)
-    change = sid[1:] != sid[:-1]
+    sid, (sacc, *counted), change = _sort_segments(cols)
     tail = jnp.concatenate([change, jnp.ones((1,), bool)])
     is_entry = tail & (sid != EMPTY_ID)
     if op == "add":
@@ -261,25 +333,30 @@ def _merge_and_close(
             n_open, n_closed)
 
 
-def _new_bank(open_m, e_ids, e_accs, e_cnts, capacity: int, neutral: int):
+def _new_bank(open_m, e_ids, e_accs, e_cnts, capacity: int, neutral):
     """The open entries, compacted to ``capacity`` bank rows (ids,
-    accs, counts)."""
+    accs, counts). ``e_accs`` is one column with its ``neutral``, or a
+    keyed table's int64[lanes, entries] with a neutral a lane;
+    ``e_cnts`` may be None (a table keeps no count)."""
     import jax.numpy as jnp
 
-    n_open, (o_ids, o_accs, o_cnts) = compact_front(
-        open_m, capacity, e_ids, e_accs, e_cnts
-    )
+    cols = [e_accs] + ([] if e_cnts is None else [e_cnts])
+    n_open, (o_ids, *o_cols) = compact_front(open_m, capacity, e_ids, *cols)
     pad = capacity - o_ids.shape[0]  # fewer entries than bank rows
     if pad:
-        o_ids, o_accs, o_cnts = (
-            jnp.concatenate([c, jnp.zeros((pad,), c.dtype)])
-            for c in (o_ids, o_accs, o_cnts)
+        o_ids, *o_cols = (
+            jnp.concatenate(
+                [c, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)], axis=-1
+            )
+            for c in (o_ids, *o_cols)
         )
     in_bank = jnp.arange(capacity, dtype=jnp.int32) < n_open
+    fill = (jnp.int64(neutral) if np.ndim(neutral) == 0
+            else jnp.asarray(neutral, dtype=jnp.int64)[:, None])
     return (
         jnp.where(in_bank, o_ids, EMPTY_ID),
-        jnp.where(in_bank, o_accs, jnp.int64(neutral)),
-        jnp.where(in_bank, o_cnts, jnp.int64(0)),
+        jnp.where(in_bank, o_cols[0], fill),
+        None if e_cnts is None else jnp.where(in_bank, o_cols[1], jnp.int64(0)),
     )
 
 
@@ -476,6 +553,80 @@ def update_top(
         ]
     )
     return header, (nb_ids, nb_accs, nb_cnts, new_wm), rows
+
+
+# header slots of `update_group` (all int64)
+GROUP_HEADER = ("n_rows", "n_keys", "n_invalid")
+
+
+def update_group(ops, bank, ids, lanes, valid,
+                 merge_scope: str = "group_merge",
+                 emit_scope: str = "group_emit"):
+    """The SERVED update of a keyed table (`dsl.GroupProgram`; traced
+    inside a chain's one program for a slice, `smartengine/tpu/
+    group_stage.py`): every row is answered by its group's accumulators
+    as they stand after it, and the table takes the slice's groups in.
+
+    ``bank`` is (ids, accs int64[lanes, capacity], None, watermark:
+    carried, not read); ``ids`` the rows' composite ids (EMPTY_ID where
+    a ``valid`` row has no key), ``lanes`` int64[lanes, rows] their
+    contributions. Under ``merge_scope``: the table's entries ahead of
+    the rows, ONE sort by (id, position) (`_sort_segments`: an
+    entry then leads its group's rows, which keep their order; nine
+    carried int64 columns compile for twelve minutes on the chip's
+    compiler, so the lanes follow by one gather of the stacked matrix),
+    the segmented scans (`segment_scans`: the scan at a row IS its
+    answer, the entry's totals included), and the new table from each
+    group's last row (`_new_bank`). Under ``emit_scope``: the rows back
+    in arrival order, by the inverse of the sort's permutation (an
+    int32 scatter of positions) and one gather of the stacked answers.
+    Returns (header [`GROUP_HEADER`], the new bank's arrays, the
+    answers int64[1 + lanes, rows]: id, then a row a lane). The caller
+    compares ``n_keys`` with the capacity; past it the other outputs
+    are not to be read."""
+    import jax
+    import jax.numpy as jnp
+
+    from fluvio_tpu.windows.spec import OP_NEUTRAL
+
+    bank_ids, bank_accs, _no_counts, watermark = bank
+    capacity = bank_ids.shape[0]
+    neutral = tuple(OP_NEUTRAL[op] for op in ops)
+    keyed = valid & (ids != EMPTY_ID)
+    with jax.named_scope(merge_scope):
+        ids = jnp.where(keyed, ids, EMPTY_ID)
+        pos = jnp.arange(capacity + ids.shape[0], dtype=jnp.int32)
+        sid, (spos,), change = _sort_segments(
+            [jnp.concatenate([bank_ids, ids]), pos], num_keys=2
+        )
+        fill = jnp.asarray(neutral, dtype=jnp.int64)[:, None]
+        merged = jnp.concatenate(
+            [bank_accs, jnp.where(keyed, lanes, fill)], axis=1
+        )
+        edge = jnp.ones((1,), bool)
+        scans = segment_scans(
+            jnp.concatenate([edge, change]),
+            jnp.take(merged, spos, axis=1, mode="clip"),
+            ops,
+        )
+        is_entry = jnp.concatenate([change, edge]) & (sid != EMPTY_ID)
+        nb_ids, nb_accs, _ = _new_bank(
+            is_entry, sid, scans, None, capacity, neutral
+        )
+    with jax.named_scope(emit_scope):
+        at = (
+            jnp.zeros(pos.shape, jnp.int32)
+            .at[spos].set(pos, unique_indices=True)[capacity:]
+        )
+        rows = jnp.take(
+            jnp.concatenate([sid[None, :], scans]), at, axis=1, mode="clip"
+        )
+    header = jnp.stack([
+        jnp.sum(keyed).astype(jnp.int64),
+        jnp.sum(is_entry).astype(jnp.int64),
+        jnp.sum(valid & ~keyed).astype(jnp.int64),
+    ])
+    return header, (nb_ids, nb_accs, None, watermark), rows
 
 
 def _merge_core(op: str, neutral: int, capacity: int, a, b):

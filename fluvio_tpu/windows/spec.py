@@ -56,6 +56,10 @@ class WindowSpec:
     capacity: int = 0  # 0 -> FLUVIO_WINDOW_CAPACITY
     emit_capacity: int = 0  # 0 -> FLUVIO_WINDOW_EMIT
     delta_only: bool = True  # FLUVIO_WINDOW_DELTA resolves this
+    # a keyed TABLE (`dsl.GroupProgram`) gives ``op`` as a tuple, one
+    # monoid per accumulator lane (the bank's ``accs`` is then
+    # int64[lanes, capacity]), and keeps no per-entry count
+    counted: bool = True
 
     def __post_init__(self):
         if self.window_ms <= 0:
@@ -66,8 +70,9 @@ class WindowSpec:
                 f"slide_ms ({slide}) must divide window_ms "
                 f"({self.window_ms})"
             )
-        if self.op not in OP_NEUTRAL:
-            raise ValueError(f"unknown combine op {self.op!r}")
+        for op in self.op if isinstance(self.op, tuple) else (self.op,):
+            if op not in OP_NEUTRAL:
+                raise ValueError(f"unknown combine op {op!r}")
         object.__setattr__(self, "slide_ms", slide)
         if self.lateness_ms < 0:
             object.__setattr__(
@@ -92,8 +97,17 @@ class WindowSpec:
         return self.slide_ms == self.window_ms
 
     @property
-    def neutral(self) -> int:
+    def neutral(self):
+        """The monoid's neutral; a tuple of them for a tuple of lanes."""
+        if isinstance(self.op, tuple):
+            return tuple(OP_NEUTRAL[op] for op in self.op)
         return OP_NEUTRAL[self.op]
+
+    @property
+    def entry_bytes(self) -> int:
+        """Device bytes of one bank entry: id, lanes, count (int64)."""
+        lanes = len(self.op) if isinstance(self.op, tuple) else 1
+        return 8 * (1 + lanes + self.counted)
 
     @property
     def mode(self) -> str:

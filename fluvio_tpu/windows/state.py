@@ -2,8 +2,9 @@
 
 The generalization of the partition carry bank (partition/runtime.py):
 instead of one (acc, win, has) triple per aggregate stage, the bank
-holds up to ``capacity`` (composite id, acc, count) rows plus one
-watermark scalar — still tiny, still constant-size, still living in
+holds up to ``capacity`` (composite id, acc, count) rows (a keyed
+table's: composite id and one acc per lane, ``accs`` then int64[lanes,
+capacity] and `WindowSpec.counted` off) plus one watermark scalar — still tiny, still constant-size, still living in
 device memory across batches so nothing but the per-batch DELTA ever
 crosses the link down.
 
@@ -22,8 +23,16 @@ import numpy as np
 
 from fluvio_tpu.windows.spec import EMPTY_ID, INT64_MIN, WindowSpec
 
-# bytes one live bank entry occupies on device (id + acc + count, i64)
+# bytes one live bank entry occupies on device (id + acc + count, i64);
+# a keyed table's: `WindowSpec.entry_bytes`
 ENTRY_BYTES = 24
+
+
+def _filled(spec: WindowSpec, n: int):
+    """``n`` empty slots of the accumulator column(s): int64[n] of the
+    monoid's neutral, or int64[lanes, n] of each lane's (numpy)."""
+    neutral = np.asarray(spec.neutral, dtype=np.int64)
+    return np.broadcast_to(neutral[..., None], neutral.shape + (n,)).copy()
 
 
 class WindowStateBank:
@@ -43,8 +52,8 @@ class WindowStateBank:
         k = self.spec.capacity
         arrs = (
             jnp.full((k,), EMPTY_ID, dtype=jnp.int64),
-            jnp.full((k,), self.spec.neutral, dtype=jnp.int64),
-            jnp.zeros((k,), dtype=jnp.int64),
+            jnp.asarray(_filled(self.spec, k)),
+            jnp.zeros((k,), dtype=jnp.int64) if self.spec.counted else None,
             jnp.int64(self.watermark),
         )
         if self.device is not None:
@@ -85,11 +94,12 @@ class WindowStateBank:
             [self.ids, jnp.full((pad,), EMPTY_ID, dtype=jnp.int64)]
         )
         self.accs = jnp.concatenate(
-            [self.accs, jnp.full((pad,), self.spec.neutral, dtype=jnp.int64)]
+            [self.accs, jnp.asarray(_filled(self.spec, pad))], axis=-1
         )
-        self.counts = jnp.concatenate(
-            [self.counts, jnp.zeros((pad,), dtype=jnp.int64)]
-        )
+        if self.counts is not None:
+            self.counts = jnp.concatenate(
+                [self.counts, jnp.zeros((pad,), dtype=jnp.int64)]
+            )
         self.spec = dataclasses.replace(self.spec, capacity=capacity)
 
     def checkpoint(self) -> tuple:
@@ -102,7 +112,7 @@ class WindowStateBank:
 
     def state_bytes(self) -> int:
         """Live device bytes (the `window_state_bytes` gauge)."""
-        return self.occupancy * ENTRY_BYTES + 8
+        return self.occupancy * self.spec.entry_bytes + 8
 
     def _note_ledger(self) -> None:
         # window_bank device-memory booking is ALWAYS-ON (state size
@@ -118,17 +128,18 @@ class WindowStateBank:
     def snapshot(self) -> Tuple[List[tuple], int]:
         """Host snapshot: ([(id, acc, count), ...] live entries, the
         watermark) — the carries/inst_state pair the CarryReplica bus
-        publishes at commit cadence."""
+        publishes at commit cadence. A keyed table's ``acc`` is the
+        tuple of its lanes and its ``count`` None."""
         import jax
 
         n = self.occupancy
-        ids, accs, counts = jax.device_get(
-            (self.ids[:n], self.accs[:n], self.counts[:n])
-        )
-        entries = [
-            (int(ids[i]), int(accs[i]), int(counts[i])) for i in range(n)
-        ]
-        return entries, self.watermark
+        ids, accs, counts = jax.device_get((
+            self.ids[:n], self.accs[..., :n],
+            None if self.counts is None else self.counts[:n],
+        ))
+        accs = accs.tolist() if accs.ndim == 1 else list(zip(*accs.tolist()))
+        counts = [None] * n if counts is None else counts.tolist()
+        return list(zip(ids.tolist(), accs, counts)), self.watermark
 
     def restore(self, entries: List[tuple], watermark: int) -> None:
         """Seed from a snapshot (promotion / migration / consumer
@@ -147,14 +158,14 @@ class WindowStateBank:
                 f"is {k} (raise FLUVIO_WINDOW_CAPACITY)"
             )
         ids = np.full((k,), EMPTY_ID, dtype=np.int64)
-        accs = np.full((k,), self.spec.neutral, dtype=np.int64)
+        accs = _filled(self.spec, k)
         counts = np.zeros((k,), dtype=np.int64)
         for i, (eid, acc, cnt) in enumerate(entries):
-            ids[i], accs[i], counts[i] = eid, acc, cnt
+            ids[i], accs[..., i], counts[i] = eid, acc, cnt or 0
         arrs = (
             jnp.asarray(ids),
             jnp.asarray(accs),
-            jnp.asarray(counts),
+            jnp.asarray(counts) if self.spec.counted else None,
             jnp.int64(watermark),
         )
         if self.device is not None:

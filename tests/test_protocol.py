@@ -359,3 +359,103 @@ class TestNativeCodecs:
         if lz4_impl == "python" or snappy_impl == "python":
             pytest.skip("no native toolchain: pure-Python fallback in use")
         assert not c._slow_codecs  # no slow-codec warning fired
+
+
+# -- one buffer on the way out (PR 39) -----------------------------------------
+
+
+def _batch_encode_by_parts(b: Batch) -> bytes:
+    """The batch's wire form, assembled the long way round: the part
+    the CRC covers in a buffer of its own, then the preamble."""
+    import struct
+    import zlib
+
+    body = (
+        struct.pack(
+            ">hiqqqhi", b.header.attributes, b.header.last_offset_delta,
+            b.header.first_timestamp, b.header.max_time_stamp,
+            b.header.producer_id, b.header.producer_epoch,
+            b.header.first_sequence,
+        )
+        + struct.pack(">i", b.records_len())
+        + b._encode_record_section()
+    )
+    return (
+        struct.pack(">qiibI", b.base_offset, 4 + 1 + 4 + len(body),
+                    b.header.partition_leader_epoch, b.header.magic,
+                    zlib.crc32(body) & 0xFFFFFFFF)
+        + body
+    )
+
+
+@pytest.mark.parametrize("lead", [0, 7], ids=["empty-writer", "after-other-bytes"])
+@pytest.mark.parametrize("raw", [False, True], ids=["records", "raw-slab"])
+def test_batch_and_record_set_encode_in_place_equal_the_parts(lead, raw):
+    """`Batch.encode` and `RecordSet.encode` write into the caller's
+    writer and patch the CRC and the lengths afterwards: the bytes are
+    those of the assembly by parts, wherever in the writer they start."""
+    import struct
+
+    batches = []
+    for base in (40, 90):
+        b = Batch.from_records(
+            [Record(value=b"v%d" % (base + i), key=b"k" if i else None)
+             for i in range(3)],
+            base_offset=base, first_timestamp=1_700_000_000_000,
+        )
+        if raw:
+            w = ByteWriter()
+            b.encode(w)
+            b = Batch.decode(ByteReader(w.bytes()), parse_records=False)
+            assert b.raw_records is not None
+        batches.append(b)
+    w = ByteWriter()
+    w.write_raw(b"\xAA" * lead)
+    batches[0].encode(w)
+    assert w.bytes() == b"\xAA" * lead + _batch_encode_by_parts(batches[0])
+    rs = RecordSet()
+    for b in batches:
+        rs.add(b)
+    w = ByteWriter()
+    w.write_raw(b"\xAA" * lead)
+    rs.encode(w)
+    body = b"".join(map(_batch_encode_by_parts, batches))
+    assert w.bytes() == b"\xAA" * lead + struct.pack(">i", len(body)) + body
+    back = RecordSet.decode(ByteReader(w.bytes()[lead:]))
+    assert [r.value for b in back.batches for r in b.memory_records()] == [
+        b"v40", b"v41", b"v42", b"v90", b"v91", b"v92"]
+    # the CRC the decoder checks is the patched one
+    Batch.decode(ByteReader(_batch_encode_by_parts(batches[1])), check_crc=True)
+    w = ByteWriter()
+    batches[1].encode(w)
+    Batch.decode(ByteReader(w.bytes()), check_crc=True)
+
+
+def test_response_frame_buffer_is_the_frame():
+    """`ResponseMessage.frame_buffer` is the length-prefixed frame in the
+    one buffer it was encoded into: what `to_frame` and the payload the
+    sink used to prefix spell."""
+    import struct
+
+    from fluvio_tpu.protocol.api import ResponseMessage
+    from fluvio_tpu.schema.spu import FetchOffsetsResponse
+
+    msg = ResponseMessage(77, FetchOffsetsResponse())
+    payload = msg.encode_payload(0)
+    frame = msg.frame_buffer(0)
+    assert isinstance(frame, bytearray)
+    assert bytes(frame) == struct.pack(">i", len(payload)) + payload
+    assert msg.to_frame(0) == bytes(frame)
+
+
+def test_large_buffers_stay_on_the_heap_where_libc_is_glibc():
+    """Best effort and repeatable: True on glibc (the settings took),
+    False elsewhere, never an exception."""
+    import platform
+
+    from fluvio_tpu.spu.server import keep_large_buffers_on_heap
+
+    took = keep_large_buffers_on_heap()
+    assert took == keep_large_buffers_on_heap()
+    if platform.libc_ver()[0] == "glibc":
+        assert took is True
