@@ -23,11 +23,12 @@ stripes.py, lower.py):
   ``.block_until_ready()``, ``jax.device_get(...)`` are forbidden in
   the kernel modules and in the executor's dispatch-side hot functions
   (the fetch side legitimately materializes).
-- **FLV004** telemetry seams stay zero-cost: engine modules may touch
-  ``TELEMETRY`` only through the guarded seam API (counter adds,
-  begin/end batch, gauge_add/gauge_set, ``enabled``) — never registry
-  internals, whose cost is not covered by the ``FLUVIO_TELEMETRY=0``
-  zero-cost contract.
+- **FLV004** telemetry seams stay zero-cost: engine modules and the
+  client's socket layer (``transport/``, which every consumer process
+  runs once per response) may touch ``TELEMETRY`` only through the
+  guarded seam API (counter adds, begin/end batch, begin/end consume,
+  gauge_add/gauge_set, ``enabled``) — never registry internals, whose
+  cost is not covered by the ``FLUVIO_TELEMETRY=0`` zero-cost contract.
 
 Repo-wide hygiene rules (the curated subset `ruff` would enforce,
 kept native so the gate holds even where ruff is not installed):
@@ -65,6 +66,7 @@ DISPATCH_HOT_FUNCS = {
 # counter adds whose cost contract telemetry/registry.py documents)
 ALLOWED_TELEMETRY_SEAMS = {
     "enabled", "begin_batch", "end_batch", "add_phase",
+    "begin_consume", "end_consume",
     "add_spill", "add_decline", "add_link_variant", "add_heal",
     "add_stripe_fallback",
     "add_retry", "add_quarantine", "add_compile", "add_jit_hit",
@@ -389,15 +391,16 @@ def lint_source(
 ) -> List[LintViolation]:
     """Lint one source blob. Role flags default from the path: kernel
     rules for the four kernel modules, telemetry-seam rules for
-    everything under ``smartengine/tpu/``, hygiene rules everywhere
-    (``__init__.py`` re-export surfaces skip the unused-import rule)."""
+    everything under ``smartengine/tpu/`` and ``transport/``, hygiene
+    rules everywhere (``__init__.py`` re-export surfaces skip the
+    unused-import rule)."""
     base = os.path.basename(path)
     norm = path.replace(os.sep, "/")
     in_tpu = "smartengine/tpu/" in norm
     if kernel_module is None:
         kernel_module = in_tpu and base in KERNEL_MODULES
     if engine_module is None:
-        engine_module = in_tpu
+        engine_module = in_tpu or "fluvio_tpu/transport/" in norm
     if check_imports is None:
         check_imports = base != "__init__.py"
     try:

@@ -962,8 +962,14 @@ class StreamFetchHandler:
     async def _push_processed(
         self, leader, result: BatchProcessResult, flow=None
     ) -> int:
-        """Send one processed-slice response (the flow's ``send``
-        phase); returns the next offset."""
+        """Send one processed-slice response; returns the next offset.
+        The flow books the response's frame build (the copy of its slab
+        into one length-prefixed buffer) as ``frame``, then the socket
+        write and drain under the connection's sink lock as ``send``, and
+        carries the response's ``stream_id`` / ``next_offset``: the key
+        that joins it to the consumer's record of the same response. A
+        consumer on this event loop receives the frame inside ``send``
+        and ``ack_wait``; its own records name that time."""
         info = leader.offsets()
         partition = FetchablePartitionResponse(
             partition_index=self.req.partition,
@@ -982,10 +988,15 @@ class StreamFetchHandler:
             stream_id=self.stream_id,
             partition=partition,
         )
-        with timed(flow, "send"):
-            await self.sink.send_response(
-                ResponseMessage(self.correlation_id, resp), self.version
+        if flow is not None:
+            flow.stream_id = self.stream_id
+            flow.next_offset = result.next_offset
+        with timed(flow, "frame"):
+            frame = ResponseMessage(self.correlation_id, resp).frame_buffer(
+                self.version
             )
+        with timed(flow, "send"):
+            await self.sink.send_frame(frame)
         nbytes = sum(b.write_size() for b in result.records.batches)
         self.ctx.metrics.outbound.add(result.records.total_records(), nbytes)
         if TELEMETRY.enabled and result.records.batches:
@@ -1005,8 +1016,11 @@ class StreamFetchHandler:
         self, target: int, end_wait: asyncio.Future, flow=None
     ) -> None:
         """Backpressure: hold the next push until the consumer acks (the
-        ``ack_wait`` phase of the flow whose push is being acked; the
-        consumer's own decode of the response is inside it)."""
+        ``ack_wait`` phase of the flow whose push is being acked). What
+        the wait holds is the consumer's: the rest of the frame's way
+        through the socket, its decode and its ack, which a consumer on
+        this event loop books as its ``consume_recv`` /
+        ``consume_decode`` / ``consume_ack``."""
         listener = self.ack_publisher.change_listener()
         with timed(flow, "ack_wait"):
             while (

@@ -40,7 +40,13 @@ entirely (event counters stay on — they are as cheap as the existing
 """
 
 from fluvio_tpu.telemetry.histogram import LatencyHistogram
-from fluvio_tpu.telemetry.flow import SLICE_PHASES, FlowRing, SliceFlow
+from fluvio_tpu.telemetry.flow import (
+    CONSUME_PHASES,
+    SLICE_PHASES,
+    ConsumeFlow,
+    FlowRing,
+    SliceFlow,
+)
 from fluvio_tpu.telemetry.spans import (
     PHASES,
     BatchSpan,
@@ -71,6 +77,8 @@ install_env_sink()
 __all__ = [
     "LatencyHistogram",
     "SLICE_PHASES",
+    "CONSUME_PHASES",
+    "ConsumeFlow",
     "FlowRing",
     "SliceFlow",
     "PHASES",

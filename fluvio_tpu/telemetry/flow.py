@@ -42,16 +42,24 @@ the root span of everything the serving task did for it —
 - ``encode``      per chunk the resume drop and one native pass from
                   the buffer's own form to the response slab (the
                   ``max_bytes`` cut inside it), then the `Batch` build,
-- ``send``        `sink.send_response`,
-- ``ack_wait``    until the consumer's ack reaches the pushed offset
-                  (the consumer's own decode is inside it),
+- ``frame``       the response's length-prefixed frame, encoded into
+                  one buffer (`ResponseMessage.frame_buffer`): the copy
+                  of the response slab,
+- ``send``        the frame's socket write and drain, under the
+                  connection's sink lock (`ExclusiveSink.send_frame`),
+- ``ack_wait``    until the consumer's ack reaches the pushed offset.
+                  A consumer on the SPU's own event loop receives,
+                  decodes and acks inside it (and inside ``send``): its
+                  `ConsumeFlow` names that time,
 - ``interpret``   the per-record fallback pass, so a slice the fast
                   path declined is not a hole,
 
 — one boolean, ``interleaved``: the stream loop dispatched the next
 slice between this slice's ``finish`` and its ``materialize``, so the
 device worked under this slice's host half (a stream's last slice has no
-next one) —
+next one); and the response's join key, ``stream_id`` and
+``next_offset`` (its ``next_filter_offset``), which the consumer's
+`ConsumeFlow` of the same response carries too —
 
 and closes AFTER its ack wait (`end_flow` follows `_wait_for_ack` in
 both stream loops): ``ack_wait`` belongs to the slice it waits for, so
@@ -62,10 +70,26 @@ connected to the batch spans they caused via Chrome-trace flow events
 (``ph: s/t/f`` with a shared ``id`` — see telemetry/trace.py); a span
 names its slice by ``flow_id``.
 
+The other side of the socket: a :class:`ConsumeFlow` per stream
+response a client's `MultiplexerSocket` receives, with its own phases
+(`CONSUME_PHASES`, no name shared with the slice's) —
+
+- ``consume_recv``   from the frame's length prefix in hand to the whole
+                     payload in hand (the dispatcher task, awaiting),
+- ``consume_decode`` payload to the decoded `StreamFetchResponse`
+                     (`AsyncResponse.next`; holds the loop),
+- ``consume_ack``    the `UpdateOffsetsRequest`'s encode and write
+                     (`send_async`), booked on the record of the
+                     response whose ``(stream_id, next_offset)`` the ack
+                     names; the record closes after it —
+
+in a ring of its own (`PipelineTelemetry.consumes`), so the slice ring
+keeps the window it held before.
+
 Cost contract: one object + a handful of clock reads per SLICE (never
-per record, never per batch chunk); `PipelineTelemetry.begin_flow`
-returns None when capture is off or ``FLUVIO_FLOW_TRACE=0``, and every
-instrumentation site guards on that.
+per record, never per batch chunk) and per response; `begin_flow` /
+`begin_consume` return None when capture is off or
+``FLUVIO_FLOW_TRACE=0``, and every instrumentation site guards on that.
 """
 
 from __future__ import annotations
@@ -80,39 +104,74 @@ from fluvio_tpu.telemetry.spans import _BoundedRing
 SLICE_PHASES = (
     "queue_wait", "batcher", "hold", "serve",
     "chain_acquire", "read", "wire_decode", "stage", "dispatch", "finish",
-    "materialize", "encode", "send", "ack_wait", "interpret",
+    "materialize", "encode", "frame", "send", "ack_wait", "interpret",
 )
 
+#: fixed phase vocabulary of a consumer's record of one response
+CONSUME_PHASES = ("consume_recv", "consume_decode", "consume_ack")
 
-class SliceFlow:
-    """One slice's causal walk through the serving pipeline.
 
-    Not thread-safe; owned by the task driving the slice (ring
-    insertion at `PipelineTelemetry.end_flow` is what synchronizes),
-    exactly like `BatchSpan`.
-    """
+class _PhasedRecord:
+    """What a slice's and a response's record share: an id from the
+    registry's one flow sequence, a clock pair, wall-positioned phases.
+    Not thread-safe; owned by the task driving it (ring insertion at
+    close is what synchronizes), exactly like `BatchSpan`."""
+
+    __slots__ = ("flow_id", "t0", "t_end", "records", "phases")
+
+    def __init__(self, flow_id: int) -> None:
+        self.flow_id = flow_id
+        self.t0 = time.perf_counter()
+        self.t_end: Optional[float] = None
+        self.records = 0
+        #: wall-positioned phases: (name, start, seconds)
+        self.phases: List[Tuple[str, float, float]] = []
+
+    def add_phase(self, name: str, start: float, seconds: float) -> None:
+        if seconds > 0.0:
+            self.phases.append((name, start, seconds))
+
+    def phase_totals(self) -> Dict[str, float]:
+        """{phase: total seconds} across this record's phases."""
+        out: Dict[str, float] = {}
+        for name, _start, s in self.phases:
+            out[name] = out.get(name, 0.0) + s
+        return out
+
+    def _phases_dict(self) -> Dict:
+        totals = self.phase_totals()
+        if not totals:
+            return {}
+        return {
+            "phases_ms": {k: round(v * 1000, 3) for k, v in totals.items()},
+            # wall-positioned: readers and gap attribution need where a
+            # phase sat, not only how long it was
+            "phases": [
+                [name, round(start, 6), round(s, 6)]
+                for name, start, s in self.phases
+            ],
+        }
+
+
+class SliceFlow(_PhasedRecord):
+    """One slice's causal walk through the serving pipeline."""
 
     #: lane-group key in the trace renderer (class attribute so the
     #: lane allocator treats flows as one track family)
     path = "slice"
 
     __slots__ = (
-        "flow_id", "chain", "tenant", "t0", "t_end", "records", "phases",
-        "decision", "holds", "cause", "sources", "batch_id", "interleaved",
+        "chain", "tenant", "decision", "holds", "cause", "sources",
+        "batch_id", "interleaved", "stream_id", "next_offset",
         "_q_t0", "_b_t0",
     )
 
     def __init__(self, flow_id: int, chain: str = "", tenant: str = "") -> None:
-        self.flow_id = flow_id
+        super().__init__(flow_id)
         self.chain = chain
         #: tenant label (topic-name prefix) — the soak scorer joins
         #: flow-ring records against the per-tenant counter families
         self.tenant = tenant
-        self.t0 = time.perf_counter()
-        self.t_end: Optional[float] = None
-        self.records = 0
-        #: wall-positioned phases: (name, start, seconds)
-        self.phases: List[Tuple[str, float, float]] = []
         #: last admission outcome ("admit" or the shed reason)
         self.decision: Optional[str] = None
         self.holds = 0  # shed-then-retry cycles survived
@@ -128,14 +187,14 @@ class SliceFlow:
         #: the next slice went out to the device between this slice's
         #: ``finish`` and its ``materialize``
         self.interleaved = False
+        #: the pushed response's join key with the consumer's record of
+        #: it (None until `_push_processed` sends one)
+        self.stream_id: Optional[int] = None
+        self.next_offset: Optional[int] = None
         self._q_t0: Optional[float] = None
         self._b_t0: Optional[float] = None
 
     # -- phase capture -------------------------------------------------------
-
-    def add_phase(self, name: str, start: float, seconds: float) -> None:
-        if seconds > 0.0:
-            self.phases.append((name, start, seconds))
 
     def hold(self, seconds: float) -> None:
         """One shed-hold released: callers measure ``seconds`` against
@@ -169,13 +228,6 @@ class SliceFlow:
 
     # -- reads ---------------------------------------------------------------
 
-    def phase_totals(self) -> Dict[str, float]:
-        """{phase: total seconds} across this flow's recorded phases."""
-        out: Dict[str, float] = {}
-        for name, _start, s in self.phases:
-            out[name] = out.get(name, 0.0) + s
-        return out
-
     def serve_seconds(self) -> float:
         end = self.t_end if self.t_end is not None else time.perf_counter()
         return max(end - self.t0, 0.0)
@@ -199,25 +251,51 @@ class SliceFlow:
         if self.cause:
             d["cause"] = self.cause
             d["sources"] = self.sources
+        if self.stream_id is not None:
+            d["stream_id"] = self.stream_id
+            d["next_offset"] = self.next_offset
         if self.t_end is not None:
             d["t_end"] = round(self.t_end, 6)
-        totals = self.phase_totals()
-        if totals:
-            d["phases_ms"] = {
-                k: round(v * 1000, 3) for k, v in totals.items()
-            }
-            # wall-positioned: readers and gap attribution need where a
-            # phase sat, not only how long it was
-            d["phases"] = [
-                [name, round(start, 6), round(s, 6)]
-                for name, start, s in self.phases
-            ]
-        return d
+        return d | self._phases_dict()
+
+
+class ConsumeFlow(_PhasedRecord):
+    """A client's record of one stream response: its `CONSUME_PHASES`,
+    the response's ``stream_id`` and ``next_offset`` (the join key with
+    the serving side's `SliceFlow`), the frame's bytes and the records
+    its batch headers count."""
+
+    path = "consume"
+
+    __slots__ = ("stream_id", "next_offset", "frame_bytes")
+
+    def __init__(self, flow_id: int) -> None:
+        super().__init__(flow_id)
+        self.stream_id: Optional[int] = None
+        self.next_offset: Optional[int] = None
+        self.frame_bytes = 0
+
+    def close(self) -> None:
+        self.t_end = time.perf_counter()
+
+    def to_dict(self) -> Dict:
+        d: Dict = {
+            "flow_id": self.flow_id,
+            "stream_id": self.stream_id,
+            "next_offset": self.next_offset,
+            "frame_bytes": self.frame_bytes,
+            "records": self.records,
+            "t0": round(self.t0, 6),
+        }
+        if self.t_end is not None:
+            d["t_end"] = round(self.t_end, 6)
+        return d | self._phases_dict()
 
 
 class FlowRing(_BoundedRing):
-    """Bounded ring of completed `SliceFlow`s (same primitive as the
-    span/event rings — one lock/slicing discipline for all three)."""
+    """Bounded ring of completed `SliceFlow`s or `ConsumeFlow`s (same
+    primitive as the span/event rings — one lock/slicing discipline for
+    all of them)."""
 
     def __init__(self, capacity: int = 512) -> None:
         super().__init__(capacity)

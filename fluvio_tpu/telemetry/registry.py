@@ -34,7 +34,12 @@ import time
 from typing import Dict, List, Optional
 
 from fluvio_tpu.telemetry.histogram import LatencyHistogram
-from fluvio_tpu.telemetry.flow import SLICE_PHASES, FlowRing, SliceFlow
+from fluvio_tpu.telemetry.flow import (
+    SLICE_PHASES,
+    ConsumeFlow,
+    FlowRing,
+    SliceFlow,
+)
 from fluvio_tpu.telemetry.spans import (
     PHASES,
     BatchSpan,
@@ -163,6 +168,11 @@ class PipelineTelemetry:
         # when either is off (the zero-cost seam every site guards on)
         self.flow_trace = env_bool("FLUVIO_FLOW_TRACE")
         self.flows = FlowRing(FLOW_RING_CAPACITY)
+        # the client side of a stream socket: one closed `ConsumeFlow`
+        # per response a consumer received, in a ring of its own (the
+        # slice ring's window must not halve); ids from the same
+        # sequence, so a trace can hold both kinds
+        self.consumes = FlowRing(FLOW_RING_CAPACITY)
         self._flow_seq = 0
         # per-phase slice lifecycle histograms (queue-wait, batcher
         # residence, shed-hold, arrival->served): the Prometheus
@@ -315,6 +325,28 @@ class PipelineTelemetry:
             on_flow = getattr(sink, "on_flow", None)
             if on_flow is not None:
                 on_flow(flow)
+
+    def begin_consume(self) -> Optional[ConsumeFlow]:
+        """A new record of one received stream response, or None when
+        capture/flow tracing is off (the client's zero-cost seam)."""
+        if not (self.enabled and self.flow_trace):
+            return None
+        with self._lock:
+            self._flow_seq += 1
+            fid = self._flow_seq
+        return ConsumeFlow(fid)
+
+    def end_consume(self, rec: Optional[ConsumeFlow]) -> None:
+        """Close a response's record and push it onto the consume ring
+        (no histogram, no Prometheus family: the trace and the benchmark
+        read the ring)."""
+        if rec is None:
+            return
+        rec.close()
+        self.consumes.push(rec)
+
+    def consumes_json(self, limit: Optional[int] = None) -> List[dict]:
+        return [c.to_dict() for c in self.consumes.recent(limit)]
 
     def add_slice_phase(self, name: str, seconds: float) -> None:
         """Record one slice-phase observation outside a flow close (the
@@ -1084,6 +1116,7 @@ class PipelineTelemetry:
         self.spans = SpanRing(self.spans.capacity)
         self.events = EventRing(self.events.capacity)
         self.flows = FlowRing(self.flows.capacity)
+        self.consumes = FlowRing(self.consumes.capacity)
 
 
 TELEMETRY = PipelineTelemetry()

@@ -33,7 +33,7 @@ import os
 import time
 from typing import Dict, List, Optional
 
-from fluvio_tpu.telemetry.flow import SliceFlow
+from fluvio_tpu.telemetry.flow import ConsumeFlow, SliceFlow
 from fluvio_tpu.telemetry.registry import TELEMETRY, PipelineTelemetry
 from fluvio_tpu.telemetry.spans import PHASES, BatchSpan, InstantEvent
 
@@ -47,8 +47,10 @@ DEFAULT_TRACE_MAX_MB = 64.0
 _PID = 1
 # tid layout: tid 0 is the instant-event track; batch lanes start at
 # path_rank * stride + 1 so each path family groups its lanes together;
-# per-slice flow lanes are their own "slice" family (rank 3)
-_PATH_RANK = {"fused": 0, "striped": 1, "interpreter": 2, "slice": 3}
+# per-slice flow lanes are their own "slice" family (rank 3), a
+# consumer's records of the responses the "consume" family (rank 4)
+_PATH_RANK = {"fused": 0, "striped": 1, "interpreter": 2, "slice": 3,
+              "consume": 4}
 _LANE_STRIDE = 100
 
 
@@ -76,7 +78,7 @@ class _LaneAllocator:
 
 
 def _tid(path: str, lane: int) -> int:
-    return _PATH_RANK.get(path, 4) * _LANE_STRIDE + lane + 1
+    return _PATH_RANK.get(path, 5) * _LANE_STRIDE + lane + 1
 
 
 def _thread_meta(path: str, lane: int) -> List[dict]:
@@ -175,18 +177,7 @@ def flow_trace_events(
             "args": args,
         }
     ]
-    for name, p_t0, s in flow.phases:
-        out.append(
-            {
-                "name": name,
-                "cat": "slice-phase",
-                "ph": "X",
-                "pid": _PID,
-                "tid": tid,
-                "ts": _us(p_t0, base),
-                "dur": round(s * 1e6, 3),
-            }
-        )
+    out.extend(_phase_events(flow, "slice-phase", tid, base))
     head = {"name": "slice-flow", "cat": "flow", "id": flow.flow_id,
             "pid": _PID}
     out.append(dict(head, ph="s", tid=tid, ts=_us(flow.t0, base)))
@@ -198,6 +189,63 @@ def flow_trace_events(
             )
         )
     out.append(dict(head, ph="f", bp="e", tid=tid, ts=_us(t_end, base)))
+    return out
+
+
+def _phase_events(record, cat: str, tid: int, base: float) -> List[dict]:
+    """A flow record's wall-positioned phases as duration events."""
+    return [
+        {
+            "name": name,
+            "cat": cat,
+            "ph": "X",
+            "pid": _PID,
+            "tid": tid,
+            "ts": _us(p_t0, base),
+            "dur": round(s * 1e6, 3),
+        }
+        for name, p_t0, s in record.phases
+    ]
+
+
+def consume_trace_events(
+    rec: ConsumeFlow,
+    lane: int,
+    base: float,
+    sent: Optional[tuple] = None,
+) -> List[dict]:
+    """One received response on the ``consume`` lane group: an envelope,
+    its ``consume_*`` phases at their wall positions, and, where the
+    slice flow that pushed it is known (``sent`` = (``send`` start,
+    that flow's tid), joined on ``stream_id`` / ``next_offset``), a
+    Chrome-trace flow arrow from the slice's ``send`` to this record's
+    start (its ``consume_recv`` starts there). Its id is the record's
+    own ``flow_id``, from the sequence the slice flows draw from, so no
+    arrow shares one."""
+    tid = _tid("consume", lane)
+    t_end = rec.t_end if rec.t_end is not None else rec.t0
+    out = [
+        {
+            "name": f"response[{rec.records}]",
+            "cat": "consume",
+            "ph": "X",
+            "pid": _PID,
+            "tid": tid,
+            "ts": _us(rec.t0, base),
+            "dur": round(max(t_end - rec.t0, 0.0) * 1e6, 3),
+            "args": {
+                "flow_id": rec.flow_id, "stream_id": rec.stream_id,
+                "next_offset": rec.next_offset,
+                "frame_bytes": rec.frame_bytes, "records": rec.records,
+            },
+        }
+    ]
+    out.extend(_phase_events(rec, "consume-phase", tid, base))
+    if sent is not None:
+        head = {"name": "response", "cat": "consume-flow",
+                "id": rec.flow_id, "pid": _PID}
+        out.append(dict(head, ph="s", tid=sent[1], ts=_us(sent[0], base)))
+        out.append(dict(head, ph="f", bp="e", tid=tid, ts=_us(rec.t0, base)))
     return out
 
 
@@ -235,17 +283,22 @@ def build_trace(
     spans: List[BatchSpan],
     events: Optional[List[InstantEvent]] = None,
     flows: Optional[List[SliceFlow]] = None,
+    consumes: Optional[List[ConsumeFlow]] = None,
 ) -> dict:
     """Assemble one complete Chrome-trace document from a span list
-    (completion order), an instant-event list, and the per-slice flow
+    (completion order), an instant-event list, the per-slice flow
     records (rendered as their own ``slice`` lane group, flow-linked to
-    the batch spans they rode)."""
+    the batch spans they rode) and a consumer's records of the responses
+    (the ``consume`` lane group, each arrow-linked from the ``send`` of
+    the slice that pushed it)."""
     events = events or []
     flows = flows or []
+    consumes = consumes or []
     times = (
         [s.t0 for s in spans]
         + [e.t for e in events]
         + [f.t0 for f in flows]
+        + [c.t0 for c in consumes]
     )
     base = min(times) if times else 0.0
     out = list(_base_meta())
@@ -265,6 +318,8 @@ def build_trace(
         out.extend(span_trace_events(span, lane, base))
     for ev in events:
         out.append(instant_trace_event(ev, base))
+    # a pushed response's (stream id, next offset): (send start, tid)
+    sends: Dict[tuple, tuple] = {}
     for flow in sorted(flows, key=lambda f: f.t0):
         lane = alloc.lane(flow)
         if ("slice", lane) not in seen:
@@ -275,13 +330,29 @@ def build_trace(
                 flow, lane, base, span_tracks.get(flow.batch_id)
             )
         )
+        send = next((p for p in flow.phases if p[0] == "send"), None)
+        if flow.stream_id is not None and send is not None:
+            sends[(flow.stream_id, flow.next_offset)] = (
+                send[1], _tid("slice", lane)
+            )
+    for rec in sorted(consumes, key=lambda c: c.t0):
+        lane = alloc.lane(rec)
+        if ("consume", lane) not in seen:
+            seen.add(("consume", lane))
+            out.extend(_thread_meta("consume", lane))
+        out.extend(consume_trace_events(
+            rec, lane, base, sends.get((rec.stream_id, rec.next_offset))
+        ))
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
 def render_trace(telemetry: Optional[PipelineTelemetry] = None) -> dict:
     """The current flight-recorder contents as one trace document."""
     t = telemetry if telemetry is not None else TELEMETRY
-    return build_trace(t.spans.recent(), t.events.recent(), t.flows.recent())
+    return build_trace(
+        t.spans.recent(), t.events.recent(), t.flows.recent(),
+        t.consumes.recent(),
+    )
 
 
 def trace_json(telemetry: Optional[PipelineTelemetry] = None) -> str:

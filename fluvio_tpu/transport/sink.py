@@ -34,7 +34,15 @@ class FluvioSink:
         await self.write_frame(msg.encode_payload())
 
     async def send_response(self, msg: ResponseMessage, version: Version) -> None:
-        self.writer.write(msg.frame_buffer(version))
+        """Encode ``msg``'s frame, then write it. The SPU's stream
+        handler does the two apart (`ResponseMessage.frame_buffer` under
+        its slice's ``frame`` phase, `send_frame` under ``send``), so
+        that the encode's copy and the socket are timed apart."""
+        await self.send_frame(msg.frame_buffer(version))
+
+    async def send_frame(self, frame: bytearray) -> None:
+        """Write one encoded length-prefixed frame and drain."""
+        self.writer.write(frame)
         await self.writer.drain()
 
     async def send_response_with_file_slices(
@@ -105,6 +113,12 @@ class ExclusiveSink:
     async def send_response(self, msg: ResponseMessage, version: Version) -> None:
         async with self._lock:
             await self._sink.send_response(msg, version)
+
+    async def send_frame(self, frame: bytearray) -> None:
+        """A frame encoded before the lock is taken: only the write and
+        the drain hold the connection."""
+        async with self._lock:
+            await self._sink.send_frame(frame)
 
     async def send_response_with_file_slices(
         self,

@@ -68,6 +68,11 @@ class FluvioSocket:
 
     async def read_frame(self) -> bytes:
         """Read one length-prefixed frame; raises SocketClosed at EOF."""
+        return await self.read_frame_body(await self.read_frame_length())
+
+    async def read_frame_length(self) -> int:
+        """A frame's length prefix (the first half of `read_frame`: a
+        reader that times the payload's arrival starts its clock here)."""
         try:
             header = await self.reader.readexactly(4)
         except (asyncio.IncompleteReadError, ConnectionResetError):
@@ -75,6 +80,10 @@ class FluvioSocket:
         (length,) = struct.unpack(">i", header)
         if length < 0:
             raise SocketClosed()
+        return length
+
+    async def read_frame_body(self, length: int) -> bytes:
+        """The ``length`` payload bytes after a frame's prefix."""
         try:
             return await self.reader.readexactly(length)
         except (asyncio.IncompleteReadError, ConnectionResetError):

@@ -516,6 +516,19 @@ def test_runtime_lock_graph_matches_static_prediction(tmp_path):
 # ---------------------------------------------------------------------------
 
 
+def test_consume_ring_adds_no_lock_and_no_nesting():
+    """The consumer's response records (`TELEMETRY.consumes`) are one
+    more `FlowRing`: its lock is the canonical ``telemetry.ring``, the
+    registry lock only guards the shared flow sequence, and the ring
+    push happens outside it (no registry -> ring edge)."""
+    import fluvio_tpu.transport.multiplexing  # noqa: F401 — the new seam
+
+    names = set(analyze_package().locks)
+    assert {"telemetry.registry", "telemetry.ring"} <= names
+    assert not [n for n in names if "consume" in n or "transport" in n]
+    assert ("telemetry.registry", "telemetry.ring") not in static_lock_graph()
+
+
 def test_admission_locks_in_static_vocabulary():
     """ISSUE-11: the admission layer's locks are created via make_lock
     under canonical names, so the FLV2xx analyzer's graph covers them

@@ -262,6 +262,28 @@ def test_telemetry_seam_rejects_registry_internals():
     assert _codes(vs) == ["FLV004", "FLV004"]
 
 
+def test_telemetry_seam_covers_the_client_socket_layer():
+    """The consumer's records of stream responses go through
+    `begin_consume` / `end_consume`; the consume ring itself is a
+    registry internal there as anywhere."""
+    ok = (
+        "from fluvio_tpu.telemetry import TELEMETRY\n"
+        "def f():\n"
+        "    rec = TELEMETRY.begin_consume()\n"
+        "    TELEMETRY.end_consume(rec)\n"
+    )
+    path = "fluvio_tpu/transport/multiplexing.py"
+    assert not lint_source(ok, path=path)
+    bad = (
+        "from fluvio_tpu.telemetry import TELEMETRY\n"
+        "def f(rec):\n"
+        "    TELEMETRY.consumes.push(rec)\n"
+    )
+    assert _codes(lint_source(bad, path=path)) == ["FLV004"]
+    # outside the seam's layers the rule does not apply
+    assert not lint_source(bad, path="fluvio_tpu/spu/monitoring.py")
+
+
 # ---------------------------------------------------------------------------
 # FLV101/FLV102 — hygiene
 # ---------------------------------------------------------------------------

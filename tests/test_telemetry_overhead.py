@@ -960,3 +960,103 @@ def test_memory_seams_zero_cost_when_telemetry_off(monkeypatch):
         TELEMETRY.enabled = prior
         TELEMETRY.reset()
         memory_mod.reset_engine()
+
+
+def test_consume_seams_zero_cost_when_telemetry_off(tmp_path, monkeypatch):
+    """The consumer's records, the strict half: with FLUVIO_TELEMETRY=0 a
+    served stream through a real SPU socket makes no consumer record and
+    reads no phase clock on either side of the socket (the multiplexer's
+    receive, decode and ack; the slice's frame and send), and the consume
+    ring stays empty."""
+    import asyncio
+
+    from fluvio_tpu.client import ConsumerConfig, Fluvio, Offset
+    from fluvio_tpu.schema.smartmodule import (
+        SmartModuleInvocation,
+        SmartModuleInvocationWasm,
+    )
+    from fluvio_tpu.spu import SpuConfig, SpuServer
+    from fluvio_tpu.storage.config import ReplicaConfig
+    from fluvio_tpu.telemetry import flow as flow_module
+    from fluvio_tpu.telemetry import spans as spans_module
+
+    def tripwire(*a, **k):
+        raise AssertionError("consume seam touched with telemetry off")
+
+    values = [f"keep-{i}".encode() for i in range(64)]
+    chain = SmartModuleInvocation(wasm=SmartModuleInvocationWasm.adhoc(
+        b"@smartmodule.filter(dsl=dsl.FilterProgram(\n"
+        b"    predicate=dsl.Contains(arg=dsl.Value(), literal=b'keep')))\n"
+        b"def fil(record):\n"
+        b"    return b'keep' in record.value\n"))
+
+    async def run():
+        config = SpuConfig(
+            id=5041, public_addr="127.0.0.1:0", log_base_dir=str(tmp_path),
+            replication=ReplicaConfig(base_dir=str(tmp_path)),
+        )
+        config.smart_engine.backend = "tpu"
+        server = SpuServer(config)
+        await server.start()
+        server.ctx.create_replica("topic", 0)
+        try:
+            client = await Fluvio.connect(server.public_addr)
+            producer = await client.topic_producer("topic")
+            for v in values:
+                await producer.send(None, v)
+            await producer.flush()
+            consumer = await client.partition_consumer("topic", 0)
+            got = [r.value async for r in consumer.stream(
+                Offset.beginning(),
+                ConsumerConfig(disable_continuous=True, smartmodules=[chain]),
+            )]
+            await client.close()
+            return got
+        finally:
+            await server.stop()
+
+    TELEMETRY.reset()
+    prior = TELEMETRY.enabled
+    TELEMETRY.enabled = False
+    try:
+        monkeypatch.setattr(flow_module.ConsumeFlow, "__init__", tripwire)
+        monkeypatch.setattr(spans_module._TimedPhase, "__init__", tripwire)
+        monkeypatch.setattr(TELEMETRY.consumes, "push", tripwire)
+        assert TELEMETRY.begin_consume() is None
+        TELEMETRY.end_consume(None)
+        assert asyncio.run(run()) == values
+        assert len(TELEMETRY.consumes) == 0 and len(TELEMETRY.flows) == 0
+    finally:
+        TELEMETRY.enabled = prior
+        TELEMETRY.reset()
+
+
+def test_client_transport_never_imports_jax():
+    """A consumer process records its responses
+    without jax (the phase annotation is a null context there), and the
+    client and transport layers import none."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    code = (
+        "import sys\n"
+        "import fluvio_tpu.client, fluvio_tpu.transport.multiplexing\n"
+        "from fluvio_tpu.telemetry import TELEMETRY\n"
+        "from fluvio_tpu.telemetry.spans import timed\n"
+        "TELEMETRY.enabled = True\n"
+        "rec = TELEMETRY.begin_consume()\n"
+        "with timed(rec, 'consume_recv'):\n"
+        "    pass\n"
+        "TELEMETRY.end_consume(rec)\n"
+        "assert [p[0] for p in rec.phases] == ['consume_recv']\n"
+        "assert len(TELEMETRY.consumes) == 1\n"
+        "print('jax' in sys.modules)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code],
+        cwd=str(Path(__file__).resolve().parents[1]),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
