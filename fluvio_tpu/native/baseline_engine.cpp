@@ -979,19 +979,32 @@ struct ColumnValues {
     }
 };
 
-// the 4-aligned flat a fetch already holds (flat-backed RecordBuffer)
+// a flat's bytes at a per-row start: the 4-aligned flat a fetch already
+// holds (flat-backed RecordBuffer, fold 0), or a view-mode fetch's spans
+// of its INPUT's flat (view-backed) with the chain's ASCII case fold:
+// 1 upper, 2 lower (buffer.py:apply_postops_host, byte for byte)
 struct FlatValues {
     const uint8_t* flat;
     int64_t flat_len;
-    const int32_t* starts;
+    const int64_t* starts;
     const int32_t* lengths;
+    uint8_t fold;
     bool ok(int64_t i) const {
         return starts[i] >= 0 && lengths[i] >= 0
-            && (int64_t)starts[i] + lengths[i] <= flat_len;
+            && starts[i] <= flat_len - lengths[i];
     }
     int64_t len(int64_t i) const { return lengths[i]; }
     void write(uint8_t*& p, int64_t i, int64_t n) const {
-        std::memcpy(p, flat + starts[i], (size_t)n);
+        const uint8_t* s = flat + starts[i];
+        if (!fold) {
+            std::memcpy(p, s, (size_t)n);
+        } else {
+            const uint8_t first = fold == 1 ? 'a' : 'A';
+            for (int64_t k = 0; k < n; k++) {
+                uint8_t c = s[k];
+                p[k] = (uint8_t)(c - first) < 26 ? (uint8_t)(c ^ 0x20) : c;
+            }
+        }
         p += n;
     }
 };
@@ -1229,11 +1242,11 @@ int64_t encode_append_columns(
 
 int64_t encode_append_flat(
     EncodedRecords* e, const uint8_t* flat, int64_t flat_len,
-    const int32_t* starts, const int32_t* lengths,
+    const int64_t* starts, const int32_t* lengths, uint8_t fold,
     const uint8_t* keys, int64_t key_width, const int32_t* key_lengths,
     const int32_t* off_delta, const int64_t* ts_delta,
     int64_t first, int64_t end, int64_t max_bytes) {
-    return append_records(e, FlatValues{flat, flat_len, starts, lengths},
+    return append_records(e, FlatValues{flat, flat_len, starts, lengths, fold},
                           MatrixKeys{keys, key_width, key_lengths},
                           off_delta, ts_delta, first, end, max_bytes);
 }

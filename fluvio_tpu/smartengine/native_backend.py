@@ -186,7 +186,8 @@ def load_library():
         ]
         lib.encode_append_flat.restype = i64
         lib.encode_append_flat.argtypes = [
-            slab, p8, i64, p32, p32, p8, i64, p32, p32, p64, i64, i64, i64,
+            slab, p8, i64, p64, p32, ctypes.c_uint8, p8, i64, p32, p32, p64,
+            i64, i64, i64,
         ]
         lib.encode_append_ints.restype = i64
         lib.encode_append_ints.argtypes = [
@@ -352,12 +353,27 @@ class RecordSlab:
         """A flat-backed buffer as its fetch left it: the 4-aligned
         value flat with per-row starts and lengths, the key matrix with
         per-row lengths (-1 = null), int32 offset deltas."""
-        rows = min(len(starts), len(lengths), len(off_delta), len(ts_delta))
+        return self.append_view(
+            flat, starts, lengths, 0, keys, key_lengths, off_delta, ts_delta,
+            first, end,
+        )
+
+    def append_view(
+        self, in_flat, base, lengths, fold: int, keys, key_lengths,
+        off_delta, ts_delta, first: int, end: int,
+    ) -> int:
+        """A view-backed buffer: row i's value is the ``lengths[i]`` bytes
+        of its INPUT's flat at int64 ``base[i]``, ASCII case-folded by
+        ``fold`` (0 none, 1 upper, 2 lower: `buffer.view_fold`), copied
+        straight into its record."""
+        if fold not in (0, 1, 2):
+            raise ValueError(f"case fold {fold} is not 0, 1 or 2")
+        rows = min(len(base), len(lengths), len(off_delta), len(ts_delta))
         self._check_rows(first, end, rows)
         arg = self._arg
         return self._kept(self._lib.encode_append_flat(
-            self._slab, arg(flat, np.uint8), len(flat),
-            arg(starts, np.int32), arg(lengths, np.int32),
+            self._slab, arg(in_flat, np.uint8), len(in_flat),
+            arg(base, np.int64), arg(lengths, np.int32), fold,
             *self._key_matrix(keys, key_lengths, end),
             arg(off_delta, np.int32), arg(ts_delta, np.int64),
             first, end, self._max_bytes,

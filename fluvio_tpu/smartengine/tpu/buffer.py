@@ -102,6 +102,80 @@ def ragged_range_select(
     return flat[keep]
 
 
+# the postops each `view_fold` byte stands for; the native record writer
+# applies the byte itself (`baseline_engine.cpp:FlatValues`)
+_FOLD_OPS = ((), ("upper",), ("lower",))
+
+
+def view_fold(postops) -> int:
+    """The one fold (0 none, 1 upper, 2 lower) a sequence of case folds
+    comes to: each maps every letter to one case and passes every other
+    byte, so the last one decides (`upper` then `lower` is `lower`)."""
+    if not postops:
+        return 0
+    return 1 if postops[-1] == "upper" else 2
+
+
+def render_view_flat(
+    in_flat: np.ndarray, base: np.ndarray, ln: np.ndarray, rows: int,
+    fold: int,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """A view-backed buffer's values as a flat of its own: the 4-aligned
+    ragged form with int32 starts over ``rows`` (padding rows start at
+    the end), built from the live rows' spans ``[base[i], +ln[i])`` of
+    the input's flat, then case-folded. O(sum of lengths), no padded
+    matrix.
+
+    Fast path: when the source ranges, each padded to 4 bytes, are
+    ascending and disjoint (whole-record survivors, JsonGet spans that
+    end 3 bytes or more before the next one starts), ONE boolean
+    range-select (diff-mark + cumsum over the input flat) extracts the
+    payload — a few sequential passes instead of the fancy-index
+    gather's int64 temporaries. Otherwise the exact gather: array
+    elements are one comma apart, so an exploded element padded to 4
+    bytes overlaps the next one and explode outputs always take it."""
+    count = len(ln)
+    ln64 = ln.astype(np.int64)
+    l4 = (ln64 + 3) & ~3
+    starts64 = np.cumsum(l4) - l4
+    total = int(l4.sum()) if count else 0
+    flat_out = np.zeros((total,), dtype=np.uint8)
+    if count and total:
+        if len(in_flat):
+            fast = (
+                base[0] >= 0
+                and base[-1] + l4[-1] <= len(in_flat)
+                and bool((base[1:] >= base[:-1] + l4[:-1]).all())
+            )
+            if fast:
+                flat_out = ragged_range_select(in_flat, base, l4)
+                # zero the alignment-pad tail bytes (<= 3/record)
+                pad = l4 - ln64
+                if pad.any():
+                    npad = int(pad.sum())
+                    padbase = np.repeat(starts64 + ln64, pad)
+                    within = np.arange(npad, dtype=np.int64) - np.repeat(
+                        np.cumsum(pad) - pad, pad
+                    )
+                    flat_out[padbase + within] = 0
+            else:
+                pos = np.arange(total, dtype=np.int64) - np.repeat(
+                    starts64, l4
+                )
+                idx = np.clip(
+                    np.repeat(base, l4) + pos, 0, len(in_flat) - 1
+                )
+                keep = pos < np.repeat(ln64, l4)
+                flat_out = np.where(keep, in_flat[idx], 0).astype(
+                    np.uint8
+                )
+        flat_out = apply_postops_host(flat_out, _FOLD_OPS[fold])
+    starts = np.zeros((rows,), dtype=np.int32)
+    starts[:count] = starts64
+    starts[count:] = total
+    return flat_out, starts
+
+
 def _next_pow2(n: int, floor: int) -> int:
     v = floor
     while v < n:
@@ -193,11 +267,28 @@ class RecordBuffer:
     _ints: Optional[np.ndarray] = None
     _render: Optional[Callable] = None
     _row_format: Optional[RowFormat] = None
+    # A VIEW-BACKED buffer (`values is None`, `_flat is None`) holds its
+    # live rows' values as spans of its INPUT's 4-aligned flat: (that
+    # flat, int64 first byte a live row, `view_fold` byte), with
+    # `lengths`, `_width` and `_rows` set. A view-mode fetch copies no
+    # byte: the served encode writes the spans straight into the wire
+    # records (`encode_into`); every other consumer gets the flat of its
+    # own, rendered once by `render_view_flat` (`_unview`).
+    _view: Optional[Tuple[np.ndarray, np.ndarray, int]] = None
+
+    def _unview(self) -> None:
+        """A view-backed buffer becomes flat-backed (else nothing)."""
+        if self._view is not None:
+            in_flat, base, fold = self._view
+            self._flat, self._starts = render_view_flat(
+                in_flat, base, self.lengths[: self.count], self._rows, fold
+            )
+            self._view = None
 
     @property
     def width(self) -> int:
         """Bucketed value-matrix width (valid in every backing mode)."""
-        if self.values is None and self._flat is None:
+        if self.values is None and self._flat is None and self._view is None:
             return self.dense_values().shape[1]
         return self.values.shape[1] if self.values is not None else self._width
 
@@ -209,6 +300,7 @@ class RecordBuffer:
         """The padded matrix; materialized on demand for flat-backed
         and int-backed buffers (slow-path consumers only — the TPU hot
         path never calls this)."""
+        self._unview()
         if self.values is None and self._flat is None:
             self.values, self.lengths = self._render(
                 self._ints, self._rows, self.count
@@ -244,6 +336,7 @@ class RecordBuffer:
         flat-backed buffers are BORN in this form (the native decoder
         emits the 4-aligned flat directly).
         """
+        self._unview()
         if self._flat is None:
             values = self.values
             if values is None:  # int-backed: render first
@@ -499,6 +592,7 @@ class RecordBuffer:
         form, so a fused slice goes packed-payload -> wire bytes without
         ever densifying."""
         n = self.count
+        self._unview()
         if self._flat is None:
             self.dense_values()  # an int-backed buffer renders here
         lengths = self.lengths[:n].astype(np.int64)
@@ -554,13 +648,19 @@ class RecordBuffer:
     def encode_into(self, slab, first: int = 0) -> Tuple[int, str]:
         """Append live rows [first, count) to a response slab
         (`native_backend.RecordSlab`) in ONE native pass that reads the
-        form this buffer already holds: the int64 column, the 4-aligned
-        flat, or (a dense matrix) the exact-packed columns of
-        `to_columns`. Returns (rows kept — fewer than asked for is the
-        slab's ``max_bytes`` cut —, the encode form taken)."""
+        form this buffer already holds: the spans of the input's flat,
+        the int64 column, the 4-aligned flat, or (a dense matrix) the
+        exact-packed columns of `to_columns`. Returns (rows kept — fewer
+        than asked for is the slab's ``max_bytes`` cut —, the encode
+        form taken)."""
         n = self.count
         meta = (self.keys, self.key_lengths, self.offset_deltas,
                 self.timestamp_deltas, first, n)
+        if self._view is not None:
+            in_flat, base, fold = self._view
+            return slab.append_view(
+                in_flat, base, self.lengths, fold, *meta
+            ), "enc-direct-view"
         if self._row_format is not None:
             return slab.append_rows(
                 self._ints, self._row_format, *meta
@@ -584,6 +684,7 @@ class RecordBuffer:
     def to_records(self) -> List[Record]:
         out: List[Record] = []
         keys = self.keys
+        self._unview()
         if self._flat is None:
             self.dense_values()  # an int-backed buffer renders here
         if self.values is None:

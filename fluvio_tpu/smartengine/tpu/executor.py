@@ -54,7 +54,7 @@ from fluvio_tpu.smartengine.tpu.buffer import (
     MAX_RECORD_WIDTH,
     RecordBuffer,
     apply_postops_host,
-    ragged_range_select,
+    view_fold,
 )
 from fluvio_tpu.smartengine.tpu.lower import (
     Unlowerable,
@@ -2176,10 +2176,11 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         already holds (shared by the descriptor-download path and the
         filter-only identity path, which derives st/ln host-side).
 
-        With result compaction armed the output is FLAT-BACKED: one
-        O(total bytes) ragged gather instead of a rows x width padded
-        matrix — the fat-record fetch wall was this very matrix (and
-        the masked re-extraction `to_columns` paid on top of it)."""
+        With result compaction armed the output is VIEW-BACKED: spans
+        of the input's flat, copied once, by the encoder, instead of a
+        rows x width padded matrix — the fat-record fetch wall was this
+        very matrix (and the masked re-extraction `to_columns` paid on
+        top of it)."""
         vw = min(self._pad_slice(max(max_v, 1)), width)
         if self._result_compact:
             return self._materialize_view_flat(
@@ -2233,66 +2234,22 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         self, buf: RecordBuffer, count: int, rows: int, vw: int,
         st: np.ndarray, ln: np.ndarray, src: np.ndarray,
     ) -> RecordBuffer:
-        """Flat-backed view materialization: gather every survivor's
-        bytes straight into the 4-aligned ragged form `RecordBuffer`
-        ships and the broker split-back consumes — O(sum of lengths)
-        work and memory, no padded matrix.
-
-        Fast path: survivor source ranges in the input flat are
-        ascending and disjoint for every real view family (whole-record
-        survivors, explode elements, JsonGet spans), so ONE boolean
-        range-select (diff-mark + cumsum over the input flat) extracts
-        the payload — ~3 sequential passes instead of the fancy-index
-        gather's many int64 temporaries, which is what the fat-record
-        fetch wall is made of. Alignment-overrun or overlapping spans
-        (possible when a span ends within 3 bytes of the next one's
-        start) fall back to the exact gather."""
-        ln64 = ln.astype(np.int64)
-        l4 = (ln64 + 3) & ~3
-        starts64 = np.cumsum(l4) - l4
-        total = int(l4.sum()) if count else 0
-        flat_out = np.zeros((total,), dtype=np.uint8)
-        if count and total:
-            in_flat, in_starts = buf.ragged_values()
-            if len(in_flat):
-                base = in_starts.astype(np.int64)[src] + st
-                fast = (
-                    base[0] >= 0
-                    and base[-1] + l4[-1] <= len(in_flat)
-                    and bool((base[1:] >= base[:-1] + l4[:-1]).all())
-                )
-                if fast:
-                    flat_out = ragged_range_select(in_flat, base, l4)
-                    # zero the alignment-pad tail bytes (<= 3/record)
-                    pad = l4 - ln64
-                    if pad.any():
-                        npad = int(pad.sum())
-                        padbase = np.repeat(starts64 + ln64, pad)
-                        within = np.arange(npad, dtype=np.int64) - np.repeat(
-                            np.cumsum(pad) - pad, pad
-                        )
-                        flat_out[padbase + within] = 0
-                else:
-                    pos = np.arange(total, dtype=np.int64) - np.repeat(
-                        starts64, l4
-                    )
-                    idx = np.clip(
-                        np.repeat(base, l4) + pos, 0, len(in_flat) - 1
-                    )
-                    keep = pos < np.repeat(ln64, l4)
-                    flat_out = np.where(keep, in_flat[idx], 0).astype(
-                        np.uint8
-                    )
-            flat_out = apply_postops_host(flat_out, self._view_postops)
+        """View-backed materialization: no byte is copied. Each survivor
+        is a span of the input's 4-aligned flat, one int64 column of
+        first bytes gathered from the input's starts; the output buffer
+        keeps that flat, the column and the chain's postops as one case
+        fold (`RecordBuffer._view`). The served encode copies the spans
+        straight into the wire records; any other consumer renders the
+        flat of its own (`buffer.render_view_flat`)."""
+        in_flat, in_starts = buf.ragged_values()
+        base = np.add(in_starts[src], st, dtype=np.int64)
         out_lengths = np.zeros((rows,), dtype=np.int32)
-        out_lengths[:count] = ln64
-        starts = np.zeros((rows,), dtype=np.int32)
-        starts[:count] = starts64
-        starts[count:] = total
+        out_lengths[:count] = ln
         out_keys, out_klens = self._view_keys(buf, count, rows, src)
-        return self._assemble(buf, count, rows, None, out_lengths,
-                              out_keys, out_klens, src,
-                              flat=flat_out, starts=starts, vw=vw)
+        return self._assemble(
+            buf, count, rows, None, out_lengths, out_keys, out_klens, src,
+            view=(in_flat, base, view_fold(self._view_postops)), vw=vw,
+        )
 
     def _fetch_bytes(
         self, buf: RecordBuffer, count: int, packed, max_v, max_k,
@@ -2556,7 +2513,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
     def _assemble(self, buf, count, rows, out_values, out_lengths, out_keys,
                   out_klens, src, flat=None, starts=None,
                   vw: int = 0, ints=None, render=None,
-                  row_format=None) -> RecordBuffer:
+                  row_format=None, view=None) -> RecordBuffer:
         """Rebuild offset/timestamp columns from survivor source rows.
 
         Row-preserving chains pass the source deltas through; fan-out
@@ -2565,7 +2522,8 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         at the engine surface, matching the interpreter's fresh
         Records). With ``flat``/``starts`` set (result compaction) the
         output buffer is FLAT-BACKED: ``out_values`` is None and the
-        padded matrix is never built. With ``ints`` set (an int-output
+        padded matrix is never built; with ``view`` set it is
+        VIEW-BACKED (`RecordBuffer._view`). With ``ints`` set (an int-output
         fetch) it is INT-BACKED: ``out_values`` and ``out_lengths`` are
         None until a consumer asks for the rendered form (``render``,
         by default one column's decimals; ``row_format``: a keyed
@@ -2597,12 +2555,13 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             base_timestamp=buf.base_timestamp,
             _flat=flat,
             _starts=starts,
-            _width=vw if flat is not None else 0,
+            _width=vw if flat is not None or view is not None else 0,
             _rows=rows if out_values is None else 0,
             _ints=ints,
             _render=(render or self._int_value_columns)
             if ints is not None else None,
             _row_format=row_format,
+            _view=view,
         )
 
     def _fanout_cap(self, buf: RecordBuffer) -> Optional[int]:

@@ -191,8 +191,8 @@ def render_prometheus(
         f"{_PREFIX}_link_variants_total",
         "Fetched batches by D2H link form "
         "(down-glz-xla / down-packed / down-raw / agg-*) and served "
-        "slices by encode form (enc-direct-bytes / enc-direct-int / "
-        "enc-columns).",
+        "slices by encode form (enc-direct-view / enc-direct-bytes / "
+        "enc-direct-int / enc-direct-rows / enc-columns).",
         "counter",
     )
     for variant, n in sorted(link_variants.items()):

@@ -1,7 +1,7 @@
-"""ISSUE-34: the native record writer's two direct entries (`RecordSlab.
-append_flat`, `.append_ints`) and its general one (`.append_columns`)
-against an encoder that shares none of their code: `protocol/record.py`'s
-per-record `Record.encode`.
+"""The native record writer's direct entries (`RecordSlab.
+append_flat`, `.append_view`, `.append_ints`) and its general one
+(`.append_columns`) against an encoder that shares none of their code:
+`protocol/record.py`'s per-record `Record.encode`.
 
 The numpy size passes that `tpu_materialize` used for the `max_bytes` cut
 (`_varint_sizes`, `_encoded_record_sizes_at`, moved here from
@@ -17,7 +17,11 @@ import pytest
 from fluvio_tpu.protocol.codec import ByteWriter
 from fluvio_tpu.protocol.record import Record
 from fluvio_tpu.smartengine import native_backend
-from fluvio_tpu.smartengine.tpu.buffer import RecordBuffer
+from fluvio_tpu.smartengine.tpu.buffer import (
+    RecordBuffer,
+    render_view_flat,
+    view_fold,
+)
 from fluvio_tpu.smartengine.tpu.executor import TpuChainExecutor
 from fluvio_tpu.spu import smart_chain
 
@@ -297,6 +301,140 @@ def test_a_row_outside_its_column_is_refused(fault):
         c.count = len(c.lengths) + 1
     with pytest.raises(ValueError):
         c.encode_into(native_backend.record_slab())
+
+
+# -- view outputs: spans of the input's flat -----------------------------------
+
+
+def _fold(value: bytes, postops) -> bytes:
+    for op in postops:
+        value = value.upper() if op == "upper" else value.lower()
+    return value
+
+
+def _element_spans(values):
+    """(row, start, length) of every element of flat JSON arrays, quotes
+    included: elements one comma apart, as the explode leaves them."""
+    spans = []
+    for i, v in enumerate(values):
+        pos = 1
+        for el in v[1:-1].split(b","):
+            spans.append((i, pos, len(el)))
+            pos += len(el) + 1
+    return spans
+
+
+def _name_spans(values):
+    """(row, start, length) of the string under "name", quotes excluded."""
+    spans = []
+    for i, v in enumerate(values):
+        at = v.index(b'"name":"') + 8
+        spans.append((i, at, v.index(b'"', at) - at))
+    return spans
+
+
+EXPLODE_IN = [b'["a12","b",3,"cdef5"]', b'[1,22,"x",""]', b'[]', b'["yy",4444]']
+JSON_IN = [b'{"name":"Fluvio-%d.Kafka_z","n":%d}' % (i, i) for i in range(7)]
+
+
+def _view_case(case):
+    """A view-backed chunk as a view-mode fetch leaves it (the input's
+    4-aligned flat with junk pad bytes, an int64 base a survivor, the
+    chain's fold), its source columns, and what the per-record path
+    writes for it: (chunk, records, first, max_bytes)."""
+    values, keys, offs, tss = _corpus(16, seed=6)
+    values_in, postops, first, max_bytes = values, (), 0, 0
+    spans = [(i, 0, len(v)) for i, v in enumerate(values) if i % 3 != 1]
+    if case == "explode":
+        values_in, spans = EXPLODE_IN, _element_spans(EXPLODE_IN)
+    elif case.startswith("json"):
+        values_in, spans = JSON_IN, _name_spans(JSON_IN)
+        postops = tuple(case.split("-")[1:])
+    elif case == "empty-values":
+        values_in = [b""] * 4
+        spans = [(i, 0, 0) for i in (0, 2, 3)]
+    elif case == "first":
+        first = 3
+    elif case.startswith("cut-"):
+        max_bytes = int(case.split("-")[1])
+    n = len(spans)
+    rows = n + 2
+    src_chunk = _flat_chunk(values_in, [None] * len(values_in),
+                            np.arange(len(values_in)), [0] * len(values_in))
+    src = np.array([s for s, _, _ in spans], np.int64)
+    base = src_chunk._starts[src].astype(np.int64) + [a for _, a, _ in spans]
+    lengths = np.zeros(rows, np.int32)
+    lengths[:n] = [ln for _, _, ln in spans]
+    keys = [keys[s] if case == "keys" else None for s in src]
+    meta = _meta_columns(keys, offs[:n], tss[:n], rows)
+    chunk = RecordBuffer(
+        values=None, lengths=lengths, count=n, _width=32, _rows=rows,
+        _view=(src_chunk._flat, base, view_fold(postops)), **meta,
+    )
+    records = _records(
+        [_fold(values_in[s][a : a + ln], postops) for s, a, ln in spans],
+        keys, offs[:n], tss[:n],
+    )
+    return chunk, records, first, max_bytes
+
+
+def _small_slab_boundaries():
+    chunk, records, _f, _m = _view_case("cut-0")
+    sizes = _sizes([r.value for r in records], [r.key for r in records],
+                   [r.offset_delta for r in records],
+                   [r.timestamp_delta for r in records])
+    return [f"cut-{int(c)}" for c in np.cumsum(sizes)]
+
+
+VIEW_CASES = [
+    "explode", "json", "json-upper", "json-lower", "json-upper-lower",
+    "identity", "empty-values", "keys", "first",
+] + _small_slab_boundaries()
+
+
+@pytest.mark.parametrize("case", VIEW_CASES)
+def test_view_spans_equal_the_rendered_flat(case):
+    chunk, records, first, max_bytes = _view_case(case)
+    in_flat, base, fold = chunk._view
+    n = chunk.count
+    if case == "explode":
+        # 4-aligned spans overlap: the render takes the exact gather
+        l4 = (chunk.lengths[:n].astype(np.int64) + 3) & ~3
+        assert not (base[1:] >= base[:-1] + l4[:-1]).all()
+    meta = (chunk.keys, chunk.key_lengths, chunk.offset_deltas,
+            chunk.timestamp_deltas, first, n)
+    slab = native_backend.record_slab(max_bytes)
+    kept, form = chunk.encode_into(slab, first)
+    raw = slab.take()
+    assert form == "enc-direct-view" and chunk._flat is None
+    flat, starts = render_view_flat(
+        in_flat, base, chunk.lengths[:n], chunk.rows, fold
+    )
+    slab = native_backend.record_slab(max_bytes)
+    assert slab.append_flat(flat, starts, chunk.lengths, *meta) == kept
+    assert slab.take() == raw
+    sizes = _sizes([r.value for r in records], [r.key for r in records],
+                   [r.offset_delta for r in records],
+                   [r.timestamp_delta for r in records])
+    keep = _numpy_keep(sizes[first:], max_bytes)
+    assert kept == keep
+    assert raw == _wire(records[first : first + keep])
+
+
+@pytest.mark.parametrize("fault", ["negative", "past-the-end", "fold"])
+def test_a_view_span_outside_the_flat_is_refused(fault):
+    chunk, _records, _f, _m = _view_case("json-upper")
+    in_flat, base, fold = chunk._view
+    base = base.copy()
+    if fault == "negative":
+        base[2] = -1
+    elif fault == "past-the-end":
+        base[4] = len(in_flat) - chunk.lengths[4] + 1
+    else:
+        fold = 3
+    chunk._view = (in_flat, base, fold)
+    with pytest.raises(ValueError):
+        chunk.encode_into(native_backend.record_slab())
 
 
 # -- int outputs ----------------------------------------------------------------

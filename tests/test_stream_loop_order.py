@@ -453,8 +453,8 @@ def _per_record_slices(broker, cfg, max_bytes, n=N):
 
 
 @pytest.mark.parametrize("config_name,compact,form", [
-    (STATELESS, "auto", "enc-direct-bytes"),
-    (FANOUT, "auto", "enc-direct-bytes"),
+    (STATELESS, "auto", "enc-direct-view"),
+    (FANOUT, "auto", "enc-direct-view"),
     (STATEFUL, "auto", "enc-direct-int"),
     (STATELESS, "off", "enc-columns"),
 ], ids=["stateless", "fanout", "stateful", "stateless-dense"])
@@ -518,3 +518,48 @@ def test_served_slice_goes_out_in_one_native_pass(
         assert "to_columns" in hits
     else:
         assert hits == []
+
+
+# -- the view form is booked by view-mode chains and by no other ------------------
+
+
+def _window_or_group_stream(tmp_path, module_name):
+    """A stream of the window (NEXmark Q5) or group (Q17) cell's chain,
+    served over several slices by that test file's own harness."""
+    import importlib
+
+    mod = importlib.import_module(module_name)
+    corpus = mod._corpus()
+
+    async def body(broker):
+        s = await mod._Stream(
+            broker, mod._chain(), mod._two_batches(corpus),
+            end=len(corpus[2]),
+        ).open()
+        await s.drain()
+        return len(s.responses), broker.slice_counts()
+
+    return mod._serve(tmp_path, corpus, body)
+
+
+@pytest.mark.parametrize("chain", [
+    "northstar", "fanout", "aggregate", "window", "group",
+])
+def test_view_form_is_booked_by_view_chains_only(tmp_path, chain):
+    if chain in ("window", "group"):
+        served, counts = _window_or_group_stream(
+            tmp_path, f"test_stream_{chain}")
+    else:
+        cfg = _config({"northstar": STATELESS, "fanout": FANOUT,
+                       "aggregate": STATEFUL}[chain])
+        flat, off = _corpus(cfg)
+
+        async def body(broker):
+            responses = await _drain(broker, int(off[PER_BATCH]) + 64)
+            return len(responses), broker.slice_counts()
+
+        served, counts = _serve(tmp_path, cfg, flat, off, body)
+    assert counts["fallback_slices"] == 0
+    assert counts["fastpath_slices"] == served > 1
+    booked = TELEMETRY.link_variant_counts().get("enc-direct-view", 0)
+    assert booked == (served if chain in ("northstar", "fanout") else 0)
