@@ -1,0 +1,23 @@
+"""Least time the chip's HBM could take to move what the session stage
+must (`spubench.session_bytes`: every padded row's key, start, last and
+count read once and written once) for the traced span's dispatches, over
+the device's busy time under `stage<i>.session_merge` +
+`stage<i>.session_close`. Bandwidth-bound by construction: a sort,
+scans, a scatter of positions and gathers of int64 columns."""
+
+from spubench.device import peaks_for
+from spubench.session_bytes import session_bytes, work_scope_seconds
+from spubench.xplane_scopes import reduce_run
+
+
+def read(obs):
+    r = reduce_run(obs)
+    spans = obs.get("trace_spans") or ()
+    if not r or not spans:
+        return None
+    busy = work_scope_seconds(r)
+    if busy <= 0:
+        return None
+    peak = peaks_for(obs["device_kind"])["hbm_bytes_per_s"]
+    moved = sum(session_bytes(s["records"]) for s in spans)
+    return 100.0 * (moved / peak) / busy

@@ -75,6 +75,7 @@ ALLOWED_TELEMETRY_SEAMS = {
     "add_windows_closed", "add_window_delta", "add_window_downlink",
     "add_window_slice", "add_window_grow",
     "add_group_slice", "add_group_grow",
+    "add_session_slice", "add_session_grow",
     "gauge_add", "gauge_set",
     "mem_acquire", "mem_release",
 }

@@ -218,7 +218,7 @@ REGISTRY: Tuple[EnvFlag, ...] = (
        "resilience/policy.py", "randomized fraction of each backoff"),
     _f("FLUVIO_RETRY_MAX", "int", "2", "attempts",
        "resilience/policy.py", "retries after the first attempt"),
-    _f("FLUVIO_SLICE_RING", "int", "512", "flows",
+    _f("FLUVIO_SLICE_RING", "int", "2048", "flows",
        "telemetry/registry.py",
        "completed per-slice flow records retained for the trace export"),
     _f("FLUVIO_SLO", "spec", "", "rule:param=v;rule:param=v",

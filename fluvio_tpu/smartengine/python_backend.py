@@ -81,6 +81,11 @@ class PythonInstance:
         # time bucket: [accumulator per lane]}; ``window_source`` is a
         # fused chain's device table the same way
         self._group_table: dict = {}
+        # `dsl.SessionProgram` state: open sessions {key: [[start, last,
+        # count], ...]} (one key's never touch) and a lower bound of
+        # their ends, below which no close is due
+        self._sessions: dict = {}
+        self._session_due = -dsl.INT64_MIN
 
     # -- init / look_back ---------------------------------------------------
 
@@ -108,19 +113,25 @@ class PythonInstance:
             {w: {k: list(v) for k, v in row.items()}
              for w, row in self._window_open.items()},
             {g: list(v) for g, v in self._group_table.items()},
+            {k: [list(x) for x in v] for k, v in self._sessions.items()},
+            self._session_due,
         )
 
     def state_restore(self, snapshot: tuple) -> None:
         (self.accumulator, self._window_start, self._window_max_ts,
-         window_open, group_table) = snapshot
+         window_open, group_table, sessions, self._session_due) = snapshot
         self._window_open = {
             w: {k: list(v) for k, v in row.items()}
             for w, row in window_open.items()
         }
         self._group_table = {g: list(v) for g, v in group_table.items()}
+        self._sessions = {k: [list(x) for x in v] for k, v in sessions.items()}
 
     def _keeps_a_table(self) -> bool:
         return isinstance(self._dsl_programs.get(self.kind), dsl.GroupProgram)
+
+    def _keeps_sessions(self) -> bool:
+        return isinstance(self._dsl_programs.get(self.kind), dsl.SessionProgram)
 
     def _load_window_source(self) -> None:
         """Take over the device bank a fused chain left behind."""
@@ -130,6 +141,12 @@ class PythonInstance:
         entries, self._window_max_ts = bank.snapshot()
         if self._keeps_a_table():
             self._group_table = {g: list(accs) for g, accs, _ in entries}
+            return
+        if self._keeps_sessions():
+            self._sessions = {}
+            for key, session, _ in entries:
+                self._sessions.setdefault(key, []).append(list(session))
+            self._session_due = dsl.INT64_MIN
             return
         self._window_open = {}
         for composite, acc, cnt in entries:
@@ -144,6 +161,12 @@ class PythonInstance:
         if self._keeps_a_table():
             return sorted(
                 (g, tuple(accs), None) for g, accs in self._group_table.items()
+            ), self._window_max_ts
+        if self._keeps_sessions():
+            return sorted(
+                (key, tuple(session), None)
+                for key, sessions in self._sessions.items()
+                for session in sessions
             ), self._window_max_ts
         entries = sorted(
             (key * dsl.WINDOW_KEY_LIMIT + w, acc, cnt)
@@ -309,6 +332,8 @@ class PythonInstance:
             self._run_dsl_window(program, sm_records, out)
         elif isinstance(program, dsl.GroupProgram):
             self._run_dsl_group(program, sm_records, out)
+        elif isinstance(program, dsl.SessionProgram):
+            self._run_dsl_session(program, sm_records, out)
         else:
             raise TypeError(f"unknown DSL program {type(program).__name__}")
         return out
@@ -464,3 +489,65 @@ class PythonInstance:
         TELEMETRY.add_group_slice(
             len(sm_records) - invalid, len(table), invalid
         )
+
+    def _run_dsl_session(
+        self,
+        program: dsl.SessionProgram,
+        sm_records: List[SmartModuleRecord],
+        out: SmartModuleOutput,
+    ) -> None:
+        """`dsl.SessionProgram` record by record: merge the record's
+        window ``[t, t + gap)`` with every open session of its key that
+        it touches, then emit every session the watermark has reached,
+        in order of (end, key). A record whose own window the watermark
+        had reached is late, one whose key is out of range invalid: both
+        dropped and counted. ``bridged`` counts the open sessions a
+        record merged into another."""
+        gap = program.gap_ms
+        reach = gap + program.lateness_ms        # last -> emitted
+        self._load_window_source()
+        open_ = self._sessions
+        ev = dsl.eval_expr
+        closed = bridged = late = invalid = 0
+        for rec in sm_records:
+            key = int(ev(program.key, rec.value, rec.key))
+            t = int(ev(program.event_time, rec.value, rec.key))
+            if not 0 <= key < dsl.WINDOW_KEY_LIMIT:
+                invalid += 1
+                continue
+            if t + reach <= self._window_max_ts:
+                late += 1
+                continue
+            merged, keep = [t, t, 1], []
+            for s in open_.get(key, ()):
+                if s[0] <= t + gap and t <= s[1] + gap:
+                    merged = [min(merged[0], s[0]), max(merged[1], s[1]),
+                              merged[2] + s[2]]
+                else:
+                    keep.append(s)
+            bridged += max(len(open_.get(key, ())) - len(keep) - 1, 0)
+            open_[key] = keep + [merged]
+            self._session_due = min(self._session_due, merged[1] + reach)
+            if t <= self._window_max_ts:
+                continue
+            self._window_max_ts = t
+            if self._session_due > t:
+                continue
+            due = []
+            for k in list(open_):
+                stay = [s for s in open_[k] if s[1] + reach > t]
+                due += [(s[1] + gap, k, s) for s in open_[k] if s[1] + reach <= t]
+                if stay:
+                    open_[k] = stay
+                else:
+                    del open_[k]
+            self._session_due = min(
+                (s[1] + reach for ss in open_.values() for s in ss),
+                default=-dsl.INT64_MIN,
+            )
+            closed += len(due)
+            for end, k, s in sorted(due, key=lambda d: d[:2]):
+                out.successes.append(Record(value=dsl.session_row_bytes(
+                    program, k, s[2], s[0], end
+                )))
+        TELEMETRY.add_session_slice(closed, bridged, late, invalid)

@@ -368,24 +368,27 @@ class GroupStage:
         """The rows as a padded value matrix + lengths, through the ONE
         rendering (`dsl.group_row_bytes`): what `dense_values()` gives a
         consumer that is not the served encoder."""
-        texts = [
-            dsl.group_row_bytes(
-                self.program, row[0] // KEY_STRIDE, row[0] % KEY_STRIDE, row[1:]
-            )
-            for row in cols.T.tolist()
-        ]
-        width = max(map(len, texts), default=1)
-        values = np.zeros((rows, _pow2_from_8(width)), dtype=np.uint8)
-        lengths = np.zeros((rows,), dtype=np.int32)
-        for i, text in enumerate(texts):
-            values[i, :len(text)] = np.frombuffer(text, dtype=np.uint8)
-            lengths[i] = len(text)
-        return values, lengths
+        return rendered_matrix(
+            [
+                dsl.group_row_bytes(
+                    self.program, row[0] // KEY_STRIDE, row[0] % KEY_STRIDE,
+                    row[1:],
+                )
+                for row in cols.T.tolist()
+            ],
+            rows,
+        )
 
 
-def _pow2_from_8(width: int) -> int:
-    """A rendered matrix's width, bucketed as the executor's are."""
-    v = 8
-    while v < width:
-        v <<= 1
-    return v
+def rendered_matrix(texts: List[bytes], rows: int):
+    """Rendered rows as a padded value matrix (width bucketed as the
+    executor's are: a power of two from 8) + lengths."""
+    width = 8
+    while width < max(map(len, texts), default=1):
+        width <<= 1
+    values = np.zeros((rows, width), dtype=np.uint8)
+    lengths = np.zeros((rows,), dtype=np.int32)
+    for i, text in enumerate(texts):
+        values[i, :len(text)] = np.frombuffer(text, dtype=np.uint8)
+        lengths[i] = len(text)
+    return values, lengths

@@ -10,7 +10,7 @@ for a slice. There is no second windowed implementation here.
 
 The rest of this module is `WindowChainMixin`, the methods
 `executor.TpuChainExecutor` inherits for such a chain, and for one that
-ends in a keyed table (`group_stage.GroupStage`, whose bank has lanes):
+ends in a keyed table or in sessions (`group_stage`, `session_stage`):
 
 - a stream's carry is a `WindowStateBank` (`StreamState.window_bank`):
   empty at the stream's first dispatch, on the device across the
@@ -209,17 +209,21 @@ class WindowStage:
 
 # the kinds of a BANKED stage: the last of its chain, its carry a
 # stream's `WindowStateBank`, served through `WindowChainMixin`
-LAST_KINDS = ("window", "group")
+LAST_KINDS = ("window", "group", "session")
 
 
 def lower_banked(prog, stages: List):
-    """`try_build`'s last branch: the banked stage of a `WindowProgram`
-    or a `GroupProgram` that may follow ``stages``; any other program
-    is one the fused path does not lower."""
+    """`try_build`'s last branch: the banked stage of a `WindowProgram`,
+    a `GroupProgram` or a `SessionProgram` that may follow ``stages``;
+    any other program is one the fused path does not lower."""
     if isinstance(prog, dsl.GroupProgram):
         from fluvio_tpu.smartengine.tpu.group_stage import GroupStage
 
         return GroupStage.lower(prog, stages)
+    if isinstance(prog, dsl.SessionProgram):
+        from fluvio_tpu.smartengine.tpu.session_stage import SessionStage
+
+        return SessionStage.lower(prog, stages)
     return WindowStage.lower(prog, stages)
 
 
@@ -228,7 +232,7 @@ def chain_outputs(state: Dict, bank):
     header and outputs ARE the result; the new bank rides in ``packed``
     because the fetch commits it, not the dispatch."""
     packed = {k: v for k, v in state.items()
-              if k.startswith(("window_rows", "group_"))}
+              if k.startswith(("window_rows", "group_", "session_"))}
     packed["window_bank"] = bank
     return state["window_header"], packed, ()
 
@@ -241,8 +245,8 @@ class WindowChainMixin:
 
     @property
     def _window(self):
-        """The chain's banked stage (`WindowStage`, `GroupStage`), or
-        None."""
+        """The chain's banked stage (`WindowStage`, `GroupStage`,
+        `SessionStage`), or None."""
         stages = self.stages
         if stages and stages[-1].kind in LAST_KINDS:
             return stages[-1]
@@ -335,7 +339,8 @@ class WindowChainMixin:
     def _fetch_window(self, buf, header, packed, span, defer):
         """A banked slice's D2H: the header sync, then what the stage
         downloads of its answer (`WindowStage.fetch`: the closed
-        windows' rows; `GroupStage.fetch`: a row per record). The
+        windows' rows; `GroupStage.fetch`: a row per record;
+        `SessionStage.fetch`: the closed sessions' rows). The
         stream's bank is committed here, after the header read clean and
         the download ended: an overflow, a fault or a discard before
         this point leaves the bank the slice started from."""

@@ -56,6 +56,10 @@ Programs (one per transform kind):
                                          GROUP BY): every record yields the
                                          row of its (key, time bucket) group
                                          as it stands after that record
+    SessionProgram(key, event_time, gap_ms, lateness_ms)
+                                         keyed event-time SESSION windows (an
+                                         aggregate-kind program): one output
+                                         row per closed session of a key
 """
 
 from __future__ import annotations
@@ -605,6 +609,47 @@ def group_row_bytes(program: GroupProgram, key: int, bucket: int,
     return b"".join(parts) + b"}"
 
 
+@_node
+@dataclass
+class SessionProgram(Expr):
+    """Keyed event-time session windows, an AGGREGATE-kind program
+    (NEXmark Q11 "user sessions" is the model case).
+
+    Every record yields a ``key`` (int in [0, 2**31)) and an
+    ``event_time`` (epoch ms, int) and opens the window ``[t, t +
+    gap_ms)``; windows of one key merge while they intersect, ends
+    included (``start <= other.end and end >= other.start``: two records
+    exactly ``gap_ms`` apart share a session). A session's start is its
+    least event time, its end its largest plus ``gap_ms``, its count the
+    records in it. The stream's watermark is ``max(event_time seen) -
+    lateness_ms``; a session is emitted, once and complete, when the
+    watermark reaches its end. A record whose own window ``[t, t +
+    gap_ms)`` the watermark had already reached is late: dropped and
+    counted; a record whose key is outside the range is dropped and
+    counted invalid. Output rows, ordered by (end, key), are fresh
+    records whose value is ``{"<key_field>":<key>,"<count_field>":<n>,
+    "starttime":<ms>,"endtime":<ms>}``. Sessions still open
+    when the stream ends are not emitted. The state (open sessions,
+    watermark) belongs to the consumer stream."""
+
+    key: Expr = None
+    event_time: Expr = None
+    gap_ms: int = 0
+    lateness_ms: int = 0
+    key_field: str = "key"
+    count_field: str = "count"
+
+
+def session_row_bytes(program: SessionProgram, key: int, count: int,
+                      start: int, end: int) -> bytes:
+    """The value of one `SessionProgram` output record: the ONE
+    rendering both executors use."""
+    return b'{"%s":%d,"%s":%d,"starttime":%d,"endtime":%d}' % (
+        program.key_field.encode(), key, program.count_field.encode(), count,
+        start, end,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Build-time resolution & interpretation (reference semantics)
 # ---------------------------------------------------------------------------
@@ -650,6 +695,9 @@ def resolve_params(expr: Expr, params: Dict[str, str]) -> Expr:
             setattr(resolved, name, int(getattr(resolved, name)))
     if isinstance(resolved, GroupProgram):
         resolved.bucket_ms = int(resolved.bucket_ms)
+    if isinstance(resolved, SessionProgram):
+        resolved.gap_ms = int(resolved.gap_ms)
+        resolved.lateness_ms = int(resolved.lateness_ms)
     return resolved
 
 

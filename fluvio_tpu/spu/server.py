@@ -36,19 +36,29 @@ def keep_large_buffers_on_heap() -> bool:
     with these settings; PERF.md section 6, PR 39) and swings with the
     host's neighbours. One arena, because the thresholds reach only the
     main one; the process's allocations are made under the GIL or are
-    few. Best effort: False where libc has no `mallopt` (not glibc),
-    and nothing is changed."""
+    few. The arena limit binds threads that allocate from now on only:
+    a stream loop's worker may still share an arena that the runtime's
+    threads made before, whose extra heaps glibc unmaps as soon as they
+    empty, whatever the trim threshold, unless the top pad is larger
+    than they are. Without that pad, about half of a session slice's
+    16 MB upload copies took 16 ms instead of 1.5 on a TPU v5e host
+    (PERF.md section 6). Best effort: False where libc has no `mallopt`
+    (not glibc), and nothing is changed."""
     import ctypes
 
-    m_trim_threshold, m_mmap_threshold, m_arena_max = -1, -3, -8  # malloc.h
+    m_trim_threshold, m_top_pad, m_mmap_threshold, m_arena_max = (
+        -1, -2, -3, -8  # malloc.h
+    )
     try:
         mallopt = ctypes.CDLL(None).mallopt
     except (OSError, AttributeError):
         return False
     # 32 MiB is the largest mmap threshold glibc takes; a heap that is
-    # never trimmed below 1 GiB of free space keeps the blocks mapped
+    # never trimmed below 1 GiB of free space keeps the blocks mapped; a
+    # top pad of a whole thread heap (64 MiB) keeps such heaps mapped
     return bool(mallopt(m_mmap_threshold, 32 << 20)
                 and mallopt(m_trim_threshold, 1 << 30)
+                and mallopt(m_top_pad, 64 << 20)
                 and mallopt(m_arena_max, 1))
 
 

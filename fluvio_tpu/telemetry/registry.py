@@ -57,7 +57,8 @@ from fluvio_tpu.analysis.envreg import env_bool, env_float, env_int
 SPAN_RING_CAPACITY = 4096
 EVENT_RING_CAPACITY = 512
 # completed per-slice lifecycle records retained for the flow-trace
-# export (one entry per SLICE, so 512 covers minutes of broker serving)
+# export (one entry per SLICE; a reader of a 33 s window needs all of
+# it, and NEXmark Q11's drain serves about 700 slices in one)
 FLOW_RING_CAPACITY = int(env_int("FLUVIO_SLICE_RING"))
 
 # recompile-storm detection: more than N compile events inside the
@@ -212,6 +213,7 @@ class PipelineTelemetry:
         self.windows_closed = 0
         self.window_deltas: Dict[str, int] = {}
         self.group_totals: Dict[str, int] = {}
+        self.session_totals: Dict[str, int] = {}
         self.window_delta_bytes = 0
         self.window_full_bytes = 0
         # device-memory plane (ISSUE-20): leak-detector counter by
@@ -710,6 +712,33 @@ class PipelineTelemetry:
         with self._lock:
             return dict(self.group_totals)
 
+    def add_session_slice(self, closed: int, bridged: int, late: int,
+                          invalid: int) -> None:
+        """What one served (or interpreted) slice of keyed sessions
+        (`dsl.SessionProgram`) counted: sessions closed, sessions of the
+        stream's bank that merged into another of theirs, rows dropped
+        late, rows dropped for a key out of range. Always-on, and
+        dropped rows date an instant event, as a window's do."""
+        with self._lock:
+            for kind, n in (("closed", closed), ("bridged", bridged),
+                            ("late", late), ("invalid", invalid)):
+                self.session_totals[kind] = self.session_totals.get(kind, 0) + n
+        for kind, rows in (("late", late), ("invalid", invalid)):
+            if rows:
+                self._event("session-drop", f"{kind}:{rows}")
+
+    def add_session_grow(self, detail: str) -> None:
+        """A served stream's session bank or emit columns outgrew their
+        shape: the slice is re-run under a doubled one, which compiles
+        (as `add_window_grow`)."""
+        self._event("session-grow", detail)
+
+    def session_counts(self) -> Dict[str, int]:
+        """Running totals of `add_session_slice` (closed, bridged, late,
+        invalid)."""
+        with self._lock:
+            return dict(self.session_totals)
+
     def add_window_downlink(self, delta_bytes: int, full_bytes: int) -> None:
         """One windowed batch's downlink split: bytes the delta
         actually shipped vs what full-state per-record emission would
@@ -1105,6 +1134,7 @@ class PipelineTelemetry:
             self.windows_closed = 0
             self.window_deltas = {}
             self.group_totals = {}
+            self.session_totals = {}
             self.window_delta_bytes = 0
             self.window_full_bytes = 0
             self.memory_leaks = {}

@@ -81,7 +81,7 @@ DEVICE_SCOPES = (
                     # through this tuple
     "repad",        # ragged flat -> padded matrix (block fetch, row
                     # shift, byte unpack), derived meta columns
-    "stage",        # stage<i>.<kind>, inner .aggregate_scan/.window_*/.group_*
+    "stage",        # stage<i>.<kind>, .aggregate_scan/.window_*/.group_*/.session_*
     "compact",      # survivor compaction, mask, header
     "pack",         # byte-mode payload / descriptor stream packing
     "link_encode",  # down-link glz encode of the packed stream
@@ -127,8 +127,8 @@ def stage_scope(index: int, kind: str) -> str:
     `stage<i>.window_top` (the per-window maximum) the same way, and a
     group stage (`stage<i>.group`: field spans, parses, the key)
     `stage<i>.group_merge` (concat with the table, the stable sort, the
-    segmented scans, the new table) and `stage<i>.group_emit` (rows
-    back in offset order, the output columns)."""
+    segmented scans, the new table) and `stage<i>.group_emit` (rows in
+    offset order); a session stage `.session_merge`, `.session_close`."""
     return f"stage{index}.{kind}"
 
 

@@ -630,6 +630,117 @@ def update_group(ops, bank, ids, lanes, valid,
     return header, (nb_ids, nb_accs, None, watermark), rows
 
 
+# header slots of `update_session` (all int64)
+SESSION_HEADER = (
+    "n_rows", "n_open", "n_closed", "n_late", "watermark", "n_invalid",
+    "n_bridged",
+)
+# a session's lanes in the bank (`accs` int64[3, capacity]): its least
+# event time, its largest, and its records
+SESSION_OPS = ("min", "max", "add")
+
+
+def update_session(gap_ms: int, lateness_ms: int, emit_cap: int, bank, keys,
+                   ts, valid, merge_scope: str = "session_merge",
+                   close_scope: str = "session_close"):
+    """The SERVED update of keyed event-time sessions
+    (`dsl.SessionProgram`; traced inside a chain's one program for a
+    slice, `smartengine/tpu/session_stage.py`): the slice's rows join
+    the stream's open sessions, sessions that touch merge, and those the
+    watermark has passed close.
+
+    ``bank`` is (ids: the key, EMPTY_ID where free; accs int64[3,
+    capacity]: start, last, count; None; watermark: the largest event
+    time seen). A row whose key is outside [0, KEY_STRIDE) is invalid, a
+    row whose own window ``[t, t + gap_ms)`` closed under the watermark
+    before the slice is late; both are counted and dropped. Under
+    ``merge_scope``: the bank's sessions ahead of the rows as (key,
+    start, last, count) and (key, t, t, 1), ONE sort by (key, start)
+    carrying the position (the key as int32, free = -1; the lanes
+    follow by one gather), the running maximum of ``last`` within each
+    key (`segment_scans`), a session head wherever a start lies past
+    the previous maximum plus the gap (a gap of exactly ``gap_ms``
+    merges), and the sessions' totals by a second `segment_scans` under
+    those heads, read at each session's last row. Under
+    ``close_scope``: the close (``last + gap_ms + lateness_ms <=`` the
+    new watermark), the closed sessions compacted to ``emit_cap`` rows
+    and the open ones to the new bank. Returns (header
+    [`SESSION_HEADER`], the new bank's arrays, rows int64[4, emit_cap]:
+    key, count, start, last, in (key, start) order, the first
+    ``n_rows`` live). ``n_bridged`` counts the bank's sessions that
+    merged into an earlier one of theirs. The caller compares ``n_open``
+    with the capacity and ``n_closed`` with ``emit_cap``; past either
+    the other outputs are not to be read."""
+    import jax
+    import jax.numpy as jnp
+
+    from fluvio_tpu.windows.spec import OP_NEUTRAL
+
+    bank_ids, bank_accs, _no_counts, watermark = bank
+    capacity = bank_ids.shape[0]
+    reach = gap_ms + lateness_ms
+    key_ok = (keys >= 0) & (keys < KEY_STRIDE)
+    invalid = valid & ~key_ok
+    late = valid & key_ok & (ts + reach <= watermark)
+    live = valid & key_ok & ~late
+    new_wm = jnp.maximum(watermark, jnp.max(
+        jnp.where(live, ts, jnp.int64(INT64_MIN + 1)), initial=INT64_MIN + 1
+    ))
+    with jax.named_scope(merge_scope):
+        n = capacity + ts.shape[0]
+        key32 = jnp.concatenate([
+            jnp.where(bank_ids == EMPTY_ID, -1, bank_ids),
+            jnp.where(live, keys, -1),
+        ]).astype(jnp.int32)
+        pos = jnp.arange(n, dtype=jnp.int32)
+        skey, (sstart, spos), change = _sort_segments(
+            [key32, jnp.concatenate([bank_accs[0], ts]), pos], num_keys=2
+        )
+        one = jnp.ones(ts.shape, jnp.int64)
+        lanes = jnp.take(
+            jnp.concatenate([bank_accs[1:], jnp.stack([ts, one])], axis=1),
+            spos, axis=1, mode="clip",
+        )
+        slast = lanes[0]
+        from_bank = (spos < capacity).astype(jnp.int64)
+        edge = jnp.ones((1,), bool)
+        key_head = jnp.concatenate([edge, change])
+        reach_max = segment_scans(key_head, slast[None], ("max",))[0]
+        before = jnp.concatenate(
+            [jnp.full((1,), INT64_MIN, jnp.int64), reach_max[:-1]]
+        )
+        head = key_head | (sstart > before + gap_ms)
+        tot = segment_scans(
+            head, jnp.stack([sstart, slast, lanes[1], from_bank]),
+            SESSION_OPS + ("add",),
+        )
+        tail = jnp.concatenate([head[1:], edge]) & (skey >= 0)
+        bridged = jnp.sum(
+            (skey >= 0) & (from_bank > 0) & (tot[3] > 1)
+        ).astype(jnp.int64)
+    with jax.named_scope(close_scope):
+        closed = tail & (tot[1] + reach <= new_wm)
+        sid = skey.astype(jnp.int64)
+        n_closed, (c_key, c_tot) = compact_front(
+            closed, emit_cap, sid, tot[:3]
+        )
+        rows = jnp.concatenate([c_key[None], c_tot[2:3], c_tot[:2]])
+        nb_ids, nb_accs, _ = _new_bank(
+            tail & ~closed, sid, tot[:3], None, capacity,
+            tuple(OP_NEUTRAL[op] for op in SESSION_OPS),
+        )
+    header = jnp.stack([
+        n_closed.astype(jnp.int64),
+        (jnp.sum(tail) - n_closed).astype(jnp.int64),
+        n_closed.astype(jnp.int64),
+        jnp.sum(late).astype(jnp.int64),
+        new_wm,
+        jnp.sum(invalid).astype(jnp.int64),
+        bridged,
+    ])
+    return header, (nb_ids, nb_accs, None, new_wm), rows
+
+
 def _merge_core(op: str, neutral: int, capacity: int, a, b):
     """Associative bank combine for striped/sharded ingest: two banks'
     entries merge into one (watermark = max). No closing and no
