@@ -73,7 +73,7 @@ ALLOWED_TELEMETRY_SEAMS = {
     "add_interp_instance", "add_breaker_short_circuit", "record_breaker",
     "add_slo_breach", "add_admission",
     "add_windows_closed", "add_window_delta", "add_window_downlink",
-    "add_window_slice", "add_window_grow",
+    "add_window_slice", "add_window_grow", "add_fanout_grow",
     "add_group_slice", "add_group_grow",
     "add_session_slice", "add_session_grow",
     "gauge_add", "gauge_set",

@@ -95,7 +95,7 @@ class ShardedChainExecutor:
         per-shard compacted view descriptors; ``kmax`` bounds their
         cross-stripe carry's outer scan."""
         (_width, kwidth, has_keys, has_offsets, ts_mode,
-         _enc, _cap, srows, kmax) = cfg
+         _enc, _cap, _bound, srows, kmax) = cfg
         ex = self.executor
         s, v = ex._stripe_s, ex._stripe_v
         with jax.named_scope("repad"):
@@ -185,7 +185,7 @@ class ShardedChainExecutor:
         device `_chain_fn_ragged`: the host link carries sum(lengths)
         bytes per shard, not rows x width)."""
         (width, kwidth, has_keys, has_offsets, ts_mode,
-         enc, fanout_cap) = cfg
+         enc, fanout_cap, fan_bound) = cfg
         with jax.named_scope("repad"):
             values, lengths = kernels_executor.ragged_repad_words(
                 uploads["flat_words"], uploads["lengths"], width
@@ -210,11 +210,11 @@ class ShardedChainExecutor:
             "timestamp_deltas": timestamp_deltas,
         }
         return self._local_step(
-            arrays, count, base_ts, carries, fanout_cap, enc=enc
+            arrays, count, base_ts, carries, fanout_cap, fan_bound, enc=enc
         )
 
     def _local_step(self, arrays: Dict, count, base_ts, carries, fanout_cap=None,
-                    enc: str = "off"):
+                    fan_bound: int = kernels.FANOUT_MIN_BOUND, enc: str = "off"):
         ex = self.executor
         ax = RECORD_AXIS
         n_local = arrays["values"].shape[0]
@@ -226,7 +226,10 @@ class ShardedChainExecutor:
         state["src_row"] = gidx
         # fanout_cap is PER SHARD: each shard scatters into its own
         # capacity block; src_row stays global so the host gather works
-        ctx = {"fanout_cap": fanout_cap, "axis_name": ax, "g0": g0}
+        ctx = {
+            "fanout_cap": fanout_cap, "fan_bound": fan_bound,
+            "axis_name": ax, "g0": g0,
+        }
         for i, stage in enumerate(ex.stages):
             ctx["stage_index"] = i
             with jax.named_scope(stage_scope(i, stage.kind)):
@@ -241,6 +244,7 @@ class ShardedChainExecutor:
         cnt = jnp.sum(valid.astype(jnp.int32))
         fan_err = state.get("fan_err", jnp.asarray(False))
         fan_total = state.get("fan_total", jnp.int32(0))
+        fan_over = [state["fan_over"]] if "fan_over" in state else []
 
         def header(max_v, max_k):
             return jnp.stack(
@@ -250,7 +254,7 @@ class ShardedChainExecutor:
                     max_k.astype(jnp.int64),
                     fan_err.astype(jnp.int64),
                     fan_total.astype(jnp.int64),
-                ]
+                ] + [v.astype(jnp.int64) for v in fan_over]
             )[None, :]
 
         packed: Dict = {}
@@ -315,7 +319,7 @@ class ShardedChainExecutor:
         )
 
     def _jitted(self, uploads: Dict, cfg: tuple):
-        striped = len(cfg) == 9  # (..., enc, fanout_cap, srows, kmax)
+        striped = len(cfg) == 10  # (..., enc, fanout_cap, fan_bound, srows, kmax)
         key = (
             tuple(sorted((k, v.shape, str(v.dtype)) for k, v in uploads.items())),
             cfg,
@@ -332,7 +336,7 @@ class ShardedChainExecutor:
                 jax.tree_util.tree_map(lambda _: rep, self._carries()),
             )
             out_specs = (
-                row,  # per-shard (1, 5) headers stack to (n, 5)
+                row,  # per-shard (1, 5) headers (fan-out: 6) stack to (n, 5)
                 self._packed_specs(striped, cfg[5]),
                 jax.tree_util.tree_map(lambda _: rep, self._carries()),
             )
@@ -566,7 +570,7 @@ class ShardedChainExecutor:
         # axis would square the worst-shard compile matrix; sharded
         # byte-mode keeps the padded ship, so packing stays off here too)
         enc_sh = ex._down_axes(striped)[0] if ex._viewable else "off"
-        cfg = cfg + (enc_sh, cap_shard)
+        cfg = cfg + (enc_sh, cap_shard, ex._fan_bound)
         if striped:
             if ex._striped_chain() is None or ex._fanout:
                 # wide batch outside the sharded stripeable subset
@@ -753,10 +757,10 @@ class ShardedChainExecutor:
         with timed(span, "wait"):
             if "down_meta" in packed:
                 hdr_got = jax.device_get([header, packed["down_meta"]])
-                hdrs = np.asarray(hdr_got[0])  # (n_shards, 5)
+                hdrs = np.asarray(hdr_got[0])  # (n_shards, 5), fan-out 6
                 down_meta = np.asarray(hdr_got[1])  # (n_shards, 3)
             else:
-                hdrs = np.asarray(jax.device_get(header))  # (n_shards, 5)
+                hdrs = np.asarray(jax.device_get(header))  # (n_shards, 5), fan-out 6
         if span is not None:
             span.mark_device_ready()
         counts = hdrs[:, 0].astype(np.int64)
@@ -770,20 +774,24 @@ class ShardedChainExecutor:
                 self._pending_carries = _prev
                 raise TpuSpill("array_map transform error: interpreter decides")
             totals = hdrs[:, 4].astype(np.int64)
-            if int(totals.max()) > cap_shard:
-                # one bigger-capacity retry at the exact (bucketed)
-                # per-shard maximum. An aggregate downstream of the
-                # explode advanced the cross-shard carry chain on the
-                # abandoned dispatch: restore the handle's pre-dispatch
-                # snapshot first so the retry chains from clean state
-                # and can never double-apply. Learn from the PER-SHARD
-                # peak (scaled to a global total), not the global sum:
-                # a persistently skewed stream would otherwise
-                # overflow-and-retry every batch
+            over = int(hdrs[:, 5].max())
+            if int(totals.max()) > cap_shard or over:
+                # one retry at the exact (bucketed) per-shard maximum
+                # where a shard's total passed its capacity, and at the
+                # grown bound where a record passed that. An aggregate
+                # downstream of the explode advanced the cross-shard
+                # carry chain on the abandoned dispatch: restore the
+                # handle's pre-dispatch snapshot first so the retry
+                # chains from clean state and can never double-apply.
+                # Learn from the PER-SHARD peak (scaled to a global
+                # total), not the global sum: a persistently skewed
+                # stream would otherwise overflow-and-retry every batch
                 self._pending_carries = _prev
-                ex._learn_cap(buf, int(totals.max()) * self.n)
+                if int(totals.max()) > cap_shard:
+                    ex._learn_cap(buf, int(totals.max()) * self.n)
+                ex._learn_bound(over)
                 self.fanout_retries += 1
-                retry_cap = ex._bucket_bytes(int(totals.max()), 8)
+                retry_cap = max(ex._bucket_bytes(int(totals.max()), 8), cap_shard)
                 handle = self.dispatch_buffer(
                     buf, cap_shard=retry_cap, reuse_span=span
                 )
@@ -798,7 +806,7 @@ class ShardedChainExecutor:
                     hdrs = np.asarray(jax.device_get(header))
                 if span is not None:
                     span.mark_device_ready()
-                if int(hdrs[:, 4].max()) > cap_shard:  # pragma: no cover
+                if int(hdrs[:, 4].max()) > cap_shard or hdrs[:, 5].any():  # pragma: no cover
                     self._pending_carries = _prev
                     raise TpuSpill(
                         f"fanout overflow after retry: {int(hdrs[:, 4].max())}",

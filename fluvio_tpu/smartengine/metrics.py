@@ -33,6 +33,9 @@ class SmartModuleChainMetrics:
     # shape bucket: hundreds of ms a stream)
     stream_chain_hits: int = 0
     stream_chain_builds: int = 0
+    # slices re-run because a record held more elements than the fan-out
+    # chain's learned bound (one compile each; the `fanout-grow` event)
+    fanout_grows: int = 0
     _lock: object = field(
         default_factory=lambda: make_lock("smartengine.metrics"), repr=False
     )
@@ -68,6 +71,11 @@ class SmartModuleChainMetrics:
             else:
                 self.stream_chain_builds += 1
 
+    def add_fanout_grows(self, n: int) -> None:
+        if n:
+            with self._lock:
+                self.fanout_grows += n
+
     def to_dict(self) -> dict:
         # snapshot under the lock: a scrape concurrent with add_* must
         # never see torn multi-field state (e.g. bytes_in advanced but
@@ -83,6 +91,7 @@ class SmartModuleChainMetrics:
                 "fallback_reasons": dict(self.fallback_reasons),
                 "stream_chain_hits": self.stream_chain_hits,
                 "stream_chain_builds": self.stream_chain_builds,
+                "fanout_grows": self.fanout_grows,
             }
 
     def to_json(self) -> str:

@@ -95,9 +95,9 @@ class TpuSpill(Exception):
 
 
 class _FanoutOverflow(Exception):
-    def __init__(self, total: int):
-        super().__init__(f"fanout total {total} exceeded capacity")
-        self.total = total
+    def __init__(self, total: int, cap: int = 0, over: int = 0):
+        super().__init__(f"fanout of {total} in {cap} rows; a record over the bound: {over}")
+        self.total, self.cap, self.over = total, cap, over
 
 
 @dataclass
@@ -157,11 +157,11 @@ class _MapStage:
 class _ArrayMapStage:
     """Fan-out explode (reference transform kind array_map,
     transforms/mod.rs:24-52). Every output element is a contiguous
-    substring of its source record, so the stage emits (local_row,
-    rel_start, len) descriptors into ``ctx["fanout_cap"]`` capacity rows
-    via prefix-sum placement; view provenance and the source-row chain
-    compose through it, and byte materialization for downstream stages is
-    DCE'd when nothing reads it. Output offset/timestamp deltas are
+    substring of its source record, so the stage places (rel_start, len)
+    descriptors, each with its record's view start and source row, into
+    ``ctx["fanout_cap"]`` rows, at most ``ctx["fan_bound"]`` a record
+    (`kernels.fanout_place`); byte materialization for downstream stages
+    is DCE'd when nothing reads it. Output offset/timestamp deltas are
     "fresh" (zero relative to the source record's batch), synthesized
     host-side from the src column."""
 
@@ -173,7 +173,7 @@ class _ArrayMapStage:
     rewrites_offsets = True
 
     def apply(self, state: Dict, carries, base_ts, ctx):
-        cap = ctx["fanout_cap"]
+        cap, bound = ctx["fanout_cap"], ctx["fan_bound"]
         if cap is None:
             raise Unlowerable("array_map needs a fanout capacity (unsharded path)")
         values, lengths, valid = state["values"], state["lengths"], state["valid"]
@@ -191,30 +191,30 @@ class _ArrayMapStage:
         ridx = jnp.arange(n, dtype=jnp.int32)
         first_err = jnp.min(jnp.where(err_v, ridx, jnp.int32(n)))
         contributing = valid & (ridx < first_err)
-        total, local_row, rel_start, elen = kernels.fanout_scatter(
-            flag, sg, lg, ff, fs, fl, contributing, cap
+        # every slot carries its record's view start, source and local row
+        total, largest, rel_start, elen, view, src, lr = kernels.fanout_place(
+            flag, sg, lg, ff, fs, fl, contributing, cap, bound,
+            (state["view_start"], state["src_row"], ridx),
         )
-        lr = jnp.clip(local_row, 0, n - 1)
         new_state: Dict = {}
         new_state["valid"] = jnp.arange(cap, dtype=jnp.int32) < jnp.minimum(
             total, jnp.int32(cap)
         )
-        new_state["view_start"] = jnp.take(state["view_start"], lr) + rel_start
-        new_state["src_row"] = jnp.take(state["src_row"], lr)
+        new_state["view_start"] = view + rel_start
+        new_state["src_row"] = src
         new_state["lengths"] = elen
         new_state["values"] = materialize_span(
             jnp.take(values, lr, axis=0), rel_start, elen
         )
         new_state["keys"] = jnp.take(state["keys"], lr, axis=0)
         new_state["key_lengths"] = jnp.take(state["key_lengths"], lr)
-        new_state["offset_deltas"] = jnp.zeros(
-            (cap,), state["offset_deltas"].dtype
-        )
-        new_state["timestamp_deltas"] = jnp.zeros(
-            (cap,), state["timestamp_deltas"].dtype
-        )
+        new_state["offset_deltas"] = jnp.zeros((cap,), state["offset_deltas"].dtype)
+        new_state["timestamp_deltas"] = jnp.zeros((cap,), state["timestamp_deltas"].dtype)
         new_state["fan_total"] = total
         new_state["fan_err"] = jnp.any(err_v)
+        # a record over the bound lost its elements past it: the header
+        # carries the largest count, and the host re-runs the slice
+        new_state["fan_over"] = jnp.where(largest > bound, largest, 0)
         ax = ctx.get("axis_name")
         if ax is not None:
             # the stage replaced each shard's n_local input rows with its
@@ -688,7 +688,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                 scoped_program(self._chain_fn_ragged),
                 static_argnames=(
                     "width", "kwidth", "has_keys", "has_offsets", "ts_mode",
-                    "fanout_cap", "enc", "pack",
+                    "fanout_cap", "fan_bound", "enc", "pack",
                 ),
                 donate_argnums=donate,
             ),
@@ -709,7 +709,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                 scoped_program(self._chain_fn_striped),
                 static_argnames=(
                     "srows", "kmax", "kwidth", "has_keys", "has_offsets",
-                    "ts_mode", "fanout_cap", "enc", "pack",
+                    "ts_mode", "fanout_cap", "fan_bound", "enc", "pack",
                 ),
                 donate_argnums=donate,
             ),
@@ -723,15 +723,15 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             for s in stages
         )
         # late materialization: when every value-writing stage is a view
-        # of the record's own bytes, the device ships descriptors
-        # (survivor bitmask + start/length per survivor) and the host
-        # rebuilds output bytes from the slab it already holds — the D2H
-        # link carries ~5x fewer bytes
+        # of the record's own bytes, the device ships descriptors (mask +
+        # start/length per survivor) and the host rebuilds output bytes
+        # from the slab it already holds — the D2H link carries ~5x less
         self._fanout = any(isinstance(s, _ArrayMapStage) for s in stages)
         self._cap_ratio: float = 0.0  # learned fan-out elements per source row
+        self._fan_bound = kernels.FANOUT_MIN_BOUND  # learned: elements a record
+        self.fanout_grows = 0  # times the bound grew, each a slice run again
         self._sharded = None  # multi-device delegate (enable_sharded)
-        # descriptor-prefetch guess: last two survivor-row buckets seen by
-        # the viewable fetch (speculation arms only when they agree)
+        # prefetch guess: the viewable fetch's last two row buckets (armed when they agree)
         self._spec_rows: Optional[int] = None
         self._spec_prev: Optional[int] = None
         # CUMULATIVE link-byte totals since executor creation (read deltas
@@ -1021,12 +1021,12 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
     # -- execution ----------------------------------------------------------
 
     def _chain_fn(self, arrays: Dict, count, base_ts, carries, fanout_cap=None,
-                  enc: str = "off", pack: bool = False):
+                  enc: str = "off", pack: bool = False,
+                  fan_bound: int = kernels.FANOUT_MIN_BOUND):
         """Fused chain body. Returns (header, packed dict, carries).
 
-        Result bytes cross the host link on every batch, so outputs ship
-        as the smallest sufficient representation — ``packed``'s keys are
-        static per executor config:
+        Result bytes cross the host link on every batch, so outputs ship as
+        the smallest representation; ``packed``'s keys are static per chain:
 
         - row-preserving chains ship the survivor set as a
           1-bit-per-input-row bitmask (the host rebuilds survivor
@@ -1037,16 +1037,16 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
           already holds.
 
         Header layout: [count, max_value_len, max_key_len, fanout_error,
-        fanout_total]; a nonzero error spills the batch to the
-        interpreter, a total above capacity triggers a bigger-capacity
-        retry.
+        fanout_total], and a fan-out chain's fanout_over (a record's count
+        over ``fan_bound``, else 0); a nonzero error spills the batch, a
+        total above capacity or a nonzero over re-runs it larger.
         """
         n = arrays["values"].shape[0]
         state = dict(arrays)
         state["valid"] = jnp.arange(n, dtype=jnp.int32) < count
         state["view_start"] = jnp.zeros((n,), dtype=jnp.int32)
         state["src_row"] = jnp.arange(n, dtype=jnp.int32)
-        ctx = {"fanout_cap": fanout_cap}
+        ctx = {"fanout_cap": fanout_cap, "fan_bound": fan_bound}
         for i, stage in enumerate(self.stages):
             ctx["stage_index"] = i  # for a stage's own inner scopes
             with jax.named_scope(stage_scope(i, stage.kind)):
@@ -1065,7 +1065,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         out_count = jnp.sum(valid.astype(jnp.int32))
         fan_err = state.get("fan_err", jnp.asarray(False))
         fan_total = state.get("fan_total", jnp.int32(0))
-
+        fan_over = [state["fan_over"]] if "fan_over" in state else []
         def _header(max_v, max_k):
             return jnp.stack(
                 [
@@ -1074,7 +1074,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                     max_k.astype(jnp.int64),
                     fan_err.astype(jnp.int64),
                     fan_total.astype(jnp.int64),
-                ]
+                ] + [v.astype(jnp.int64) for v in fan_over]
             )
 
         packed: Dict = {}
@@ -1177,6 +1177,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         has_offsets: bool,
         ts_mode: str,
         fanout_cap: Optional[int] = None,
+        fan_bound: int = kernels.FANOUT_MIN_BOUND,
         enc: str = "off",
         pack: bool = False,
     ):
@@ -1188,11 +1189,10 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         re-pad fetches aligned blocks and shifts them into place
         (`ragged_repad_words`): no gather index per byte or per word,
         which is what the TPU's gather throughput is sensitive to.
-        Derivable columns never cross
-        the link: row starts come from a device cumsum of the aligned
-        lengths, arange offset deltas (``has_offsets=False``) and zero
-        timestamp deltas (``ts_mode='zero'``) are synthesized, and
-        narrowed timestamps (``ts_mode`` u16/i32) widen on device.
+        Derivable columns never cross the link: row starts come from a
+        device cumsum of the aligned lengths, arange offset deltas
+        (``has_offsets=False``) and zero timestamp deltas (``ts_mode=
+        'zero'``) are synthesized, narrowed ones (u16/i32) widen here.
         """
         with jax.named_scope("repad"):
             values, lengths = ragged_repad_words(flat, lengths, width)
@@ -1212,7 +1212,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             "timestamp_deltas": timestamp_deltas,
         }
         return self._chain_fn(
-            arrays, count, base_ts, carries, fanout_cap, enc=enc, pack=pack
+            arrays, count, base_ts, carries, fanout_cap, enc=enc, pack=pack, fan_bound=fan_bound
         )
 
     # -- striped wide-record path -------------------------------------------
@@ -1302,19 +1302,20 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         has_offsets: bool,
         ts_mode: str,
         fanout_cap: Optional[int] = None,
+        fan_bound: int = kernels.FANOUT_MIN_BOUND,
         enc: str = "off",
         pack: bool = False,
     ):
         """Striped chain body: same ragged flat upload as the narrow
         path, re-padded into ``srows`` stripe rows of ``_stripe_s`` bytes
         with the segment sidecar derived on device from the lengths.
-        Filters reduce per segment, aggregates
-        run on the segment axis (the narrow scan stages, reused), and
-        outputs ship as the segment survivor bitmask / aggregate ints /
-        span view descriptors / fan-out descriptors — the narrow fetch
-        paths consume all four. ``kmax`` is the static per-record
-        stripe-count bound the JsonGet cross-stripe carry scans over
-        (0 when the chain has no span stage).
+        Filters reduce per segment, aggregates run on the segment axis
+        (the narrow scan stages, reused), and outputs ship as the segment
+        survivor bitmask / aggregate ints / span view descriptors /
+        fan-out descriptors — the narrow fetch paths consume all four.
+        ``kmax`` is the static per-record stripe-count bound the JsonGet
+        cross-stripe carry scans over (0 without a span stage); no
+        ``fan_bound`` cuts a stripe row (`kernels.fanout_scatter`).
         """
         with jax.named_scope("repad"):
             lengths = lengths.astype(jnp.int32)
@@ -1363,9 +1364,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                 flag, st_g, len_g, zeros_b, zeros_i, zeros_i,
                 contributing, fanout_cap,
             )
-            src_seg = jnp.take(
-                plan["seg"], jnp.clip(local_row, 0, srows - 1)
-            )
+            src_seg = jnp.take(plan["seg"], jnp.clip(local_row, 0, srows - 1))
             out_count = jnp.minimum(total, jnp.int32(fanout_cap))
             packed["span_start"] = rel_start
             packed["span_len"] = elen
@@ -1377,6 +1376,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                     jnp.int64(0),
                     jnp.int64(0),  # split mode cannot error
                     total.astype(jnp.int64),
+                    jnp.int64(0),  # nor is a stripe row cut by a bound
                 ]
             )
             return header, packed, carries
@@ -1510,8 +1510,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         device; key columns are synthesized on device when the batch has
         no keys. Remaining columns go as separate arrays — the host link
         runs per-array transfer streams concurrently. ``span`` (a
-        telemetry BatchSpan, or None) collects the host-side phase
-        clock pairs: stage / h2d / dispatch.
+        telemetry BatchSpan, or None) books stage / h2d / dispatch.
         """
         if self._window is not None:
             carries = self._stream_bank().arrays()
@@ -1570,6 +1569,7 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
                 has_offsets=has_offsets,
                 ts_mode=ts_mode,
                 fanout_cap=fanout_cap,
+                fan_bound=self._fan_bound,
                 enc=enc,
                 pack=pack,
             )
@@ -2036,9 +2036,9 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             raise TpuSpill("array_map transform error: interpreter decides")
         if self._fanout:
             cap = packed["span_len" if self._viewable else "lengths"].shape[0]
-            total = int(hdr[4])
-            if total > cap:
-                raise _FanoutOverflow(total)
+            total, over = int(hdr[4]), int(hdr[5])
+            if total > cap or over:
+                raise _FanoutOverflow(total, cap, over)
         width = buf.width
 
         def _mat(rows, st, ln, src):
@@ -2582,6 +2582,17 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         # 25% headroom over the observed density
         self._cap_ratio = max(self._cap_ratio, 1.25 * total / rows)
 
+    def _learn_bound(self, largest: int) -> None:
+        """The most elements one record of a batch held, where that
+        passed the chain's bound (0 where none did): the bound grows to
+        the next power of two, so a few compiled bounds serve any
+        stream, and the grow books a `fanout-grow` event."""
+        if largest > self._fan_bound:
+            grown = 1 << (largest - 1).bit_length()
+            TELEMETRY.add_fanout_grow(f"rows:{self._fan_bound}->{grown}")
+            self._fan_bound = grown
+            self.fanout_grows += 1
+
     def enable_sharded(self, n_devices: int, devices=None) -> None:
         """Switch this chain to the multi-device engine mode: the same
         stage pipeline under `shard_map` over an ``n_devices`` record
@@ -3001,9 +3012,13 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
         try:
             out = self._fetch(buf, header, packed, spec, defer=defer)
         except _FanoutOverflow as o:
-            self._learn_cap(buf, o.total)
+            # one re-run at the exact (bucketed) capacity where the total
+            # passed it, and at the grown bound where a record passed that
+            if o.total > o.cap:
+                self._learn_cap(buf, o.total)
+            self._learn_bound(o.over)
             self._device_carries = prev_carries
-            cap = self._bucket_bytes(o.total, 1024)
+            cap = max(self._bucket_bytes(o.total, 1024), o.cap)
             header, packed = self._dispatch(buf, fanout_cap=cap, span=span)
             if span is not None:
                 span.mark_dispatched()
@@ -3243,11 +3258,13 @@ class TpuChainExecutor(window_stage.WindowChainMixin):
             # layout stage striped — or spill from _dispatch when the
             # chain is outside the stripeable subset.
             raise TpuSpill(str(e), reason="record-too-wide") from None
+        grows = self.fanout_grows
         out = self.process_buffer(buf)
         if self.agg_configs:
             self._ensure_host_state()
         if metrics is not None:
             metrics.add_fuel_used(buf.count * max(len(self.stages), 1))
+            metrics.add_fanout_grows(self.fanout_grows - grows)
         return SmartModuleOutput(successes=out.to_records())
 
     # -- state mirroring ----------------------------------------------------

@@ -812,38 +812,38 @@ def fanout_scatter(
 ):
     """Compact element descriptors into ``cap`` output rows.
 
-    Placement: exclusive prefix sum of per-record element counts gives
-    each record's base row; elements order by emission position; the
-    final-segment slot lands after a record's grid elements. Returns
-    (total, local_row[cap], rel_start[cap], elen[cap]) — total is exact
-    (pre-cap), so the caller can detect overflow and retry with a larger
-    bucketed capacity.
+    Placement: each record's elements in emission order (its grid
+    columns, then its final-segment slot) after every element of the
+    records before it; a record that is not ``contributing`` places
+    none. Returns (total, local_row[cap], rel_start[cap], elen[cap]) —
+    total is exact (pre-cap), so the caller can detect overflow and
+    retry with a larger bucketed capacity; slots past the total, or
+    past the cap, read 0.
 
-    The output slots read their elements by gather through
-    `compaction_sources` of the flags (the flattened row-major,
-    final-slot-after-grid order is the output order): one int32
-    permutation with distinct indices. A NON-unique scatter of the
-    descriptors (dropped elements share an index) lowers on TPU to
-    sort-based loops, a binary search for the sources to a loop of
-    dependent gathers: each was the explode chain's largest device cost.
+    This is `fanout_place` with a slot for every column of a record, so
+    nothing is ever cut and no bound is learned: the form for callers
+    whose records have none (the striped split, where a stripe row is
+    the record, and the tests' oracles). ``local_row`` is the record
+    index, placed as any per-record column is. The array_map stage
+    calls `fanout_place` itself, with its chain's learned bound of
+    elements a record, and places the columns it ships (the view start,
+    the source row) the same way, so it gathers none by ``local_row``.
+
+    Neither form flattens the grid to one index a byte slot. A prefix
+    sum, a permutation scatter (which the chip's compiler made one sort
+    of index pairs) and two gathers over rows x (width + 1) slots, nine
+    in ten of them empty at six elements a 64-byte record, were the
+    explode chain's largest device operations (PERF.md section 6).
+    Placement is now a select-sum inside each record's row and log2
+    shifted selects of the flat table: no gather, scatter or sort, and
+    no while loop.
     """
     n, width = flag.shape
-    flag = flag & contributing[:, None]
-    fflag = fflag & contributing
-    # flattened emission order: each record's grid columns then its
-    # final-segment slot — one (n, width+1) flag/start/len set
-    allflag = jnp.concatenate([flag, fflag[:, None]], axis=1).reshape(-1)
-    allstart = jnp.concatenate([start_g, fstart[:, None]], axis=1).reshape(-1)
-    alllen = jnp.concatenate([len_g, flen[:, None]], axis=1).reshape(-1)
-    total, src = compaction_sources(
-        allflag, jnp.cumsum(allflag.astype(jnp.int32))
+    row = jnp.arange(n, dtype=jnp.int32)
+    total, _, out_start, out_len, out_row = fanout_place(
+        flag, start_g, len_g, fflag, fstart, flen, contributing, cap,
+        width + 1, (row,),
     )
-    pos = jnp.pad(src, (0, max(0, cap - src.shape[0])))[:cap]
-    live = jnp.arange(cap, dtype=jnp.int32) < jnp.minimum(total, jnp.int32(cap))
-    out_row = jnp.where(live, pos // jnp.int32(width + 1), 0)
-    # `pos` is in bounds; the default fill mode drops the caller's scope
-    out_start = jnp.where(live, jnp.take(allstart, pos, mode="clip"), 0)
-    out_len = jnp.where(live, jnp.take(alllen, pos, mode="clip"), 0)
     return total, out_row, out_start, out_len
 
 
@@ -1311,3 +1311,117 @@ def compaction_sources(mask: jnp.ndarray, rank: jnp.ndarray):
     dest = jnp.where(mask, rank - 1, count + row - rank)
     src = jnp.zeros((n,), jnp.int32).at[dest].set(row, unique_indices=True)
     return count, src
+
+
+# ---------------------------------------------------------------------------
+# Fan-out placement by record (the array_map stage)
+# ---------------------------------------------------------------------------
+
+# the least bound of elements a record `fanout_place` is compiled for: a
+# chain's learned bound starts here and grows by powers of two
+FANOUT_MIN_BOUND = 8
+
+
+def _running_count(flags: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running count of int32[n, c] ``flags`` along each row,
+    by log2(c) adds of the row shifted by a power of two (static slices,
+    elementwise work): `jnp.cumsum` is a reduce-window on the chip."""
+    count = flags
+    step = 1
+    while step < flags.shape[1]:
+        count = count + jnp.pad(count[:, :-step], ((0, 0), (step, 0)))
+        step *= 2
+    return count
+
+
+def _shift_compact(shift, cols):
+    """Move every entry of the flat ``cols`` whose ``shift`` is not
+    negative (a live one) left by that shift, one power of two at a
+    time, the lowest first. Live shifts must never decrease from one
+    live entry to the next and must bring the entries to distinct slots,
+    as a stable compaction's do: then no two entries meet at any step,
+    and a step is a select between each slot and the one ``2**b`` to
+    its right — static slices, no gather, scatter or sort. Returns the
+    live mask and the columns after the last step."""
+    n = shift.shape[0]
+
+    def right(x, d, fill):
+        return jnp.pad(x[d:], (0, d), constant_values=fill)
+
+    for b in range(max(n - 1, 0).bit_length()):
+        d = 1 << b
+        incoming = right(shift, d, -1)
+        arrive = (incoming >= 0) & ((incoming & d) != 0)
+        leave = (shift >= 0) & ((shift & d) != 0)
+        shift = jnp.where(arrive, incoming, jnp.where(leave, -1, shift))
+        cols = [jnp.where(arrive, right(c, d, 0), c) for c in cols]
+    return shift >= 0, cols
+
+
+def fanout_place(
+    flag, start_g, len_g, fflag, fstart, flen, contributing, cap: int,
+    bound: int, row_cols=(),
+):
+    """Place the fan-out's elements into ``cap`` output rows in
+    `fanout_scatter`'s order, at most ``bound`` of them a record: work
+    in proportion to records x ``bound``, not to records x (width + 1)
+    byte slots.
+
+    Each record's elements (its grid columns in order, then its
+    final-segment slot) first go to ``bound`` slots of its own row: slot
+    j takes the column where the row's running count of flags reaches
+    j + 1, a one-hot select-sum over the row. Where ``bound`` is not
+    below the columns, the grid is that table and nothing is cut. The
+    table then compacts as one flat array (`_shift_compact`): a record's
+    elements go after every element of the records before it.
+
+    Returns (total, largest, out_start[cap], out_len[cap], *placed):
+    ``total`` counts every element (pre-cap, pre-bound), ``largest`` is
+    the largest count of one record — above ``bound``, the records over
+    it lost their elements past the bound, and the caller re-runs the
+    slice at a larger one — and ``placed`` holds each of ``row_cols``
+    (per record, int32[n]) at its element's slot. Slots past what was
+    placed, or past ``cap``, read 0."""
+    from fluvio_tpu.windows.kernels import prefix_sum
+
+    n, width = flag.shape
+    cols = width + 1
+    live = jnp.concatenate([flag, fflag[:, None]], axis=1) & contributing[:, None]
+    start = jnp.concatenate([start_g, fstart[:, None]], axis=1)
+    length = jnp.concatenate([len_g, flen[:, None]], axis=1)
+    rank = _running_count(live.astype(jnp.int32))
+    count = rank[:, -1]
+    if bound < cols:
+        slot = jnp.arange(1, bound + 1, dtype=jnp.int32)[None, :, None]
+        pick = live[:, None, :] & (rank[:, None, :] == slot)
+
+        def select(x):
+            return jnp.sum(
+                jnp.where(pick, x[:, None, :], 0), axis=2, dtype=jnp.int32
+            )
+
+        start, length = select(start), select(length)
+        kept = jnp.minimum(count, bound)
+        place = jnp.broadcast_to(jnp.arange(bound, dtype=jnp.int32), (n, bound))
+        live = place < kept[:, None]
+    else:
+        kept = count
+        place = rank - 1
+    k = live.shape[1]
+    base = prefix_sum(kept) - kept
+    pos = jnp.arange(n * k, dtype=jnp.int32).reshape(n, k)
+    shift = jnp.where(live, pos - base[:, None] - place, -1)
+    payload = [start, length] + [
+        jnp.broadcast_to(c[:, None], (n, k)) for c in row_cols
+    ]
+    live, placed = _shift_compact(
+        shift.reshape(-1), [p.reshape(-1) for p in payload]
+    )
+
+    def first(x):
+        return jnp.pad(x, (0, max(0, cap - x.shape[0])))[:cap]
+
+    keep = first(live)
+    out = [jnp.where(keep, first(p), 0) for p in placed]
+    return (jnp.sum(count, dtype=jnp.int32), jnp.max(count), *out)
+

@@ -858,6 +858,7 @@ def _tpu_fetch_inner(
     parts = pending.parts = []
     finished = 0
     last = len(pending.chunks) - 1
+    grows = tpu.fanout_grows
     with timed(pending.flow, "finish"):
         try:
             for b, h in pending.chunks:
@@ -911,6 +912,11 @@ def _tpu_fetch_inner(
             )
             _decline(metrics, "fused-error")
             return False
+        finally:
+            # a chunk that re-ran at a grown fan-out bound, whatever the
+            # slice's outcome
+            if metrics is not None:
+                metrics.add_fanout_grows(tpu.fanout_grows - grows)
 
 
 def _split_back_of(part):

@@ -585,6 +585,7 @@ def _render_spu(w: _Writer, m: dict) -> None:
         ("fallback_slices", "Read slices that fell back to the per-record loop."),
         ("stream_chain_hits", "Stream opens served from the stream-chain cache."),
         ("stream_chain_builds", "Stream opens that built their chain."),
+        ("fanout_grows", "Slices re-run at a grown fan-out bound."),
     )
     for field, help_text in scalar_fields:
         name = f"{_PREFIX}_smartmodule_{field}_total"

@@ -688,6 +688,13 @@ class PipelineTelemetry:
         `add_chain_build`)."""
         self._event("window-grow", detail)
 
+    def add_fanout_grow(self, detail: str) -> None:
+        """A fan-out chain met a record with more elements than its
+        learned bound (`kernels.fanout_place`): the slice is re-run at a
+        bound grown to the next power of two, which compiles (as
+        `add_window_grow`)."""
+        self._event("fanout-grow", detail)
+
     def add_group_slice(self, rows: int, keys: int, invalid: int) -> None:
         """What one served (or interpreted) slice of a keyed running
         aggregate (`dsl.GroupProgram`) counted: rows answered, the

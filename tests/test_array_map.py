@@ -218,6 +218,61 @@ class TestOverflowRetry:
             ex._fetch(buf, header, packed)
 
 
+class TestLearnedBound:
+    """The chain places at most a learned bound of elements a record
+    (`kernels.fanout_place`); a record over it re-runs its slice at the
+    next power of two, once, and the bound stays with the chain."""
+
+    @staticmethod
+    def _grows():
+        from fluvio_tpu.telemetry import TELEMETRY
+
+        return sum(1 for e in TELEMETRY.events.recent() if e.kind == "fanout-grow")
+
+    @pytest.mark.parametrize("path", ["engine", "slices"])
+    def test_record_over_the_bound_grows_it_once(self, path):
+        from fluvio_tpu.smartengine.metrics import SmartModuleChainMetrics
+
+        wide = ("[" + ",".join(str(i) for i in range(20)) + "]").encode()
+        stream = [
+            [b"[1,2,3]", b'["a","b"]'] * 4,           # within the first bound
+            [b"[1,2]", wide, b"[3]", b'["x"]'] * 2,   # a record over it
+            [wide, b"[4,5]"] * 4,                      # at the grown bound
+        ]
+        tc = _chain("tpu", ("array-map-json", None))
+        pc = _chain("python", ("array-map-json", None))
+        ex = tc.tpu_chain
+        assert ex._fan_bound == kernels.FANOUT_MIN_BOUND
+        metrics = SmartModuleChainMetrics()
+        grows0 = self._grows()
+        for k, values in enumerate(stream):
+            if path == "engine":
+                got = tc.process(
+                    SmartModuleInput.from_records(_records(values), 7, 500), metrics
+                ).successes
+                want = pc.process(
+                    SmartModuleInput.from_records(_records(values), 7, 500)
+                ).successes
+                assert [r.value for r in got] == [r.value for r in want]
+            else:
+                base = 10 * k
+                fast = process_batches(
+                    tc, _encode_batches([_records(values)], [base], [1000]),
+                    1 << 20, metrics,
+                )
+                slow = process_batches(
+                    pc, _encode_batches([_records(values)], [base], [1000]),
+                    1 << 20,
+                )
+                assert _flat(fast) == _flat(slow)
+                assert fast.next_offset == slow.next_offset == base + len(values)
+        # 20 elements in one record: the bound grew 8 -> 32, once
+        assert ex._fan_bound == 32
+        assert self._grows() - grows0 == 1
+        assert metrics.fanout_grows == 1
+        assert metrics.to_dict()["fanout_grows"] == 1
+
+
 def _encode_batches(record_groups, bases, first_ts):
     w = ByteWriter()
     for recs, base, ts in zip(record_groups, bases, first_ts):

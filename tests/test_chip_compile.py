@@ -366,22 +366,74 @@ def test_window_scans_and_gathers_keep_their_scope(one_chip, as_tpu, which):
     rewrite of one long reduce-window keeps no metadata at all: the
     merge's prefix sums were 2.2 % of `q5-drain`'s device time under no
     scope (PR 35). `prefix_sum` and `compact_front` keep theirs; the
-    compaction's ONE int32 scatter (PR 40: `compaction_sources`) and the
-    gathers through it keep the caller's scope in both callers, with no
-    search `while` left. `fanout_scatter`'s own `jnp.cumsum` is not
-    held to it (the prefix sum PR 40 left as it was)."""
+    window compaction's ONE int32 scatter (`compaction_sources`)
+    and the gathers through it keep the caller's scope, with no search
+    `while` left. The fan-out places with no scatter, gather or sort at
+    all; its one prefix sum, over the records, keeps the scope."""
     scope, hlo = _scoped_compaction_hlo(which, one_chip)
     lines = hlo.splitlines()
     scatters = [ln for ln in lines if " scatter(" in ln]
-    assert len(scatters) == 1 and "unique_indices=true" in scatters[0]
+    gathers = [ln for ln in lines if " gather(" in ln]
+    windows = [ln for ln in lines if " reduce-window(" in ln]
     assert not [ln for ln in lines if " while(" in ln]
-    ops = scatters + [ln for ln in lines if " gather(" in ln] + (
-        [ln for ln in lines if " reduce-window(" in ln]
-        if which == "compact_front" else []
-    )
-    assert len(ops) >= 3
+    if which == "compact_front":
+        assert len(scatters) == 1 and "unique_indices=true" in scatters[0]
+        ops = scatters + gathers + windows
+        assert len(ops) >= 3
+    else:
+        assert not scatters and not gathers
+        assert not [ln for ln in lines if " sort(" in ln]
+        ops = windows
+        assert ops
     for ln in ops:
         assert scope in ln, ln[:200]
+
+
+# `explode-drain`'s slice: 524,288 padded records of width 64 (six
+# elements each), a learned capacity of 3,145,728 output rows and a
+# learned bound of 8 elements a record
+EXPLODE_ROWS, EXPLODE_WIDTH, EXPLODE_CAP, EXPLODE_BOUND = 1 << 19, 64, 3 << 20, 8
+
+
+def test_fanout_place_at_the_explode_cell_shape(one_chip, as_tpu):
+    """The placement flattened the grid to 524,288 x 65 = 34,078,720
+    byte slots and sorted, scattered and gathered over all of them, nine
+    in ten empty: the largest device operations of `explode-drain`
+    (PERF.md section 5). At the cell's own shape no sort, scatter,
+    gather or reduce-window of that size is left, and each that is left
+    keeps `stage0.array_map`, by which `device_chain_ms_per_mrec` books
+    it."""
+    from fluvio_tpu.smartengine.tpu.kernels import fanout_place
+
+    n, w = EXPLODE_ROWS, EXPLODE_WIDTH
+
+    def fan(flag, start, length, fflag, fstart, flen, contributing, view, src):
+        with jax.named_scope("stage0.array_map"):
+            return fanout_place(
+                flag, start, length, fflag, fstart, flen, contributing,
+                EXPLODE_CAP, EXPLODE_BOUND,
+                (view, src, jnp.arange(n, dtype=jnp.int32)),
+            )
+
+    b, i = jnp.bool_, jnp.int32
+    hlo = _compile(
+        jax.jit(fan),
+        _sds((n, w), b, one_chip), _sds((n, w), i, one_chip),
+        _sds((n, w), i, one_chip), _sds((n,), b, one_chip),
+        _sds((n,), i, one_chip), _sds((n,), i, one_chip),
+        _sds((n,), b, one_chip), _sds((n,), i, one_chip),
+        _sds((n,), i, one_chip),
+    )
+    heavy = [
+        ln for ln in hlo.splitlines()
+        if re.search(r" (sort|scatter|gather|reduce-window)\(", ln)
+    ]
+    assert heavy  # the records' prefix sum
+    for ln in heavy:
+        assert "stage0.array_map" in ln, ln[:200]
+        for dims in re.findall(r"[a-z]\d*\[([\d,]+)\]", ln.split(" = ", 1)[1][:200]):
+            size = int(np.prod([int(d) for d in dims.split(",")]))
+            assert size != n * (w + 1), ln[:200]
 
 
 def _compile_striped(ex, n_records, sharding):

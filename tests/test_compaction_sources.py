@@ -1,12 +1,16 @@
-"""Stable compaction by one int32 permutation scatter (PR 40).
+"""Stable compaction without a search loop.
 
 `kernels.compaction_sources` replaced `searchsorted(rank, 1..cap)` in
-`windows/kernels.py:compact_front` and `kernels.fanout_scatter`: on the
-chip the search was a `while` of log2(n) dependent gathers, the largest
-device operation of `q5-drain` and `explode-drain`. The old search form
-is kept HERE as the oracle, beside `np.flatnonzero`; every live slot
-must equal both, every dead slot must be zero. The structural guard
-holds the search loop out of the lowered programs.
+`windows/kernels.py:compact_front` with one int32 permutation scatter:
+on the chip the search was a `while` of log2(n) dependent gathers, the
+largest device operation of `q5-drain`. The fan-out's placement
+(`kernels.fanout_place`, and `kernels.fanout_scatter` on it) does
+without the scatter too: a select-sum inside each record's row and
+shifted selects of the flat table. The old search form is kept HERE as
+the oracle, beside `np.flatnonzero`; every live slot must equal both,
+every dead slot must be zero. The structural guards hold the search
+loop, and for the fan-out every sort, scatter and gather, out of the
+lowered programs.
 """
 
 from __future__ import annotations
@@ -153,6 +157,85 @@ def _check_fanout(mask, cap, rng):
     )
 
 
+# `fanout_place` at a bound of 8 elements a record (the explode corpus
+# has six): each case is a grid of records x (width + 1) slots
+BOUND = 8
+BOUND_CASES = ("empty", "full", "at_bound", "over_bound", "holes", "cap_under")
+
+
+def _bound_case(case: str, rng):
+    """(grid, contributing, cap, bound) for one case. "full" fills every
+    column of every record and places them uncut (a bound of the
+    columns, the `fanout_scatter` form)."""
+    n, cols = 40, 12
+    grid = np.zeros((n, cols), bool)
+    bound = BOUND
+    if case == "full":
+        grid[:] = True
+        bound = cols
+    elif case != "empty":
+        for r in range(n):
+            k = int(rng.integers(0, 7))  # up to six elements a record
+            grid[r, rng.choice(cols, k, replace=False)] = True
+    if case == "at_bound":
+        grid[3] = False
+        grid[3, rng.choice(cols, BOUND, replace=False)] = True
+    if case == "over_bound":
+        grid[5] = False
+        grid[5, rng.choice(cols, BOUND + 3, replace=False)] = True
+    contributing = np.ones(n, bool)
+    if case == "holes":
+        contributing[rng.choice(n, 9, replace=False)] = False
+    total = int((grid & contributing[:, None]).sum())
+    cap = total // 2 + 1 if case == "cap_under" else total + 17
+    return grid, contributing, cap, bound
+
+
+def _check_fanout_place(case: str, rng):
+    grid, contributing, cap, bound = _bound_case(case, rng)
+    n, cols = grid.shape
+    starts = rng.integers(0, 1 << 20, grid.shape).astype(np.int32)
+    lens = rng.integers(0, 1 << 10, grid.shape).astype(np.int32)
+    view = rng.integers(0, 1 << 24, n).astype(np.int32)
+    args = (
+        grid[:, :-1], starts[:, :-1], lens[:, :-1],
+        grid[:, -1], starts[:, -1], lens[:, -1], contributing,
+    )
+    total, largest, st, ln, vs, row = jax.jit(
+        lambda *a: kernels.fanout_place(
+            *a, cap, bound, (jnp.asarray(view), jnp.arange(n, dtype=jnp.int32))
+        )
+    )(*args)
+    kept = grid & contributing[:, None]
+    counts = kept.sum(axis=1)
+    assert int(total) == int(counts.sum())  # exact, past the cap and bound
+    assert int(largest) == int(counts.max())
+    # the oracle: each record's first `bound` elements, in column order
+    placed = np.zeros_like(kept)
+    for r in range(n):
+        placed[r, np.flatnonzero(kept[r])[:bound]] = True
+    rows = np.flatnonzero(placed.reshape(-1))
+    record = np.arange(kept.size) // cols
+    for got, col in (
+        (st, starts.reshape(-1)), (ln, lens.reshape(-1)),
+        (row, record), (vs, view[record]),
+    ):
+        np.testing.assert_array_equal(np.asarray(got), _packed(rows, cap, col))
+    if counts.max() > bound:
+        # flagged: the records over the bound lost their elements past it
+        assert case == "over_bound" and len(rows) < int(counts.sum())
+        return
+    # within the bound the placement is the one before it, slot for slot
+    old = jax.jit(lambda *a: _old_fanout(*a, cap))(*args)
+    for got, want in zip((total, row, st, ln), old):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("case", BOUND_CASES)
+def test_fanout_place_cuts_records_at_the_bound(case):
+    _check_fanout_place(case, np.random.default_rng(zlib.crc32(case.encode())))
+
+
 @pytest.mark.parametrize("n", LENGTHS)
 @pytest.mark.parametrize("kind", MASKS)
 @pytest.mark.parametrize(
@@ -187,11 +270,20 @@ def _lowered(fn: str) -> str:
 
 @pytest.mark.parametrize("fn", ("compact_front", "fanout_scatter"))
 def test_compaction_lowers_to_one_unique_scatter_and_no_loop(fn):
-    """The search was a `while` on the chip; the inverse is ONE scatter
-    of distinct in-range indices (`unique_indices = true`), the form
-    that does not lower to sort-based loops."""
+    """The search was a `while` on the chip; `compact_front`'s inverse
+    is ONE scatter of distinct in-range indices (`unique_indices =
+    true`), the form that does not lower to sort-based loops. The
+    fan-out places without one: no scatter, sort or gather at all (the
+    described-v5e compile in `test_chip_compile.py` holds its prefix
+    sums, which lower to reduce-windows only on the chip, to its
+    records)."""
     text = _lowered(fn)
     assert "stablehlo.while" not in text
     scatters = [ln for ln in text.splitlines() if '"stablehlo.scatter"(' in ln]
-    assert len(scatters) == 1, scatters
-    assert "unique_indices = true" in scatters[0]
+    if fn == "compact_front":
+        assert len(scatters) == 1, scatters
+        assert "unique_indices = true" in scatters[0]
+        return
+    assert not scatters
+    for op in ("sort", "gather", "dynamic_gather"):
+        assert f'"stablehlo.{op}"(' not in text and f"stablehlo.{op} " not in text

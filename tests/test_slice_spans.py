@@ -331,7 +331,7 @@ def test_served_slice_ships_its_flat_raw(tmp_path, monkeypatch, config_name):
     assert args[0].dtype == np.int32 and args[0].ndim == 1
     assert len(args) == 9 and set(kwargs) == {
         "width", "kwidth", "has_keys", "has_offsets", "ts_mode",
-        "fanout_cap", "enc", "pack",
+        "fanout_cap", "fan_bound", "enc", "pack",
     }
     found = _scopes_in(jit.__wrapped__.lower(*args, **kwargs).compile().as_text())
     assert "repad" in found and "link_decode" not in found, found
