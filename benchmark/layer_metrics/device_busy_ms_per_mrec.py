@@ -1,7 +1,9 @@
 """Device time per million input records: the device's busy share of the
 traced span (union of device-operation intervals over the span between
 the trace's two markers) times the window's seconds per million input
-records. The traced span is the second half of the window."""
+records. The traced span is the second half of the window. On a cell of
+several chips the busy time is the mean over its chips (`trace_reduce`),
+so this is the average chip's time per million records of them all."""
 
 
 def read(obs):

@@ -1,4 +1,6 @@
-"""1 - device busy time / traced span, from the profiler trace."""
+"""1 - device busy time / traced span, from the profiler trace. On a cell
+of several chips the busy time is the mean over the cell's chips
+(`trace_reduce`): the idle share of the average chip."""
 
 
 def read(obs):

@@ -1,12 +1,14 @@
-"""Least time the chip's HBM could take to move the traced span's staged
-input and result buffers once each, over the device's busy time.
+"""Least time the chips' HBM could take to move the traced span's staged
+input and result buffers once each, over the mean chip's busy time.
 
-Bytes come from shapes, by `spubench.shapes.slice_bytes` (a copy of the
-program's bucketing rules kept with the benchmark); the peak comes from
-`spubench/peaks.json`, keyed by the device kind. Bandwidth-bound by
-construction: these chains do byte scans, no matrix work."""
+Bytes come from shapes, by `spubench.shapes.span_bytes` (a copy of the
+program's bucketing rules kept with the benchmark), summed over every
+chip's dispatches; the peak is `spubench/peaks.json`'s, keyed by the
+device kind, times the cell's chips (`device.hbm_peak_of_run`).
+Bandwidth-bound by construction: these chains do byte scans, no matrix
+work."""
 
-from spubench.device import peaks_for
+from spubench.device import hbm_peak_of_run
 from spubench.shapes import span_bytes
 
 
@@ -15,6 +17,6 @@ def read(obs):
     spans = obs.get("trace_spans") or ()
     if not t or not spans or t["busy_s"] <= 0:
         return None
-    peak = peaks_for(obs["device_kind"])["hbm_bytes_per_s"]
+    peak = hbm_peak_of_run(obs)
     moved = sum(span_bytes(s["records"], obs["shape"]) for s in spans)
     return 100.0 * (moved / peak) / t["busy_s"]

@@ -3,9 +3,11 @@ must (`spubench.session_bytes`: every padded row's key, start, last and
 count read once and written once) for the traced span's dispatches, over
 the device's busy time under `stage<i>.session_merge` +
 `stage<i>.session_close`. Bandwidth-bound by construction: a sort,
-scans, a scatter of positions and gathers of int64 columns."""
+scans, a scatter of positions and gathers of int64 columns.
+The peak is the cell's chips' (`device.hbm_peak_of_run`): the bytes are
+summed over every chip's dispatches, the busy time is the mean chip's."""
 
-from spubench.device import peaks_for
+from spubench.device import hbm_peak_of_run
 from spubench.session_bytes import session_bytes, work_scope_seconds
 from spubench.xplane_scopes import reduce_run
 
@@ -18,6 +20,6 @@ def read(obs):
     busy = work_scope_seconds(r)
     if busy <= 0:
         return None
-    peak = peaks_for(obs["device_kind"])["hbm_bytes_per_s"]
+    peak = hbm_peak_of_run(obs)
     moved = sum(session_bytes(s["records"]) for s in spans)
     return 100.0 * (moved / peak) / busy

@@ -5,9 +5,11 @@ over the device's busy time under `stage<i>.window_merge`.
 The replicas a record counts in are the cell's own (`window_replicas`,
 which the event-time mode notes from the configuration's window and
 slide): None where the run states none. Bandwidth-bound by
-construction: a sort, scans and gathers of int64 columns."""
+construction: a sort, scans and gathers of int64 columns.
+The peak is the cell's chips' (`device.hbm_peak_of_run`): the bytes are
+summed over every chip's dispatches, the busy time is the mean chip's."""
 
-from spubench.device import peaks_for
+from spubench.device import hbm_peak_of_run
 from spubench.window_bytes import merge_bytes, merge_scope_seconds
 from spubench.xplane_scopes import reduce_run
 
@@ -21,6 +23,6 @@ def read(obs):
     busy = merge_scope_seconds(r)
     if busy <= 0:
         return None
-    peak = peaks_for(obs["device_kind"])["hbm_bytes_per_s"]
+    peak = hbm_peak_of_run(obs)
     moved = sum(merge_bytes(s["records"], replicas) for s in spans)
     return 100.0 * (moved / peak) / busy

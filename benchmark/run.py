@@ -85,13 +85,22 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 def _result(cell, session, dev, obs, workload, seed, trace) -> dict:
     from spubench import device, window
 
-    faults = list(obs["faults"]) + window.truth_faults(
-        session.c_start, obs["c_close"]
-    )
     obs["setup_s"] = session.setup_s
     obs["device_kind"] = dev["kind"]
+    obs["chips"] = cell.chips
     obs["shape"] = session.shape
     dev["memory_peak_bytes"] = device.memory_peak_bytes()
+    obs["window_spans"] = window.spans_between(obs["t_open"], obs["t_close"])
+    reduced = None
+    if trace:
+        tr = session.tracer
+        if tr.t1 is not None:
+            reduced = tr.reduce(window.phase_intervals(tr.t0, tr.t1))
+            obs["trace_spans"] = window.spans_between(tr.t0, tr.t1)
+        obs["trace"] = reduced
+    faults = (list(obs["faults"])
+              + window.truth_faults(session.c_start, obs["c_close"])
+              + window.placement_faults(session.broker, reduced))
     result = {
         "correct": not faults,
         "attempted": int(obs["attempted"]),
@@ -107,14 +116,7 @@ def _result(cell, session, dev, obs, workload, seed, trace) -> dict:
         } | {"samples": len(obs.get("ages_s", ()))} | obs["delta"]
         | obs.get("counts", {}),
     }
-    obs["window_spans"] = window.spans_between(obs["t_open"], obs["t_close"])
     if trace:
-        tr = session.tracer
-        reduced = None
-        if tr.t1 is not None:
-            reduced = tr.reduce(window.phase_intervals(tr.t0, tr.t1))
-            obs["trace_spans"] = window.spans_between(tr.t0, tr.t1)
-        obs["trace"] = reduced
         if reduced is not None:
             dev["busy_s"] = reduced["busy_s"]
             dev["window_s"] = reduced["window_s"]
@@ -129,7 +131,8 @@ def _result(cell, session, dev, obs, workload, seed, trace) -> dict:
         result["metrics"] = _read_metrics(
             cell, "end_to_end", cell.end_to_end, obs
         )
-    result["compared"] = window.compared(obs, session.c_start, obs["c_close"])
+    result["compared"] = window.compared(
+        obs, session.c_start, obs["c_close"], session.broker, reduced)
     return result
 
 

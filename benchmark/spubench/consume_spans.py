@@ -21,7 +21,7 @@ from collections import defaultdict
 
 from spubench import xplane_scopes as xs
 from spubench.trace_reduce import (
-    DEVICE_PLANE_PREFIX, MIN_GAP_S, OPS_LINE, _union,
+    OPS_LINE, _union, device_planes, idle_gaps,
 )
 
 # the consumer's phases that hold the event loop; ``consume_recv``
@@ -119,7 +119,7 @@ _CACHE: dict = {}
 
 
 def idle_by_phase(raw: bytes, program_phases, consume_phases) -> dict | None:
-    """{phase: seconds} of the first device's idle gaps (as
+    """{phase: seconds} of chip 0's idle gaps (as
     `xplane_scopes.reduce_xspace` cuts them) under the program's
     ``fluvio/<phase>`` annotations, by this reader's own ranking: a piece
     goes to the covering phase that holds its thread before one that
@@ -128,35 +128,28 @@ def idle_by_phase(raw: bytes, program_phases, consume_phases) -> dict | None:
     phase; among awaiting ones, to the consumer's before the server's
     (the server awaits the consumer); then to the latest started. A
     piece under none is `xplane_scopes.UNNAMED`. None where no operation
-    ran on a device plane."""
+    ran on chip 0."""
     planes = xs.parse_xspace(raw)
     m0, m1 = xs._marks(planes)
-    first = None
+    (chip0,) = device_planes(1)
+    iv = []
     for plane in planes:
-        if not plane["name"].startswith(DEVICE_PLANE_PREFIX):
+        if plane["name"] != chip0:
             continue
         for line in plane["lines"]:
             if line.name != OPS_LINE:
                 continue
-            iv = []
             for _mid, a, b, _s in line.events:
                 if m0 is not None and m1 is not None:
                     a, b = max(a, m0), min(b, m1)
                 if b > a:
                     iv.append((a, b))
-            if iv and first is None:
-                first = _union(iv)
-    if first is None:
+    if not iv:
         return None
+    first = _union(iv)
     if m0 is None or m1 is None:
         m0, m1 = first[0][0], first[-1][1]
-    gaps, edge = [], m0
-    for a, b in first:
-        if a - edge >= MIN_GAP_S * 1e9:
-            gaps.append((edge, a))
-        edge = max(edge, b)
-    if m1 - edge >= MIN_GAP_S * 1e9:
-        gaps.append((edge, m1))
+    gaps = idle_gaps(first, m0, m1)
     phases = xs._host_phases(planes, m0, m1)
     out = defaultdict(float)
     for a, b in gaps:

@@ -53,3 +53,19 @@ def peaks_for(device_kind: str) -> dict:
             f"no published peaks for device kind {device_kind!r} in peaks.json"
         )
     return table[device_kind]
+
+
+def chips_of(obs) -> int:
+    """The chips a run's readings are over: the cell's, which `run.py`
+    puts in every run's observations; 1 where an observation states none
+    (one written by hand)."""
+    return int(obs.get("chips", 1))
+
+
+def hbm_peak_of_run(obs) -> float:
+    """HBM bytes a second the cell's chips move at their published peak:
+    the one-chip peak times the chips. A roofline share divides bytes
+    summed over every chip by it and by `busy_s`, the mean chip's busy
+    time, so that four chips that each move a quarter of the bytes in
+    the same time read as one chip would."""
+    return peaks_for(obs["device_kind"])["hbm_bytes_per_s"] * chips_of(obs)

@@ -55,6 +55,43 @@ class Cell:
     bench_dir: Path    # where plugins (modes, corpora, ...) are looked up
 
 
+def partition_records(config: dict) -> list:
+    """Records of each partition's backlog: the configuration's
+    ``partition_records`` (one count a partition of
+    ``deployment.partitions``, summing to ``backlog_records``), or the
+    whole backlog in the one partition of a configuration that states
+    none. Skew between partitions is data."""
+    n = int(config["deployment"]["partitions"])
+    total = int(config["backlog_records"])
+    counts = config.get("partition_records")
+    if counts is None:
+        if n != 1:
+            raise ManifestError(
+                f"{config['name']}: {n} partitions need `partition_records`")
+        return [total]
+    counts = [int(c) for c in counts]
+    if len(counts) != n or sum(counts) != total or min(counts) < 1:
+        raise ManifestError(
+            f"{config['name']}: `partition_records` {counts} is not one "
+            f"count a partition of {n} summing to `backlog_records` {total}")
+    return counts
+
+
+def check_chips(chips: int, config: dict) -> None:
+    """A cell asks for the chips its configuration is deployed on, and a
+    placed configuration places over exactly those chips."""
+    dep = config["deployment"]
+    if chips != int(dep["chips"]):
+        raise ManifestError(
+            f"the cell asks for {chips} chip(s), its configuration "
+            f"{config['name']} is deployed on {dep['chips']}")
+    placement = dep.get("placement")
+    if placement is not None and int(placement["groups"]) != chips:
+        raise ManifestError(
+            f"the cell asks for {chips} chip(s), its configuration "
+            f"{config['name']} places over {placement['groups']} groups")
+
+
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
     root = Path(root)
     manifest = load_manifest(root)
@@ -68,6 +105,8 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         raise ManifestError(f"no traffic file {traffic_file}")
     with open(traffic_file) as f:
         traffic = json.load(f)
+    check_chips(int(w["chips"]), config)
+    partition_records(config)
     return Cell(
         name=workload,
         chips=int(w["chips"]),

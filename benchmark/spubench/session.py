@@ -38,10 +38,11 @@ class Session:
         self.setup_s = None
         self.c_start = None         # program counters before any traffic
         self.c_open = None          # ... when the window opened
-        self.shape = None           # record shapes, from the last reference
+        self.shape = None           # record shapes: the widest of the references
         self.tmp = tempfile.mkdtemp(prefix="spubench-")
         self.broker = Broker(self.config, self.tmp + "/log")
-        self.tracer = Tracer(self.tmp + "/trace", enabled=trace)
+        self.tracer = Tracer(self.tmp + "/trace", enabled=trace,
+                             chips=cell.chips)
         self._corpus_mod = manifest.load_plugin(
             cell.bench_dir, "corpora", self.config["corpus"]["generator"]
         )
@@ -84,28 +85,33 @@ class Session:
 
     def reference(self, flat, off, lo: int) -> check.Reference:
         """The host reference over a corpus whose first record has log
-        offset ``lo``."""
+        offset ``lo``. The record shapes the readers size buffers by are
+        the widest over every reference of the run (one a partition)."""
         ref = check.Reference(
             self._reference_mod, to_values(flat, off), lo,
             self.config["reference"].get("params", {}),
         )
         self.note(f"reference over {len(off) - 1} records, {len(ref.lens)} out")
         n_in = len(off) - 1
-        self.shape = {
+        shape = {
             "max_in_len": int((off[1:] - off[:-1]).max()),
             "max_out_len": int(ref.lens.max()) if len(ref.lens) else 1,
             "fanout": max(1, -(-len(ref.lens) // n_in)),
         }
+        if self.shape is not None:
+            shape = {k: max(v, self.shape[k]) for k, v in shape.items()}
+        self.shape = shape
         return ref
 
-    async def write_backlog(self, flat, off) -> int:
-        """The configuration's backlog, one stored batch per write, as a
+    async def write_backlog(self, flat, off, partition: int = 0) -> int:
+        """A partition's backlog, one stored batch per write, as a
         producer's batches would have arrived."""
         per = int(self.config["stored_batch_records"])
         for batch in encode_batches(flat, off, 0, len(off) - 1, per):
-            await self.broker.write([batch])
-        self.note(f"backlog written, log end {self.broker.log_end()}")
-        return self.broker.log_end()
+            await self.broker.write([batch], partition)
+        end = self.broker.log_end(partition)
+        self.note(f"backlog written to partition {partition}, log end {end}")
+        return end
 
     # -- lifecycle -----------------------------------------------------------
 

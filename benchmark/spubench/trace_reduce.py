@@ -1,14 +1,19 @@
 """From a `jax.profiler` trace to device busy time, top operations and
 attributed idle gaps. Read with `jax.profiler.ProfileData` alone.
 
-Busy is the union of the intervals in which an operation ran on the
-device (the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane), averaged
-over the devices; idle share is 1 - busy / window. The window is the
-span between the two host markers the `Tracer` writes into the trace
-(`MARK_OPEN`, `MARK_CLOSE`), so idle time before the first and after the
-last operation counts. The markers also tie the trace's clock to
+Busy is the union of the intervals in which an operation ran on a chip
+(the ``XLA Ops`` line of its ``/device:TPU:<n>`` plane). The reduction
+reads exactly the planes of the cell's chips, ``/device:TPU:0`` to
+``/device:TPU:<chips-1>``; a chip that ran nothing, or has no plane,
+counts as busy 0. ``busy_s`` is the mean over those chips, so the idle
+share (1 - busy / window) and every reader that scales ``busy_s`` speak
+of the AVERAGE chip; ``busy_s_by_device`` keeps each chip's own, in chip
+order, for the skew between them. The window is the span between the two
+host markers the `Tracer` writes into the trace (`MARK_OPEN`,
+`MARK_CLOSE`), so idle time before the first and after the last
+operation counts. The markers also tie the trace's clock to
 `time.perf_counter`, which lets a gap be attributed to the program phase
-span that covers it on the host.
+span that covers it on the host. Idle gaps are chip 0's alone.
 """
 
 from __future__ import annotations
@@ -33,9 +38,10 @@ class Tracer:
     start is a timer on the event loop and not a response boundary: a
     cell whose responses are seconds apart is traced on time too."""
 
-    def __init__(self, out_dir: str, enabled: bool):
+    def __init__(self, out_dir: str, enabled: bool, chips: int = 1):
         self.out_dir = out_dir
         self.enabled = enabled
+        self.chips = chips             # the cell's: the planes reduced
         self.active = False
         self.t0 = self.t1 = None       # perf_counter at the two markers
         self._timer = None
@@ -89,7 +95,7 @@ class Tracer:
         path = max(paths, key=os.path.getmtime)
         reduced = reduce_profile(
             jax.profiler.ProfileData.from_file(path), (self.t0, self.t1),
-            host_spans,
+            host_spans, self.chips,
         )
         if reduced is not None:
             # the file this run wrote: `xplane_scopes.reduce_run` reads
@@ -133,19 +139,42 @@ def _find_marks(data):
     return found.get(MARK_OPEN), found.get(MARK_CLOSE)
 
 
-def reduce_profile(data, host_window=None, host_spans=()) -> dict | None:
-    """-> busy_s, window_s, device_ops, idle_gaps; None when no operation
-    ran on a device plane (a reader then has nothing to read).
+def device_planes(chips: int) -> list:
+    """The names of the planes of a cell's chips, in chip order."""
+    return [f"{DEVICE_PLANE_PREFIX}{i}" for i in range(chips)]
+
+
+def idle_gaps(busy, m0, m1) -> list:
+    """[(start, end)] of the gaps of at least `MIN_GAP_S` between the
+    merged busy intervals ``busy`` of one chip inside [m0, m1]."""
+    gaps = []
+    edge = m0
+    for a, b in busy:
+        if a - edge >= MIN_GAP_S * 1e9:
+            gaps.append((edge, a))
+        edge = max(edge, b)
+    if m1 - edge >= MIN_GAP_S * 1e9:
+        gaps.append((edge, m1))
+    return gaps
+
+
+def reduce_profile(data, host_window=None, host_spans=(),
+                   chips: int = 1) -> dict | None:
+    """-> busy_s (mean over the cell's ``chips``), busy_s_by_device,
+    devices (= ``chips``), window_s, device_ops (summed over the chips),
+    idle_gaps (chip 0's); None when no operation ran on any of the
+    chips' planes (a reader then has nothing to read).
 
     ``host_window`` is (perf_counter at MARK_OPEN, at MARK_CLOSE);
     ``host_spans`` is [(label, start, end)] on the perf_counter clock."""
     m0, m1 = _find_marks(data)
-    per_device = []
+    names = device_planes(chips)
+    intervals = {name: [] for name in names}
     op_seconds = defaultdict(float)
     for plane in data.planes:
-        if not plane.name.startswith(DEVICE_PLANE_PREFIX):
+        if plane.name not in intervals:
             continue
-        iv = []
+        iv = intervals[plane.name]
         for line in plane.lines:
             if line.name != OPS_LINE:
                 continue
@@ -159,36 +188,27 @@ def reduce_profile(data, host_window=None, host_spans=()) -> dict | None:
                         continue
                 iv.append((a, b))
                 op_seconds[short_name(e.name)] += (b - a) / 1e9
-        if iv:
-            per_device.append(_union(iv))
-    if not per_device:
+    per_device = [_union(intervals[name]) for name in names]
+    if not any(per_device):
         return None
     if m0 is None or m1 is None:
         # no markers (a trace not written by `Tracer`): the window is the
         # span of the operations themselves
-        m0 = min(d[0][0] for d in per_device)
-        m1 = max(d[-1][1] for d in per_device)
+        m0 = min(d[0][0] for d in per_device if d)
+        m1 = max(d[-1][1] for d in per_device if d)
     window_s = (m1 - m0) / 1e9
-    busy_s = sum(
-        sum(b - a for a, b in d) for d in per_device
-    ) / 1e9 / len(per_device)
+    busy_by_device = [sum(b - a for a, b in d) / 1e9 for d in per_device]
+    busy_s = sum(busy_by_device) / chips
 
-    # idle gaps of the first device, attributed on the host clock
-    gaps = []
-    edge = m0
-    for a, b in per_device[0]:
-        if a - edge >= MIN_GAP_S * 1e9:
-            gaps.append((edge, a))
-        edge = max(edge, b)
-    if m1 - edge >= MIN_GAP_S * 1e9:
-        gaps.append((edge, m1))
+    # idle gaps of chip 0, attributed on the host clock
     by_label = defaultdict(float)
-    for a, b in gaps:
+    for a, b in idle_gaps(per_device[0], m0, m1):
         by_label[_label(a, b, m0, host_window, host_spans)] += (b - a) / 1e9
     return {
         "busy_s": busy_s,
+        "busy_s_by_device": busy_by_device,
         "window_s": window_s,
-        "devices": len(per_device),
+        "devices": chips,
         "device_ops": _top(op_seconds),
         "idle_gaps": _top(by_label),
     }

@@ -31,6 +31,10 @@ def snapshot(broker) -> dict:
         "retries": sum(counters["retries"].values()),
         "quarantined": counters["quarantined"],
         "interp_records": counters["interp_instance"]["records"],
+        # a slice the partition gate failed to place serves unplaced
+        # (`spu/smart_chain.py:_enter_partition_scope`) and books this
+        "placement_errors": counters["declines"].get(
+            "partition-placement-error", 0),
     }
 
 
@@ -59,12 +63,13 @@ def delta(c0: dict, c1: dict) -> dict:
 
 # counters of the program that may not move in a run
 STILL_COUNTERS = ("heals", "stripe_fallbacks", "spills", "retries",
-                  "quarantined", "interp_records")
+                  "quarantined", "interp_records", "placement_errors")
 
 
 def truth_faults(c_start: dict, c_end: dict) -> list:
     """`correct` part (c) over the WHOLE run (set-up and window): the fast
-    path served every slice and nothing healed, spilled or interpreted."""
+    path served every slice and nothing healed, spilled, interpreted or
+    failed its placement."""
     faults = []
     s0, s1 = c_start["slices"], c_end["slices"]
     if s1["fastpath_slices"] - s0["fastpath_slices"] <= 0:
@@ -80,11 +85,33 @@ def truth_faults(c_start: dict, c_end: dict) -> list:
     return faults
 
 
-def compared(obs: dict, c_start: dict, c_end: dict) -> dict:
+def idle_chips(reduced) -> list:
+    """The cell's chips on which no operation ran in the traced span."""
+    return [i for i, b in enumerate(reduced["busy_s_by_device"]) if b <= 0]
+
+
+def placement_faults(broker, reduced=None) -> list:
+    """`correct` for the cell's chips: a configuration that states a
+    placement was served with the program's partition gate armed (the
+    gate's own resolution logs and stays unarmed where it cannot build),
+    and in a traced run every chip of the cell ran an operation."""
+    faults = []
+    if broker.placement is not None and broker.gate is None:
+        faults.append("the configuration states a placement and the "
+                      "program's partition gate is not armed")
+    if reduced is not None and idle_chips(reduced):
+        faults.append(f"no operation ran on chip(s) {idle_chips(reduced)} "
+                      "of the cell in the traced span")
+    return faults
+
+
+def compared(obs: dict, c_start: dict, c_end: dict, broker=None,
+             reduced=None) -> dict:
     """Each number `correct` rests on beside its limit, for the result
     line and the last lines of standard error. The comparison with the
-    reference is exact, so every limit is 0 but the floor of one
-    fast-path slice (``at_least``)."""
+    reference is exact, so every limit is 0 but the floors of one
+    fast-path slice and, for a placed configuration, of an armed
+    partition gate (``at_least``)."""
     s0, s1 = c_start["slices"], c_end["slices"]
     out = {
         # what the mode found against the reference: warm-up output not
@@ -103,6 +130,12 @@ def compared(obs: dict, c_start: dict, c_end: dict) -> dict:
     }
     for key in STILL_COUNTERS:
         out[key] = {"value": c_end[key] - c_start[key], "limit": 0}
+    if broker is not None and broker.placement is not None:
+        out["partition_gate_armed"] = {
+            "value": int(broker.gate is not None), "limit": 1,
+            "at_least": True}
+    if reduced is not None:
+        out["idle_chips"] = {"value": len(idle_chips(reduced)), "limit": 0}
     return out
 
 

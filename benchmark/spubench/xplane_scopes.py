@@ -21,8 +21,9 @@ import os
 import re
 from collections import defaultdict
 
+from spubench.device import chips_of
 from spubench.trace_reduce import (
-    DEVICE_PLANE_PREFIX, MARK_CLOSE, MARK_OPEN, MIN_GAP_S, OPS_LINE, _union,
+    MARK_CLOSE, MARK_OPEN, OPS_LINE, _union, device_planes, idle_gaps,
 )
 
 ANNOTATION_PREFIX = "fluvio/"
@@ -278,35 +279,39 @@ def _host_phases(planes, m0, m1):
     return out
 
 
-def reduce_xspace(raw: bytes, vocabulary, slice_phases=()) -> dict | None:
-    """-> busy_s, window_s, scope_s {scope: seconds}, unnamed_ops
-    {operation: seconds}, idle_gaps {phase: seconds}, phases_seen; None
-    when no operation ran on a device plane.
+def reduce_xspace(raw: bytes, vocabulary, slice_phases=(),
+                  chips: int = 1) -> dict | None:
+    """-> busy_s, busy_s_by_device, devices, window_s, scope_s {scope:
+    seconds}, unnamed_ops {operation: seconds}, idle_gaps {phase:
+    seconds}, phases_seen; None when no operation ran on any of the
+    cell's chips.
 
-    ``vocabulary`` is the program's scope tuple; ``slice_phases`` the
-    names of the slice's own phases, which label a gap before a chunk's
-    (executor) phases do."""
+    It reads the planes of the cell's ``chips`` as
+    `trace_reduce.reduce_profile` does: a chip that ran nothing counts as
+    busy 0, and busy_s, scope_s and unnamed_ops are means over the chips
+    (the average chip); idle gaps are chip 0's. ``vocabulary`` is the
+    program's scope tuple; ``slice_phases`` the names of the slice's own
+    phases, which label a gap before a chunk's (executor) phases do."""
     planes = parse_xspace(raw)
     m0, m1 = _marks(planes)
     scope_ns = defaultdict(float)
     unnamed_ns = defaultdict(float)
-    per_device = []
+    names = device_planes(chips)
+    intervals = {name: [] for name in names}
     for plane in planes:
-        if not plane["name"].startswith(DEVICE_PLANE_PREFIX):
+        if plane["name"] not in intervals:
             continue
+        iv = intervals[plane["name"]]
         scopes = _metadata_scopes(plane, vocabulary)
         for line in plane["lines"]:
             if line.name != OPS_LINE:
                 continue
             events = line.events
-            iv = []
             for mid, a, b, _s in events:
                 if m0 is not None and m1 is not None:
                     a, b = max(a, m0), min(b, m1)
                 if b > a:
                     iv.append((a, b))
-            if iv:
-                per_device.append(_union(iv))
             for mid, self_ns in _self_times(events, m0, m1):
                 scope = scopes.get(mid)
                 if scope is None:
@@ -314,33 +319,28 @@ def reduce_xspace(raw: bytes, vocabulary, slice_phases=()) -> dict | None:
                     unnamed_ns[name.partition(" = ")[0][:48]] += self_ns
                 else:
                     scope_ns[scope] += self_ns
-    if not per_device:
+    per_device = [_union(intervals[name]) for name in names]
+    if not any(per_device):
         return None
     if m0 is None or m1 is None:
-        m0 = min(d[0][0] for d in per_device)
-        m1 = max(d[-1][1] for d in per_device)
-    busy_ns = sum(sum(b - a for a, b in d) for d in per_device) / len(per_device)
+        m0 = min(d[0][0] for d in per_device if d)
+        m1 = max(d[-1][1] for d in per_device if d)
+    busy_by_device = [sum(b - a for a, b in d) for d in per_device]
+    busy_ns = sum(busy_by_device) / chips
 
-    # device-idle gaps of the first device under the host's own phases
+    # device-idle gaps of chip 0 under the host's own phases
     phases = _host_phases(planes, m0, m1)
-    gaps = []
-    edge = m0
-    for a, b in per_device[0]:
-        if a - edge >= MIN_GAP_S * 1e9:
-            gaps.append((edge, a))
-        edge = max(edge, b)
-    if m1 - edge >= MIN_GAP_S * 1e9:
-        gaps.append((edge, m1))
     by_phase = defaultdict(float)
-    for a, b in gaps:
+    for a, b in idle_gaps(per_device[0], m0, m1):
         for label, ns in _split_gap(a, b, phases, slice_phases):
             by_phase[label] += ns
-    n_dev = len(per_device)
     return {
         "busy_s": busy_ns / 1e9,
+        "busy_s_by_device": [ns / 1e9 for ns in busy_by_device],
+        "devices": chips,
         "window_s": (m1 - m0) / 1e9,
-        "scope_s": {k: v / 1e9 / n_dev for k, v in scope_ns.items()},
-        "unnamed_ops": {k: v / 1e9 / n_dev for k, v in sorted(
+        "scope_s": {k: v / 1e9 / chips for k, v in scope_ns.items()},
+        "unnamed_ops": {k: v / 1e9 / chips for k, v in sorted(
             unnamed_ns.items(), key=lambda kv: -kv[1])[:10]},
         "idle_gaps": {k: v / 1e9 for k, v in by_phase.items()},
         "phases_seen": sorted({p[0] for p in phases}),
@@ -413,11 +413,12 @@ def reduce_run(obs) -> dict | None:
     path = t.get("path")
     if not path or not os.path.isfile(path):
         return None
-    key = (path, os.path.getmtime(path))
-    if key not in _CACHE:
+    chips = chips_of(obs)
+    by_chips = _CACHE.setdefault((path, os.path.getmtime(path)), {})
+    if chips not in by_chips:
         with open(path, "rb") as f:
-            _CACHE[key] = reduce_xspace(f.read(), *vocab)
-    r = _CACHE[key]
+            by_chips[chips] = reduce_xspace(f.read(), *vocab, chips=chips)
+    r = by_chips[chips]
     if not r or abs(r["busy_s"] - t["busy_s"]) > BUSY_TOLERANCE * t["busy_s"]:
         return None
     return r
@@ -556,10 +557,12 @@ def unnamed_share(obs) -> float | None:
     return 100.0 * (1.0 - named / (t1 - t0))
 
 
-if __name__ == "__main__":   # python3 xplane_scopes.py <file.xplane.pb>
+if __name__ == "__main__":   # python3 xplane_scopes.py <file.xplane.pb> [chips]
     import json
     import sys
 
     vocab = _vocabulary()
     with open(sys.argv[1], "rb") as _f:
-        print(json.dumps(reduce_xspace(_f.read(), *vocab), indent=1))
+        print(json.dumps(reduce_xspace(
+            _f.read(), *vocab,
+            chips=int(sys.argv[2]) if len(sys.argv) > 2 else 1), indent=1))

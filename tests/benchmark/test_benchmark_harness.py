@@ -376,10 +376,20 @@ def _tiny_config(cfg: dict, backlog: int) -> dict:
     batch where that is smaller than `REHEARSAL_BATCH` (a deployment of
     wide records states a few to a batch: 512 of them would be tens of MB
     a batch), and then holds four such batches and whatever short tail
-    ``backlog`` asks for beyond whole rehearsal batches."""
+    ``backlog`` asks for beyond whole rehearsal batches. A configuration of
+    several partitions (``partition_records``) shares that backlog among
+    them in its own proportions."""
     per = min(REHEARSAL_BATCH, int(cfg["stored_batch_records"]))
     cfg["stored_batch_records"] = per
+    total = int(cfg["backlog_records"])
     cfg["backlog_records"] = min(backlog, 4 * per + backlog % REHEARSAL_BATCH)
+    if "partition_records" in cfg:
+        # a topic of several partitions keeps its skew: each partition its
+        # share of the cut backlog, at least one record
+        cfg["partition_records"] = [
+            max(1, n * cfg["backlog_records"] // total)
+            for n in cfg["partition_records"]]
+        cfg["backlog_records"] = sum(cfg["partition_records"])
     return cfg
 
 
@@ -452,7 +462,7 @@ def test_cell_rehearsal_on_cpu(monkeypatch, tmp_path, cell):
     assert r["counts"]["records_in"] > 0
     json.dumps(r)   # the result line is plain JSON
     # ... and ends with each number compared beside its limit; all hold
-    assert list(r)[-1] == "compared" and len(r["compared"]) == 11
+    assert list(r)[-1] == "compared" and len(r["compared"]) == 12
     assert all((c["value"] >= c["limit"]) if c.get("at_least")
                else (c["value"] <= c["limit"]) for c in r["compared"].values())
 
